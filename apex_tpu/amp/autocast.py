@@ -25,6 +25,7 @@ import contextlib
 import threading
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from . import lists
@@ -36,39 +37,8 @@ _tls = threading.local()
 
 # jit-cache salt: a jax user context carrying the active policy — part of
 # the tracing/lowering/compilation cache key, so jit distinguishes traces
-# made under different ambient policies. Older jax has no
-# make_user_context; there the salt rides the XLA-metadata context
-# instead (``xla_metadata_context_manager`` sits in ``trace_context()``
-# on every jax this repo supports), carrying a content fingerprint of
-# the policy as a frontend attribute — semantics-free HLO metadata whose
-# only load-bearing property is membership in the jit cache key. Last
-# resort (neither API): thread-local state only, with trace_token() for
-# manual static-arg salting.
-try:
-    import jax as _jax
-
-    _policy_state = _jax.make_user_context(default_value=None)
-except AttributeError:
-    try:
-        from jax.experimental.xla_metadata import \
-            set_xla_metadata as _set_xla_metadata
-
-        def _policy_state(policy):
-            # repr of the frozen Policy dataclass: a stable CONTENT
-            # fingerprint (two equal policies share one trace; id()
-            # would retrace per object and could alias after gc)
-            return _set_xla_metadata(apex_tpu_amp_policy=repr(policy))
-    except ImportError:  # pragma: no cover - jax without either API
-        import warnings
-
-        warnings.warn(
-            "this jax has neither make_user_context nor xla_metadata: "
-            "the ambient amp policy cannot be salted into the jit cache "
-            "key, so a function YOU jit and call under different "
-            "autocast policies will silently reuse its first trace's "
-            "cast decisions. Re-jit per policy, or upgrade jax.",
-            stacklevel=2)
-        _policy_state = None
+# made under different ambient policies.
+_policy_state = jax.make_user_context(default_value=None)
 
 
 def active_policy():
@@ -96,10 +66,7 @@ def autocast(policy):
     prev = getattr(_tls, "policy", None)
     _tls.policy = policy
     try:
-        if _policy_state is not None:
-            with _policy_state(policy):
-                yield policy
-        else:
+        with _policy_state(policy):
             yield policy
     finally:
         _tls.policy = prev

@@ -159,7 +159,7 @@ def _causal_fwd(x, scale, interpret):
         out_specs=pl.BlockSpec((1, bq, sk), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, sq, sk), x.dtype),
         scratch_shapes=[pltpu.VMEM((bq, sk), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="causal_softmax_fwd",
     )(x)
     return out, out
 
@@ -175,7 +175,7 @@ def _causal_bwd(scale, interpret, p, g):
                   pl.BlockSpec((1, bq, sk), lambda i, j: (i, j, 0))],
         out_specs=pl.BlockSpec((1, bq, sk), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, sq, sk), p.dtype),
-        interpret=interpret,
+        interpret=interpret, name="causal_softmax_bwd",
     )(p, g)
     return (dx,)
 

@@ -203,7 +203,7 @@ def _decode_pallas(q3, k3, v3, len3, scale, bk, interpret, ks3=None,
             pltpu.VMEM((8, 128), jnp.float32),    # m
             pltpu.VMEM((8, 128), jnp.float32),    # l
         ],
-        interpret=interpret,
+        interpret=interpret, name="decode_attention",
     )(len3, *scale_ops, q3.reshape(bh, 1, d), k3, v3)
     return out.reshape(bh, d)
 
@@ -356,7 +356,7 @@ def _paged_decode_kernel(pt_ref, len_ref, *refs, scale, page_len, quant):
 
     @pl.when(j * page_len < length)
     def _body():
-        q = q_ref[0, 0][None, :].astype(jnp.float32)          # [1, d]
+        q = q_ref[0, 0].astype(jnp.float32)                   # [1, d]
         k = k_ref[0, 0].astype(jnp.float32)                   # [pl, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -385,7 +385,7 @@ def _paged_decode_kernel(pt_ref, len_ref, *refs, scale, page_len, quant):
     def _finish():
         l = l_ref[:1, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:1, :] / l_safe)[0].astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[:1, :] / l_safe).astype(o_ref.dtype)
 
 
 def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, scale,
@@ -397,9 +397,13 @@ def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, scale,
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                page_len=page_len, quant=quant)
     # the dequant scales ride as two extra scalar-prefetch operands (the
-    # variadic tail absorbs them — only the kernel body reads them)
+    # variadic tail absorbs them — only the kernel body reads them).
+    # q/out carry a unit row axis ([B, h, 1, d]): Mosaic wants a block's
+    # last two dims to tile (8, 128) or EQUAL the array's, and a (1, d)
+    # block over [.., 1, d] is the latter where (1, d) over [.., h, d]
+    # is neither (the contiguous kernel's [bh, 1, d] does the same).
     def _q_idx(b, hh, j, pt, ln, *_scales):
-        return (b, hh, 0)
+        return (b, hh, 0, 0)
 
     def _kv_idx(b, hh, j, pt, ln, *_scales):
         return (pt[b, j], hh, 0, 0)
@@ -409,22 +413,23 @@ def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, scale,
         num_scalar_prefetch=n_prefetch,   # page_table, lengths[, ks, vs]
         grid=(B, h, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, d), _q_idx),
+            pl.BlockSpec((1, 1, 1, d), _q_idx),
             pl.BlockSpec((1, 1, page_len, d), _kv_idx),
             pl.BlockSpec((1, 1, page_len, d), _kv_idx),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), _q_idx),
+        out_specs=pl.BlockSpec((1, 1, 1, d), _q_idx),
         scratch_shapes=[
             pltpu.VMEM((8, d), jnp.float32),      # acc (row 0 live)
             pltpu.VMEM((8, 128), jnp.float32),    # m
             pltpu.VMEM((8, 128), jnp.float32),    # l
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, h, d), q.dtype),
-        interpret=interpret,
-    )(pt, lengths, *extra_ops, q, k_pool, v_pool)
+        out_shape=jax.ShapeDtypeStruct((B, h, 1, d), q.dtype),
+        interpret=interpret, name="paged_decode_attention",
+    )(pt, lengths, *extra_ops, q.reshape(B, h, 1, d), k_pool, v_pool)
+    return out.reshape(B, h, d)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,

@@ -394,9 +394,7 @@ def _match_vma(x, like):
         cur = jax.typeof(x).vma
         missing = tuple(sorted(set(vma) - set(cur)))
         if missing:
-            if hasattr(jax.lax, "pcast"):
-                return jax.lax.pcast(x, missing, to="varying")
-            return jax.lax.pvary(x, missing)
+            return jax.lax.pcast(x, missing, to="varying")
     except (AttributeError, TypeError):
         pass
     return x
@@ -570,7 +568,7 @@ def _fwd_pallas(q3, k3, v3, segq, segk, scale, causal, bq, bk, interpret,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_attention_fwd",
     )(q3, k3, v3, segq, segk, bias3, seed1)
     return o, lse
 
@@ -641,7 +639,7 @@ def _bwd_pallas(q3, k3, v3, do3, lse, delta, segq, segk, scale, causal, bq, bk,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_attention_bwd_dkv",
     )(q3, k3, v3, do3, lse, delta, segq, segk, bias3, seed1)
 
     dq_out_specs = [pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))]
@@ -671,7 +669,7 @@ def _bwd_pallas(q3, k3, v3, do3, lse, delta, segq, segk, scale, causal, bq, bk,
         out_specs=dq_out_specs,
         out_shape=dq_out_shape,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_attention_bwd_dq",
     )(q3, k3, v3, do3, lse, delta, segq, segk, bias3, seed1)
     dq = dq_res[0]
     dlog = dq_res[1] if emit_dlog else None
@@ -721,7 +719,7 @@ def _bwd_pallas(q3, k3, v3, do3, lse, delta, segq, segk, scale, causal, bq, bk,
             out_specs=[pl.BlockSpec((1, bq, bk),
                                     lambda c, i, j, r: (c, i, j))],
             out_shape=[_sds((B, sq, sk), jnp.float32, q3)],
-            interpret=interpret,
+            interpret=interpret, name="flash_attention_bwd_dbias",
         )(q3, k3, v3, do3, lse, delta, segq, segk, bias3, seed1)[0]
 
     return dq, dkdv[0], dkdv[1], dlog

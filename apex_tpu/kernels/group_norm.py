@@ -205,7 +205,7 @@ def _gn_fwd(x3, gamma, beta, groups, eps, act, interpret):
         in_specs=_row_specs(1, bs, c),
         out_specs=[_vec_spec(c), _vec_spec(c)],
         out_shape=[jax.ShapeDtypeStruct((n, 1, c), jnp.float32)] * 2,
-        interpret=interpret,
+        interpret=interpret, name="group_norm_fwd_stats",
     )(xp)
     mean_c, rstd_c = _group_stats(mean_ch[:, 0], m2_ch[:, 0], groups, s,
                                   eps)
@@ -224,7 +224,7 @@ def _gn_fwd(x3, gamma, beta, groups, eps, act, interpret):
         out_specs=pl.BlockSpec((1, bs, c), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, sp, c), x3.dtype),
-        interpret=interpret,
+        interpret=interpret, name="group_norm_fwd",
     )(xp, mean_c, rstd_c, g2, b2)
     return y[:, :s], (x3, gamma, beta, mean_c, rstd_c)
 
@@ -247,7 +247,7 @@ def _gn_bwd(groups, eps, act, interpret, res, dy):
                                          const_vec, const_vec],
         out_specs=[_vec_spec(c), _vec_spec(c)],
         out_shape=[jax.ShapeDtypeStruct((n, 1, c), jnp.float32)] * 2,
-        interpret=interpret,
+        interpret=interpret, name="group_norm_bwd_stats",
     )(dyp, xp, mean_c, rstd_c, g2, b2)
     sdz2, sdzx2 = sdz[:, 0], sdzx[:, 0]                     # [N, C]
     dgamma = jnp.sum(sdzx2, axis=0).astype(gamma.dtype)
@@ -273,7 +273,7 @@ def _gn_bwd(groups, eps, act, interpret, res, dy):
         out_specs=pl.BlockSpec((1, bs, c), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, sp, c), x3.dtype),
-        interpret=interpret,
+        interpret=interpret, name="group_norm_bwd",
     )(dyp, xp, mean_c, rstd_c, g2, b2, c1_c, c2_c)
     return dx[:, :s], dgamma, dbeta
 

@@ -189,7 +189,7 @@ def _ln_fwd_pallas(x2, gamma, beta, eps, rms, interpret):
             jax.ShapeDtypeStruct((rows_p, 1), jnp.float32),
             jax.ShapeDtypeStruct((rows_p, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="layer_norm_fwd",
     )(xp, g2, b2)
     return y[:n], mean[:n], rstd[:n]
 
@@ -238,7 +238,7 @@ def _ln_bwd_pallas(dy2, src2, gamma, aux, rstd, rms, interpret,
             jax.ShapeDtypeStruct((1, h), jnp.float32),
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="layer_norm_bwd",
     )(dyp, srcp, g2, aux_arr, rstdp)
     return dx[:n], dg.reshape(h), db.reshape(h)
 

@@ -91,7 +91,7 @@ def _masked_fwd(x, mask_i8, scale, rep, interpret):
                                lambda i, j: (i // rep, j, 0))],
         out_specs=pl.BlockSpec((1, bq, sk), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, sq, sk), x.dtype),
-        interpret=interpret,
+        interpret=interpret, name="masked_softmax_fwd",
     )(x, mask_i8)
     return out, out
 
@@ -106,7 +106,7 @@ def _masked_bwd(scale, rep, interpret, p, g):
                   pl.BlockSpec((1, bq, sk), lambda i, j: (i, j, 0))],
         out_specs=pl.BlockSpec((1, bq, sk), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, sq, sk), p.dtype),
-        interpret=interpret,
+        interpret=interpret, name="masked_softmax_bwd",
     )(p, g)
     return (dx, None)
 

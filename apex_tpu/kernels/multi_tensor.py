@@ -101,7 +101,7 @@ def fused_scale(flat, scale, interpret: bool = False):
         out_specs=[_row_spec(bm), _acc_spec()],
         out_shape=[jax.ShapeDtypeStruct(x2.shape, flat.dtype),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="multi_tensor_scale",
     )(scale, x2)
     return out.reshape(-1)[:n], flag[0, 0] > 0
 
@@ -153,7 +153,7 @@ def fused_axpby(flat_x, flat_y, a, b, interpret: bool = False):
         out_specs=[_row_spec(bm), _acc_spec()],
         out_shape=[jax.ShapeDtypeStruct(x2.shape, flat_x.dtype),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="multi_tensor_axpby",
     )(ab, x2, y2)
     return out.reshape(-1)[:n], flag[0, 0] > 0
 
@@ -185,7 +185,7 @@ def fused_l2norm(flat, interpret: bool = False):
         in_specs=[_row_spec(bm)],
         out_specs=_acc_spec(),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name="multi_tensor_l2norm",
     )(x2)
     return jnp.sqrt(acc[0, 0])
 
@@ -271,7 +271,7 @@ def fused_adam_step(flat_p, flat_m, flat_v, flat_g, *, lr, beta1, beta2, eps,
         out_shape=[jax.ShapeDtypeStruct((rows_p, _LANES), flat_p.dtype),
                    jax.ShapeDtypeStruct((rows_p, _LANES), jnp.float32),
                    jax.ShapeDtypeStruct((rows_p, _LANES), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="multi_tensor_adam",
     )(scalars, p2, m2, v2, g2)
     return (p_new.reshape(-1)[:n], m_new.reshape(-1)[:n],
             v_new.reshape(-1)[:n])
@@ -448,6 +448,6 @@ def fused_sgd_step(flat_p, flat_buf, flat_g, *, lr, momentum=0.0,
         out_specs=[_row_spec(bm)] * 2,
         out_shape=[jax.ShapeDtypeStruct((rows_p, _LANES), flat_p.dtype),
                    jax.ShapeDtypeStruct((rows_p, _LANES), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="multi_tensor_sgd",
     )(scalars, p2, b2, g2)
     return p_new.reshape(-1)[:n], buf_new.reshape(-1)[:n]

@@ -203,7 +203,7 @@ def _prefill_pallas(q3, k3, v3, off3, scale, bq, bk, interpret,
             pltpu.VMEM((bq, 128), jnp.float32),    # m (col 0 live)
             pltpu.VMEM((bq, 128), jnp.float32),    # l (col 0 live)
         ],
-        interpret=interpret,
+        interpret=interpret, name="prefill_attention",
     )(off3, *scale_ops, q3, k3, v3)
 
 
@@ -413,7 +413,7 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pt, offsets, scale, bq,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, h, C, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_prefill_attention",
     )(pt, offsets, *extra_ops, q, k_pool, v_pool)
 
 

@@ -115,6 +115,13 @@ _TUNED_DIR = os.path.join(os.path.dirname(__file__), "tuned")
 _auto_load_done = False
 
 
+def packaged_path(device_kind: str) -> str:
+    """Where the packaged tuned file of ``device_kind`` lives (it may
+    not exist: a kind nobody swept runs on the heuristic blocks)."""
+    return os.path.join(_TUNED_DIR,
+                        device_kind.lower().replace(" ", "_") + ".json")
+
+
 def _auto_load_packaged() -> None:
     global _auto_load_done
     if _auto_load_done:
@@ -126,8 +133,7 @@ def _auto_load_packaged() -> None:
         kind = getattr(jax.devices()[0], "device_kind", "")
     except Exception:  # noqa: BLE001 — no backend is a valid state
         return
-    path = os.path.join(_TUNED_DIR,
-                        kind.lower().replace(" ", "_") + ".json")
+    path = packaged_path(kind)
     if not os.path.isfile(path):
         return
     try:

@@ -144,7 +144,7 @@ def _xent_fwd(logits, labels, smoothing, interpret):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="xentropy_fwd",
     )(logits, _col(labels, n))
     return loss.reshape(n), (logits, labels, mlse)
 
@@ -169,7 +169,7 @@ def _xent_bwd(smoothing, interpret, res, g):
         ],
         out_specs=pl.BlockSpec((br, v), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, v), logits.dtype),
-        interpret=interpret,
+        interpret=interpret, name="xentropy_bwd",
     )(logits, _col(labels, n), _col(mlse, n),
       _col(g.astype(jnp.float32), n))
     return dlogits, None

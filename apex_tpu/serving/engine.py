@@ -1062,7 +1062,7 @@ class Engine:
             return ()
         ids = self._slot_adapter if slot is None \
             else self._slot_adapter[slot:slot + 1]
-        return (self.lora.arena, jnp.asarray(ids))
+        return (self.lora.arena, jnp.asarray(ids.copy()))
 
     def lora_register(self, name: str, sites, *,
                       alpha: float = 1.0) -> None:
@@ -1502,7 +1502,7 @@ class Engine:
                 lambda: self._with_prefill_blocks(
                     lambda: self._jit_prefill(
                         self.params, self.cache, jnp.asarray(tokens),
-                        jnp.asarray(self._page_table[slot:slot + 1]),
+                        jnp.asarray(self._page_table[slot:slot + 1].copy()),
                         np.int32(n), np.float32(temperature),
                         self._next_key(), *self._lora_args(slot))))
             self._host_len[slot] = n
@@ -1610,7 +1610,7 @@ class Engine:
             self.cache, token, finite = self._runtime_call(
                 lambda: self._jit_chunk(
                     self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(self._page_table[slot:slot + 1]),
+                    jnp.asarray(self._page_table[slot:slot + 1].copy()),
                     np.int32(offset), np.int32(n),
                     np.float32(temperature), np.float32(fault_bias),
                     self._next_key(), *self._lora_args(slot)))
@@ -2324,8 +2324,8 @@ class Engine:
                 lambda: self._jit_decode(
                     self.params, self.cache,
                     jnp.asarray(last_tokens, jnp.int32),
-                    jnp.asarray(self._page_table),
-                    jnp.asarray(self._host_len),
+                    jnp.asarray(self._page_table.copy()),
+                    jnp.asarray(self._host_len.copy()),
                     jnp.asarray(temperatures, jnp.float32),
                     jnp.asarray(fault_bias), self._next_key(),
                     *self._lora_args()))
@@ -2409,7 +2409,14 @@ class Engine:
         inverting the ``serving.heartbeat.*`` split and letting
         healthy CPU decode breach the watchdog's host budget. The ~µs
         of true dispatch overhead this misattributes on silicon is
-        noise."""
+        noise.
+
+        Callers hand host state the allocator keeps mutating (page
+        table, lengths, adapter bindings) over as a ``.copy()``: the
+        CPU backend may alias a numpy buffer instead of copying it, and
+        a program that has not read its operand yet when the host bumps
+        a length in place computes the NEXT step's position (seen as a
+        dropped token under CPU load)."""
         t0 = time.perf_counter()
         out = fn()
         self.device_wait_s += time.perf_counter() - t0
@@ -2643,6 +2650,50 @@ class Engine:
         out = np.asarray(self.cache.lengths)    # device sync
         self.device_wait_s += time.perf_counter() - tw
         return out
+
+    def program_kernels(self) -> dict:
+        """Which Pallas kernels the decode and chunk-prefill programs
+        hold once compiled for the attached backend: ``{"decode":
+        {kernel: n_calls}, "chunk": {...}}``, counted from each
+        program's compiled HLO (:func:`apex_tpu.utils.chip
+        .kernel_calls`). The attention dispatchers pick kernel or jnp
+        reference at trace time from geometry and backend
+        (``page_len % 128``, ``head_dim % 8``, row-block alignment);
+        this is where a caller sees which one its geometry got — an
+        empty dict means the reference ran.
+
+        Lowers and compiles both programs afresh at the heartbeat's
+        operand shapes (with the persistent compile cache on that is a
+        read). Trace counters are restored afterwards, so
+        :attr:`compiled_programs` keeps counting only what serving
+        itself compiled."""
+        from apex_tpu.utils.chip import kernel_calls
+
+        slots_f32 = np.zeros(self.slots, np.float32)
+        last = np.zeros(self.slots, np.int32)
+        chunk = np.zeros((1, self.chunk_len), np.int32)
+        scalars = (np.int32(0), np.int32(1), np.float32(0),
+                   np.float32(0), self._key)
+        if self.paged:
+            decode_ops = (last, self._page_table, self._host_len)
+            chunk_ops = (chunk, self._page_table[:1])
+        else:
+            decode_ops = (last, np.zeros(self.slots, bool))
+            chunk_ops = (chunk, np.int32(0))
+        traces = (self.decode_traces, self.chunk_traces)
+        try:
+            programs = {
+                "decode": self._jit_decode.lower(
+                    self.params, self.cache, *decode_ops, slots_f32,
+                    slots_f32, self._key, *self._lora_args()),
+                "chunk": self._jit_chunk.lower(
+                    self.params, self.cache, *chunk_ops, *scalars,
+                    *self._lora_args(0)),
+            }
+        finally:
+            self.decode_traces, self.chunk_traces = traces
+        return {name: kernel_calls(lowered.compile().as_text())
+                for name, lowered in programs.items()}
 
     def close(self) -> None:
         """Stop the engine's :class:`~apex_tpu.serving.SwapWorker`

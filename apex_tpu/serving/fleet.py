@@ -279,6 +279,52 @@ _WIRE_SCHED_KW = ("max_queue", "default_timeout_s", "eos_id",
                   "speculative", "pipeline_depth", "slo")
 
 
+def _host_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from the device files the
+    TPU runtime opens — WITHOUT touching jax, whose first backend call
+    would take the chips for this process."""
+    import glob
+
+    return len(glob.glob("/dev/accel[0-9]*")
+               or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def check_worker_backend(n_workers: int) -> None:
+    """Refuse, at once and by name, a fleet whose workers could never
+    reach their device. Workers inherit this process's environment
+    verbatim and are not (yet — ROADMAP R6) given a chip each, and a
+    TPU chip belongs to one process at a time: so a parent whose own
+    jax backend already holds the chips, and any accelerator fleet of
+    more than one worker (wider than the host's chips or not), would
+    sit out ``spawn_timeout_s`` and die with a transport error.
+    ``JAX_PLATFORMS=cpu`` in the environment means CPU workers, which
+    share nothing and always start."""
+    if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
+        return
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        import jax
+
+        platform = jax.default_backend()
+        if platform == "cpu":
+            return
+        raise RuntimeError(
+            f"FleetController: this process's jax backend is already "
+            f"live on {platform} and holds its chip(s); worker "
+            f"processes inherit the environment and could not open "
+            f"them. Start the fleet from a process that has not "
+            f"touched jax, or export JAX_PLATFORMS=cpu for CPU workers")
+    chips = _host_tpu_chips()
+    if chips and n_workers > 1:
+        raise RuntimeError(
+            f"FleetController: {n_workers} workers asked for on a host "
+            f"with {chips} TPU chip(s). A chip belongs to one process "
+            f"and workers are not yet given a chip each (ROADMAP R6): "
+            f"every worker would try to open all of them. Run one "
+            f"worker, or export JAX_PLATFORMS=cpu for CPU workers")
+
+
 class FleetController:
     """N out-of-process replica workers behind one prefix-aware
     least-loaded ``submit()`` — the :class:`~apex_tpu.serving.Router`
@@ -355,6 +401,7 @@ class FleetController:
             raise ValueError(f"roles has {len(self.roles)} entries "
                              f"for {len(specs)} workers")
         self._validate_role_mix(self.roles)
+        check_worker_backend(len(specs))
         self.registry = registry
         self.route_policy = route_policy
         self.fault_plan = fault_plan
@@ -1071,6 +1118,8 @@ class FleetController:
         index = len(self.workers)
         self._validate_role_mix([w.role for w in self.workers
                                  if w.alive] + [str(role)])
+        check_worker_backend(
+            sum(w.alive for w in self.workers) + 1)
         self._specs.append(spec)
         self.roles.append(str(role))
         proc = self._launch(index)

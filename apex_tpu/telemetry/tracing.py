@@ -9,7 +9,7 @@ every lifecycle stage (``submit`` → ``route`` → ``queue_wait`` →
 ``admit`` → ``prefill_chunk``* → ``heartbeat``* / ``draft`` /
 ``verify`` → ``swap_out`` / ``swap_in`` → terminal ``finish`` /
 ``expired`` / ``failed``, with ``quarantine`` sub-spans on faults —
-the full taxonomy is documented in docs/serving.md and pinned by the
+the full catalogue is documented in docs/serving.md and pinned by the
 span-name lint in tests/L0/test_serving_metrics_lint.py).
 
 Design constraints, in order:

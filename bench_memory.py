@@ -221,4 +221,6 @@ if __name__ == "__main__":
     # crash contract: any failure still ends in one parseable JSON
     # line ({"metric", "error", "rc": 1}) instead of a bare traceback
     from apex_tpu.telemetry import guard_bench_main
+    from apex_tpu.utils.chip import enable_compile_cache
+    enable_compile_cache()
     guard_bench_main(lambda: main(sys.argv[1:]), "bench_memory")

@@ -3354,7 +3354,9 @@ def main_lora():
 
 if __name__ == "__main__":
     from apex_tpu.telemetry import guard_bench_main
+    from apex_tpu.utils.chip import enable_compile_cache
 
+    enable_compile_cache()
     if "--mixed-prompts" in sys.argv[1:]:
         guard_bench_main(main_mixed, MIXED_METRIC)
     elif "--shared-prefix" in sys.argv[1:]:

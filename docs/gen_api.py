@@ -52,7 +52,7 @@ PAGES = {
               "apex_tpu.utils.sharded_checkpoint", "apex_tpu.utils.pytree",
               "apex_tpu.utils.memory_report",
               "apex_tpu.utils.schedule_report", "apex_tpu.utils.compat",
-              "apex_tpu.pyprof"],
+              "apex_tpu.utils.chip", "apex_tpu.pyprof"],
     "telemetry": ["apex_tpu.telemetry", "apex_tpu.telemetry.sinks",
                   "apex_tpu.telemetry.summarize",
                   "apex_tpu.telemetry.tracing", "apex_tpu.log_util"],

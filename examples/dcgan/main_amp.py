@@ -135,4 +135,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.chip import enable_compile_cache
+
+    print(f"=> compile cache: {enable_compile_cache()}")
     main()

@@ -42,6 +42,7 @@ import optax
 
 from apex_tpu import amp
 from apex_tpu.models import create_model
+from apex_tpu.utils import chip
 from apex_tpu.utils.compat import shard_map
 
 
@@ -286,7 +287,10 @@ class data_prefetcher:
             yield batch
 
 
-def main(argv=None):
+def main(argv=None, on_step=None):
+    """Train and validate. ``on_step(it, metrics)``, when given, sees
+    every train step's metrics right after dispatch (a caller that
+    wants per-step wall time blocks on them there)."""
     args = parse_args(argv)
     if args.accum_steps < 1:
         raise SystemExit("--accum-steps must be >= 1")
@@ -423,6 +427,7 @@ def main(argv=None):
 
     best_prec1 = 0.0
     last_batch = None          # for --prof-device after the loops
+    compiled = None
     for epoch in range(start_epoch, args.epochs):
         t0 = None
         imgs = 0
@@ -457,7 +462,15 @@ def main(argv=None):
             if args.prof and it == 5:
                 jax.profiler.start_trace("/tmp/apex_tpu_trace")
             last_batch = batch
-            state, metrics = jit_step(state, batch)
+            if compiled is None:
+                # one ahead-of-time compile, so the recipe can say
+                # what the step holds on this backend
+                compiled, _, line = chip.compile_and_report(
+                    f"{args.arch} train step", jit_step, state, batch)
+                print(line)
+            state, metrics = compiled(state, batch)
+            if on_step is not None:
+                on_step(it, metrics)
             if args.prof and it == 5 + args.prof:
                 metrics["loss"].block_until_ready()
                 jax.profiler.stop_trace()
@@ -497,7 +510,7 @@ def main(argv=None):
             print("device throughput: n/a (no training step ran)")
         else:
             line = pyprof.device_throughput_line(
-                jit_step, state, last_batch, args.prof_device,
+                compiled, state, last_batch, args.prof_device,
                 args.batch_size, "img/s")
             if line:
                 print(line)
@@ -510,4 +523,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    print(f"=> compile cache: {chip.enable_compile_cache()}")
     main()

@@ -35,6 +35,7 @@ from apex_tpu import amp
 from apex_tpu.kernels.xentropy import softmax_cross_entropy_loss
 from apex_tpu.models.transformer_lm import create_lm
 from apex_tpu.optimizers import fused_adam
+from apex_tpu.utils import chip
 
 
 def parse_args(argv=None):
@@ -893,7 +894,10 @@ def assert_trees_close(got, want, rtol=2e-4, atol=5e-5):
         got, want)
 
 
-def run_parallel(args, policy):
+def run_parallel(args, policy, on_step=None):
+    """The dp x tp x pp training loop. ``on_step(it, metrics)``, when
+    given, sees every step's metrics right after dispatch (a caller
+    that wants per-step wall time blocks on them there)."""
     if args.iters < 1:
         raise SystemExit("--iters must be >= 1")
     if args.remat:
@@ -930,6 +934,8 @@ def run_parallel(args, policy):
                                          args.seq_len, args.vocab_size)
             state, metrics = jit_step(state, batch)
             loss_history.append(metrics["loss"])
+            if on_step is not None:
+                on_step(it, metrics)
             if it == start_it + 2:
                 metrics["loss"].block_until_ready()
                 t0 = time.perf_counter()
@@ -1008,7 +1014,10 @@ def _maybe_generate(args, model, params, tele):
     """--generate N: serve synthetic variable-length prompts through the
     compiled KV-cache engine (apex_tpu.serving) with the just-trained
     params — the recipe's end-to-end inference leg. Returns the
-    completed requests (for callers/tests inspecting the outputs)."""
+    completed requests, the model and ``Engine`` geometry keywords they
+    were served with and the Pallas kernels each serving program holds
+    (for callers inspecting the outputs or serving the same stream on
+    another engine), or None without --generate."""
     if not args.generate:
         return None
     import numpy as _np
@@ -1037,14 +1046,26 @@ def _maybe_generate(args, model, params, tele):
           f"{dt:.2f}s ({toks / dt:,.0f} tokens/s), "
           f"ttft p50 {sorted(ttfts)[len(ttfts) // 2] * 1e3:.1f} ms, "
           f"compiled programs: {engine.compiled_programs}")
+    kernels = engine.program_kernels()
+    print(f"=> serving programs (paged, page_len {engine.page_len}, "
+          f"chunk_len {engine.chunk_len}): " + "; ".join(
+              f"{name}: {chip.format_kernels(k)}"
+              for name, k in kernels.items()))
     preview = done[0]
     print(f"   sample [{preview.finish_reason}]: "
           f"{list(preview.prompt)[:8]}... -> "
           f"{preview.output_tokens[:16]}")
-    return done
+    return {"requests": done, "kernels": kernels, "seconds": dt,
+            "model": model, "page_len": engine.page_len,
+            "geometry": {"slots": engine.slots, "max_len": engine.max_len,
+                         "prefill_len": engine.prefill_len,
+                         "chunk_len": engine.chunk_len}}
 
 
-def main(argv=None):
+def main(argv=None, on_step=None):
+    """Train (and with --generate, serve). ``on_step(it, metrics)``,
+    when given, sees every train step's metrics right after dispatch
+    (a caller that wants per-step wall time blocks on them there)."""
     args = parse_args(argv)
     if args.iters < 1:
         raise SystemExit("--iters must be >= 1")
@@ -1086,7 +1107,7 @@ def main(argv=None):
             raise SystemExit("--fused-head is shard_map-only under "
                              "parallelism (gspmd keeps the materialized "
                              "vocab-parallel loss)")
-        return run_parallel(args, policy)
+        return run_parallel(args, policy, on_step)
     if args.partitioning == "gspmd":
         raise SystemExit("--partitioning gspmd needs a mesh: pass "
                          "--data-parallel and/or --tensor-parallel > 1")
@@ -1144,6 +1165,7 @@ def main(argv=None):
     toks = 0
     metrics = None
     loss_history = []
+    compiled = kernels = None
     for it in range(start_it, args.iters):
         rng, sub = jax.random.split(rng)
         if args.deterministic:
@@ -1156,8 +1178,16 @@ def main(argv=None):
         # [B, S+1] → [N, B/N, S+1]: the microbatch scan axis of
         # make_train_step(accum_steps=N); identity at N=1
         batch = amp.to_microbatches(batch, args.accum_steps)
-        state, metrics = jit_step(state, batch)
+        if compiled is None:
+            # one ahead-of-time compile, so the recipe can say which
+            # fused kernels the step really holds on this backend
+            compiled, kernels, line = chip.compile_and_report(
+                "LM train step", jit_step, state, batch)
+            print(line)
+        state, metrics = compiled(state, batch)
         loss_history.append(metrics["loss"])
+        if on_step is not None:
+            on_step(it, metrics)
         if it == start_it + 4:
             metrics["loss"].block_until_ready()
             t0 = time.perf_counter()
@@ -1174,12 +1204,14 @@ def main(argv=None):
     if metrics is None:
         _finish_telemetry(tele)
         return None
-    _maybe_prof_device(args, jit_step, state, batch)
+    _maybe_prof_device(args, compiled, state, batch)
     _maybe_save(args, state, rng)
-    _maybe_generate(args, model, state.params, tele)
+    generated = _maybe_generate(args, model, state.params, tele)
     _finish_telemetry(tele)
     metrics = dict(metrics)
     metrics["final_state"] = state
+    metrics["kernels"] = kernels
+    metrics["generate"] = generated
     # one device-to-host transfer for the whole history
     metrics["loss_history"] = np.asarray(jnp.stack(loss_history),
                                          np.float32).tolist()
@@ -1187,4 +1219,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    print(f"=> compile cache: {chip.enable_compile_cache()}")
     main()

@@ -84,14 +84,14 @@ a rename breaks the lint loudly instead of silently un-scoping it.
 This file also owns the **span-name lint** (the tracing tentpole's
 version of the metric-name loop): span names are stringly typed at
 their emit sites (``tracer.event(uid, "admit", ...)``), so a renamed
-span silently orphans its row in the ``### Span taxonomy`` table in
+span silently orphans its row in the ``### Span catalogue`` table in
 ``docs/serving.md`` — and a documented span nobody emits is a Perfetto
 lane a reader will wait for forever. The lint AST-scans
 ``apex_tpu/serving/`` for calls to the three tracer recording methods
 (``.event`` / ``.event_current`` / ``.end_trace``) and extracts each
 call's first string-literal positional argument (the span name —
 trace ids are never literals), then pins that set EQUAL to the
-backticked first column of the taxonomy table. And the **tracer
+backticked first column of the catalogue table. And the **tracer
 force-lint**: the tracer's recording methods run inside the
 dispatch-ahead regions' dynamic extent (the heartbeat/swap hooks call
 them between dispatch and reconcile), so they get the same
@@ -518,13 +518,13 @@ def _spans_emitted():
 
 def _spans_documented():
     """The backticked first column of every row of the
-    ``### Span taxonomy`` table in docs/serving.md."""
+    ``### Span catalogue`` table in docs/serving.md."""
     names = set()
     in_section = False
     with open(DOC) as f:
         for line in f:
             if line.startswith("#"):
-                in_section = line.strip() == "### Span taxonomy"
+                in_section = line.strip() == "### Span catalogue"
                 continue
             if in_section and line.startswith("| `"):
                 names.add(line.split("`")[1])
@@ -561,7 +561,7 @@ def test_span_scan_surface_is_alive():
             f"span {name!r} not emitted by the engine — migration " \
             "tracing went dark"
     assert _spans_documented(), "docs/serving.md has no " \
-        "'### Span taxonomy' table — doc section missing/renamed?"
+        "'### Span catalogue' table — doc section missing/renamed?"
 
 
 def test_every_emitted_span_is_documented():
@@ -570,14 +570,14 @@ def test_every_emitted_span_is_documented():
     missing = {k: v for k, v in emitted.items() if k not in documented}
     assert not missing, (
         f"spans emitted in code but absent from docs/serving.md's "
-        f"span-taxonomy table (add a row): {missing}")
+        f"span-catalogue table (add a row): {missing}")
 
 
 def test_every_documented_span_is_emitted():
     emitted = set(_spans_emitted())
     stale = _spans_documented() - emitted
     assert not stale, (
-        f"docs/serving.md's span-taxonomy table names spans no "
+        f"docs/serving.md's span-catalogue table names spans no "
         f"serving code emits (stale rows — delete them or wire the "
         f"emitter): {stale}")
 

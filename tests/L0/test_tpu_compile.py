@@ -1,0 +1,255 @@
+"""The chip rehearsal, kept as tests: compile the main path's kernels
+for a DESCRIBED TPU v5e (no chip attached) at GPT-2 shapes and assert
+each really is a Mosaic kernel (``tpu_custom_call``) in the compiled
+program.
+
+Interpret mode — what every other kernel test here runs — cannot see
+what the TPU compiler refuses: a block whose last two dims neither tile
+(8, 128) nor equal the array's (how ``paged_decode_attention`` was
+refused at every shape until PR 21), or a working set past scoped VMEM.
+The TPU compiler is installed with jaxlib's libtpu and compiles for a
+topology that is described, not attached, at about two seconds a kernel.
+
+Steering is done HERE, not by an option of the program: the kernels ask
+``jax.default_backend()`` (cpu -> interpret) and read their tuned blocks
+by ``jax.devices()[0].device_kind``, so the fixture answers "tpu" and
+loads the packaged v5e blocks for the duration of a case. A compile
+that passes is not a chip run (``chip_smoke.py`` is).
+"""
+
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from apex_tpu.kernels import vmem
+from apex_tpu.utils.chip import kernel_calls
+
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                    os.pardir, os.pardir))
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """Sharding on one device of a described v5e 2x2 host; skips where
+    this installation cannot describe the topology."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_v5e(monkeypatch):
+    """Kernel dispatch takes its chip branch with the packaged v5e
+    blocks; the persistent compile cache is off (a described-device
+    compile is written to it but cannot be read back without a chip)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    saved, loaded = vmem.overrides(), vmem._auto_load_done
+    vmem._auto_load_done = True
+    vmem.load_overrides(vmem.packaged_path("TPU v5 lite"))
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+        vmem.clear_overrides()
+        for k, v in saved.items():
+            vmem.set_override(k, v)
+        vmem._auto_load_done = loaded
+
+
+# GPT-2 small: 12 heads x 64; the 16 x 128 cases are the next width up
+# (the "medium" preset's heads at a 128-lane head_dim).
+B, S, H, V = 8, 1024, 768, 50304
+PAGE, MAX_PAGES = 128, 8
+
+
+def _flash():
+    from apex_tpu.kernels.flash_attention import flash_attention
+
+    def fn(q, k, v):
+        return jax.grad(lambda *a: flash_attention(
+            *a, causal=True).astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
+    return fn, [((B, 12, S, 64), BF16)] * 3
+
+
+def _layer_norm():
+    from apex_tpu.kernels.layer_norm import layer_norm
+
+    def fn(x, g, b):
+        return jax.grad(lambda *a: layer_norm(*a).astype(F32).sum(),
+                        argnums=(0, 1, 2))(x, g, b)
+    return fn, [((B * S, H), BF16), ((H,), F32), ((H,), F32)]
+
+
+def _xentropy():
+    from apex_tpu.kernels.xentropy import softmax_cross_entropy_loss
+
+    def fn(logits, labels):
+        return jax.grad(lambda lg: softmax_cross_entropy_loss(
+            lg, labels).mean())(logits)
+    return fn, [((B, S, V), F32), ((B, S), I32)]
+
+
+def _adam():
+    from apex_tpu.kernels.multi_tensor import fused_adam_step
+
+    def fn(p, g, m, v):
+        return fused_adam_step(p, g, m, v, lr=1e-3, beta1=0.9,
+                               beta2=0.999, eps=1e-8, weight_decay=0.01,
+                               step=1)
+    return fn, [((124_475_904,), F32)] * 4      # GPT-2 small, flat
+
+
+def _scales(heads, kv_dtype):
+    return [((heads,), F32)] * 2 if kv_dtype == I8 else [None, None]
+
+
+def _decode(heads, d, kv_dtype):
+    from apex_tpu.kernels.decode_attention import decode_attention
+
+    def fn(q, k, v, lengths, ks, vs):
+        return decode_attention(q, k, v, lengths, k_scale=ks, v_scale=vs)
+    L = PAGE * MAX_PAGES
+    return fn, [((B, heads, d), BF16), ((B, heads, L, d), kv_dtype),
+                ((B, heads, L, d), kv_dtype), ((B,), I32),
+                *_scales(heads, kv_dtype)]
+
+
+def _paged_decode(heads, d, kv_dtype):
+    from apex_tpu.kernels.decode_attention import paged_decode_attention
+
+    def fn(q, k, v, table, lengths, ks, vs):
+        return paged_decode_attention(q, k, v, table, lengths,
+                                      k_scale=ks, v_scale=vs)
+    pool = (B * MAX_PAGES + 1, heads, PAGE, d)
+    return fn, [((B, heads, d), BF16), (pool, kv_dtype), (pool, kv_dtype),
+                ((B, MAX_PAGES), I32), ((B,), I32),
+                *_scales(heads, kv_dtype)]
+
+
+def _prefill(heads, d, kv_dtype, chunk=256):
+    from apex_tpu.kernels.prefill_attention import prefill_attention
+
+    def fn(q, k, v, offsets, ks, vs):
+        return prefill_attention(q, k, v, offsets, k_scale=ks, v_scale=vs)
+    L = PAGE * MAX_PAGES
+    return fn, [((1, heads, chunk, d), BF16), ((1, heads, L, d), kv_dtype),
+                ((1, heads, L, d), kv_dtype), ((1,), I32),
+                *_scales(heads, kv_dtype)]
+
+
+def _paged_prefill(heads, d, kv_dtype, chunk=256):
+    from apex_tpu.kernels.prefill_attention import paged_prefill_attention
+
+    def fn(q, k, v, table, offsets, ks, vs):
+        return paged_prefill_attention(q, k, v, table, offsets,
+                                       k_scale=ks, v_scale=vs)
+    pool = (MAX_PAGES + 1, heads, PAGE, d)
+    return fn, [((1, heads, chunk, d), BF16), (pool, kv_dtype),
+                (pool, kv_dtype), ((1, MAX_PAGES), I32), ((1,), I32),
+                *_scales(heads, kv_dtype)]
+
+
+CASES = {
+    "flash_fwd_bwd": (_flash, (), ["flash_attention_fwd",
+                                   "flash_attention_bwd_dq",
+                                   "flash_attention_bwd_dkv"]),
+    "layer_norm_fwd_bwd": (_layer_norm, (), ["layer_norm_fwd",
+                                             "layer_norm_bwd"]),
+    "xentropy_padded_vocab": (_xentropy, (), ["xentropy_fwd",
+                                              "xentropy_bwd"]),
+    "flat_fused_adam": (_adam, (), ["multi_tensor_adam"]),
+    "decode_bf16": (_decode, (12, 64, BF16), ["decode_attention"]),
+    "decode_int8": (_decode, (12, 64, I8), ["decode_attention"]),
+    "paged_decode_bf16_12x64": (_paged_decode, (12, 64, BF16),
+                                ["paged_decode_attention"]),
+    "paged_decode_int8_12x64": (_paged_decode, (12, 64, I8),
+                                ["paged_decode_attention"]),
+    "paged_decode_bf16_16x128": (_paged_decode, (16, 128, BF16),
+                                 ["paged_decode_attention"]),
+    "paged_decode_int8_16x128": (_paged_decode, (16, 128, I8),
+                                 ["paged_decode_attention"]),
+    "prefill_bf16": (_prefill, (12, 64, BF16), ["prefill_attention"]),
+    "paged_prefill_bf16": (_paged_prefill, (12, 64, BF16),
+                           ["paged_prefill_attention"]),
+    "paged_prefill_int8": (_paged_prefill, (12, 64, I8),
+                           ["paged_prefill_attention"]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e_and_stays_a_kernel(case, one_chip, as_v5e):
+    build, build_args, want = CASES[case]
+    fn, shapes = build(*build_args)
+    args = [None if s is None
+            else jax.ShapeDtypeStruct(s[0], s[1], sharding=one_chip)
+            for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()   # raises what the chip would
+    have = kernel_calls(compiled.as_text())
+    assert all(have.get(k) for k in want), \
+        f"{case}: expected Mosaic kernels {want}, the program holds {have}"
+
+
+def test_xentropy_at_unpadded_gpt2_vocab_takes_the_reference(one_chip,
+                                                            as_v5e):
+    """The gate chip_smoke's per-kernel check exists for: at 50257 (not
+    a multiple of 128) the fused loss compiles — to ``xent_reference``,
+    with no kernel in the program."""
+    fn, shapes = _xentropy()
+    shapes[0] = ((B, S, 50257), F32)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    assert kernel_calls(jax.jit(fn).lower(*args).compile().as_text()) == {}
+
+
+def test_chip_smoke_refuses_the_cpu_without_compiling(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_LOG_COMPILES="1",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+        capture_output=True, text=True, env=env, cwd=str(tmp_path),
+        timeout=300)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "no accelerator" in proc.stderr
+    assert "Compiling" not in proc.stderr
+    assert not os.path.exists(tmp_path / "cache")
+
+
+def test_ensure_devices_raises_on_a_short_accelerator(monkeypatch):
+    """A TPU backend with fewer chips than asked must raise, naming
+    both numbers — never carry on with virtual CPU devices."""
+    import jax.extend.backend as backend
+
+    from apex_tpu import comm
+
+    class FakeChip:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    def no_switch():
+        raise AssertionError("ensure_devices switched backends")
+
+    chips = [FakeChip()]
+    monkeypatch.setattr(comm.jax, "devices", lambda: chips)
+    monkeypatch.setattr(backend, "clear_backends", no_switch)
+    with pytest.raises(RuntimeError, match=r"tpu backend has 1 device"
+                       r".*TPU v5 lite.*4 were asked"):
+        comm.ensure_devices(4)
+    assert comm.ensure_devices(1) is chips

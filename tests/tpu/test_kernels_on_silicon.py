@@ -268,7 +268,7 @@ def test_group_norm_fwd_bwd(tpu_backend, act):
 def test_fp16_inputs_take_the_xla_fallback(tpu_backend):
     """TPU Mosaic has no fp16: every public fused op must detect float16
     operands and route to its jnp fallback (where XLA upconverts) instead
-    of crashing the remote compile — found by the on-silicon scaler soak.
+    of crashing the compile — found by the on-silicon scaler soak.
     bf16 stays on the Pallas path."""
     import jax
     import jax.numpy as jnp
@@ -299,3 +299,75 @@ def test_fp16_inputs_take_the_xla_fallback(tpu_backend):
     dx = jax.grad(lambda x: jnp.sum(jnp.asarray(
         layer_norm(x, g, b), jnp.float32)))(x16)
     assert dx.dtype == jnp.float16
+
+
+# ------------------------------------------- serving attention (PR 21)
+def _serving_case(kind, kv, seed=0):
+    """Random operands at GPT-2 head geometry (12 x 64) over a 128-page
+    layout: ``(kernel_fn, reference_fn, operands, kernel_name)``. int8
+    pools carry per-head dequant scales; page tables are a random
+    permutation of the pool so a wrong gather cannot pass."""
+    import importlib
+
+    rng = np.random.default_rng(seed)
+    B, h, d, page, max_pages, C = 4, 12, 64, 128, 5, 256
+    L = page * max_pages
+    paged = kind.startswith("paged")
+    decode = kind.endswith("decode")
+    rows = B if decode else 1
+    q_shape = (rows, h, d) if decode else (rows, h, C, d)
+    q = jnp.asarray(rng.standard_normal(q_shape), jnp.bfloat16)
+    kv_shape = (rows * max_pages + 1, h, page, d) if paged \
+        else (rows, h, L, d)
+    if kv == "int8":
+        k, v = (jnp.asarray(rng.integers(-127, 128, kv_shape), jnp.int8)
+                for _ in range(2))
+        scales = dict(
+            k_scale=jnp.asarray(rng.uniform(0.01, 0.03, h), jnp.float32),
+            v_scale=jnp.asarray(rng.uniform(0.01, 0.03, h), jnp.float32))
+    else:
+        k, v = (jnp.asarray(rng.standard_normal(kv_shape), jnp.bfloat16)
+                for _ in range(2))
+        scales = {}
+    if decode:
+        # lengths: empty slot, one token, mid-page, page boundary
+        pos = jnp.asarray([0, 1, 300, L][:rows], jnp.int32)
+    else:
+        pos = jnp.asarray([page], jnp.int32)     # chunk starts on page 1
+    ops = [q, k, v]
+    if paged:
+        table = rng.permutation(np.arange(1, rows * max_pages + 1))
+        ops.append(jnp.asarray(table.reshape(rows, max_pages), jnp.int32))
+    ops.append(pos)
+    # by module path: the package re-exports same-named FUNCTIONS
+    mod = importlib.import_module(
+        "apex_tpu.kernels."
+        + ("decode_attention" if decode else "prefill_attention"))
+    kernel = getattr(mod, f"{kind}_attention")
+    reference = getattr(mod, f"{kind}_attention_reference")
+    scale = 1.0 / d ** 0.5
+    return (lambda *a: kernel(*a, **scales),
+            lambda *a: reference(*a, scale=scale, **scales),
+            ops, f"{kind}_attention")
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "paged_decode", "prefill",
+                                  "paged_prefill"])
+def test_serving_attention_matches_reference(tpu_backend, kind, kv):
+    """The four serving attention kernels, compiled by Mosaic and RUN,
+    against their gather/jnp oracles — and really the kernel: the
+    compiled program must hold the ``tpu_custom_call`` (at these aligned
+    shapes a silent give-way to the reference would compare the oracle
+    with itself)."""
+    from apex_tpu.utils.chip import kernel_calls
+
+    kernel, reference, ops, name = _serving_case(kind, kv)
+    compiled = jax.jit(kernel).lower(*ops).compile()
+    assert kernel_calls(compiled.as_text()).get(name), \
+        f"{name} gave way to its reference on {jax.default_backend()}"
+    got = compiled(*ops)
+    want = reference(*ops)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert bool(jnp.all(jnp.isfinite(jnp.asarray(got, jnp.float32))))
+    _close(got, want, 2e-2)
