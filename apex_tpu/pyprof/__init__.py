@@ -25,11 +25,15 @@ import glob
 import gzip
 import json
 import os
+import re
 import time
+import warnings
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+
+from apex_tpu.telemetry import tracing
 
 __all__ = ["init", "annotate", "trace", "cost_report", "analyze", "report",
            "device_busy", "step_device_throughput",
@@ -53,11 +57,14 @@ def init(enabled: bool = True):
 @contextlib.contextmanager
 def annotate(name: str):
     """Named range visible in both the XLA profile (named_scope) and host
-    timeline (TraceAnnotation). Usable inside and outside jit."""
+    timeline. Usable inside and outside jit. The host half is
+    :func:`apex_tpu.telemetry.tracing.phase` - the one way this package
+    marks a host region: ``apex.<name>`` on the profiler's host plane
+    and a record in the flight recorder."""
     if not _enabled:
         yield
         return
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
+    with jax.named_scope(name), tracing.phase(name):
         yield
 
 
@@ -105,8 +112,11 @@ def cost_report(fn: Callable, *args, **kwargs) -> Dict[str, Any]:
 
 
 def _trace_files(trace_dir: str) -> List[str]:
-    """The newest profile run's chrome-trace dumps under ``trace_dir``
-    (one per host), or ``trace_dir`` itself if it is already a dump."""
+    """The newest profile run's dumps under ``trace_dir`` (one per
+    host), or ``trace_dir`` itself if it is already a dump: the
+    chrome-trace ``*.trace.json.gz`` where the profiler wrote them,
+    else the run's ``*.xplane.pb`` (all that this JAX writes on the
+    chip)."""
     if os.path.isfile(trace_dir):
         return [trace_dir]
     runs = sorted(glob.glob(os.path.join(
@@ -115,9 +125,11 @@ def _trace_files(trace_dir: str) -> List[str]:
         raise FileNotFoundError(
             f"no profile runs under {trace_dir!r} — capture one with "
             "pyprof.trace(log_dir) first")
-    files = sorted(glob.glob(os.path.join(runs[-1], "*.trace.json.gz")))
+    files = sorted(glob.glob(os.path.join(runs[-1], "*.trace.json.gz"))) \
+        or sorted(glob.glob(os.path.join(runs[-1], "*.xplane.pb")))
     if not files:
-        raise FileNotFoundError(f"no *.trace.json.gz in {runs[-1]!r}")
+        raise FileNotFoundError(
+            f"no *.trace.json.gz and no *.xplane.pb in {runs[-1]!r}")
     return files
 
 
@@ -160,6 +172,50 @@ def _leaf_spans(evs: List[dict],
     return out
 
 
+_HLO_OPCODE = re.compile(r" = .*? ([\w\-]+)\(")
+
+
+def _xplane_events(path: str, fi: int) -> List[tuple]:
+    """One ``.xplane.pb`` (or a recording kept as gzipped text proto,
+    ``.txtpb.gz``) as the same (lane_name, file_idx, event) triples a
+    chrome dump gives: a device plane's ``XLA Ops`` line (one event per
+    executed HLO operation; the other lines mirror the same execution)
+    and every line of the host planes, times in microseconds. On this
+    JAX a device event is named by its whole HLO instruction: the
+    instruction's own name is kept, its opcode stands in for a missing
+    ``hlo_category``, and an event's stats are its ``args``."""
+    from jax.profiler import ProfileData
+
+    if path.endswith(".txtpb.gz"):
+        with gzip.open(path, "rt") as f:
+            pd = ProfileData.from_serialized_xspace(
+                ProfileData.text_proto_to_serialized_xspace(f.read()))
+    else:
+        pd = ProfileData.from_file(path)
+    events: List[tuple] = []
+    with warnings.catch_warnings():
+        # nanobind's stats iterator has no __module__: a warning an event
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for pid, plane in enumerate(pd.planes):
+            dev = plane.name.startswith("/device:")
+            for tid, line in enumerate(plane.lines):
+                if dev and line.name != "XLA Ops":
+                    continue
+                for ev in line.events:
+                    args = dict(ev.stats)
+                    name = ev.name
+                    if dev:
+                        op = _HLO_OPCODE.search(name)
+                        name = name.split(" = ", 1)[0].strip().lstrip("%")
+                        args.setdefault("hlo_category",
+                                        op.group(1) if op else "")
+                    events.append((plane.name, fi, {
+                        "name": name, "ph": "X", "pid": pid, "tid": tid,
+                        "ts": ev.start_ns / 1e3, "dur": ev.duration_ns / 1e3,
+                        "args": args}))
+    return events
+
+
 def _load_events(trace_dir: str) -> List[tuple]:
     """All complete ('X') events of the newest dump as (lane_name,
     file_idx, event) triples. pid namespaces are PER FILE (one dump per
@@ -167,6 +223,9 @@ def _load_events(trace_dir: str) -> List[tuple]:
     process_name metadata and lanes never mix across files."""
     events: List[tuple] = []
     for fi, path in enumerate(_trace_files(trace_dir)):
+        if not path.endswith(".json.gz"):
+            events += _xplane_events(path, fi)
+            continue
         with gzip.open(path, "rt") as f:
             data = json.load(f)
         evs = data.get("traceEvents", [])

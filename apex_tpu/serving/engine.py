@@ -115,10 +115,17 @@ runtime — forced reads (token readback, finite flags), the
 :meth:`Engine.sync` barrier, and the compiled calls themselves
 (:meth:`Engine._runtime_call`: the CPU backend executes
 donated-buffer programs synchronously inside dispatch, so the call's
-block time IS device execution there; on silicon async dispatch makes
-it ~µs) — charges its block time to :attr:`Engine.device_wait_s`,
-which the scheduler differences per heartbeat into the
-``serving.heartbeat.*`` host-think / device-wait split.
+block time IS device execution there) — charges its block time to
+:attr:`Engine.device_wait_s`, which the scheduler differences per
+heartbeat into the ``serving.heartbeat.*`` host-think / device-wait
+split. The same seconds are kept apart where the work happens, as
+phases (``apex.engine.upload`` / ``launch`` / ``readback``, see
+:func:`apex_tpu.telemetry.tracing.phase`) and as the counters
+:attr:`Engine.upload_s`, :attr:`Engine.launch_s` and
+:attr:`Engine.readback_s`, which sum to ``device_wait_s``: a program's
+operands are built and uploaded (:meth:`Engine._operands`) before the
+compiled call that takes them, so that what a launch costs on silicon
+is a number and not an assumption.
 
 **Tensor parallelism** (``mesh=...``, paged only): the same programs,
 shard_map'd over a 1-D tensor-parallel mesh axis
@@ -181,6 +188,7 @@ import numpy as np
 
 from apex_tpu.kernels import vmem
 from apex_tpu.log_util import get_logger
+from apex_tpu.telemetry import tracing
 
 from .host_tier import HostTier, SwapWorker
 from .kv_cache import KVCache, PagedKVCache, PagePool
@@ -798,6 +806,13 @@ class Engine:
         # serving.heartbeat.* gauges and the pipelined watchdog's
         # host-portion budget.
         self.device_wait_s = 0.0
+        # the same seconds, split where the work happens (always on; the
+        # three sum to device_wait_s): building and uploading a
+        # program's operands, the compiled call itself, and the forced
+        # reads that wait for its results
+        self.upload_s = 0.0
+        self.launch_s = 0.0
+        self.readback_s = 0.0
         # the non-finite guard's host-side view, refreshed by every
         # sampling call: per-slot flags for the last decode step, one
         # flag each for the last chunk/monolithic prefill. True means
@@ -1498,26 +1513,24 @@ class Engine:
             # slots' promises) with enough pages to hold it
             self.release_slot(slot, keep_reservation=True)
             self._grow_slot(slot, -(-self.prefill_len // self.page_len))
-            self.cache, token, finite = self._runtime_call(
-                lambda: self._with_prefill_blocks(
-                    lambda: self._jit_prefill(
-                        self.params, self.cache, jnp.asarray(tokens),
-                        jnp.asarray(self._page_table[slot:slot + 1].copy()),
-                        np.int32(n), np.float32(temperature),
-                        self._next_key(), *self._lora_args(slot))))
-            self._host_len[slot] = n
+            ops = self._operands(lambda: (
+                jnp.asarray(tokens),
+                jnp.asarray(self._page_table[slot:slot + 1].copy()),
+                np.int32(n), np.float32(temperature),
+                self._next_key(), *self._lora_args(slot)))
         else:
-            self.cache, token, finite = self._runtime_call(
-                lambda: self._with_prefill_blocks(
-                    lambda: self._jit_prefill(
-                        self.params, self.cache, jnp.asarray(tokens),
-                        np.int32(n), np.int32(slot),
-                        np.float32(temperature), self._next_key(),
-                        *self._lora_args(slot))))
-        tw = time.perf_counter()
-        token = int(token)                  # device sync
-        self.last_prefill_finite = bool(finite)
-        self.device_wait_s += time.perf_counter() - tw
+            ops = self._operands(lambda: (
+                jnp.asarray(tokens), np.int32(n), np.int32(slot),
+                np.float32(temperature), self._next_key(),
+                *self._lora_args(slot)))
+        self.cache, token, finite = self._runtime_call(
+            "prefill", lambda: self._with_prefill_blocks(
+                lambda: self._jit_prefill(self.params, self.cache,
+                                          *ops)))
+        if self.paged:
+            self._host_len[slot] = n
+        token, self.last_prefill_finite = self._readback(
+            "prefill", lambda: (int(token), bool(finite)))  # device sync
         if not self.last_prefill_finite:
             self._count_nonfinite(1)
         if self._registry is not None:
@@ -1607,21 +1620,23 @@ class Engine:
                 self.release_slot(slot, keep_reservation=True)
             self._grow_slot(
                 slot, -(-(offset + self.chunk_len) // self.page_len))
-            self.cache, token, finite = self._runtime_call(
-                lambda: self._jit_chunk(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(self._page_table[slot:slot + 1].copy()),
-                    np.int32(offset), np.int32(n),
-                    np.float32(temperature), np.float32(fault_bias),
-                    self._next_key(), *self._lora_args(slot)))
-            self._host_len[slot] = offset + n
+            ops = self._operands(lambda: (
+                jnp.asarray(tokens),
+                jnp.asarray(self._page_table[slot:slot + 1].copy()),
+                np.int32(offset), np.int32(n),
+                np.float32(temperature), np.float32(fault_bias),
+                self._next_key(), *self._lora_args(slot)))
         else:
-            self.cache, token, finite = self._runtime_call(
-                lambda: self._jit_chunk(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    np.int32(slot), np.int32(offset), np.int32(n),
-                    np.float32(temperature), np.float32(fault_bias),
-                    self._next_key(), *self._lora_args(slot)))
+            ops = self._operands(lambda: (
+                jnp.asarray(tokens),
+                np.int32(slot), np.int32(offset), np.int32(n),
+                np.float32(temperature), np.float32(fault_bias),
+                self._next_key(), *self._lora_args(slot)))
+        self.cache, token, finite = self._runtime_call(
+            "chunk", lambda: self._jit_chunk(self.params, self.cache,
+                                             *ops))
+        if self.paged:
+            self._host_len[slot] = offset + n
         return PendingPrefill(
             token=token, finite=finite, slot=slot, final=final,
             t_dispatch=t0, dispatch_s=time.perf_counter() - t0)
@@ -1637,9 +1652,9 @@ class Engine:
             raise RuntimeError("PendingPrefill already reconciled")
         pending.reconciled = True
         tw = time.perf_counter()
-        token = int(pending.token)          # device sync
-        self.last_chunk_finite = bool(pending.finite)
-        self.device_wait_s += time.perf_counter() - tw
+        token, self.last_chunk_finite = self._readback(
+            "chunk", lambda: (int(pending.token),      # device sync
+                              bool(pending.finite)))
         if not self.last_chunk_finite:
             self._count_nonfinite(1)
         if self._registry is not None:
@@ -1901,8 +1916,9 @@ class Engine:
         # asynchronously (~0.1 ms) instead of executing it inline.
         ids = np.zeros(self.max_pages, np.int32)
         ids[:m] = list(pages)
+        ids_dev = self._operands(lambda: jnp.asarray(ids))
         k_dev, v_dev = self._runtime_call(
-            lambda: self._jit_swap_out(self.cache, jnp.asarray(ids)))
+            "swap_out", lambda: self._jit_swap_out(self.cache, ids_dev))
         tr = self._tracer
         ctx = None
         if tr is not None:
@@ -1954,10 +1970,11 @@ class Engine:
         inline = worker is None \
             or threading.current_thread() is not worker._thread
         tw = time.perf_counter()
-        k_host = np.asarray(k_dev)[:, :m]   # the deferred force
-        v_host = np.asarray(v_dev)[:, :m]
-        if inline:
-            self.device_wait_s += time.perf_counter() - tw
+        # the deferred force
+        force = lambda: (np.asarray(k_dev)[:, :m],    # noqa: E731
+                         np.asarray(v_dev)[:, :m])
+        k_host, v_host = self._readback("swap_out", force) if inline \
+            else force()
         stored = tier.complete(key, k_host, v_host)
         tr = self._tracer
         if tr is not None and trace_id is not None:
@@ -2033,9 +2050,9 @@ class Engine:
             joined = True
             if self._registry is not None:
                 self._registry.counter_inc("serving.swap.swap_join_waits")
-            tw = time.perf_counter()
             try:
-                self._swap_worker.join(key)
+                self._readback("swap_join",
+                               lambda: self._swap_worker.join(key))
             except Exception as e:  # noqa: BLE001 — degrade, never crash
                 # the job died before completing: the record is still
                 # pending, so take() below returns None and the hit
@@ -2044,7 +2061,6 @@ class Engine:
                                 "worker (%s: %s) — degrading its hit "
                                 "to a verified miss", key,
                                 type(e).__name__, e)
-            self.device_wait_s += time.perf_counter() - tw
         rec = tier.take(key) if tier is not None else None
         if rec is None or not rec.valid:
             pcache.drop(key)
@@ -2091,10 +2107,11 @@ class Engine:
         k_blk[:, :m], v_blk[:, :m] = k_host, v_host
         ids = np.zeros(P, np.int32)
         ids[:m] = pages
-        self.cache = self._runtime_call(
-            lambda: self._jit_swap_in(self.cache, jnp.asarray(k_blk),
+        ops = self._operands(lambda: (jnp.asarray(k_blk),
                                       jnp.asarray(v_blk),
                                       jnp.asarray(ids)))
+        self.cache = self._runtime_call(
+            "swap_in", lambda: self._jit_swap_in(self.cache, *ops))
         pcache.swap_in_complete(key, pages)
         self._trace_swap_in(t0, key, joined, "restored", m)
         if self._registry is not None:
@@ -2316,30 +2333,31 @@ class Engine:
             # active slot's write page exists BEFORE the program runs
             # (reservation at admission guarantees the pool can cover
             # it; a slot at max_len clamps onto its last page)
-            for s in np.flatnonzero(act):
-                pos = int(self._host_len[s])
-                if pos < self.max_len:
-                    self._grow_slot(s, self.pool.pages_for(pos + 1))
-            self.cache, tokens, finite = self._runtime_call(
-                lambda: self._jit_decode(
-                    self.params, self.cache,
-                    jnp.asarray(last_tokens, jnp.int32),
-                    jnp.asarray(self._page_table.copy()),
-                    jnp.asarray(self._host_len.copy()),
-                    jnp.asarray(temperatures, jnp.float32),
-                    jnp.asarray(fault_bias), self._next_key(),
-                    *self._lora_args()))
+            with tracing.phase("engine.grow"):
+                for s in np.flatnonzero(act):
+                    pos = int(self._host_len[s])
+                    if pos < self.max_len:
+                        self._grow_slot(s, self.pool.pages_for(pos + 1))
+            ops = self._operands(lambda: (
+                jnp.asarray(last_tokens, jnp.int32),
+                jnp.asarray(self._page_table.copy()),
+                jnp.asarray(self._host_len.copy()),
+                jnp.asarray(temperatures, jnp.float32),
+                jnp.asarray(fault_bias), self._next_key(),
+                *self._lora_args()))
+        else:
+            ops = self._operands(lambda: (
+                jnp.asarray(last_tokens, jnp.int32),
+                jnp.asarray(act),
+                jnp.asarray(temperatures, jnp.float32),
+                jnp.asarray(fault_bias), self._next_key(),
+                *self._lora_args()))
+        self.cache, tokens, finite = self._runtime_call(
+            "decode", lambda: self._jit_decode(self.params, self.cache,
+                                               *ops))
+        if self.paged:
             grow = act & (self._host_len < self.max_len)
             self._host_len[grow] += 1
-        else:
-            self.cache, tokens, finite = self._runtime_call(
-                lambda: self._jit_decode(
-                    self.params, self.cache,
-                    jnp.asarray(last_tokens, jnp.int32),
-                    jnp.asarray(act),
-                    jnp.asarray(temperatures, jnp.float32),
-                    jnp.asarray(fault_bias), self._next_key(),
-                    *self._lora_args()))
         return PendingDecode(tokens=tokens, finite=finite, active=act,
                              t_dispatch=t0)
 
@@ -2367,12 +2385,11 @@ class Engine:
         pending.reconciled = True
         valid = pending.active if valid is None \
             else np.asarray(valid, bool)
-        tw = time.perf_counter()
-        out = np.asarray(pending.tokens)    # device sync: step latency
-        finite = np.asarray(pending.finite, bool)
-        now = time.perf_counter()
-        self.device_wait_s += now - tw
-        dt = now - pending.t_dispatch
+        # device sync: the step's latency surfaces here
+        out, finite = self._readback(
+            "decode", lambda: (np.asarray(pending.tokens),
+                               np.asarray(pending.finite, bool)))
+        dt = time.perf_counter() - pending.t_dispatch
         self.last_decode_finite = finite
         bad = int(np.sum(valid & ~finite))
         if bad:
@@ -2394,22 +2411,23 @@ class Engine:
         dispatch order — but benches and tests use it to close a
         timing window, and the wait is charged to
         :attr:`device_wait_s` like any other forced sync."""
-        tw = time.perf_counter()
-        jax.block_until_ready(jax.tree_util.tree_leaves(self.cache))
-        self.device_wait_s += time.perf_counter() - tw
+        self._readback("sync", lambda: jax.block_until_ready(
+            jax.tree_util.tree_leaves(self.cache)))
 
-    def _runtime_call(self, fn):
-        """Invoke one compiled program, charging the call's block time
-        to :attr:`device_wait_s`. On real accelerators JAX dispatch is
-        asynchronous — the call returns in ~µs and the real wait
-        surfaces at the forced read — but the CPU backend executes
-        DONATED-buffer programs synchronously inside the call (the
-        cache is donated on every program here), so without this the
-        whole device execution would masquerade as host think-time,
-        inverting the ``serving.heartbeat.*`` split and letting
-        healthy CPU decode breach the watchdog's host budget. The ~µs
-        of true dispatch overhead this misattributes on silicon is
-        noise.
+    def _runtime_call(self, program: str, fn):
+        """Invoke one compiled program (``apex.engine.launch``),
+        charging the call's block time to :attr:`launch_s` and
+        :attr:`device_wait_s`. On real accelerators JAX dispatch is
+        asynchronous — the call returns once the program is enqueued
+        and the real wait surfaces at the forced read — but the CPU
+        backend executes DONATED-buffer programs synchronously inside
+        the call (the cache is donated on every program here), so
+        without this the whole device execution would masquerade as
+        host think-time, inverting the ``serving.heartbeat.*`` split
+        and letting healthy CPU decode breach the watchdog's host
+        budget. What the launch costs on silicon is read from
+        :attr:`launch_s`, apart from the operands' upload
+        (:meth:`_operands`) and the readback (:meth:`_readback`).
 
         Callers hand host state the allocator keeps mutating (page
         table, lengths, adapter bindings) over as a ``.copy()``: the
@@ -2417,9 +2435,34 @@ class Engine:
         a program that has not read its operand yet when the host bumps
         a length in place computes the NEXT step's position (seen as a
         dropped token under CPU load)."""
-        t0 = time.perf_counter()
-        out = fn()
-        self.device_wait_s += time.perf_counter() - t0
+        return self._charged("engine.launch", "launch_s", fn,
+                             program=program)
+
+    def _operands(self, build):
+        """Build one program's operands (``apex.engine.upload``): the
+        ``jnp.asarray`` transfers of host state, the sampling key, the
+        adapter arguments - timed apart from the compiled call that
+        takes them, charged to :attr:`upload_s` and
+        :attr:`device_wait_s`."""
+        return self._charged("engine.upload", "upload_s", build)
+
+    def _readback(self, program: str, read):
+        """Run one forced read of a program's results
+        (``apex.engine.readback``): the host waits here until the
+        device has finished and the bytes have crossed. Charged to
+        :attr:`readback_s` and :attr:`device_wait_s`."""
+        return self._charged("engine.readback", "readback_s", read,
+                             program=program)
+
+    def _charged(self, name: str, counter: str, fn, **args):
+        """``fn()`` as one phase, its seconds added to ``counter`` and
+        to :attr:`device_wait_s` from the same two clock reads - so the
+        three counters sum to the fourth exactly."""
+        with tracing.phase(name, **args) as p:
+            out = fn()
+        dt = p.t1 - p.t0
+        setattr(self, counter, getattr(self, counter) + dt)
+        self.device_wait_s += dt
         return out
 
     def verify_batch(self, drafts, *, fault_bias=None, offsets=None):
@@ -2507,9 +2550,8 @@ class Engine:
         if self.paged:
             lens = self._host_len
         else:
-            tw = time.perf_counter()
-            lens = np.asarray(self.cache.lengths)[:self.slots]
-            self.device_wait_s += time.perf_counter() - tw
+            lens = self._readback("lengths", lambda: np.asarray(
+                self.cache.lengths))[:self.slots]
         for s in np.flatnonzero(active):
             off = int(lens[s])
             if not 0 < off or off + K + 1 > self.max_len:
@@ -2534,26 +2576,25 @@ class Engine:
             # their fixed-shape writes can never land on a live page
             vt = np.where(active[:, None], self._page_table, 0)
             vlen = np.where(active, self._host_len, 0)
-            self.cache, out, n_accepted, finite = self._runtime_call(
-                lambda: self._jit_verify(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(vt.astype(np.int32)),
-                    jnp.asarray(vlen.astype(np.int32)),
-                    jnp.asarray(n_drafted), jnp.asarray(fault_bias),
-                    *self._lora_args()))
+            ops = self._operands(lambda: (
+                jnp.asarray(tokens),
+                jnp.asarray(vt.astype(np.int32)),
+                jnp.asarray(vlen.astype(np.int32)),
+                jnp.asarray(n_drafted), jnp.asarray(fault_bias),
+                *self._lora_args()))
         else:
-            self.cache, out, n_accepted, finite = self._runtime_call(
-                lambda: self._jit_verify(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(n_drafted), jnp.asarray(fault_bias),
-                    *self._lora_args()))
-        tw = time.perf_counter()
+            ops = self._operands(lambda: (
+                jnp.asarray(tokens),
+                jnp.asarray(n_drafted), jnp.asarray(fault_bias),
+                *self._lora_args()))
+        self.cache, dev_out, dev_acc, dev_fin = self._runtime_call(
+            "verify", lambda: self._jit_verify(self.params, self.cache,
+                                               *ops))
         # ONE batched readback per verify dispatch (tokens, acceptance,
         # verdicts) — the host never int()s a device element per slot
-        out = np.asarray(out)           # device sync: step latency
-        n_accepted = np.asarray(n_accepted, np.int32)
-        finite = np.asarray(finite, bool)
-        self.device_wait_s += time.perf_counter() - tw
+        out, n_accepted, finite = self._readback("verify", lambda: (
+            np.asarray(dev_out),            # device sync: step latency
+            np.asarray(dev_acc, np.int32), np.asarray(dev_fin, bool)))
         if self.paged:
             # rollback IS this assignment, per slot: the rejected tail's
             # K/V sits at [offset + m + 1, offset + K + 1), past the
@@ -2646,10 +2687,8 @@ class Engine:
         path; a device read on the contiguous one)."""
         if self.paged:
             return self._host_len[:self.slots].copy()
-        tw = time.perf_counter()
-        out = np.asarray(self.cache.lengths)    # device sync
-        self.device_wait_s += time.perf_counter() - tw
-        return out
+        return self._readback("lengths", lambda: np.asarray(
+            self.cache.lengths))            # device sync
 
     def program_kernels(self) -> dict:
         """Which Pallas kernels the decode and chunk-prefill programs

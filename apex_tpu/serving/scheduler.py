@@ -170,6 +170,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.log_util import get_logger
+from apex_tpu.telemetry import tracing
 
 from .faults import FaultPolicy, PoolAuditor, fault_kind
 from .slo import SLOConfig, TenantLedger
@@ -2160,39 +2161,46 @@ class Scheduler:
         the budget is (under pipelining the whole point is that
         device-wait stops inflating beat wall, so budgeting wall would
         re-conflate the two)."""
-        t_tick = time.perf_counter()
         tick = self._tick
         self._tick += 1
-        if self.fault_plan is not None:
-            # injected heartbeat stall (the watchdog-breach probe)
-            self.fault_plan.maybe_stall(tick)
-            tier = getattr(self.engine, "host_tier", None)
-            if tier is not None:
-                # injected host-arena bit rot (the swap_corruption
-                # kind): the NEXT swap-in of the victim entry must
-                # fail its checksum and degrade to a verified miss
-                self.fault_plan.maybe_corrupt_swap(tick, tier)
-                # injected handoff bit rot (the handoff_corruption
-                # kind): victimizes uid-keyed handoff records only, so
-                # the next IMPORT's CRC fails and degrades to the
-                # verified-miss re-prefill on the decode side — never
-                # a wrong token
-                self.fault_plan.maybe_corrupt_handoff(tick, tier)
-        compiled0 = getattr(self.engine, "compiled_programs", 0)
-        dw0 = getattr(self.engine, "device_wait_s", 0.0)
+        eng = self.engine
+        dw0 = getattr(eng, "device_wait_s", 0.0)
+        launch0 = getattr(eng, "launch_s", 0.0)
+        read0 = getattr(eng, "readback_s", 0.0)
+        compiled0 = getattr(eng, "compiled_programs", 0)
         # requests riding this beat, snapshotted BEFORE the body so
         # finish/quarantine churn inside it cannot drop participants
         # (None when tracing is off — no allocation on the hot path)
         uids0 = [r.uid for r in self._running if r is not None] \
             if self.tracer is not None else None
+        beat = tracing.phase("serve.beat", tick=tick)
         try:
-            if self.pipeline_depth > 0:
-                return self._step_body_pipelined(tick)
-            return self._step_body(tick)
+            with beat:
+                if self.fault_plan is not None:
+                    # injected heartbeat stall (the watchdog-breach
+                    # probe)
+                    self.fault_plan.maybe_stall(tick)
+                    tier = getattr(eng, "host_tier", None)
+                    if tier is not None:
+                        # injected host-arena bit rot (the
+                        # swap_corruption kind): the NEXT swap-in of
+                        # the victim entry must fail its checksum and
+                        # degrade to a verified miss
+                        self.fault_plan.maybe_corrupt_swap(tick, tier)
+                        # injected handoff bit rot (the
+                        # handoff_corruption kind): victimizes
+                        # uid-keyed handoff records only, so the next
+                        # IMPORT's CRC fails and degrades to the
+                        # verified-miss re-prefill on the decode side —
+                        # never a wrong token
+                        self.fault_plan.maybe_corrupt_handoff(tick,
+                                                              tier)
+                if self.pipeline_depth > 0:
+                    return self._step_body_pipelined(tick)
+                return self._step_body(tick)
         finally:
-            elapsed = time.perf_counter() - t_tick
-            dwait = max(0.0, getattr(self.engine, "device_wait_s", 0.0)
-                        - dw0)
+            t_tick, elapsed = beat.t0, beat.t1 - beat.t0
+            dwait = max(0.0, getattr(eng, "device_wait_s", 0.0) - dw0)
             host_s = max(elapsed - dwait, 0.0)
             if self.tracer is not None and uids0:
                 # one heartbeat span per request that rode this beat,
@@ -2209,6 +2217,14 @@ class Scheduler:
                                       host_s)
                 self.registry.observe("serving.heartbeat.device_wait_s",
                                       dwait)
+                # the device-wait's two ends, where the engine keeps
+                # them apart: the compiled calls and the forced reads
+                self.registry.observe(
+                    "serving.heartbeat.launch_s",
+                    getattr(eng, "launch_s", 0.0) - launch0)
+                self.registry.observe(
+                    "serving.heartbeat.readback_s",
+                    getattr(eng, "readback_s", 0.0) - read0)
                 if elapsed > 0:
                     self.registry.gauge_set(
                         "serving.heartbeat.duty_cycle", dwait / elapsed)
@@ -2228,9 +2244,10 @@ class Scheduler:
                         self.registry.observe(
                             "serving.watchdog.warmup_s", elapsed)
                 elif host_s > self.fault_policy.watchdog_budget_s:
-                    self._on_watchdog_breach(tick, host_s)
+                    self._on_watchdog_breach(tick, host_s, beat)
 
-    def _on_watchdog_breach(self, tick: int, host_s: float) -> None:
+    def _on_watchdog_breach(self, tick: int, host_s: float,
+                            beat=None) -> None:
         """A heartbeat blew its HOST-portion budget (beat wall minus
         time blocked on device results — injected stalls, runaway
         drafting and slow bookkeeping all land here; healthy device
@@ -2242,25 +2259,49 @@ class Scheduler:
             self.registry.counter_inc("serving.watchdog.stall")
             self.registry.observe("serving.watchdog.stall_s", host_s)
         _logger.warning("heartbeat %d stalled: %.3fs of host time "
-                        "against a %.3fs watchdog budget", tick, host_s,
-                        self.fault_policy.watchdog_budget_s)
+                        "against a %.3fs watchdog budget; largest "
+                        "phases: %s", tick, host_s,
+                        self.fault_policy.watchdog_budget_s,
+                        self._largest_phases(beat))
         if self.fault_policy.on_stall is not None:
             self.fault_policy.on_stall(host_s)
 
+    @staticmethod
+    def _largest_phases(beat, top: int = 3) -> str:
+        """The ``top`` phases of ``beat`` by self time, from the flight
+        recorder (:data:`~apex_tpu.telemetry.tracing.phases`): what the
+        breach line names instead of one number."""
+        if beat is None or beat.id is None:
+            return "not recorded"
+        recs = [r for r in tracing.phases.records(since=beat.t0)
+                if r.root == beat.id]
+        own = tracing.phases.self_times(recs)
+        by_name: Dict[str, float] = {}
+        for r in recs:
+            by_name[r.name] = by_name.get(r.name, 0.0) + own[r.id]
+        return ", ".join(f"{n} {t * 1e3:.1f} ms" for n, t in sorted(
+            by_name.items(), key=lambda kv: -kv[1])[:top])
+
     def _step_body(self, tick: int) -> bool:
-        self._expire(time.perf_counter())
-        self._admit()
-        chunks = self._prefill_tick(tick) if self.chunked else 0
-        # the chunk budget bounds the stall imposed ON in-flight
-        # decodes; while nothing is decoding there is nothing to stall,
-        # so keep ingesting back-to-back (cold-start/queue-drain bursts
-        # reach full slot occupancy without idle heartbeats)
-        while chunks and not any(r is not None and r.status == "running"
-                                 for r in self._running):
-            more = self._prefill_tick(tick)
-            if not more:
-                break
-            chunks += more
+        with tracing.phase("serve.expire"):
+            self._expire(time.perf_counter())
+        with tracing.phase("serve.admit"):
+            self._admit()
+        with tracing.phase("serve.chunk") as ph:
+            chunks = self._prefill_tick(tick) if self.chunked else 0
+            # the chunk budget bounds the stall imposed ON in-flight
+            # decodes; while nothing is decoding there is nothing to
+            # stall, so keep ingesting back-to-back (cold-start/queue-
+            # drain bursts reach full slot occupancy without idle
+            # heartbeats)
+            while chunks and not any(
+                    r is not None and r.status == "running"
+                    for r in self._running):
+                more = self._prefill_tick(tick)
+                if not more:
+                    break
+                chunks += more
+            ph.note(n=chunks)
         self.beats_total += 1
         if chunks:
             self.beats_with_prefill += 1
@@ -2275,78 +2316,83 @@ class Scheduler:
             # draft → verify-or-decode: verified slots already advanced
             # (possibly by several tokens) and sit out this tick's
             # decode batch; empty drafts fall through to plain decode
-            spec_slots, spec_calls, spec_emitted = self._spec_tick(tick)
-        active = np.array([r is not None and r.status == "running"
-                           and slot not in spec_slots
-                           for slot, r in enumerate(self._running)])
-        self._emit_beat_gauges(active)
-        if not active.any():
-            self._set_spec_gauge(spec_calls, spec_emitted, 0, 0)
-            return chunks > 0 or spec_calls > 0
-        bias = None
-        if self.fault_plan is not None:
-            bias = self.fault_plan.decode_bias(tick, self.engine.slots)
-        t0 = time.perf_counter()
-        try:
+            with tracing.phase("serve.spec"):
+                spec_slots, spec_calls, spec_emitted = \
+                    self._spec_tick(tick)
+        with tracing.phase("serve.decode"):
+            active = np.array([r is not None and r.status == "running"
+                               and slot not in spec_slots
+                               for slot, r in enumerate(self._running)])
+            self._emit_beat_gauges(active)
+            if not active.any():
+                self._set_spec_gauge(spec_calls, spec_emitted, 0, 0)
+                return chunks > 0 or spec_calls > 0
+            bias = None
             if self.fault_plan is not None:
-                self.fault_plan.maybe_raise("decode", tick)
-            tokens = self.engine.decode_step(self._last_tokens, active,
-                                             self._temps,
-                                             fault_bias=bias)
-        except Exception as e:  # noqa: BLE001 — containment edge
-            # a failed decode call produced no tokens (injected faults
-            # raise INSTEAD of the call; a real mid-call failure left
-            # the host token state unconsumed either way): quarantine
-            # the attributed victim when the exception names one, else
-            # every running request absorbs one retry — the engine
-            # survives and the next beat retries the survivors
-            self._count_transient()
-            victim = getattr(e, "slot", -1)
-            desc = f"{type(e).__name__}: {e}"
-            # honor the attribution only if the victim was actually in
-            # the decode batch; otherwise charge the decoding requests
-            # — prefilling slots (and slots that already took a verify
-            # step this tick) were not in the failed call and keep
-            # their progress either way
-            if 0 <= victim < self.engine.slots \
-                    and victim not in spec_slots \
-                    and self._running[victim] is not None \
-                    and self._running[victim].status == "running":
-                self._quarantine(self._running[victim], victim, desc)
-            else:
-                for slot, r in enumerate(self._running):
-                    if r is not None and r.status == "running" \
-                            and slot not in spec_slots:
-                        self._quarantine(r, slot, desc)
-            return True
+                bias = self.fault_plan.decode_bias(tick, self.engine.slots)
+            t0 = time.perf_counter()
+            try:
+                if self.fault_plan is not None:
+                    self.fault_plan.maybe_raise("decode", tick)
+                tokens = self.engine.decode_step(self._last_tokens, active,
+                                                 self._temps,
+                                                 fault_bias=bias)
+            except Exception as e:  # noqa: BLE001 — containment edge
+                # a failed decode call produced no tokens (injected faults
+                # raise INSTEAD of the call; a real mid-call failure left
+                # the host token state unconsumed either way): quarantine
+                # the attributed victim when the exception names one, else
+                # every running request absorbs one retry — the engine
+                # survives and the next beat retries the survivors
+                self._count_transient()
+                victim = getattr(e, "slot", -1)
+                desc = f"{type(e).__name__}: {e}"
+                # honor the attribution only if the victim was actually in
+                # the decode batch; otherwise charge the decoding requests
+                # — prefilling slots (and slots that already took a verify
+                # step this tick) were not in the failed call and keep
+                # their progress either way
+                if 0 <= victim < self.engine.slots \
+                        and victim not in spec_slots \
+                        and self._running[victim] is not None \
+                        and self._running[victim].status == "running":
+                    self._quarantine(self._running[victim], victim, desc)
+                else:
+                    for slot, r in enumerate(self._running):
+                        if r is not None and r.status == "running" \
+                                and slot not in spec_slots:
+                            self._quarantine(r, slot, desc)
+                return True
         dt = time.perf_counter() - t0
         self._step_s_ema = dt if self._step_s_ema is None \
             else 0.8 * self._step_s_ema + 0.2 * dt
-        finite = self.engine.last_decode_finite
-        lengths = self.engine.lengths()
-        decode_emitted = 0
-        for slot, r in enumerate(self._running):
-            if r is None or r.status != "running" or slot in spec_slots:
-                continue
-            if not finite[slot]:
-                # the in-program guard flagged this slot's logits:
-                # its sampled token is garbage — quarantine the slot's
-                # request; batchmates' tokens are untouched (the guard
-                # and the bias are per-slot, the program is shared)
-                self._quarantine(r, slot, "non-finite decode logits")
-                continue
-            token = int(tokens[slot])
-            r.output_tokens.append(token)
-            self._last_tokens[slot] = token
-            decode_emitted += 1
-            if self.eos_id is not None and token == self.eos_id:
-                self._finish(r, "eos", slot)
-            elif len(r.output_tokens) >= r.max_new_tokens:
-                self._finish(r, "max_new_tokens", slot)
-            elif int(lengths[slot]) >= self.engine.max_len:
-                # cache exhausted: the NEXT token would have nowhere to
-                # attend from
-                self._finish(r, "max_len", slot)
+        with tracing.phase("serve.emit") as ph:
+            finite = self.engine.last_decode_finite
+            lengths = self.engine.lengths()
+            decode_emitted = 0
+            for slot, r in enumerate(self._running):
+                if r is None or r.status != "running" or slot in spec_slots:
+                    continue
+                if not finite[slot]:
+                    # the in-program guard flagged this slot's logits:
+                    # its sampled token is garbage — quarantine the slot's
+                    # request; batchmates' tokens are untouched (the guard
+                    # and the bias are per-slot, the program is shared)
+                    self._quarantine(r, slot, "non-finite decode logits")
+                    continue
+                token = int(tokens[slot])
+                r.output_tokens.append(token)
+                self._last_tokens[slot] = token
+                decode_emitted += 1
+                if self.eos_id is not None and token == self.eos_id:
+                    self._finish(r, "eos", slot)
+                elif len(r.output_tokens) >= r.max_new_tokens:
+                    self._finish(r, "max_new_tokens", slot)
+                elif int(lengths[slot]) >= self.engine.max_len:
+                    # cache exhausted: the NEXT token would have nowhere to
+                    # attend from
+                    self._finish(r, "max_len", slot)
+            ph.note(tokens=decode_emitted)
         self._set_spec_gauge(spec_calls, spec_emitted, 1, decode_emitted)
         return True
 
@@ -2387,18 +2433,22 @@ class Scheduler:
         path's because every token still flows through the same
         compiled programs and the same per-token finish checks, just
         read back one batched transfer later."""
-        self._expire(time.perf_counter())
-        self._admit()
-        chunks = self._prefill_tick(tick) if self.chunked else 0
-        # cold-queue burst (same contract as the sync beat): only while
-        # nothing is decoding AND nothing is in flight
-        while chunks and not self._pipeline \
-                and not any(r is not None and r.status == "running"
-                            for r in self._running):
-            more = self._prefill_tick(tick)
-            if not more:
-                break
-            chunks += more
+        with tracing.phase("serve.expire"):
+            self._expire(time.perf_counter())
+        with tracing.phase("serve.admit"):
+            self._admit()
+        with tracing.phase("serve.chunk") as ph:
+            chunks = self._prefill_tick(tick) if self.chunked else 0
+            # cold-queue burst (same contract as the sync beat): only
+            # while nothing is decoding AND nothing is in flight
+            while chunks and not self._pipeline \
+                    and not any(r is not None and r.status == "running"
+                                for r in self._running):
+                more = self._prefill_tick(tick)
+                if not more:
+                    break
+                chunks += more
+            ph.note(n=chunks)
         self.beats_total += 1
         if chunks:
             self.beats_with_prefill += 1
@@ -2416,8 +2466,11 @@ class Scheduler:
             # overlapped this beat's expire/admit/chunk work), then
             # draft → verify-or-decode exactly like the sync beat
             reconciled += self._reconcile_all()
-            spec_slots, spec_calls, spec_emitted = self._spec_tick(tick)
-        active = self._dispatch_decode(tick, spec_slots)
+            with tracing.phase("serve.spec"):
+                spec_slots, spec_calls, spec_emitted = \
+                    self._spec_tick(tick)
+        with tracing.phase("serve.decode"):
+            active = self._dispatch_decode(tick, spec_slots)
         self._emit_beat_gauges(active if active is not None
                                else np.zeros(self.engine.slots, bool))
         while len(self._pipeline) > self.pipeline_depth:
@@ -2540,8 +2593,9 @@ class Scheduler:
                     and r.status == "running":
                 valid[slot] = True
         try:
-            tokens, finite, dt = eng.decode_reconcile(rec.pending,
-                                                      valid=valid)
+            with tracing.phase("serve.decode"):
+                tokens, finite, dt = eng.decode_reconcile(rec.pending,
+                                                          valid=valid)
         except Exception as e:  # noqa: BLE001 — containment edge
             # a dispatched-ahead step can fail at its DEFERRED force:
             # async backends surface runtime errors at the first read,
@@ -2565,37 +2619,39 @@ class Scheduler:
             return 0
         self._step_s_ema = dt if self._step_s_ema is None \
             else 0.8 * self._step_s_ema + 0.2 * dt
-        emitted = discarded = 0
-        for slot in sorted(rec.uids):
-            if not valid[slot]:
-                discarded += 1
-                continue
-            r = self._running[slot]
-            if not finite[slot]:
-                # the in-program guard flagged this slot's logits (same
-                # quarantine as the sync beat); any younger in-flight
-                # step for it discards at ITS reconcile by uid mismatch
-                self._quarantine(r, slot, "non-finite decode logits")
-                continue
-            token = int(tokens[slot])
-            r.output_tokens.append(token)
-            self._last_tokens[slot] = token
-            emitted += 1
-            if self.eos_id is not None and token == self.eos_id:
-                self._finish(r, "eos", slot)
-            elif len(r.output_tokens) >= r.max_new_tokens:
-                self._finish(r, "max_new_tokens", slot)
-            elif len(r.prompt) + len(r.output_tokens) - 1 \
-                    >= eng.max_len:
-                # committed length (prompt + outputs - 1) reached the
-                # cache — the same condition the sync beat reads back
-                # from engine.lengths(), computed host-side here so
-                # reconcile forces nothing beyond the token readback
-                self._finish(r, "max_len", slot)
-            elif self.speculative:
-                # outputs settled until the next reconcile: start the
-                # next draft on the worker now, overlapping the device
-                self._presubmit_draft(r)
+        with tracing.phase("serve.emit") as ph:
+            emitted = discarded = 0
+            for slot in sorted(rec.uids):
+                if not valid[slot]:
+                    discarded += 1
+                    continue
+                r = self._running[slot]
+                if not finite[slot]:
+                    # the in-program guard flagged this slot's logits (same
+                    # quarantine as the sync beat); any younger in-flight
+                    # step for it discards at ITS reconcile by uid mismatch
+                    self._quarantine(r, slot, "non-finite decode logits")
+                    continue
+                token = int(tokens[slot])
+                r.output_tokens.append(token)
+                self._last_tokens[slot] = token
+                emitted += 1
+                if self.eos_id is not None and token == self.eos_id:
+                    self._finish(r, "eos", slot)
+                elif len(r.output_tokens) >= r.max_new_tokens:
+                    self._finish(r, "max_new_tokens", slot)
+                elif len(r.prompt) + len(r.output_tokens) - 1 \
+                        >= eng.max_len:
+                    # committed length (prompt + outputs - 1) reached the
+                    # cache — the same condition the sync beat reads back
+                    # from engine.lengths(), computed host-side here so
+                    # reconcile forces nothing beyond the token readback
+                    self._finish(r, "max_len", slot)
+                elif self.speculative:
+                    # outputs settled until the next reconcile: start the
+                    # next draft on the worker now, overlapping the device
+                    self._presubmit_draft(r)
+            ph.note(tokens=emitted)
         if discarded and self.registry is not None:
             self.registry.counter_inc("serving.heartbeat.discarded",
                                       discarded)

@@ -63,6 +63,7 @@ from .emit import (account_collective, collective_bytes, emit_metrics,
                    global_norm)
 from .sinks import (JsonlSink, MemorySink, NullSink, Sink, StdoutSink,
                     make_sink)
+from . import tracing
 from .tracing import Span, Trace, Tracer
 
 __all__ = [
@@ -142,14 +143,17 @@ def from_env(var: str = ENV_VAR) -> Optional[MetricsRegistry]:
 def timed(name: str, registry: Optional[MetricsRegistry] = None):
     """Host-side latency observation: wall seconds of the block go into
     histogram ``name`` (+ counter ``name.calls``) — for eager sections
-    (checkpoint saves, eval passes) the in-jit path can't time."""
-    t0 = time.perf_counter()
+    (checkpoint saves, eval passes) the in-jit path can't time. The
+    block is one :func:`tracing.phase`: it shows as ``apex.<name>`` in
+    a profiler session and in the flight recorder too."""
+    p = tracing.phase(name)
     try:
-        yield
+        with p:
+            yield
     finally:
         if _enabled:
             reg = registry if registry is not None else get_registry()
-            reg.observe(name, time.perf_counter() - t0)
+            reg.observe(name, p.t1 - p.t0)
             reg.counter_inc(f"{name}.calls")
 
 

@@ -1,14 +1,15 @@
 """Run-summary CLI:
 
-    python -m apex_tpu.telemetry summarize run.jsonl [--tag T] [--json]
-                                                      [--trace DIR]
+    python -m apex_tpu.telemetry summarize [run.jsonl] [--tag T] [--json]
+                                                        [--trace DIR]
     python -m apex_tpu.telemetry trace spans.jsonl [--requests RUN]
                                                     [--json]
 
 ``summarize`` renders per-metric count/mean/p50/p95/p99 aggregates of a
 telemetry JSONL run file; ``--trace`` additionally joins a
 ``pyprof.trace`` capture into a device step-time breakdown (ms/step per
-HLO category, collective-op latency).
+HLO category, collective-op latency) and the device's idle seconds by
+the innermost ``apex.*`` host span (``tracing.phase``) the host was in.
 
 ``trace`` summarizes a request-trace JSONL file (what
 :meth:`~apex_tpu.telemetry.Tracer.export_jsonl` wrote): per-stage span
@@ -25,7 +26,8 @@ import argparse
 import json
 import sys
 
-from .summarize import (load_records, render_breakdown, render_summary,
+from .summarize import (load_records, phase_idle, render_breakdown,
+                        render_phase_idle, render_summary,
                         render_trace_summary, summarize_records,
                         summarize_trace, trace_breakdown)
 
@@ -37,7 +39,9 @@ def main(argv=None):
     sub = p.add_subparsers(dest="cmd", required=True)
     s = sub.add_parser("summarize",
                        help="aggregate a telemetry JSONL run file")
-    s.add_argument("run", help="JSONL file a JsonlSink wrote")
+    s.add_argument("run", nargs="?", default=None,
+                   help="JSONL file a JsonlSink wrote (may be left out "
+                        "with --trace: the capture alone is read)")
     s.add_argument("--tag", default=None,
                    help="only records with this tag (default: all)")
     s.add_argument("--trace", default=None, metavar="DIR",
@@ -59,20 +63,25 @@ def main(argv=None):
 
     if args.cmd == "trace":
         return _main_trace(args)
-    try:
-        records = load_records(args.run)
-    except OSError as e:
-        raise SystemExit(str(e))
-    if not records:
-        raise SystemExit(f"no telemetry records in {args.run!r}")
+    if args.run is None and not args.trace:
+        p.error("summarize needs a run file, --trace DIR, or both")
+    records = []
+    if args.run is not None:
+        try:
+            records = load_records(args.run)
+        except OSError as e:
+            raise SystemExit(str(e))
+        if not records:
+            raise SystemExit(f"no telemetry records in {args.run!r}")
     summary = summarize_records(records, tag=args.tag)
 
-    breakdown = None
+    breakdown = idle = None
     if args.trace:
         n_steps = max(summary["steps"].values(), default=0) \
             if summary["steps"] else 0
         try:
             breakdown = trace_breakdown(args.trace, n_steps)
+            idle = phase_idle(args.trace)
         except FileNotFoundError as e:
             raise SystemExit(str(e))
 
@@ -80,12 +89,18 @@ def main(argv=None):
         out = dict(summary)
         if breakdown is not None:
             out["device_breakdown"] = breakdown
+        if idle is not None:
+            out["device_idle_by_phase"] = idle
         print(json.dumps(out))
     else:
-        print(render_summary(summary))
+        if records:
+            print(render_summary(summary))
         if breakdown is not None:
             print()
             print(render_breakdown(breakdown))
+        if idle is not None:
+            print()
+            print(render_phase_idle(idle))
     return 0
 
 

@@ -22,7 +22,8 @@ from typing import Any, Dict, List, Optional
 from .core import META_KEYS, StreamingHistogram
 
 __all__ = ["load_records", "summarize_records", "render_summary",
-           "trace_breakdown", "render_breakdown",
+           "trace_breakdown", "render_breakdown", "phase_idle",
+           "render_phase_idle",
            "summarize_trace", "render_trace_summary"]
 
 #: hlo_category substrings that identify collective/communication ops
@@ -259,7 +260,135 @@ def render_breakdown(bd: Dict[str, Any]) -> str:
         lines += ["", "comm op device latency:"]
         hdr = f"{'op':<44} {'n':>7} {'mean_ms':>10} {'total_ms':>12}"
         lines += [hdr, "-" * len(hdr)]
-        for c in bd["comm_ops"]:
+        for c in bd["comm_ops"][:20]:
             lines.append(f"{c['name'][:44]:<44} {c['occurrences']:>7} "
                          f"{c['mean_ms']:>10.4f} {c['total_ms']:>12.3f}")
+        if len(bd["comm_ops"]) > 20:
+            lines.append(f"(+{len(bd['comm_ops']) - 20} more)")
+    return "\n".join(lines)
+
+
+def _innermost_pieces(spans: List[tuple]) -> List[tuple]:
+    """Properly nested ``(start, end, name)`` spans of one thread cut
+    into the pieces each has to itself (its interval less its
+    children's): sorted, non-overlapping ``(start, end, name)``."""
+    out: List[tuple] = []
+    stack: List[list] = []              # [end, name, resume-from]
+
+    def close(upto):
+        while stack and stack[-1][0] <= upto:
+            end, name, t = stack.pop()
+            if end > t:
+                out.append((t, end, name))
+            if stack:
+                stack[-1][2] = max(stack[-1][2], end)
+
+    for start, end, name in sorted(spans, key=lambda x: (x[0], -x[1])):
+        close(start)
+        if stack and start > stack[-1][2]:
+            out.append((stack[-1][2], start, stack[-1][1]))
+        stack.append([end, name, start])
+    close(float("inf"))
+    return sorted(out)
+
+
+def phase_idle(trace_dir: str) -> Optional[Dict[str, Any]]:
+    """Where the host was while the device had nothing to run: the idle
+    stretches of the busiest device lane (between merged device-op
+    intervals, first op to last), cut at the boundaries of the
+    ``apex.*`` host spans (:func:`~apex_tpu.telemetry.tracing.phase`)
+    and each piece put down to the innermost span that held it. The
+    host lane read is the one whose outermost spans (a beat, a loop
+    turn: those that carry ``pc_ns``) cover the most time. None when
+    the capture has no device lane.
+
+    ``clock`` gives what maps ``time.perf_counter_ns()`` onto the
+    trace's clock - the median over the outermost spans of (their start
+    on the trace's clock - their ``pc_ns`` stat) - for joining records
+    kept on the host clock (the flight recorder, a ``Tracer`` export)
+    with the device lanes."""
+    import bisect
+
+    from apex_tpu import pyprof
+    from .tracing import PHASE_PREFIX
+
+    events = pyprof._load_events(trace_dir)
+    ops, file_of = pyprof._device_ops(events)
+    if not ops:
+        return None
+    lanes: Dict[tuple, list] = {}
+    for e in ops:
+        lanes.setdefault((file_of[id(e)], e.get("pid"), e.get("tid")),
+                         []).append((float(e["ts"]),
+                                     float(e["ts"]) + float(e["dur"])))
+    ivs = sorted(max(lanes.values(),
+                     key=lambda v: sum(b - a for a, b in v)))
+    gaps, end = [], ivs[0][1]
+    for a, b in ivs[1:]:
+        if a > end:
+            gaps.append((end, a))
+        end = max(end, b)
+    window = (end - ivs[0][0]) / 1e6
+    host: Dict[tuple, list] = {}
+    outer: Dict[tuple, float] = {}
+    offsets = []
+    for lane, fi, e in events:
+        if lane.startswith("/device:") \
+                or not e["name"].startswith(PHASE_PREFIX):
+            continue
+        key = (fi, e.get("pid"), e.get("tid"))
+        host.setdefault(key, []).append(
+            (float(e["ts"]), float(e["ts"]) + float(e["dur"]), e["name"]))
+        if "pc_ns" in e.get("args", {}):
+            outer[key] = outer.get(key, 0.0) + float(e["dur"])
+            offsets.append(e["ts"] * 1e3 - float(e["args"]["pc_ns"]))
+    pieces = _innermost_pieces(host[max(outer, key=outer.get)]) \
+        if outer else []
+    # idle microseconds before a moment: whole stretches ended by then
+    # and the part of the one it falls in
+    starts = [g[0] for g in gaps]
+    before = [0.0]
+    for a, b in gaps:
+        before.append(before[-1] + b - a)
+
+    def idle_until(t):
+        k = bisect.bisect_right(starts, t)
+        if k == 0:
+            return 0.0
+        return before[k - 1] + min(t, gaps[k - 1][1]) - gaps[k - 1][0]
+
+    idle: Dict[str, float] = {}
+    for a, b, name in pieces:
+        idle[name] = idle.get(name, 0.0) + idle_until(b) - idle_until(a)
+    total = before[-1]
+    idle["(outside any apex.* span)"] = total - sum(idle.values())
+    rows = [{"phase": n, "idle_s": t / 1e6,
+             "pct_of_window": 100.0 * t / 1e6 / window if window else 0.0}
+            for n, t in sorted(idle.items(), key=lambda kv: -kv[1])
+            if t > 0]
+    clock = None
+    if offsets:
+        offsets.sort()
+        clock = {"trace_minus_perf_counter_ns":
+                 offsets[len(offsets) // 2], "spans": len(offsets),
+                 "spread_ns": offsets[-1] - offsets[0]}
+    return {"window_s": window, "idle_s": total / 1e6, "phases": rows,
+            "clock": clock}
+
+
+def render_phase_idle(pi: Dict[str, Any]) -> str:
+    lines = [f"device idle by host phase ({pi['idle_s']:.4f}s idle of "
+             f"{pi['window_s']:.4f}s, busiest device):"]
+    hdr = f"{'innermost apex.* span':<36} {'idle_s':>10} {'% window':>9}"
+    lines += [hdr, "-" * len(hdr)]
+    for r in pi["phases"]:
+        lines.append(f"{r['phase'][:36]:<36} {r['idle_s']:>10.4f} "
+                     f"{r['pct_of_window']:>9.2f}")
+    c = pi.get("clock")
+    if c:
+        lines.append(
+            f"clock: trace_ns = perf_counter_ns + "
+            f"{c['trace_minus_perf_counter_ns']:.0f} (pc_ns of "
+            f"{c['spans']} outermost spans, spread "
+            f"{c['spread_ns'] / 1e3:.1f} us)")
     return "\n".join(lines)

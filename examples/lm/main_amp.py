@@ -35,6 +35,7 @@ from apex_tpu import amp
 from apex_tpu.kernels.xentropy import softmax_cross_entropy_loss
 from apex_tpu.models.transformer_lm import create_lm
 from apex_tpu.optimizers import fused_adam
+from apex_tpu.telemetry import tracing
 from apex_tpu.utils import chip
 
 
@@ -206,9 +207,14 @@ def data_batch(data, rng, batch_size, seq_len):
     sampler on the single-chip and model-parallel paths. Gathered in
     numpy and shipped as ONE host-to-device transfer; maxval is
     exclusive, so len-seq_len admits the last valid window start."""
-    idx = np.asarray(jax.random.randint(rng, (batch_size,), 0,
-                                        len(data) - seq_len))
-    return jnp.asarray(np.stack([data[i:i + seq_len + 1] for i in idx]))
+    with tracing.phase("train.batch_draw"):
+        with tracing.phase("train.rng_readback"):
+            idx = np.asarray(jax.random.randint(rng, (batch_size,), 0,
+                                                len(data) - seq_len))
+        with tracing.phase("train.gather"):
+            rows = np.stack([data[i:i + seq_len + 1] for i in idx])
+        with tracing.phase("train.h2d"):
+            return jnp.asarray(rows)
 
 
 # --------------------------------------------------------------------------
@@ -923,28 +929,33 @@ def run_parallel(args, policy, on_step=None):
     loss_history = []
     with mesh:
         for it in range(start_it, args.iters):
-            rng, sub = jax.random.split(rng)
-            if args.deterministic:
-                sub = jax.random.PRNGKey(it)
-            if data is not None:
-                batch = data_batch(data, sub, args.batch_size,
-                                   args.seq_len)
-            else:
-                batch = synthetic_tokens(sub, args.batch_size,
-                                         args.seq_len, args.vocab_size)
-            state, metrics = jit_step(state, batch)
-            loss_history.append(metrics["loss"])
-            if on_step is not None:
-                on_step(it, metrics)
-            if it == start_it + 2:
-                metrics["loss"].block_until_ready()
-                t0 = time.perf_counter()
-                toks = 0
-            toks += args.batch_size * args.seq_len
-            if it % 10 == 0 or it == args.iters - 1:
-                print(f"[{it}/{args.iters}] loss "
-                      f"{float(metrics['loss']):.4f} loss_scale "
-                      f"{float(metrics['loss_scale']):g}")
+            with tracing.phase("train.turn", it=it):
+                rng, sub = jax.random.split(rng)
+                if args.deterministic:
+                    sub = jax.random.PRNGKey(it)
+                if data is not None:
+                    batch = data_batch(data, sub, args.batch_size,
+                                       args.seq_len)
+                else:
+                    batch = synthetic_tokens(sub, args.batch_size,
+                                             args.seq_len,
+                                             args.vocab_size)
+                with tracing.phase("train.dispatch"):
+                    state, metrics = jit_step(state, batch)
+                loss_history.append(metrics["loss"])
+                if on_step is not None:
+                    with tracing.phase("train.on_step"):
+                        on_step(it, metrics)
+                if it == start_it + 2:
+                    metrics["loss"].block_until_ready()
+                    t0 = time.perf_counter()
+                    toks = 0
+                toks += args.batch_size * args.seq_len
+                if it % 10 == 0 or it == args.iters - 1:
+                    with tracing.phase("train.log"):
+                        print(f"[{it}/{args.iters}] loss "
+                              f"{float(metrics['loss']):.4f} loss_scale "
+                              f"{float(metrics['loss_scale']):g}")
     jax.tree_util.tree_leaves(state.params)[0].block_until_ready()
     if t0 is not None and args.iters - start_it > 3:
         dt = time.perf_counter() - t0
@@ -1167,35 +1178,44 @@ def main(argv=None, on_step=None):
     loss_history = []
     compiled = kernels = None
     for it in range(start_it, args.iters):
-        rng, sub = jax.random.split(rng)
-        if args.deterministic:
-            sub = jax.random.PRNGKey(it)
-        if data is not None:
-            batch = data_batch(data, sub, args.batch_size, args.seq_len)
-        else:
-            batch = synthetic_tokens(sub, args.batch_size, args.seq_len,
-                                     args.vocab_size)
-        # [B, S+1] → [N, B/N, S+1]: the microbatch scan axis of
-        # make_train_step(accum_steps=N); identity at N=1
-        batch = amp.to_microbatches(batch, args.accum_steps)
-        if compiled is None:
-            # one ahead-of-time compile, so the recipe can say which
-            # fused kernels the step really holds on this backend
-            compiled, kernels, line = chip.compile_and_report(
-                "LM train step", jit_step, state, batch)
-            print(line)
-        state, metrics = compiled(state, batch)
-        loss_history.append(metrics["loss"])
-        if on_step is not None:
-            on_step(it, metrics)
-        if it == start_it + 4:
-            metrics["loss"].block_until_ready()
-            t0 = time.perf_counter()
-            toks = 0
-        toks += args.batch_size * args.seq_len
-        if it % 10 == 0 or it == args.iters - 1:
-            print(f"[{it}/{args.iters}] loss {float(metrics['loss']):.4f} "
-                  f"loss_scale {float(metrics['loss_scale']):g}")
+        # the turn's regions are ``with`` blocks in THIS frame, not a
+        # helper: a caller's on_step may read main()'s locals (state,
+        # batch, compiled) through the frame it was called from
+        with tracing.phase("train.turn", it=it):
+            rng, sub = jax.random.split(rng)
+            if args.deterministic:
+                sub = jax.random.PRNGKey(it)
+            if data is not None:
+                batch = data_batch(data, sub, args.batch_size,
+                                   args.seq_len)
+            else:
+                batch = synthetic_tokens(sub, args.batch_size,
+                                         args.seq_len, args.vocab_size)
+            # [B, S+1] → [N, B/N, S+1]: the microbatch scan axis of
+            # make_train_step(accum_steps=N); identity at N=1
+            batch = amp.to_microbatches(batch, args.accum_steps)
+            if compiled is None:
+                # one ahead-of-time compile, so the recipe can say which
+                # fused kernels the step really holds on this backend
+                compiled, kernels, line = chip.compile_and_report(
+                    "LM train step", jit_step, state, batch)
+                print(line)
+            with tracing.phase("train.dispatch"):
+                state, metrics = compiled(state, batch)
+            loss_history.append(metrics["loss"])
+            if on_step is not None:
+                with tracing.phase("train.on_step"):
+                    on_step(it, metrics)
+            if it == start_it + 4:
+                metrics["loss"].block_until_ready()
+                t0 = time.perf_counter()
+                toks = 0
+            toks += args.batch_size * args.seq_len
+            if it % 10 == 0 or it == args.iters - 1:
+                with tracing.phase("train.log"):
+                    print(f"[{it}/{args.iters}] loss "
+                          f"{float(metrics['loss']):.4f} "
+                          f"loss_scale {float(metrics['loss_scale']):g}")
     jax.tree_util.tree_leaves(state.params)[0].block_until_ready()
     if t0 is not None and args.iters - start_it > 5:
         dt = time.perf_counter() - t0

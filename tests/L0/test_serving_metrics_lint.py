@@ -91,7 +91,9 @@ lane a reader will wait for forever. The lint AST-scans
 (``.event`` / ``.event_current`` / ``.end_trace``) and extracts each
 call's first string-literal positional argument (the span name —
 trace ids are never literals), then pins that set EQUAL to the
-backticked first column of the catalogue table. And the **tracer
+backticked first column of the catalogue table; the beat's phases
+(``tracing.phase("serve.admit")``) are scanned the same way and listed
+there under ``apex.`` + name. And the **tracer
 force-lint**: the tracer's recording methods run inside the
 dispatch-ahead regions' dynamic extent (the heartbeat/swap hooks call
 them between dispatch and reconcile), so they get the same
@@ -489,6 +491,12 @@ def test_pool_gathers_are_exactly_the_padded_allowlist():
 # string literal — it's ``request.uid`` — so "first str literal" is
 # position-agnostic across all three signatures).
 _SPAN_METHODS = {"event", "event_current", "end_trace"}
+# ``tracing.phase("serve.admit", ...)``: a region of the beat itself,
+# annotated on the profiler's host plane as "apex." + name - the name
+# the catalogue lists it under (``Engine._charged`` is the engine's one
+# caller of it: a phase plus the counter at the same boundary)
+_PHASE_METHODS = {"phase", "_charged"}
+_PHASE_PREFIX = "apex."
 TRACING_PY = os.path.join(ROOT, "apex_tpu", "telemetry", "tracing.py")
 
 
@@ -505,13 +513,16 @@ def _spans_emitted():
                 continue
             f = node.func
             if not (isinstance(f, ast.Attribute)
-                    and f.attr in _SPAN_METHODS):
+                    and (f.attr in _SPAN_METHODS
+                         or f.attr in _PHASE_METHODS)):
                 continue
             lits = [a.value for a in node.args
                     if isinstance(a, ast.Constant)
                     and isinstance(a.value, str)]
             if lits:
-                refs.setdefault(lits[0], []).append(
+                name = lits[0] if f.attr in _SPAN_METHODS \
+                    else _PHASE_PREFIX + lits[0]
+                refs.setdefault(name, []).append(
                     os.path.relpath(path, ROOT))
     return refs
 
@@ -560,6 +571,19 @@ def test_span_scan_surface_is_alive():
         assert engine_py in emitted.get(name, []), \
             f"span {name!r} not emitted by the engine — migration " \
             "tracing went dark"
+    # the beat's phases: the scheduler's own and the engine's three
+    # ends of a program (upload, launch, readback)
+    for name in ("apex.serve.beat", "apex.serve.expire",
+                 "apex.serve.admit", "apex.serve.chunk",
+                 "apex.serve.spec", "apex.serve.decode",
+                 "apex.serve.emit"):
+        assert sched in emitted.get(name, []), \
+            f"phase {name!r} not marked by the scheduler — the beat " \
+            "went dark on the profiler's clock"
+    for name in ("apex.engine.grow", "apex.engine.upload",
+                 "apex.engine.launch", "apex.engine.readback"):
+        assert engine_py in emitted.get(name, []), \
+            f"phase {name!r} not marked by the engine"
     assert _spans_documented(), "docs/serving.md has no " \
         "'### Span catalogue' table — doc section missing/renamed?"
 

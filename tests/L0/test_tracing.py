@@ -36,6 +36,7 @@ markers like the rest of the fault tier.
 """
 
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -195,6 +196,35 @@ def test_tracer_none_is_bitwise_invisible(engine, monkeypatch):
     assert engine.compiled_programs == programs0, \
         "tracing traced new programs"
     assert len(tr.traces()) == len(traced)
+
+
+def test_phases_are_bitwise_invisible_on_off_and_under_a_tracer(engine):
+    """The same contract for the beat's phases (``tracing.phase``, always
+    on): greedy streams bitwise equal with the flight recorder on, with
+    it off, and with a ``Tracer`` attached beside it; no new compiled
+    program either way; off records nothing."""
+    runs = {}
+    programs0 = None
+    try:
+        for name, on, tr in (("on", True, None), ("off", False, None),
+                             ("tracer", True, Tracer())):
+            engine.reset(clear_prefixes=True)
+            tracing.phases.enabled = on
+            t_run = time.perf_counter()
+            reqs = _stream()
+            Scheduler(engine, retain_prefixes=True,
+                      fault_policy=_fast_policy(), tracer=tr).run(reqs)
+            runs[name] = _tokens(reqs)
+            beats = tracing.phases.records(name="serve.beat",
+                                           since=t_run)
+            assert (len(beats) > 0) == on
+            if programs0 is None:
+                programs0 = engine.compiled_programs
+            assert engine.compiled_programs == programs0
+    finally:
+        tracing.phases.enabled = True
+    assert runs["on"] == runs["off"] == runs["tracer"]
+    assert all(len(t) > 0 for t in runs["on"])
 
 
 # ------------------------------------------------------ lifecycle
