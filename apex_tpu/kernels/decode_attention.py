@@ -297,10 +297,73 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
 
 
 # ------------------------------------------------------------ paged variant
-def gather_pages(pool, page_table):
+def _layer_pool_shape(name, k_pool, v_pool, layer):
+    """One layer's ``(num_pages, heads, page_len, head_dim)`` of a paged
+    pool handed over in either of its two forms — shared by the two
+    paged dispatchers:
+
+    - one layer alone, ``[num_pages, heads, page_len, head_dim]``
+      (``layer=None``);
+    - the serving engine's whole STACKED pool ``[layers, num_pages,
+      heads, head_dim, page_len]`` with the static ``layer`` to read.
+      Its pages are held transposed (``page_len`` is the lane
+      dimension) because that is the one form the chip stores without
+      padding AND the kernels' page DMA reads as it lies: with
+      ``head_dim`` 64 in the lanes a row-major page pads every tile to
+      128, so the compiler keeps such a pool the other way round and
+      relays every layer of it out (and back) around each kernel call —
+      the pool-sized copies that were three quarters of a decode step.
+    """
+    if v_pool.shape != k_pool.shape or k_pool.ndim not in (4, 5):
+        raise ValueError(f"{name}: pools {k_pool.shape}/{v_pool.shape} "
+                         f"must be equal-shaped [num_pages, heads, "
+                         f"page_len, head_dim], or stacked [layers, "
+                         f"num_pages, heads, head_dim, page_len]")
+    if (k_pool.ndim == 5) != (layer is not None):
+        raise ValueError(f"{name}: layer={layer!r} with a "
+                         f"{k_pool.ndim}-D pool; a stacked "
+                         f"[layers, num_pages, ...] pool takes the layer "
+                         f"to read, a single layer's pool takes none")
+    if layer is None:
+        return k_pool.shape
+    if not 0 <= int(layer) < k_pool.shape[0]:
+        raise ValueError(f"{name}: layer {layer} outside the pool's "
+                         f"{k_pool.shape[0]} layers")
+    _, P, h, d, page_len = k_pool.shape
+    return P, h, page_len, d
+
+
+def _page_block_spec(page_len, d, page_idx, layer):
+    """The K/V ``BlockSpec`` of the two paged kernels: one pool page of
+    one head, chosen by the scalar-prefetch index map ``page_idx``. On a
+    stacked pool the static ``layer`` is one more (squeezed) block index
+    in front and the page arrives as it is stored, ``[d, page_len]``
+    (the kernel bodies' ``kt`` form): it is DMA'd out of the pool where
+    it lives, and no layer of the pool is ever sliced out in HBM."""
+    if layer is None:
+        return pl.BlockSpec((1, 1, page_len, d), page_idx)
+    return pl.BlockSpec((None, 1, 1, d, page_len),
+                        lambda *a: (layer,) + page_idx(*a))
+
+
+def _page_dots(kt: bool):
+    """``dot_general`` dimension numbers of a page's two products, for a
+    page held ``[page_len, d]`` or (``kt``, the stacked pool's form)
+    ``[d, page_len]``: (q·K^T contracting ``d``, p·V contracting
+    ``page_len``). Same products either way; only which axis of the
+    page block is contracted moves."""
+    if kt:
+        return (((1,), (0,)), ((), ())), (((1,), (1,)), ((), ()))
+    return (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ()))
+
+
+def gather_pages(pool, page_table, layer=None):
     """Materialise a contiguous per-row cache view from a paged pool:
     ``pool`` [num_pages, heads, page_len, d] + ``page_table``
     [batch, max_pages] int32 -> [batch, heads, max_pages * page_len, d].
+    With ``layer`` the pool is the stacked ``[layers, num_pages, heads,
+    d, page_len]`` one (see :func:`_layer_pool_shape`) and only that
+    layer's pages are gathered, straight out of it.
 
     The paged kernels' oracle building block (and the CPU/unaligned
     fallback's first step): positions ``[j*page_len, (j+1)*page_len)``
@@ -308,35 +371,46 @@ def gather_pages(pool, page_table):
     row's allocated pages point at the sentinel page — garbage the
     length/causal masks keep out of every softmax."""
     B, P = page_table.shape
-    h, page_len, d = pool.shape[1], pool.shape[2], pool.shape[3]
-    gathered = pool[page_table]              # [B, P, h, page_len, d]
-    return gathered.transpose(0, 2, 1, 3, 4).reshape(
+    if layer is None:
+        h, page_len, d = pool.shape[1:]
+        gathered = pool[page_table]          # [B, P, h, page_len, d]
+        return gathered.transpose(0, 2, 1, 3, 4).reshape(
+            B, h, P * page_len, d)
+    h, d, page_len = pool.shape[2:]
+    gathered = pool[layer, page_table]       # [B, P, h, d, page_len]
+    return gathered.transpose(0, 2, 1, 4, 3).reshape(
         B, h, P * page_len, d)
 
 
 def paged_decode_attention_reference(q, k_pool, v_pool, page_table,
                                      lengths, *, scale: float = 1.0,
-                                     k_scale=None, v_scale=None):
+                                     k_scale=None, v_scale=None,
+                                     layer=None):
     """fp32-math oracle: gather the page-table view, then the exact
     contiguous decode reference. ``q`` [b, h, d]; pools
-    [num_pages, h, page_len, d]; ``page_table`` [b, max_pages];
+    [num_pages, h, page_len, d] (or the stacked pool, with ``layer``);
+    ``page_table`` [b, max_pages];
     ``lengths`` [b] int32. With ``k_scale``/``v_scale`` ([h] fp32) the
     gathered int8 pages are dequantized before the exact math — the
     gather-dequant oracle of the quantized-cache tier."""
-    k = gather_pages(k_pool, page_table)
-    v = gather_pages(v_pool, page_table)
+    k = gather_pages(k_pool, page_table, layer)
+    v = gather_pages(v_pool, page_table, layer)
     return decode_attention_reference(q, k, v, lengths, scale=scale,
                                       k_scale=k_scale, v_scale=v_scale)
 
 
-def _paged_decode_kernel(pt_ref, len_ref, *refs, scale, page_len, quant):
+def _paged_decode_kernel(pt_ref, len_ref, *refs, scale, page_len, quant,
+                         kt=False):
     """Grid (b, h, max_pages): one batch row x head, one pool page per
     step. The (m, l) recurrence is :func:`_decode_kernel`'s; the page
     the DMA fetched was chosen by the scalar-prefetch index map
     (``pt_ref[b, j]``), so the kernel body only needs the length skip/
     mask on GLOBAL positions ``j * page_len + lane``. ``quant``
     (static) adds two scalar-prefetch scale refs and the same fused
-    per-head dequant multiplies as :func:`_decode_kernel`."""
+    per-head dequant multiplies as :func:`_decode_kernel`. ``kt``
+    (static): the page blocks are ``[d, page_len]``, the stacked pool's
+    form — only the contracted axis of the two products moves."""
+    qk_dims, pv_dims = _page_dots(kt)
     if quant:
         ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, \
             l_ref = refs
@@ -357,9 +431,9 @@ def _paged_decode_kernel(pt_ref, len_ref, *refs, scale, page_len, quant):
     @pl.when(j * page_len < length)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)                   # [1, d]
-        k = k_ref[0, 0].astype(jnp.float32)                   # [pl, d]
+        k = k_ref[0, 0].astype(jnp.float32)       # [pl, d] (kt: [d, pl])
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, qk_dims,
             preferred_element_type=jnp.float32) * scale       # [1, pl]
         if quant:
             s = s * ks_ref[hh]
@@ -374,7 +448,7 @@ def _paged_decode_kernel(pt_ref, len_ref, *refs, scale, page_len, quant):
         l_ref[:1, :1] = alpha * l_ref[:1, :1] + jnp.sum(
             p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p, v_ref[0, 0].astype(jnp.float32), pv_dims,
             preferred_element_type=jnp.float32)
         if quant:
             pv = pv * vs_ref[hh]
@@ -389,13 +463,14 @@ def _paged_decode_kernel(pt_ref, len_ref, *refs, scale, page_len, quant):
 
 
 def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, scale,
-                         interpret, ks=None, vs=None):
+                         interpret, ks=None, vs=None, layer=None):
     B, h, d = q.shape
-    page_len = k_pool.shape[2]
+    kt = layer is not None           # stacked pool: pages [d, page_len]
+    page_len = k_pool.shape[-1 if kt else -2]
     max_pages = pt.shape[1]
     quant = ks is not None
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               page_len=page_len, quant=quant)
+                               page_len=page_len, quant=quant, kt=kt)
     # the dequant scales ride as two extra scalar-prefetch operands (the
     # variadic tail absorbs them — only the kernel body reads them).
     # q/out carry a unit row axis ([B, h, 1, d]): Mosaic wants a block's
@@ -408,14 +483,15 @@ def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, scale,
     def _kv_idx(b, hh, j, pt, ln, *_scales):
         return (pt[b, j], hh, 0, 0)
 
+    kv_spec = _page_block_spec(page_len, d, _kv_idx, layer)
     n_prefetch, extra_ops = (4, (ks, vs)) if quant else (2, ())
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,   # page_table, lengths[, ks, vs]
         grid=(B, h, max_pages),
         in_specs=[
             pl.BlockSpec((1, 1, 1, d), _q_idx),
-            pl.BlockSpec((1, 1, page_len, d), _kv_idx),
-            pl.BlockSpec((1, 1, page_len, d), _kv_idx),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((1, 1, 1, d), _q_idx),
         scratch_shapes=[
@@ -435,12 +511,19 @@ def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, scale,
 def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
                            scale: Optional[float] = None,
                            k_scale=None, v_scale=None,
+                           layer: Optional[int] = None,
                            interpret: bool = False):
     """Single-token attention against a PAGED, length-masked KV pool.
 
     ``q`` [batch, heads, head_dim]; ``k_pool``/``v_pool``
     [num_pages, heads, page_len, head_dim] (one layer of the serving
-    pool — pages are shared across batch rows); ``page_table``
+    pool — pages are shared across batch rows), or the whole stacked
+    pool [layers, num_pages, heads, head_dim, page_len] with the static
+    ``layer`` to attend (the serving engine's form, pages transposed:
+    :func:`_layer_pool_shape` says why): the layer is then one more
+    block index of the page DMA, so the serving programs hand over the
+    pool they write in place and never slice a layer out of it;
+    ``page_table``
     [batch, max_pages] int32 maps row ``b``'s logical block ``j`` to a
     pool page (sentinel ids for unallocated blocks — masked, never
     attended); ``lengths`` [batch] int32 as in
@@ -457,8 +540,9 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     gather-then-reference oracle.
     """
     B, h, d = q.shape
-    P, hp, page_len, dp = k_pool.shape
-    if v_pool.shape != k_pool.shape or hp != h or dp != d:
+    P, hp, page_len, dp = _layer_pool_shape("paged_decode_attention",
+                                            k_pool, v_pool, layer)
+    if hp != h or dp != d:
         raise ValueError(f"paged_decode_attention: pools "
                          f"{k_pool.shape}/{v_pool.shape} do not match q "
                          f"{q.shape}")
@@ -479,7 +563,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
             or (not interpret and not mosaic_dtype_ok(q, k_pool, v_pool)):
         return paged_decode_attention_reference(
             q, k_pool, v_pool, page_table, lengths, scale=scale,
-            k_scale=k_scale, v_scale=v_scale)
+            k_scale=k_scale, v_scale=v_scale, layer=layer)
     pt = jnp.asarray(page_table, jnp.int32)
     len32 = jnp.asarray(lengths, jnp.int32)
     ks = vs = None
@@ -487,6 +571,6 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
         ks = jnp.asarray(k_scale, jnp.float32)
         vs = jnp.asarray(v_scale, jnp.float32)
     out = _paged_decode_pallas(q, k_pool, v_pool, pt, len32, scale,
-                               interpret, ks, vs)
+                               interpret, ks, vs, layer)
     live = (lengths > 0)[:, None, None]
     return jnp.where(live, out, 0).astype(q.dtype)

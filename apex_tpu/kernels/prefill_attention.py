@@ -74,6 +74,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.kernels import mosaic_dtype_ok, vmem
+from apex_tpu.kernels.decode_attention import (_check_head_scales,
+                                               _layer_pool_shape,
+                                               _page_block_spec,
+                                               _page_dots, gather_pages)
 
 __all__ = ["prefill_attention", "prefill_attention_reference",
            "paged_prefill_attention", "paged_prefill_attention_reference"]
@@ -252,7 +256,6 @@ def prefill_attention(q, k, v, offsets, *, scale: Optional[float] = None,
     if offsets.shape != (b,):
         raise ValueError(f"prefill_attention: offsets {offsets.shape} "
                          f"must be [{b}]")
-    from apex_tpu.kernels.decode_attention import _check_head_scales
     _check_head_scales("prefill_attention", h, k_scale, v_scale)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -285,29 +288,31 @@ def prefill_attention(q, k, v, offsets, *, scale: Optional[float] = None,
 # ------------------------------------------------------------ paged variant
 def paged_prefill_attention_reference(q, k_pool, v_pool, page_table,
                                       offsets, *, scale: float = 1.0,
-                                      k_scale=None, v_scale=None):
+                                      k_scale=None, v_scale=None,
+                                      layer=None):
     """fp32-math oracle: gather the page-table view, then the exact
     contiguous chunk-prefill reference. ``q`` [b, h, C, d]; pools
-    [num_pages, h, page_len, d]; ``page_table`` [b, max_pages];
+    [num_pages, h, page_len, d] (or the stacked pool, with ``layer``);
+    ``page_table`` [b, max_pages];
     ``offsets`` [b] int32. With ``k_scale``/``v_scale`` ([h] fp32) the
     gathered int8 pages are dequantized before the exact math — the
     quantized tier's gather-dequant oracle."""
-    from apex_tpu.kernels.decode_attention import gather_pages
-
-    k = gather_pages(k_pool, page_table)
-    v = gather_pages(v_pool, page_table)
+    k = gather_pages(k_pool, page_table, layer)
+    v = gather_pages(v_pool, page_table, layer)
     return prefill_attention_reference(q, k, v, offsets, scale=scale,
                                        k_scale=k_scale, v_scale=v_scale)
 
 
 def _paged_prefill_kernel(pt_ref, off_ref, *refs, scale, block_q,
-                          page_len, quant):
+                          page_len, quant, kt=False):
     """Grid (b, h, nq, max_pages): one batch row x head, q-blocked
     chunk, one pool page per KV step. :func:`_prefill_kernel`'s (m, l)
     recurrence and global-position shifted-causal mask; the page the
     DMA fetched was chosen by the scalar-prefetch index map. ``quant``
     (static) adds two scalar-prefetch scale refs and the fused per-head
-    dequant multiplies."""
+    dequant multiplies. ``kt`` (static): the page blocks are
+    ``[d, page_len]``, the stacked pool's form."""
+    qk_dims, pv_dims = _page_dots(kt)
     if quant:
         ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, \
             l_ref = refs
@@ -330,9 +335,9 @@ def _paged_prefill_kernel(pt_ref, off_ref, *refs, scale, block_q,
     @pl.when(ji * page_len <= offset + qi * block_q + block_q - 1)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)                  # [bq, d]
-        k = k_ref[0, 0].astype(jnp.float32)                  # [pl, d]
+        k = k_ref[0, 0].astype(jnp.float32)      # [pl, d] (kt: [d, pl])
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, qk_dims,
             preferred_element_type=jnp.float32) * scale      # [bq, pl]
         if quant:
             s = s * ks_ref[hh]
@@ -348,7 +353,7 @@ def _paged_prefill_kernel(pt_ref, off_ref, *refs, scale, block_q,
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p, v_ref[0, 0].astype(jnp.float32), pv_dims,
             preferred_element_type=jnp.float32)
         if quant:
             pv = pv * vs_ref[hh]
@@ -364,14 +369,15 @@ def _paged_prefill_kernel(pt_ref, off_ref, *refs, scale, block_q,
 
 
 def _paged_prefill_pallas(q, k_pool, v_pool, pt, offsets, scale, bq,
-                          interpret, ks=None, vs=None):
+                          interpret, ks=None, vs=None, layer=None):
     B, h, C, d = q.shape
-    page_len = k_pool.shape[2]
+    kt = layer is not None           # stacked pool: pages [d, page_len]
+    page_len = k_pool.shape[-1 if kt else -2]
     max_pages = pt.shape[1]
     quant = ks is not None
     kernel = functools.partial(_paged_prefill_kernel, scale=scale,
                                block_q=bq, page_len=page_len,
-                               quant=quant)
+                               quant=quant, kt=kt)
 
     # the dequant scales ride as two extra scalar-prefetch operands;
     # the index maps' variadic tails absorb them (only the kernel body
@@ -393,6 +399,7 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pt, offsets, scale, bq,
         last = (off[b] + (C - 1)) // page_len
         return (pt[b, jnp.minimum(j, last)], hh, 0, 0)
 
+    kv_spec = _page_block_spec(page_len, d, _kv_page, layer)
     n_prefetch, extra_ops = (4, (ks, vs)) if quant else (2, ())
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -400,8 +407,8 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pt, offsets, scale, bq,
         grid=(B, h, C // bq, max_pages),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), _q_idx),
-            pl.BlockSpec((1, 1, page_len, d), _kv_page),
-            pl.BlockSpec((1, 1, page_len, d), _kv_page),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), _q_idx),
         scratch_shapes=[
@@ -428,6 +435,7 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
                             scale: Optional[float] = None,
                             block_q: Optional[int] = None,
                             k_scale=None, v_scale=None,
+                            layer: Optional[int] = None,
                             interpret: bool = False):
     """Chunk-of-queries attention against a PAGED cached prefix.
 
@@ -435,7 +443,11 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
     whose K/V are already written into the pool at logical positions
     ``[offsets[b], offsets[b] + C)`` of row ``b``'s pages; ``k_pool``/
     ``v_pool`` [num_pages, heads, page_len, head_dim] (one layer of the
-    serving pool); ``page_table`` [batch, max_pages] int32;
+    serving pool), or the whole stacked pool [layers, num_pages, heads,
+    head_dim, page_len] with the static ``layer`` to attend (the
+    serving engine's form, pages transposed; the layer is one more
+    block index of the page DMA — no layer is sliced out);
+    ``page_table`` [batch, max_pages] int32;
     ``offsets`` [batch] int32. Query row ``i`` attends logical cache
     positions ``[0, offsets[b] + i]`` — the shifted-causal mask of
     chunked prefill, unchanged by the paging. ``scale`` defaults to
@@ -460,8 +472,9 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
     pool page by construction).
     """
     B, h, C, d = q.shape
-    P, hp, page_len, dp = k_pool.shape
-    if v_pool.shape != k_pool.shape or hp != h or dp != d:
+    P, hp, page_len, dp = _layer_pool_shape("paged_prefill_attention",
+                                            k_pool, v_pool, layer)
+    if hp != h or dp != d:
         raise ValueError(f"paged_prefill_attention: pools "
                          f"{k_pool.shape}/{v_pool.shape} do not match q "
                          f"{q.shape}")
@@ -471,7 +484,6 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
     if offsets.shape != (B,):
         raise ValueError(f"paged_prefill_attention: offsets "
                          f"{offsets.shape} must be [{B}]")
-    from apex_tpu.kernels.decode_attention import _check_head_scales
     _check_head_scales("paged_prefill_attention", h, k_scale, v_scale)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -485,7 +497,7 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
             or (not interpret and not mosaic_dtype_ok(q, k_pool, v_pool)):
         return paged_prefill_attention_reference(
             q, k_pool, v_pool, page_table, offsets, scale=scale,
-            k_scale=k_scale, v_scale=v_scale)
+            k_scale=k_scale, v_scale=v_scale, layer=layer)
     pt = jnp.asarray(page_table, jnp.int32)
     off32 = jnp.asarray(offsets, jnp.int32)
     ks = vs = None
@@ -493,4 +505,4 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
         ks = jnp.asarray(k_scale, jnp.float32)
         vs = jnp.asarray(v_scale, jnp.float32)
     return _paged_prefill_pallas(q, k_pool, v_pool, pt, off32, scale, bq,
-                                 interpret, ks, vs).astype(q.dtype)
+                                 interpret, ks, vs, layer).astype(q.dtype)

@@ -77,6 +77,38 @@ def _dense_factory(weight_quant: bool, dense_dtype, param_dtype):
     return _dense
 
 
+def _pool_write_tokens(pool, layer, page_ids, off, new):
+    """Write one token per batch row into the stacked paged pool, IN
+    PLACE: ``pool`` ``[layers, num_pages, h, d, page_len]``, ``new``
+    ``[B, h, d]`` (already in the pool's storage dtype) lands at
+    ``pool[layer, page_ids[b], :, :, off[b]]``.
+
+    Written as read-modify-write of each row's whole write page — gather
+    the B pages, replace lane ``off[b]``, scatter the pages back —
+    because a scatter whose window is a whole page runs on the pool as
+    it lies, while a scatter of the ``[h, d]`` column alone makes the
+    TPU compiler re-lay the entire pool out (the window dims must be its
+    minor ones) and back. A live row's write page is its own (shared
+    pages are full); inactive rows all name the sentinel page, whose
+    contents nothing reads."""
+    pages = pool[layer, page_ids]                     # [B, h, d, pl]
+    lane = jax.lax.broadcasted_iota(jnp.int32, pages.shape, 3)
+    pages = jnp.where(lane == off[:, None, None, None], new[..., None],
+                      pages)
+    return pool.at[layer, page_ids].set(pages)
+
+
+def _pool_write_pages(pool, layer, page_ids, new):
+    """Write whole pages into the stacked paged pool, in place:
+    ``new`` ``[B, h, n * page_len, d]`` (storage dtype) fills pages
+    ``page_ids`` ``[B, n]`` of ``layer``, each stored ``[h, d,
+    page_len]``."""
+    B, h, S, d = new.shape
+    n = page_ids.shape[1]
+    new = new.reshape(B, h, n, S // n, d).transpose(0, 2, 1, 4, 3)
+    return pool.at[layer, page_ids].set(new)          # [B, n, h, d, pl]
+
+
 class SelfAttention(nn.Module):
     """Causal MHA with four modes sharing one set of weights:
 
@@ -97,13 +129,19 @@ class SelfAttention(nn.Module):
       including itself (write-then-attend, shifted-causal) via
       :func:`apex_tpu.kernels.prefill_attention.prefill_attention`.
     - **paged decode / chunked prefill** (``cache=(k_pool, v_pool,
-      page_table)``): same two modes over the serving engine's paged
-      pool — K/V scatter by page id (``page_table[b, pos // page_len]``
-      at in-page offset ``pos % page_len``) and attention gathers
-      through the table via the ``paged_*`` kernel variants. The
-      returned aux is the UPDATED POOL pair (pages are shared across
-      rows), not per-row caches; chunk writes must be page-aligned and
-      whole-page (the engine enforces ``chunk_len % page_len == 0``).
+      page_table)`` + the static ``layer``): same two modes over the
+      serving engine's paged pool, which arrives WHOLE — the stacked
+      ``[layers, num_pages, h, d, page_len]`` K and V that every layer
+      shares — and is WRITTEN IN PLACE: K/V land in the pool itself at
+      ``(layer, page_table[b, pos // page_len], :, :, pos % page_len)``
+      and attention reads the pool itself through the table via the
+      ``paged_*`` kernel variants, the layer one more block index of
+      their page DMA. No layer is sliced out of the pool and nothing
+      pool-shaped is stacked, transposed or copied, so the buffer the
+      engine donates is the buffer it gets back. The returned aux is
+      the UPDATED POOL pair (pages are shared across rows), not per-row
+      caches; chunk writes must be page-aligned and whole-page (the
+      engine enforces ``chunk_len % page_len == 0``).
     - **unaligned append** (``unaligned_append=True``, paged ``S > 1``):
       the speculative-verify write shape — a SMALL block of S draft
       tokens landing at an arbitrary (non-page-aligned) cache offset
@@ -167,7 +205,8 @@ class SelfAttention(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool, cache=None, positions=None,
                  return_kv: bool = False, unaligned_append: bool = False,
-                 kv_scales=None, lora=None, adapter_ids=None):
+                 kv_scales=None, lora=None, adapter_ids=None,
+                 layer: Optional[int] = None):
         # dtype=None → O1 engine: GEMMs are FP16_FUNCS 'linear'
         from apex_tpu.amp.autocast import resolve_dtype
         dense_dtype = resolve_dtype(self.dtype, "linear", jnp.float32)
@@ -211,12 +250,15 @@ class SelfAttention(nn.Module):
             paged = len(cache) == 3
             if paged:
                 # paged layout: (k_pool, v_pool, page_table) — pool
-                # [num_pages, h, page_len, d] shared across rows, table
-                # [B, max_pages] int32 mapping logical blocks to pages.
-                # Writes scatter by page id; attention gathers through
-                # the table (the serving engine's block-table refactor).
+                # [layers, num_pages, h, d, page_len] shared across rows
+                # AND layers, table [B, max_pages] int32 mapping logical
+                # blocks to pages. Writes land in the pool itself at
+                # (layer, page id); attention reads it through the table
+                # with the layer as one more block index — the pool is
+                # never sliced per layer (the serving engine's block-
+                # table refactor, written in place).
                 k_cache, v_cache, page_table = cache
-                page_len = k_cache.shape[2]
+                page_len = k_cache.shape[4]
                 L = page_table.shape[1] * page_len
             else:
                 k_cache, v_cache = cache             # [B, h, L, d]
@@ -240,13 +282,15 @@ class SelfAttention(nn.Module):
                         page_table, (pos // page_len)[:, None],
                         axis=1)[:, 0]
                     off = pos % page_len
-                    k_cache = k_cache.at[page_ids, :, off].set(
+                    k_cache = _pool_write_tokens(
+                        k_cache, layer, page_ids, off,
                         _store(k[:, :, 0], k_cache.dtype, ks, 1))
-                    v_cache = v_cache.at[page_ids, :, off].set(
+                    v_cache = _pool_write_tokens(
+                        v_cache, layer, page_ids, off,
                         _store(v[:, :, 0], v_cache.dtype, vs, 1))
                     ctx = paged_decode_attention(
                         q[:, :, 0], k_cache, v_cache, page_table,
-                        pos + 1, k_scale=ks, v_scale=vs)
+                        pos + 1, k_scale=ks, v_scale=vs, layer=layer)
                 else:
                     bidx = jnp.arange(B)
                     k_cache = k_cache.at[bidx, :, pos].set(
@@ -263,7 +307,7 @@ class SelfAttention(nn.Module):
                 if paged and unaligned_append:
                     # speculative verify: S is small (draft_len + 1)
                     # and the offset is an arbitrary mid-generation
-                    # position — scatter each position individually
+                    # position — write each position individually
                     # (the decode write, unrolled over the static S)
                     for s in range(S):
                         p = pos + s                             # [B]
@@ -271,14 +315,17 @@ class SelfAttention(nn.Module):
                             page_table, (p // page_len)[:, None],
                             axis=1)[:, 0]
                         off = p % page_len
-                        k_cache = k_cache.at[page_ids, :, off].set(
+                        k_cache = _pool_write_tokens(
+                            k_cache, layer, page_ids, off,
                             _store(k[:, :, s], k_cache.dtype, ks, 1))
-                        v_cache = v_cache.at[page_ids, :, off].set(
+                        v_cache = _pool_write_tokens(
+                            v_cache, layer, page_ids, off,
                             _store(v[:, :, s], v_cache.dtype, vs, 1))
                     ctx = paged_prefill_attention(q, k_cache, v_cache,
                                                   page_table, pos,
                                                   k_scale=ks,
-                                                  v_scale=vs)
+                                                  v_scale=vs,
+                                                  layer=layer)
                 elif paged:
                     # chunk writes must cover whole pages: the serving
                     # engine pins chunk_len % page_len == 0 and page-
@@ -293,18 +340,17 @@ class SelfAttention(nn.Module):
                         npg, dtype=jnp.int32)[None, :]
                     chunk_pages = jnp.take_along_axis(page_table, idx,
                                                       axis=1)  # [B, npg]
-                    def _pages(x, dtype, scale):
-                        return _store(x, dtype, scale, 1).reshape(
-                            B, heads, npg, page_len, d
-                        ).transpose(0, 2, 1, 3, 4)   # [B, npg, h, pl, d]
-                    k_cache = k_cache.at[chunk_pages].set(
-                        _pages(k, k_cache.dtype, ks))
-                    v_cache = v_cache.at[chunk_pages].set(
-                        _pages(v, v_cache.dtype, vs))
+                    k_cache = _pool_write_pages(
+                        k_cache, layer, chunk_pages,
+                        _store(k, k_cache.dtype, ks, 1))
+                    v_cache = _pool_write_pages(
+                        v_cache, layer, chunk_pages,
+                        _store(v, v_cache.dtype, vs, 1))
                     ctx = paged_prefill_attention(q, k_cache, v_cache,
                                                   page_table, pos,
                                                   k_scale=ks,
-                                                  v_scale=vs)
+                                                  v_scale=vs,
+                                                  layer=layer)
                 else:
                     # chunked prefill: S tokens land at [pos, pos + S)
                     # of each row's cache (vmapped per-row offsets)
@@ -364,11 +410,12 @@ class SelfAttention(nn.Module):
 class TransformerBlock(nn.Module):
     """Pre-LN block: x + attn(LN(x)); x + mlp(LN(x)).
 
-    ``cache``/``positions``/``return_kv`` thread straight through to
-    :class:`SelfAttention` (see its docstring for the three modes); with
-    either inference mode on, the block returns ``(x, aux)`` where aux is
-    the updated layer cache (decode) or this layer's ``(k, v)``
-    (prefill).
+    ``cache``/``positions``/``return_kv``/``layer`` thread straight
+    through to :class:`SelfAttention` (see its docstring for the modes);
+    with either inference mode on, the block returns ``(x, aux)`` where
+    aux is the updated layer cache (decode; on the paged layout the
+    whole pool, written in place at ``layer``) or this layer's
+    ``(k, v)`` (prefill).
     """
 
     hidden: int
@@ -385,7 +432,8 @@ class TransformerBlock(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool, cache=None, positions=None,
                  return_kv: bool = False, unaligned_append: bool = False,
-                 kv_scales=None, lora=None, adapter_ids=None):
+                 kv_scales=None, lora=None, adapter_ids=None,
+                 layer: Optional[int] = None):
         # FusedLayerNorm resolves 'layer_norm' (FP32) itself from the raw
         # self.dtype; the Dense sites resolve 'linear' (FP16) here
         from apex_tpu.amp.autocast import resolve_dtype
@@ -409,7 +457,8 @@ class TransformerBlock(nn.Module):
                                               unaligned_append,
                                               kv_scales=kv_scales,
                                               lora=lora,
-                                              adapter_ids=adapter_ids)
+                                              adapter_ids=adapter_ids,
+                                              layer=layer)
         if cache is not None or return_kv:
             attn_out, aux = attn_out
         x = x + attn_out
@@ -475,6 +524,14 @@ class TransformerLM(nn.Module):
       + s``, K/V written to cache ``[positions[b], positions[b] + C)``,
       shifted-causal attention over the cached prefix (the engine's
       chunk-prefill program; one chunk per decode heartbeat).
+    - **paged decode / chunked prefill**: the same two with
+      ``cache=(k_pool, v_pool, page_table)`` — the stacked pools
+      ``[layers, num_pages, h, d, page_len]`` are ONE value carried
+      through the layer loop: each block writes its K/V into them in
+      place and its kernel reads them in place (see
+      :class:`SelfAttention`), and they are returned as they are —
+      nothing is sliced per layer or restacked, so a donated pool is
+      updated where it lives.
     - **speculative verify**: chunked prefill with
       ``unaligned_append=True`` — a ``[B, K+1]`` draft block landing at
       an arbitrary mid-generation offset; paged caches switch to
@@ -564,6 +621,9 @@ class TransformerLM(nn.Module):
         if self.remat and cache is None and not return_kv:
             block_cls = nn.remat(TransformerBlock, static_argnums=(2,))
         kv_out = ([], [])
+        paged = cache is not None and len(cache) == 3
+        if paged:
+            k_pool, v_pool, page_table = cache
         for i in range(self.num_layers):
             block = block_cls(self.hidden, self.num_heads, self.mlp_ratio,
                               self.dropout, self.dtype, self.param_dtype,
@@ -590,13 +650,22 @@ class TransformerLM(nn.Module):
                             lora["mlp_out_b"][i]),
                 "alpha": lora["alpha"],
             }
-            if cache is not None:
-                # 2-tuple: per-slot rows [layers, B, h, L, d]; 3-tuple:
-                # paged pools [layers, P, h, page_len, d] + one shared
-                # [B, max_pages] page table (see SelfAttention)
+            if paged:
+                # paged pools [layers, P, h, d, page_len] + one shared
+                # [B, max_pages] page table: the WHOLE pools go in with
+                # the layer to write and read, and come back updated in
+                # place (see SelfAttention) — never sliced, never
+                # restacked
+                x, (k_pool, v_pool) = block(
+                    x, train, cache=(k_pool, v_pool, page_table),
+                    positions=positions,
+                    unaligned_append=unaligned_append,
+                    kv_scales=layer_scales, lora=layer_lora,
+                    adapter_ids=adapter_ids, layer=i)
+            elif cache is not None:
+                # per-slot rows [layers, B, h, L, d], sliced per layer
+                # and restacked below (the contiguous layout)
                 layer_cache = (cache[0][i], cache[1][i])
-                if len(cache) == 3:
-                    layer_cache = layer_cache + (cache[2],)
                 x, (lk, lv) = block(x, train, cache=layer_cache,
                                     positions=positions,
                                     unaligned_append=unaligned_append,
@@ -644,6 +713,8 @@ class TransformerLM(nn.Module):
                              jnp.asarray(embed.embedding, jnp.float32).T)
             if self.weight_quant:
                 logits = logits * embed.embedding_scale
+        if paged:
+            return logits, (k_pool, v_pool)
         if cache is not None or return_kv:
             return logits, (jnp.stack(kv_out[0]), jnp.stack(kv_out[1]))
         return logits

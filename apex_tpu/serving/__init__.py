@@ -6,8 +6,8 @@ workload the north star calls for — serving a stream of variable-length
 generation requests from a fixed set of compiled programs:
 
 - :class:`PagedKVCache` + :class:`PagePool` (:mod:`.kv_cache`) — the
-  DEFAULT cache layout: a dense ``[layers, num_pages, heads, page_len,
-  head_dim]`` page pool plus a host-side allocator (free list, page
+  DEFAULT cache layout: a dense ``[layers, num_pages, heads, head_dim,
+  page_len]`` page pool plus a host-side allocator (free list, page
   refcounts, admission reservations). Requests own page lists, not
   rows: short prompts stop paying ``max_len`` HBM, freed pages return
   to the pool immediately, and prefix hits are copy-on-write page

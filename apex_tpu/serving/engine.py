@@ -132,8 +132,8 @@ shard_map'd over a 1-D tensor-parallel mesh axis
 (:mod:`apex_tpu.serving.sharding`). Params split per a
 ``match_partition_rules`` table (qkv/MLP-up column-parallel, proj/
 MLP-down row-parallel, embeddings replicated), the KV pool shards
-along the HEADS axis (``[layers, num_pages, heads/tp, page_len,
-head_dim]`` per shard) so attention never crosses ICI, and the only
+along the HEADS axis (``[layers, num_pages, heads/tp, head_dim,
+page_len]`` per shard) so attention never crosses ICI, and the only
 collectives are the two canonical TP all-reduces per block
 (post-attention, post-MLP) plus ONE all-gather of the sampled logits
 rows (the tied head computes vocab/tp slices per shard) — 2 psums per
@@ -648,7 +648,7 @@ class Engine:
                     dtype=cache_dtype, k_scale=k_scale, v_scale=v_scale)
             else:
                 # heads-axis pool sharding: each shard holds
-                # [layers, num_pages, heads/tp, page_len, head_dim] —
+                # [layers, num_pages, heads/tp, head_dim, page_len] —
                 # attention never crosses ICI; page tables, lengths and
                 # the allocator stay replicated host state. Allocated
                 # DIRECTLY into the sharded layout (zeros_sharded): a
@@ -657,7 +657,7 @@ class Engine:
                 # scales shard ALONG the pool's heads axis
                 # ([layers, heads/tp] per shard), so each shard
                 # de/quantizes its own heads collective-free.
-                shape = (layers, num_pages, heads, page_len, head_dim)
+                shape = (layers, num_pages, heads, head_dim, page_len)
                 pspec = _sharding.cache_pspec(self._tp_axis)
                 if k_scale is not None:
                     sspec = _sharding.scale_pspec(self._tp_axis)
@@ -934,7 +934,7 @@ class Engine:
 
     def _swap_block_pspec(self):
         """A swapped page block's partition spec: ``[layers,
-        max_pages, heads/tp, page_len, head_dim]`` per shard — the
+        max_pages, heads/tp, head_dim, page_len]`` per shard — the
         SAME heads-axis split as the pool itself, so each shard's swap
         gather/scatter moves exactly its own slice and the programs
         need no collective at all. None on a single-chip engine."""
@@ -1351,10 +1351,10 @@ class Engine:
             if pad:
                 new = jnp.pad(new, ((0, 0), (0, 0), (0, 0), (0, pad),
                                     (0, 0)))
-            # [layers, 1, h, m*pl, d] -> [layers, m, h, pl, d]
+            # [layers, 1, h, m*pl, d] -> [layers, m, h, d, pl]
             new = new[:, 0].reshape(cache.layers, cache.heads, m, pl_,
-                                    cache.head_dim).transpose(0, 2, 1, 3,
-                                                              4)
+                                    cache.head_dim).transpose(0, 2, 1, 4,
+                                                              3)
             return pool.at[:, pages].set(new)
 
         cache = cache.replace(k=_scatter(cache.k, k_new),
@@ -1444,7 +1444,7 @@ class Engine:
     def _swap_out_impl(self, cache, page_ids):
         """The hierarchical-KV tier's OUTBOUND compiled program: gather
         the pool pages named by ``page_ids`` ``[max_pages]`` int32 into
-        a fresh ``[layers, max_pages, heads, page_len, head_dim]``
+        a fresh ``[layers, max_pages, heads, head_dim, page_len]``
         snapshot block per pool array — ONE dispatch per swap-out,
         fixed shape (entries shorter than ``max_pages`` pad their
         trailing ids with the page-0 sentinel, whose garbage is sliced
@@ -1465,8 +1465,8 @@ class Engine:
 
     def _swap_in_impl(self, cache, k_blk, v_blk, page_ids):
         """The hierarchical-KV tier's INBOUND compiled program: scatter
-        a host-restored page block ``[layers, max_pages, heads, page_len,
-        head_dim]`` into the pool rows named by ``page_ids``
+        a host-restored page block ``[layers, max_pages, heads, head_dim,
+        page_len]`` into the pool rows named by ``page_ids``
         ``[max_pages]`` int32 — ONE dispatch per swap-in, fixed shape
         (entries shorter than ``max_pages`` pad their trailing ids with
         the page-0 sentinel, whose garbage absorbs the padded writes
@@ -2070,7 +2070,7 @@ class Engine:
         k_host, v_host = rec.k, rec.v
         c = self.cache
         want = (c.layers, k_host.shape[1] if k_host.ndim == 5 else -1,
-                c.heads, c.page_len, c.head_dim)
+                *c.page_shape)
         if k_host.shape != want or v_host.shape != want \
                 or k_host.dtype != np.dtype(c.dtype) \
                 or v_host.dtype != np.dtype(c.dtype):
@@ -2101,7 +2101,7 @@ class Engine:
         # idiom), so every swap-in of every entry size shares ONE
         # executable and ONE dispatch
         P = self.max_pages
-        blk_shape = (c.layers, P, c.heads, c.page_len, c.head_dim)
+        blk_shape = (c.layers, P, *c.page_shape)
         k_blk = np.zeros(blk_shape, k_host.dtype)
         v_blk = np.zeros(blk_shape, v_host.dtype)
         k_blk[:, :m], v_blk[:, :m] = k_host, v_host
@@ -2708,6 +2708,51 @@ class Engine:
         itself compiled."""
         from apex_tpu.utils.chip import kernel_calls
 
+        return {name: kernel_calls(compiled.as_text())
+                for name, compiled in self._compile_programs().items()}
+
+    def program_memory(self) -> dict:
+        """What the decode and chunk-prefill programs hold in device
+        memory beside their operands, by the compiler's own count:
+        ``{"decode": {"argument_bytes", "alias_bytes", "temp_bytes"},
+        "chunk": {...}}`` from each compiled program's buffer
+        assignment (:func:`apex_tpu.utils.memory_report
+        .executable_memory`). The KV pool is donated to both programs
+        and written in place, so ``alias_bytes`` holds the pool and
+        ``temp_bytes`` stays a small fraction of it; temporaries of the
+        pool's size or more mean the compiler is copying the pool
+        (a layout the kernels, the writes and the stored array do not
+        share), which costs its bytes in time every step and in
+        capacity always. Sets the gauges ``serving.kv.pool_bytes``,
+        ``serving.kv.decode_temp_bytes`` and
+        ``serving.kv.chunk_temp_bytes``.
+
+        Compiles like :meth:`program_kernels` (same programs, same
+        restored trace counters). On the CPU the interpreted Pallas
+        kernels carry their operands through a loop, so the temporaries
+        read there say nothing about a chip."""
+        from apex_tpu.utils.memory_report import executable_memory
+
+        out = {}
+        for name, compiled in self._compile_programs().items():
+            m = executable_memory(compiled)
+            out[name] = {"argument_bytes": m.argument_bytes,
+                         "alias_bytes": m.alias_bytes,
+                         "temp_bytes": m.temp_bytes}
+        if self._registry is not None:
+            self._registry.gauge_set("serving.kv.pool_bytes",
+                                     float(self.cache.nbytes()))
+            self._registry.gauge_set("serving.kv.decode_temp_bytes",
+                                     float(out["decode"]["temp_bytes"]))
+            self._registry.gauge_set("serving.kv.chunk_temp_bytes",
+                                     float(out["chunk"]["temp_bytes"]))
+        return out
+
+    def _compile_programs(self) -> dict:
+        """``{"decode": compiled, "chunk": compiled}``: both heartbeat
+        programs lowered and compiled afresh at their operand shapes,
+        trace counters restored (:meth:`program_kernels`,
+        :meth:`program_memory`)."""
         slots_f32 = np.zeros(self.slots, np.float32)
         last = np.zeros(self.slots, np.int32)
         chunk = np.zeros((1, self.chunk_len), np.int32)
@@ -2731,7 +2776,7 @@ class Engine:
             }
         finally:
             self.decode_traces, self.chunk_traces = traces
-        return {name: kernel_calls(lowered.compile().as_text())
+        return {name: lowered.compile()
                 for name, lowered in programs.items()}
 
     def close(self) -> None:

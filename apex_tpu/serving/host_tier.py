@@ -13,7 +13,7 @@ swapped-out prefix page blocks, keyed by the owning prefix-cache
 entry's synthetic key:
 
 - **put** (swap-out): the engine copies an evicted entry's page bytes
-  device→host (``[layers, m, heads, page_len, head_dim]`` K and V, in
+  device→host (``[layers, m, heads, head_dim, page_len]`` K and V, in
   the pool's storage dtype — int8 under the ``kv_quant`` tier, which
   halves the transfer bytes for free) and the arena stores them with a
   per-shard CRC32 checksum (one CRC per tensor-parallel shard of the
@@ -83,7 +83,7 @@ _logger = get_logger("serving")
 def _shard_checksums(k: np.ndarray, v: np.ndarray,
                      shards: int) -> Tuple[int, ...]:
     """Per-shard CRC32s over the HEADS axis (axis 2 of
-    ``[layers, m, heads, page_len, head_dim]``): shard ``t`` covers
+    ``[layers, m, heads, head_dim, page_len]``): shard ``t`` covers
     heads ``[t*h/tp, (t+1)*h/tp)`` of K then V — exactly the slice a
     tensor-parallel shard owns, so a mesh engine's arena records carry
     one verifiable checksum per shard. ``shards=1`` is the classic
@@ -127,7 +127,7 @@ class HostTierRecord:
     :meth:`HostTier.take` fills in when it re-verifies the checksums
     at swap-in."""
 
-    k: Optional[np.ndarray]  # [layers, m, heads, page_len, head_dim]
+    k: Optional[np.ndarray]  # [layers, m, heads, head_dim, page_len]
     v: Optional[np.ndarray]
     nbytes: int
     crc: Tuple[int, ...]

@@ -38,8 +38,11 @@ cache to both of its compiled programs).
 
 **Paged layout** (the serving engine's default since the block-table
 refactor): :class:`PagedKVCache` replaces the per-slot rows with a
-dense pool of fixed-size pages ``[layers, num_pages, heads, page_len,
-head_dim]`` plus a host-side :class:`PagePool` allocator. A request
+dense pool of fixed-size pages ``[layers, num_pages, heads, head_dim,
+page_len]`` (each page held transposed, ``page_len`` in the lanes: the
+form the chip stores unpadded and the kernels read as it lies — see
+:class:`PagedKVCache`) plus a host-side :class:`PagePool` allocator. A
+request
 owns a *page list* instead of a row: its logical positions ``[0, L)``
 live on pages ``table[0] .. table[ceil(L/page_len)-1]`` at in-page
 offsets ``pos % page_len``. The engine materialises the per-slot lists
@@ -294,14 +297,30 @@ class KVCache:
 
 @flax.struct.dataclass
 class PagedKVCache:
-    """Paged KV pool pytree: ``[layers, num_pages, heads, page_len,
-    head_dim]`` K and V. Pure device storage — lengths and page tables
+    """Paged KV pool pytree: ``[layers, num_pages, heads, head_dim,
+    page_len]`` K and V. Pure device storage — lengths and page tables
     are host state (the engine's :class:`PagePool` + numpy tables,
     passed as per-call operands), so the donated pytree is exactly the
-    two hot arrays."""
+    two hot arrays.
 
-    k: jnp.ndarray        # [layers, num_pages, heads, page_len, head_dim]
-    v: jnp.ndarray        # [layers, num_pages, heads, page_len, head_dim]
+    **Written in place.** The decode, chunk and verify programs take
+    the donated pool, write each layer's new K/V into it where it lies
+    and hand the same buffer back: the model threads the whole stacked
+    pool through its layers (no layer is sliced out or restacked) and
+    the paged kernels read their pages straight out of it
+    (:class:`~apex_tpu.models.transformer_lm.SelfAttention`). That
+    only holds if the program, the kernels' page DMA and the chip's own
+    storage agree on ONE physical form, which is why a page is held
+    ``[head_dim, page_len]``: with ``page_len`` (a multiple of 128) in
+    the lanes every tile is full, so the chip's default layout is the
+    plain row-major one the kernels read; with a 64-wide ``head_dim``
+    there instead the compiler stores the pool the other way round to
+    dodge the padding and re-lays a layer of it out and back around
+    every kernel call (``Engine.program_memory`` reads the difference
+    as the programs' temporaries)."""
+
+    k: jnp.ndarray        # [layers, num_pages, heads, head_dim, page_len]
+    v: jnp.ndarray        # [layers, num_pages, heads, head_dim, page_len]
     # quantized storage tier (kv_quant): per-[layer, head] fp32 dequant
     # scales; None on the bf16 default. Per-head — NOT per-page — so a
     # copy-on-write share never copies scale state alongside its pages.
@@ -322,12 +341,18 @@ class PagedKVCache:
         return self.k.shape[2]
 
     @property
-    def page_len(self) -> int:
+    def head_dim(self) -> int:
         return self.k.shape[3]
 
     @property
-    def head_dim(self) -> int:
+    def page_len(self) -> int:
         return self.k.shape[4]
+
+    @property
+    def page_shape(self) -> Tuple[int, int, int]:
+        """One page as stored: ``(heads, head_dim, page_len)`` — the
+        trailing dims of the pool and of every swapped page block."""
+        return self.k.shape[2:]
 
     @property
     def dtype(self):
@@ -349,7 +374,7 @@ class PagedKVCache:
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "sentinel/garbage page)")
-        shape = (layers, num_pages, heads, page_len, head_dim)
+        shape = (layers, num_pages, heads, head_dim, page_len)
         return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                    k_scale=k_scale, v_scale=v_scale)
 

@@ -29,7 +29,7 @@ Scale layout — the design's load-bearing choice::
   overwritten write-then-attend, with no scale state to unwind.
 - **tensor parallelism shards scales with the pool**: ``[layers,
   heads]`` splits along the heads axis next to ``[layers, num_pages,
-  heads/tp, page_len, head_dim]`` — each shard quantizes and
+  heads/tp, head_dim, page_len]`` — each shard quantizes and
   dequantizes its own heads with its own scale slice, collective-free.
 
 Numerics: symmetric linear quantization to ``[-127, 127]`` (qmax

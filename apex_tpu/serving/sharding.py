@@ -32,7 +32,7 @@ tensor-parallel mesh axis:
 
 3. **cache/pool specs** (:func:`cache_pspec`): the paged KV pool is
    sharded along the HEADS axis — ``[layers, num_pages, heads/tp,
-   page_len, head_dim]`` per shard — so every attention gather, page
+   head_dim, page_len]`` per shard — so every attention gather, page
    scatter and per-page kernel step is shard-local. Attention NEVER
    crosses ICI: each shard runs the unchanged paged kernels over fewer
    heads (the grid over ``batch x heads`` simply has fewer rows), and
@@ -245,7 +245,7 @@ def shard_params(params, mesh, *, num_heads: int, axis: str = None,
 
 def cache_pspec(axis: str = "tp") -> PartitionSpec:
     """The paged KV pool's partition spec: ``[layers, num_pages,
-    heads/tp, page_len, head_dim]`` per shard — heads-axis sharding, so
+    heads/tp, head_dim, page_len]`` per shard — heads-axis sharding, so
     attention never crosses ICI (each shard's paged kernels run
     unchanged over fewer heads; page tables and lengths stay replicated
     host state)."""

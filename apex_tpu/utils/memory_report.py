@@ -35,7 +35,8 @@ from typing import Any, Callable, Optional, Sequence
 
 import jax
 
-__all__ = ["MemoryStats", "compiled_memory", "price_contract",
+__all__ = ["MemoryStats", "compiled_memory", "executable_memory",
+           "price_contract",
            "xentropy_contract", "lm_head_contract", "flash_contract",
            "remat_mlp_contract",
            "causal_softmax_contract", "masked_softmax_contract",
@@ -51,6 +52,9 @@ class MemoryStats:
     output_bytes: int
     temp_bytes: int
     peak_bytes: int
+    # bytes of the outputs that live in a donated argument's buffer (an
+    # in-place update counts here and not under temp_bytes)
+    alias_bytes: int = 0
 
     @property
     def live_overhead_bytes(self) -> int:
@@ -59,17 +63,23 @@ class MemoryStats:
         return self.peak_bytes - self.argument_bytes - self.output_bytes
 
 
-def compiled_memory(fn: Callable, *avals: Any) -> MemoryStats:
-    """Compile ``fn`` at abstract ``avals`` (ShapeDtypeStructs or arrays)
-    and return its buffer-assignment byte counters. Nothing executes."""
-    c = jax.jit(fn).lower(*avals).compile()
-    ma = c.memory_analysis()
+def executable_memory(compiled) -> MemoryStats:
+    """The buffer-assignment byte counters of an already compiled
+    executable (``jax.jit(f).lower(...).compile()``)."""
+    ma = compiled.memory_analysis()
     return MemoryStats(
         argument_bytes=int(ma.argument_size_in_bytes),
         output_bytes=int(ma.output_size_in_bytes),
         temp_bytes=int(ma.temp_size_in_bytes),
         peak_bytes=int(ma.peak_memory_in_bytes),
+        alias_bytes=int(ma.alias_size_in_bytes),
     )
+
+
+def compiled_memory(fn: Callable, *avals: Any) -> MemoryStats:
+    """Compile ``fn`` at abstract ``avals`` (ShapeDtypeStructs or arrays)
+    and return its buffer-assignment byte counters. Nothing executes."""
+    return executable_memory(jax.jit(fn).lower(*avals).compile())
 
 
 def xentropy_contract(n: int, v: int):

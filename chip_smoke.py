@@ -144,6 +144,27 @@ def missing_kernels(what: str, have, need) -> list:
             for k in need if not (have or {}).get(k)]
 
 
+def pool_copies(memory, pool_bytes) -> list:
+    """The paged pool is written in place: a serving program whose
+    temporaries reach the pool's size is copying it (the compiler's
+    count, ``Engine.program_memory()``, which the recipe prints beside
+    the programs' kernels; it means something on a chip only —
+    interpreted kernels carry their operands on the CPU). At
+    this geometry the pool is 99 MB and a chunk's fp32 logits alone are
+    52 MB, so the line drawn is the whole pool (a program that slices
+    and restacks it reads two pools here) and not the tenth of it that
+    a deployment's pool of gigabytes is held to."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return []
+    return [f"lm serve {prog} program: {m['temp_bytes']} bytes of "
+            f"temporaries beside a KV pool of {pool_bytes}: the pool is "
+            f"being copied"
+            for prog, m in memory.items()
+            if m["temp_bytes"] >= pool_bytes]
+
+
 def lm_phase(argv) -> list:
     """LM train + serve through the recipe; returns the failures."""
     from apex_tpu import serving
@@ -177,6 +198,7 @@ def lm_phase(argv) -> list:
     for prog, kernel in SERVE_KERNELS.items():
         failures += missing_kernels(f"lm serve {prog} program",
                                     gen["kernels"][prog], [kernel])
+    failures += pool_copies(gen["memory"], gen["pool_bytes"])
 
     # the reference: the contiguous engine, same parameters and prompts
     oracle = serving.Engine(gen["model"], metrics["final_state"].params,

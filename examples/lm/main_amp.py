@@ -1026,9 +1026,10 @@ def _maybe_generate(args, model, params, tele):
     compiled KV-cache engine (apex_tpu.serving) with the just-trained
     params — the recipe's end-to-end inference leg. Returns the
     completed requests, the model and ``Engine`` geometry keywords they
-    were served with and the Pallas kernels each serving program holds
-    (for callers inspecting the outputs or serving the same stream on
-    another engine), or None without --generate."""
+    were served with, the Pallas kernels each serving program holds and
+    the bytes it keeps beside its operands (for callers inspecting the
+    outputs or serving the same stream on another engine), or None
+    without --generate."""
     if not args.generate:
         return None
     import numpy as _np
@@ -1062,11 +1063,19 @@ def _maybe_generate(args, model, params, tele):
           f"chunk_len {engine.chunk_len}): " + "; ".join(
               f"{name}: {chip.format_kernels(k)}"
               for name, k in kernels.items()))
+    memory = engine.program_memory()
+    pool_bytes = engine.cache.nbytes()
+    print(f"=> serving programs beside a KV pool of "
+          f"{pool_bytes / 2**20:.1f} MiB: " + "; ".join(
+              f"{name}: temporaries {m['temp_bytes'] / 2**20:.1f} MiB, "
+              f"{m['alias_bytes'] / 2**20:.1f} MiB updated in place"
+              for name, m in memory.items()))
     preview = done[0]
     print(f"   sample [{preview.finish_reason}]: "
           f"{list(preview.prompt)[:8]}... -> "
           f"{preview.output_tokens[:16]}")
     return {"requests": done, "kernels": kernels, "seconds": dt,
+            "memory": memory, "pool_bytes": pool_bytes,
             "model": model, "page_len": engine.page_len,
             "geometry": {"slots": engine.slots, "max_len": engine.max_len,
                          "prefill_len": engine.prefill_len,

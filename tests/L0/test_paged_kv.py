@@ -206,6 +206,85 @@ def test_paged_prefill_kernel_matches_oracle_across_offsets():
         paged_prefill_attention(q, kp, vp, pt, off[:1])
 
 
+# ------------------------------------ the stacked pool, read where it lies
+def _stacked_case(kernel, dtype, page_len):
+    """(call on the stacked pool at ``layer``, call on one layer's 4-D
+    pool) for the decode or prefill kernel: a 3-layer pool as the
+    serving engine holds it, ``[layers, pages, heads, d, page_len]``,
+    bf16 or int8 with per-head scales. ``page_len`` 128 takes the
+    (interpreted) Pallas kernel, 24 its jnp fallback."""
+    rng = np.random.default_rng(7)
+    LYR, B, H, D, NP, MAXP, C = 3, 2, 2, 16, 6, 3, 16
+    shape = (LYR, NP, H, D, page_len)
+    scales = {}
+    if dtype == "int8":
+        kp = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        vp = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        scales = {"k_scale": jnp.asarray(rng.uniform(.01, .03, H),
+                                         jnp.float32),
+                  "v_scale": jnp.asarray(rng.uniform(.01, .03, H),
+                                         jnp.float32)}
+    else:
+        kp = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+        vp = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    pt = jnp.asarray(rng.integers(0, NP, size=(B, MAXP)), jnp.int32)
+    if kernel == "decode":
+        q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.bfloat16)
+        where = jnp.asarray([page_len + 3, 3 * page_len], jnp.int32)
+        fn = paged_decode_attention
+    else:
+        q = jnp.asarray(rng.normal(size=(B, H, C, D)), jnp.bfloat16)
+        where = jnp.asarray([0, 2 * page_len - 5], jnp.int32)
+        fn = paged_prefill_attention
+
+    def stacked(layer):
+        return fn(q, kp, vp, pt, where, layer=layer, **scales)
+
+    def one_layer(layer):       # that layer's pages, each [page_len, d]
+        return fn(q, kp[layer].swapaxes(-1, -2),
+                  vp[layer].swapaxes(-1, -2), pt, where, **scales)
+    return stacked, one_layer, (q, kp, vp, pt, where)
+
+
+@pytest.mark.parametrize("page_len", [128, 24], ids=["pallas", "fallback"])
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_stacked_pool_with_layer_equals_that_layers_pool(kernel, dtype,
+                                                         page_len):
+    """The serving programs hand the kernels the whole stacked pool and
+    a layer; that reads exactly what the 4-D call reads on that layer
+    alone — first, middle and last layer."""
+    stacked, one_layer, _ = _stacked_case(kernel, dtype, page_len)
+    outs = []
+    for layer in (0, 1, 2):
+        got, want = stacked(layer), one_layer(layer)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert (np.asarray(got, np.float32)
+                == np.asarray(want, np.float32)).all()
+        outs.append(np.asarray(got, np.float32))
+    # and the layers differ, so the index was not ignored
+    assert not (outs[0] == outs[1]).all()
+    assert not (outs[1] == outs[2]).all()
+
+
+def test_stacked_pool_needs_its_layer_and_a_layer_its_stacked_pool():
+    stacked, _, (q, kp, vp, pt, where) = _stacked_case("decode", "bf16",
+                                                       128)
+    with pytest.raises(ValueError, match="layer"):
+        paged_decode_attention(q, kp, vp, pt, where)
+    with pytest.raises(ValueError, match="layer"):
+        paged_decode_attention(q, kp[0], vp[0], pt, where, layer=0)
+    with pytest.raises(ValueError, match="outside"):
+        stacked(3)
+    with pytest.raises(ValueError, match="layer"):
+        paged_prefill_attention(q[:, :, None].repeat(8, 2), kp, vp, pt,
+                                where)
+    # the reference's gather reads the stacked pool the same way
+    assert (np.asarray(gather_pages(kp, pt, 1), np.float32)
+            == np.asarray(gather_pages(kp[1].swapaxes(-1, -2), pt),
+                          np.float32)).all()
+
+
 # ------------------------------------------------------------ engines
 def _tiny_lm(max_seq_len=64, **kw):
     return TransformerLM(vocab_size=VOCAB, hidden=32, num_layers=2,

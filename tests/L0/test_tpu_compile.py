@@ -132,13 +132,22 @@ def _decode(heads, d, kv_dtype):
                 *_scales(heads, kv_dtype)]
 
 
-def _paged_decode(heads, d, kv_dtype):
+def _pool(pages, heads, d, stacked):
+    """One layer's pool, or (``stacked``) three layers of it as the
+    serving engine holds them, the middle one read."""
+    if stacked:
+        return (3, pages, heads, d, PAGE), 1
+    return (pages, heads, PAGE, d), None
+
+
+def _paged_decode(heads, d, kv_dtype, stacked=False):
     from apex_tpu.kernels.decode_attention import paged_decode_attention
+
+    pool, layer = _pool(B * MAX_PAGES + 1, heads, d, stacked)
 
     def fn(q, k, v, table, lengths, ks, vs):
         return paged_decode_attention(q, k, v, table, lengths,
-                                      k_scale=ks, v_scale=vs)
-    pool = (B * MAX_PAGES + 1, heads, PAGE, d)
+                                      k_scale=ks, v_scale=vs, layer=layer)
     return fn, [((B, heads, d), BF16), (pool, kv_dtype), (pool, kv_dtype),
                 ((B, MAX_PAGES), I32), ((B,), I32),
                 *_scales(heads, kv_dtype)]
@@ -155,13 +164,14 @@ def _prefill(heads, d, kv_dtype, chunk=256):
                 *_scales(heads, kv_dtype)]
 
 
-def _paged_prefill(heads, d, kv_dtype, chunk=256):
+def _paged_prefill(heads, d, kv_dtype, stacked=False, chunk=256):
     from apex_tpu.kernels.prefill_attention import paged_prefill_attention
+
+    pool, layer = _pool(MAX_PAGES + 1, heads, d, stacked)
 
     def fn(q, k, v, table, offsets, ks, vs):
         return paged_prefill_attention(q, k, v, table, offsets,
-                                       k_scale=ks, v_scale=vs)
-    pool = (MAX_PAGES + 1, heads, PAGE, d)
+                                       k_scale=ks, v_scale=vs, layer=layer)
     return fn, [((1, heads, chunk, d), BF16), (pool, kv_dtype),
                 (pool, kv_dtype), ((1, MAX_PAGES), I32), ((1,), I32),
                 *_scales(heads, kv_dtype)]
@@ -186,6 +196,14 @@ CASES = {
                                  ["paged_decode_attention"]),
     "paged_decode_int8_16x128": (_paged_decode, (16, 128, I8),
                                  ["paged_decode_attention"]),
+    "paged_decode_stacked_bf16": (_paged_decode, (12, 64, BF16, True),
+                                  ["paged_decode_attention"]),
+    "paged_decode_stacked_int8": (_paged_decode, (12, 64, I8, True),
+                                  ["paged_decode_attention"]),
+    "paged_prefill_stacked_bf16": (_paged_prefill, (12, 64, BF16, True),
+                                   ["paged_prefill_attention"]),
+    "paged_prefill_stacked_int8": (_paged_prefill, (12, 64, I8, True),
+                                   ["paged_prefill_attention"]),
     "prefill_bf16": (_prefill, (12, 64, BF16), ["prefill_attention"]),
     "paged_prefill_bf16": (_paged_prefill, (12, 64, BF16),
                            ["paged_prefill_attention"]),
