@@ -7,11 +7,6 @@ nothing here is part of the benchmark's result.
         each phase's self time, the idle seconds by phase, and what
         ``python -m apex_tpu.telemetry summarize --trace`` says of the
         kept trace.
-    python3 benchmarks/checks/probe_phases.py slow --lead spin|sleep --seed N
-        cell B's engine in a fresh process that first spins on empty
-        ``Scheduler.step`` calls a millisecond apart for 2 s (or sleeps
-        2 s), then serves the backlog for ``--seconds``; per phase the
-        p50 of its self time per beat, and the beat's own p50.
     python3 benchmarks/checks/probe_phases.py run --ring 0|1 -- <run.py arguments>
         one benchmark run with the flight recorder off or on.
     python3 benchmarks/checks/probe_phases.py micro
@@ -206,110 +201,12 @@ def cmd_table(a):
                       "apex_events": res["apex_events_on_host_plane"]}))
 
 
-def cmd_slow(a):
-    from apex_tpu.telemetry import tracing
-    from benchmarks.lib import common, serve, traffic as traffic_mod
-
-    t_start = time.perf_counter()
-    bench = common.benchmark_json()
-    cell = next(w for w in bench["workloads"]
-                if w["name"] == "gpt2l.serve.backlog")
-    cfg = common.load_json(common.ROOT, next(
-        c["file"] for c in bench["configs"] if c["name"] == cell["config"]))
-    tr = common.load_json(common.BENCH_DIR, "traffic",
-                          cell["traffic"] + ".json")
-    common.require_chip(1, not a.cpu)
-    common.enable_compile_cache()
-    if a.cpu:
-        from benchmarks.checks import tiny, tiny_serve
-        cfg, tr = dict(tiny.TINY_SERVE_CFG), tiny_serve.serve_traffic()
-        tr["feed"], tr["rate_per_s"] = "as_queue_has_room", 0
-    schedule = traffic_mod.schedule(tr, a.seed, int(cfg["vocab_size"]))
-    engine, sched = serve.build_engine(cfg, tr, a.seed)
-    # the lead: what a server with no request yet does
-    t_lead = time.perf_counter()
-    if a.lead == "spin":
-        while time.perf_counter() - t_lead < 2.0:
-            sched.step()
-            time.sleep(1e-3)
-    else:
-        time.sleep(2.0)
-    loop = serve.Loop(engine, sched, schedule, tr)
-    warm = loop.Request(prompt=[1] * 16, max_new_tokens=2, temperature=0.0)
-    sched.submit(warm)
-    while not warm.status.terminal:
-        sched.step()
-    loop.start()
-    while loop.slots_used < int(tr["engine"]["slots"]):
-        loop.beat()
-    t0 = time.perf_counter()
-    tok0 = engine.tokens_generated
-    while loop.beat() - t0 < a.seconds:
-        pass
-    t1 = time.perf_counter()
-    ring = tracing.phases
-    beats = [b for b in ring.records(name="serve.beat") if b.t0 >= t0]
-    chunky = chunk_beats(ring, t0)
-    plain = [b for b in beats if b.id not in chunky]
-    res = {"probe": "slow", "lead": a.lead, "seed": a.seed,
-           "beats": len(beats), "beats_no_chunk": len(plain),
-           "tokens_per_s": (engine.tokens_generated - tok0) / (t1 - t0),
-           "setup_s": t0 - t_start,
-           "no_chunk": phase_table(ring, plain),
-           "with_chunk": phase_table(
-               ring, [b for b in beats if b.id in chunky])}
-    per_launch = {}
-    for r in ring.records(since=t0):
-        if r.name in ("engine.launch", "engine.readback"):
-            per_launch.setdefault(f"{r.name}:{r.args['program']}",
-                                  []).append(r.dur * 1e3)
-    res["per_call_p50_ms"] = {k: _pct(v, 50) for k, v in per_launch.items()}
-    print_table(f"slow probe, lead {a.lead}, seed {a.seed}: beats without "
-                f"a chunk ({len(plain)})", res["no_chunk"])
-    res["placement"] = placement()
-    engine.close()
-    print(json.dumps(res))
-
-
-def placement():
-    """Where this process's threads last ran and how much CPU each has
-    used: a process is fast or slow for its whole life (PERF.md), and
-    which cores its runtime threads sit on is one thing that lasts as
-    long."""
-    out = {"affinity": sorted(os.sched_getaffinity(0)), "threads": []}
-    try:
-        for node in sorted(os.listdir("/sys/devices/system/node")):
-            if node.startswith("node") and node[4:].isdigit():
-                with open(f"/sys/devices/system/node/{node}/cpulist") as f:
-                    out.setdefault("numa", {})[node] = f.read().strip()
-    except OSError:
-        pass
-    for tid in os.listdir("/proc/self/task"):
-        try:
-            with open(f"/proc/self/task/{tid}/stat") as f:
-                stat = f.read()
-        except OSError:
-            continue
-        comm = stat[stat.index("(") + 1:stat.rindex(")")]
-        rest = stat[stat.rindex(")") + 2:].split()
-        ticks = int(rest[11]) + int(rest[12])        # utime + stime
-        if ticks:
-            out["threads"].append([comm, int(rest[36]), ticks])
-    out["threads"].sort(key=lambda t: -t[2])
-    out["threads"] = out["threads"][:12]
-    return out
-
-
 def cmd_run(a):
     from apex_tpu.telemetry import tracing
     from benchmarks import run
 
     tracing.phases.enabled = bool(a.ring)
-    try:
-        run.main(a.rest)
-    finally:
-        print(f"[probe] placement {json.dumps(placement())}",
-              file=sys.stderr)
+    run.main(a.rest)
 
 
 def cmd_micro(a):
@@ -383,9 +280,6 @@ def cmd_fleet(a):
             # a benchmark run under a probe: its own result line
             if ln.startswith('{"correct"'):
                 rec["bench_result"] = json.loads(ln)
-        for ln in p.stderr.splitlines():
-            if ln.startswith("[probe] placement "):
-                rec["placement"] = json.loads(ln[len("[probe] placement "):])
         if p.returncode != 0:
             rec["stderr_tail"] = p.stderr[-1500:]
         with open(path, "a") as f:
@@ -407,11 +301,6 @@ def main():
     t.add_argument("--tiny", choices=("serve", "train"), default=None)
     t.add_argument("--seed", type=int, required=True)
     t.add_argument("--seconds", type=float, default=45)
-    s = sub.add_parser("slow")
-    s.add_argument("--lead", choices=("spin", "sleep"), required=True)
-    s.add_argument("--seed", type=int, default=61)
-    s.add_argument("--seconds", type=float, default=20)
-    s.add_argument("--cpu", type=int, default=0)
     r = sub.add_parser("run")
     r.add_argument("--ring", type=int, choices=(0, 1), required=True)
     r.add_argument("rest", nargs=argparse.REMAINDER)
@@ -422,8 +311,8 @@ def main():
     a = ap.parse_args()
     if getattr(a, "rest", None) and a.rest[0] == "--":
         a.rest = a.rest[1:]
-    {"table": cmd_table, "slow": cmd_slow, "run": cmd_run,
-     "micro": cmd_micro, "fleet": cmd_fleet}[a.cmd](a)
+    {"table": cmd_table, "run": cmd_run, "micro": cmd_micro,
+     "fleet": cmd_fleet}[a.cmd](a)
 
 
 if __name__ == "__main__":

@@ -57,3 +57,44 @@ def test_lengths_fit_the_engine(mix):
         assert spec["prompt"]["min"] <= p <= spec["prompt"]["max"]
         assert 1 <= o <= spec["output"]["max"]
         assert p + o <= spec["engine"]["max_len"]
+
+
+PARENT_640 = "24c9b255532516402484cf86b354c55f901c5fc9aea8960ac5c3f150d595f2ee"
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_a_longer_schedule_keeps_its_first_requests(mix):
+    """40 blocks open with the 640 requests that 10 blocks were, letter
+    for letter: a window meets the requests it met before the schedule
+    grew. The backlog's are held to the digest of PR 26's schedule."""
+    import hashlib
+    import json
+
+    spec = _spec(mix)
+    assert spec["blocks"] == 40
+    full = traffic.schedule(spec, 2147483999, 50257)
+    short = traffic.schedule(dict(spec, blocks=10), 2147483999, 50257)
+    assert len(short) == 640 and full[:640] == short
+    if mix == "serve.backlog":
+        assert hashlib.sha256(json.dumps(short).encode()
+                              ).hexdigest() == PARENT_640
+
+
+def test_the_chat_rate_is_the_share_its_why_states():
+    """``rate_per_s`` of the chat mix is a share of what the backlog cell
+    completes; the cell's ``why`` states the rate, the share and that
+    completion rate, and the three agree with the file."""
+    import re
+
+    spec = _spec("serve.chat")
+    cell = next(w for w in common.benchmark_json()["workloads"]
+                if w["traffic"] == "serve.chat")
+    m = re.search(r"at ([\d.]+) req/s = ([\d.]+) of the ([\d.]+) req/s",
+                  cell["why"])
+    assert m, cell["why"]
+    rate, share, backlog = map(float, m.groups())
+    assert rate == spec["rate_per_s"]
+    assert share == spec["rate_share_of_backlog"]
+    assert backlog == spec["backlog_req_per_s"]
+    assert 0.6 <= share <= 0.8
+    assert rate == pytest.approx(share * backlog, abs=0.006)

@@ -172,11 +172,14 @@ def memory_peak_bytes(in_window: int = 0, program_temp: int = 0) -> int:
 
 
 def emit_result(*, bench, cell, trace, correct, attempted, failed, values,
-                device, compared, extra_device=None, breakdown=None):
+                device, compared, extra_device=None, breakdown=None,
+                extra=None):
     """Print each number compared beside its limit (standard error, last
     lines), then the one result object as the last line of standard
     output. ``values`` maps metric name -> number for whatever was read;
-    a reader that found nothing left its metric out."""
+    a reader that found nothing left its metric out. ``extra``: further
+    keys of the line, which the driver ignores (the schedule's use,
+    the engine's own seconds per beat)."""
     defs = bench["per_layer"] if trace else bench["end_to_end"]
     metrics = {}
     for m in defs:
@@ -195,6 +198,7 @@ def emit_result(*, bench, cell, trace, correct, attempted, failed, values,
             "failed": int(failed), "metrics": metrics, "device": dev}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line.update(extra or {})
     line["compared"] = compared
     sys.stdout.flush()
     for name, c in compared.items():

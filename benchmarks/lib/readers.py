@@ -1,7 +1,9 @@
 """The reader kinds a per-layer metric's file may name. Each metric is a
 file ``benchmarks/metrics/<name>.json`` with a ``kind`` and that kind's
 parameters; a later PR adds a metric of a kind that is here as one new
-file and one new entry of ``BENCHMARK.json``.
+file and one new entry of ``BENCHMARK.json``; a variant of a metric
+that is here (``<name>.<variant>``, for cells that report another
+end-to-end metric) is one new entry alone.
 
 A reader is given the run's context (counters and series the driver
 collected, the reduced trace, the configuration, the traffic, the peaks)
@@ -13,6 +15,7 @@ of a peak is never reported as 0.
 from __future__ import annotations
 
 import importlib
+import os
 
 from . import common, flops
 
@@ -107,6 +110,18 @@ KINDS = {"counter": counter, "span_stat": span_stat, "rate_mfu": rate_mfu,
          "kernel_roofline": kernel_roofline}
 
 
+def _spec(name: str):
+    """``metrics/<name>.json``; a quantity split by the end-to-end metric
+    its cells report (``decode_prog_ms_p50.chat``) reads the one file of
+    the quantity, ``metrics/decode_prog_ms_p50.json``, unless the variant
+    brings a file of its own."""
+    for stem in (name, name.split(".", 1)[0]):
+        path = os.path.join(common.BENCH_DIR, "metrics", stem + ".json")
+        if os.path.exists(path):
+            return common.load_json(path)
+    raise FileNotFoundError(f"no benchmarks/metrics file for {name!r}")
+
+
 def read_all(bench, cell, ctx):
     """Every per-layer metric that lists this cell (or lists none)."""
     out = {}
@@ -114,8 +129,7 @@ def read_all(bench, cell, ctx):
         cells = m.get("workloads")
         if cells is not None and cell not in cells:
             continue
-        spec = common.load_json(common.BENCH_DIR, "metrics",
-                                m["name"] + ".json")
+        spec = _spec(m["name"])
         v = _by_name(spec["kind"], KINDS)(ctx, spec)
         if v is not None:
             out[m["name"]] = v

@@ -41,7 +41,7 @@ _NULL = contextlib.nullcontext()
 
 class _Tracked:
     __slots__ = ("req", "due", "submitted", "seen", "chunks", "token_t",
-                 "first_t", "done_t")
+                 "first_t", "done_t", "admit_t")
 
     def __init__(self, req, due):
         self.req, self.due = req, due
@@ -51,6 +51,7 @@ class _Tracked:
         self.token_t = []
         self.first_t = None
         self.done_t = None
+        self.admit_t = None     # start of the beat that gave it a slot
 
 
 def build_engine(cfg, tr, seed, registry=None):
@@ -153,6 +154,11 @@ class Loop:
             ctx_sum, n_dec, chunks, still = 0, 0, [], []
             for tk in self.live:
                 r = tk.req
+                if tk.admit_t is None:
+                    if r.status == "queued":
+                        still.append(tk)
+                        continue
+                    tk.admit_t = t_a
                 plen = len(r.prompt)
                 if r.chunks > tk.chunks:
                     for c in range(tk.chunks, r.chunks):
@@ -255,7 +261,10 @@ def run(cell, cfg, tr, args, bench, *, device_check=True, fault=None,
     out = common.out_dir(cell["name"])
     compiles = common.CompileCounter()
     V = int(cfg["vocab_size"])
+    t_s = time.perf_counter()
     schedule = traffic_mod.schedule(tr, args.seed, V)
+    common.log(f"schedule of {len(schedule)} requests made in "
+               f"{time.perf_counter() - t_s:.2f}s")
     engine, sched = build_engine(cfg, tr, args.seed)
     if fault is not None:
         _plant(engine, fault)
@@ -306,11 +315,13 @@ def run(cell, cfg, tr, args, bench, *, device_check=True, fault=None,
         n0 = compiles.n
         live0, waiting0 = len(loop.live), sum(
             1 for tk in loop.live if tk.first_t is None)
+        calls0 = _runtime_seconds(engine)
         t0 = time.perf_counter()
         setup_s = t0 - common.T_PROCESS_START
         while loop.beat() - t0 < args.seconds:
             pass
         t1 = loop.beats[-1][1]
+        calls1 = _runtime_seconds(engine)
         compiles_in_window = compiles.n - n0
     live1, waiting1 = len(loop.live), sum(
         1 for tk in loop.live if tk.first_t is None)
@@ -332,11 +343,22 @@ def run(cell, cfg, tr, args, bench, *, device_check=True, fault=None,
                          "window; raise 'blocks' in the traffic file")
 
     tokens, gaps, ttft, failed, attempted = window_metrics(loop, t0, t1)
+    # first tokens of the window again, from the start of the beat that
+    # gave the request its slot: what prefill costs, whatever the queue
+    ttft_admit = [(tk.first_t - tk.admit_t) * 1e3 for tk in loop.all
+                  if tk.first_t is not None and t0 < tk.first_t <= t1]
     window = t1 - t0
+    used = sum(1 for tk in loop.all if t0 <= tk.submitted < t1)
+    use = {"submitted_in_window": used, "left": len(loop.todo) - loop.next}
     sample = [(list(tk.req.prompt), list(tk.req.output_tokens),
                tk.req.max_new_tokens) for tk in sample_finished(
                    loop, t0, t1, int(tr["check"]["sample"]), args.seed)]
     beats = [b for b in loop.beats if t0 < b[1] <= t1]
+    # what the engine's own counters say a beat of the window spent in
+    # the runtime's calls: how this process differs from the next, as the
+    # program saw it (a key of the line for diagnosis, never a metric)
+    per_beat = {k: (calls1[k] - calls0[k]) * 1e3 / len(beats)
+                for k in calls0}
     traced_beats = [b for b in loop.beats
                     if traced and traced[0] <= b[0] and b[1] <= traced[1]]
     late_ms = [(tk.submitted - loop.t_base - tk.due) * 1e3
@@ -362,7 +384,9 @@ def run(cell, cfg, tr, args, bench, *, device_check=True, fault=None,
                f"{beats[worst][1] - t0:.2f}s (median "
                f"{np.median(bw) * 1e3:.1f} ms); submitted and not ended "
                f"{live0} as it opened ({waiting0} before their first "
-               f"token), {live1} ({waiting1}) as it closed")
+               f"token), {live1} ({waiting1}) as it closed; the window "
+               f"submitted {used} requests of the schedule, "
+               f"{use['left']} are left")
     inf = float("inf")
     ttft_clean = [1e9 if t == inf else t for t in ttft]
     if ttft_clean:
@@ -370,9 +394,13 @@ def run(cell, cfg, tr, args, bench, *, device_check=True, fault=None,
                    f"{common.percentile(ttft_clean, 50):.0f} ms, p90 "
                    f"{common.percentile(ttft_clean, 90):.0f} ms over "
                    f"{len(ttft_clean)}")
-    values = {"serve_tokens_per_s": tokens / window,
-              "itl_p95_ms": common.percentile(gaps, 95) if gaps else None,
-              "setup_s": setup_s}
+    # one tail, two names: a cell below capacity reports it as
+    # ``itl_p95_chat_ms`` under a bound of its own (an open loop's tail
+    # swings more than a full server's); which a cell reports is
+    # BENCHMARK.json's to say
+    itl_p95 = common.percentile(gaps, 95) if gaps else None
+    values = {"serve_tokens_per_s": tokens / window, "itl_p95_ms": itl_p95,
+              "itl_p95_chat_ms": itl_p95, "setup_s": setup_s}
     events = []
     for b in beats:
         events += [("chunk",) + c for c in b[5]]
@@ -411,7 +439,8 @@ def run(cell, cfg, tr, args, bench, *, device_check=True, fault=None,
         ctx = {"counters": {"compiles_in_window": compiles_in_window},
                "series": {"beat_host_ms": [b[2] * 1e3 for b in beats],
                           "gen_late_ms": late_ms,
-                          "ttft_ms": ttft_clean},
+                          "ttft_ms": ttft_clean,
+                          "ttft_admit_ms": ttft_admit},
                "rates": {"model_flops_per_s": model_flops / window},
                "cfg": cfg, "traffic": tr, "peaks": device["peaks"],
                "chips": cell["chips"], "trace": tr_,
@@ -427,8 +456,15 @@ def run(cell, cfg, tr, args, bench, *, device_check=True, fault=None,
     common.emit_result(bench=bench, cell=cell["name"], trace=args.trace,
                        correct=ok, attempted=attempted, failed=failed,
                        values=values, device=device, compared=compared,
-                       extra_device=extra, breakdown=breakdown)
+                       extra_device=extra, breakdown=breakdown,
+                       extra={"schedule": use,
+                              "engine_ms_per_beat": per_beat})
     return ok
+
+
+def _runtime_seconds(engine):
+    return {"upload": engine.upload_s, "launch": engine.launch_s,
+            "readback": engine.readback_s}
 
 
 def _plant(engine, fault):
