@@ -79,11 +79,31 @@ DEFAULT_BLOCK_K = 256
 
 
 # --------------------------------------------------------------- jnp reference
+def _group_size(name, h, h_kv):
+    """Query heads per K/V head (grouped-query attention): query head
+    ``i`` reads K/V head ``i // G``. 1 is plain multi-head attention."""
+    if h_kv < 1 or h % h_kv:
+        raise ValueError(f"{name}: {h} query heads are not a multiple of "
+                         f"{h_kv} K/V heads")
+    return h // h_kv
+
+
+def _repeat_kv_heads(h, k, v):
+    """``k``/``v`` ``[b, h_kv, L, d]`` with each K/V head repeated for the
+    query heads of its group (the references' way; the kernels index)."""
+    G = h // k.shape[1]
+    if G == 1:
+        return k, v
+    return jnp.repeat(k, G, axis=1), jnp.repeat(v, G, axis=1)
+
+
 def decode_attention_reference(q, k, v, lengths, *, scale: float = 1.0,
                                k_scale=None, v_scale=None):
     """fp32-math oracle: masked softmax over the valid cache prefix.
 
-    ``q`` [b, h, d]; ``k``/``v`` [b, h, L, d]; ``lengths`` [b] int32.
+    ``q`` [b, h, d]; ``k``/``v`` [b, h_kv, L, d] with ``h_kv`` dividing
+    ``h`` (grouped heads: query head ``i`` reads K/V head ``i // (h //
+    h_kv)``); ``lengths`` [b] int32.
     Returns [b, h, d] in ``q.dtype``; rows with ``lengths == 0`` are 0.
     ``k_scale``/``v_scale`` ([h] fp32) are the quantized-cache tier's
     per-head dequantization scales: when given, ``k``/``v`` hold int8
@@ -97,6 +117,7 @@ def decode_attention_reference(q, k, v, lengths, *, scale: float = 1.0,
         k32 = k32 * jnp.asarray(k_scale, jnp.float32)[None, :, None, None]
     if v_scale is not None:
         v32 = v32 * jnp.asarray(v_scale, jnp.float32)[None, :, None, None]
+    k32, v32 = _repeat_kv_heads(q.shape[1], k32, v32)
     s = jnp.einsum("bhd,bhld->bhl", q32, k32) * scale
     L = k.shape[2]
     valid = (jnp.arange(L, dtype=jnp.int32)[None, None, :]
@@ -109,8 +130,11 @@ def decode_attention_reference(q, k, v, lengths, *, scale: float = 1.0,
 
 
 # -------------------------------------------------------------------- kernel
-def _decode_kernel(len_ref, *refs, scale, block_k, quant):
-    """Grid (bh, nk): one batch·head row, blockwise over cached KV.
+def _decode_kernel(len_ref, *refs, scale, block_k, quant, G=1):
+    """Grid (bh, nk): one batch·K/V-head row, blockwise over cached KV;
+    the ``G`` query heads of the row's group are the rows of one
+    ``[G, d] x [d, block_k]`` product against the one fetched block
+    (``G`` = 1: plain multi-head attention, one query row).
 
     Online softmax identical to the training forward kernel's (m, l)
     recurrence, with the causal tile-skip replaced by a length skip:
@@ -142,11 +166,11 @@ def _decode_kernel(len_ref, *refs, scale, block_k, quant):
 
     @pl.when(ki * block_k < length)
     def _body():
-        q = q_ref[0].astype(jnp.float32)                      # [1, d]
+        q = q_ref[0].astype(jnp.float32)                      # [G, d]
         k = k_ref[0].astype(jnp.float32)                      # [bk, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [1, bk]
+            preferred_element_type=jnp.float32) * scale       # [G, bk]
         if quant:
             # dequant-in-kernel: the per-head K scale is constant over
             # the row, so it factors out of the int8 dot product
@@ -154,35 +178,42 @@ def _decode_kernel(len_ref, *refs, scale, block_k, quant):
         cols = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
         s = jnp.where(cols < length, s, _NEG_INF)
-        m_prev = m_ref[:1, :1]                                # [1, 1]
+        m_prev = m_ref[:G, :1]                                # [G, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                                # [1, bk]
+        p = jnp.exp(s - m_new)                                # [G, bk]
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:1, :1] = alpha * l_ref[:1, :1] + jnp.sum(
+        l_ref[:G, :1] = alpha * l_ref[:G, :1] + jnp.sum(
             p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         if quant:
             pv = pv * vs_ref[b]
-        acc_ref[:1, :] = acc_ref[:1, :] * alpha + pv
-        m_ref[:1, :1] = m_new
+        acc_ref[:G, :] = acc_ref[:G, :] * alpha + pv
+        m_ref[:G, :1] = m_new
 
     @pl.when(ki == nk - 1)
     def _finish():
-        l = l_ref[:1, :1]
+        l = l_ref[:G, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:1, :] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:G, :] / l_safe).astype(o_ref.dtype)
+
+
+def _scratch_rows(G):
+    """Sublane rows of the (acc, m, l) scratch: the group's ``G`` live
+    rows rounded up to a whole tile of 8."""
+    return -(-G // 8) * 8
 
 
 def _decode_pallas(q3, k3, v3, len3, scale, bk, interpret, ks3=None,
                    vs3=None):
-    bh, d = q3.shape
+    bh, G, d = q3.shape              # rows: batch x K/V heads; G per group
     L = k3.shape[1]
     quant = ks3 is not None
     kernel = functools.partial(_decode_kernel, scale=scale, block_k=bk,
-                               quant=quant)
+                               quant=quant, G=G)
+    R = _scratch_rows(G)
     scale_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2 \
         if quant else []
     scale_ops = (ks3, vs3) if quant else ()
@@ -192,20 +223,20 @@ def _decode_pallas(q3, k3, v3, len3, scale, bk, interpret, ks3=None,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                # lengths
             *scale_specs,                         # k/v dequant scales
-            pl.BlockSpec((1, 1, d), lambda b, j: (b, 0, 0)),      # q
+            pl.BlockSpec((1, G, d), lambda b, j: (b, 0, 0)),      # q
             pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),     # k
             pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),     # v
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q3.dtype),
+        out_specs=pl.BlockSpec((1, G, d), lambda b, j: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((bh, G, d), q3.dtype),
         scratch_shapes=[
-            pltpu.VMEM((8, d), jnp.float32),      # acc (row 0 live)
-            pltpu.VMEM((8, 128), jnp.float32),    # m
-            pltpu.VMEM((8, 128), jnp.float32),    # l
+            pltpu.VMEM((R, d), jnp.float32),      # acc (rows :G live)
+            pltpu.VMEM((R, 128), jnp.float32),    # m
+            pltpu.VMEM((R, 128), jnp.float32),    # l
         ],
         interpret=interpret, name="decode_attention",
-    )(len3, *scale_ops, q3.reshape(bh, 1, d), k3, v3)
-    return out.reshape(bh, d)
+    )(len3, *scale_ops, q3, k3, v3)
+    return out
 
 
 # ------------------------------------------------------------------ dispatch
@@ -236,8 +267,11 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
                      interpret: bool = False):
     """Single-token attention against a length-masked KV cache.
 
-    ``q`` [batch, heads, head_dim]; ``k``/``v`` [batch, heads, max_len,
-    head_dim] (the serving cache's per-layer view); ``lengths`` [batch]
+    ``q`` [batch, heads, head_dim]; ``k``/``v`` [batch, kv_heads, max_len,
+    head_dim] (the serving cache's per-layer view; ``kv_heads`` divides
+    ``heads`` — grouped-query attention, query head ``i`` reading K/V
+    head ``i // (heads // kv_heads)``, the group's query heads sharing
+    each fetched block); ``lengths`` [batch]
     int32 — positions ``[0, lengths[b])`` are attended, everything past
     is masked. The current token's own K/V must already be written at
     position ``lengths[b] - 1`` (the serving engine's write-then-attend
@@ -261,14 +295,15 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
     clamped to the largest aligned divisor of ``max_len``).
     """
     b, h, d = q.shape
-    L = k.shape[2]
-    if k.shape != (b, h, L, d) or v.shape != k.shape:
+    h_kv, L = k.shape[1], k.shape[2]
+    if k.shape != (b, h_kv, L, d) or v.shape != k.shape:
         raise ValueError(f"decode_attention: k/v {k.shape}/{v.shape} do "
                          f"not match q {q.shape} + max_len")
+    G = _group_size("decode_attention", h, h_kv)
     if lengths.shape != (b,):
         raise ValueError(f"decode_attention: lengths {lengths.shape} must "
                          f"be [{b}]")
-    _check_head_scales("decode_attention", h, k_scale, v_scale)
+    _check_head_scales("decode_attention", h_kv, k_scale, v_scale)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     from apex_tpu.kernels.flash_attention import _fit_block, _has_vma
@@ -281,10 +316,10 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
         return decode_attention_reference(q, k, v, lengths, scale=scale,
                                           k_scale=k_scale,
                                           v_scale=v_scale)
-    q3 = q.reshape(b * h, d)
-    k3 = k.reshape(b * h, L, d)
-    v3 = v.reshape(b * h, L, d)
-    len3 = jnp.repeat(jnp.asarray(lengths, jnp.int32), h)
+    q3 = q.reshape(b * h_kv, G, d)
+    k3 = k.reshape(b * h_kv, L, d)
+    v3 = v.reshape(b * h_kv, L, d)
+    len3 = jnp.repeat(jnp.asarray(lengths, jnp.int32), h_kv)
     ks3 = vs3 = None
     if k_scale is not None:
         # flattened bh rows walk heads fastest: row b*h + hh -> head hh
@@ -400,9 +435,11 @@ def paged_decode_attention_reference(q, k_pool, v_pool, page_table,
 
 
 def _paged_decode_kernel(pt_ref, len_ref, *refs, scale, page_len, quant,
-                         kt=False):
-    """Grid (b, h, max_pages): one batch row x head, one pool page per
-    step. The (m, l) recurrence is :func:`_decode_kernel`'s; the page
+                         kt=False, G=1):
+    """Grid (b, h_kv, max_pages): one batch row x K/V head, one pool page
+    per step; the ``G`` query heads of the head's group are the rows of
+    one ``[G, d] x [d, page_len]`` product against the one fetched page
+    (``G`` = 1: plain multi-head attention, one query row). The (m, l) recurrence is :func:`_decode_kernel`'s; the page
     the DMA fetched was chosen by the scalar-prefetch index map
     (``pt_ref[b, j]``), so the kernel body only needs the length skip/
     mask on GLOBAL positions ``j * page_len + lane``. ``quant``
@@ -430,36 +467,36 @@ def _paged_decode_kernel(pt_ref, len_ref, *refs, scale, page_len, quant,
 
     @pl.when(j * page_len < length)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)                   # [1, d]
+        q = q_ref[0, 0].astype(jnp.float32)                   # [G, d]
         k = k_ref[0, 0].astype(jnp.float32)       # [pl, d] (kt: [d, pl])
         s = jax.lax.dot_general(
             q, k, qk_dims,
-            preferred_element_type=jnp.float32) * scale       # [1, pl]
+            preferred_element_type=jnp.float32) * scale       # [G, pl]
         if quant:
             s = s * ks_ref[hh]
         cols = j * page_len + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_len), 1)
         s = jnp.where(cols < length, s, _NEG_INF)
-        m_prev = m_ref[:1, :1]                                # [1, 1]
+        m_prev = m_ref[:G, :1]                                # [G, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                                # [1, pl]
+        p = jnp.exp(s - m_new)                                # [G, pl]
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:1, :1] = alpha * l_ref[:1, :1] + jnp.sum(
+        l_ref[:G, :1] = alpha * l_ref[:G, :1] + jnp.sum(
             p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p, v_ref[0, 0].astype(jnp.float32), pv_dims,
             preferred_element_type=jnp.float32)
         if quant:
             pv = pv * vs_ref[hh]
-        acc_ref[:1, :] = acc_ref[:1, :] * alpha + pv
-        m_ref[:1, :1] = m_new
+        acc_ref[:G, :] = acc_ref[:G, :] * alpha + pv
+        m_ref[:G, :1] = m_new
 
     @pl.when(j == nj - 1)
     def _finish():
-        l = l_ref[:1, :1]
+        l = l_ref[:G, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:1, :] / l_safe).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[:G, :] / l_safe).astype(o_ref.dtype)
 
 
 def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, scale,
@@ -467,16 +504,20 @@ def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, scale,
     B, h, d = q.shape
     kt = layer is not None           # stacked pool: pages [d, page_len]
     page_len = k_pool.shape[-1 if kt else -2]
+    h_kv = k_pool.shape[2 if kt else 1]
+    G = h // h_kv                    # query heads per K/V head
+    R = _scratch_rows(G)
     max_pages = pt.shape[1]
     quant = ks is not None
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               page_len=page_len, quant=quant, kt=kt)
+                               page_len=page_len, quant=quant, kt=kt, G=G)
     # the dequant scales ride as two extra scalar-prefetch operands (the
     # variadic tail absorbs them — only the kernel body reads them).
-    # q/out carry a unit row axis ([B, h, 1, d]): Mosaic wants a block's
-    # last two dims to tile (8, 128) or EQUAL the array's, and a (1, d)
-    # block over [.., 1, d] is the latter where (1, d) over [.., h, d]
-    # is neither (the contiguous kernel's [bh, 1, d] does the same).
+    # q/out carry the group's rows as their own axis ([B, h_kv, G, d];
+    # G = 1 is a unit row axis): Mosaic wants a block's last two dims to
+    # tile (8, 128) or EQUAL the array's, and a (G, d) block over
+    # [.., G, d] is the latter where (1, d) over [.., h, d] is neither
+    # (the contiguous kernel's [bh, G, d] does the same).
     def _q_idx(b, hh, j, pt, ln, *_scales):
         return (b, hh, 0, 0)
 
@@ -487,24 +528,24 @@ def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, scale,
     n_prefetch, extra_ops = (4, (ks, vs)) if quant else (2, ())
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,   # page_table, lengths[, ks, vs]
-        grid=(B, h, max_pages),
+        grid=(B, h_kv, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, d), _q_idx),
+            pl.BlockSpec((1, 1, G, d), _q_idx),
             kv_spec,
             kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, d), _q_idx),
+        out_specs=pl.BlockSpec((1, 1, G, d), _q_idx),
         scratch_shapes=[
-            pltpu.VMEM((8, d), jnp.float32),      # acc (row 0 live)
-            pltpu.VMEM((8, 128), jnp.float32),    # m
-            pltpu.VMEM((8, 128), jnp.float32),    # l
+            pltpu.VMEM((R, d), jnp.float32),      # acc (rows :G live)
+            pltpu.VMEM((R, 128), jnp.float32),    # m
+            pltpu.VMEM((R, 128), jnp.float32),    # l
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, h, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, h_kv, G, d), q.dtype),
         interpret=interpret, name="paged_decode_attention",
-    )(pt, lengths, *extra_ops, q.reshape(B, h, 1, d), k_pool, v_pool)
+    )(pt, lengths, *extra_ops, q.reshape(B, h_kv, G, d), k_pool, v_pool)
     return out.reshape(B, h, d)
 
 
@@ -516,9 +557,12 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     """Single-token attention against a PAGED, length-masked KV pool.
 
     ``q`` [batch, heads, head_dim]; ``k_pool``/``v_pool``
-    [num_pages, heads, page_len, head_dim] (one layer of the serving
-    pool — pages are shared across batch rows), or the whole stacked
-    pool [layers, num_pages, heads, head_dim, page_len] with the static
+    [num_pages, kv_heads, page_len, head_dim] (one layer of the serving
+    pool — pages are shared across batch rows; ``kv_heads`` divides
+    ``heads``: grouped-query attention, query head ``i`` reading K/V
+    head ``i // (heads // kv_heads)``, a group's query heads sharing ONE
+    fetch of each page), or the whole stacked
+    pool [layers, num_pages, kv_heads, head_dim, page_len] with the static
     ``layer`` to attend (the serving engine's form, pages transposed:
     :func:`_layer_pool_shape` says why): the layer is then one more
     block index of the page DMA, so the serving programs hand over the
@@ -542,17 +586,18 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     B, h, d = q.shape
     P, hp, page_len, dp = _layer_pool_shape("paged_decode_attention",
                                             k_pool, v_pool, layer)
-    if hp != h or dp != d:
+    if dp != d:
         raise ValueError(f"paged_decode_attention: pools "
                          f"{k_pool.shape}/{v_pool.shape} do not match q "
                          f"{q.shape}")
+    _group_size("paged_decode_attention", h, hp)
     if page_table.ndim != 2 or page_table.shape[0] != B:
         raise ValueError(f"paged_decode_attention: page_table "
                          f"{page_table.shape} must be [{B}, max_pages]")
     if lengths.shape != (B,):
         raise ValueError(f"paged_decode_attention: lengths "
                          f"{lengths.shape} must be [{B}]")
-    _check_head_scales("paged_decode_attention", h, k_scale, v_scale)
+    _check_head_scales("paged_decode_attention", hp, k_scale, v_scale)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     from apex_tpu.kernels.flash_attention import _has_vma

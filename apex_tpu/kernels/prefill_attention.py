@@ -75,9 +75,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.kernels import mosaic_dtype_ok, vmem
 from apex_tpu.kernels.decode_attention import (_check_head_scales,
+                                               _group_size,
                                                _layer_pool_shape,
                                                _page_block_spec,
-                                               _page_dots, gather_pages)
+                                               _page_dots, _repeat_kv_heads,
+                                               gather_pages)
 
 __all__ = ["prefill_attention", "prefill_attention_reference",
            "paged_prefill_attention", "paged_prefill_attention_reference"]
@@ -92,7 +94,9 @@ def prefill_attention_reference(q, k, v, offsets, *, scale: float = 1.0,
                                 k_scale=None, v_scale=None):
     """fp32-math oracle: per-row shifted-causal softmax over the cache.
 
-    ``q`` [b, h, C, d]; ``k``/``v`` [b, h, L, d]; ``offsets`` [b] int32.
+    ``q`` [b, h, C, d]; ``k``/``v`` [b, h_kv, L, d] with ``h_kv``
+    dividing ``h`` (grouped heads: query head ``i`` reads K/V head ``i //
+    (h // h_kv)``); ``offsets`` [b] int32.
     Query row ``i`` attends cache positions ``j <= offsets[b] + i``.
     Returns [b, h, C, d] in ``q.dtype``. ``k_scale``/``v_scale`` ([h]
     fp32) dequantize an int8 cache before the exact math (the
@@ -105,6 +109,7 @@ def prefill_attention_reference(q, k, v, offsets, *, scale: float = 1.0,
         k32 = k32 * jnp.asarray(k_scale, jnp.float32)[None, :, None, None]
     if v_scale is not None:
         v32 = v32 * jnp.asarray(v_scale, jnp.float32)[None, :, None, None]
+    k32, v32 = _repeat_kv_heads(q.shape[1], k32, v32)
     s = jnp.einsum("bhqd,bhld->bhql", q32, k32) * scale
     C, L = q.shape[2], k.shape[2]
     rows = (offsets[:, None, None, None]
@@ -181,10 +186,13 @@ def _prefill_kernel(off_ref, *refs, scale, block_q, block_k, quant):
 
 
 def _prefill_pallas(q3, k3, v3, off3, scale, bq, bk, interpret,
-                    ks3=None, vs3=None):
+                    ks3=None, vs3=None, G=1):
     bh, C, d = q3.shape
     L = k3.shape[1]
     quant = ks3 is not None
+    # grouped heads: query row r (batch x query heads, heads fastest)
+    # reads K/V row r // G (batch x K/V heads); G = 1 keeps the plain map
+    kv_row = (lambda b: b) if G == 1 else (lambda b: b // G)
     kernel = functools.partial(_prefill_kernel, scale=scale, block_q=bq,
                                block_k=bk, quant=quant)
     scale_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2 \
@@ -197,8 +205,8 @@ def _prefill_pallas(q3, k3, v3, off3, scale, bq, bk, interpret,
             pl.BlockSpec(memory_space=pltpu.SMEM),                 # offsets
             *scale_specs,                          # k/v dequant scales
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),   # q
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),   # k
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),   # v
+            pl.BlockSpec((1, bk, d), lambda b, i, j: (kv_row(b), j, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, i, j: (kv_row(b), j, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, C, d), q3.dtype),
@@ -231,8 +239,10 @@ def prefill_attention(q, k, v, offsets, *, scale: Optional[float] = None,
 
     ``q`` [batch, heads, C, head_dim] — C consecutive prompt tokens whose
     K/V are already written into the cache at ``[offsets[b],
-    offsets[b] + C)``; ``k``/``v`` [batch, heads, max_len, head_dim] (the
-    serving cache's per-layer view); ``offsets`` [batch] int32. Query
+    offsets[b] + C)``; ``k``/``v`` [batch, kv_heads, max_len, head_dim]
+    (the serving cache's per-layer view; ``kv_heads`` divides ``heads``,
+    query head ``i`` reading K/V head ``i // (heads // kv_heads)``);
+    ``offsets`` [batch] int32. Query
     row ``i`` attends cache positions ``[0, offsets[b] + i]`` — the
     shifted-causal mask of chunked prefill. ``scale`` defaults to
     ``1/sqrt(head_dim)``.
@@ -249,14 +259,15 @@ def prefill_attention(q, k, v, offsets, *, scale: Optional[float] = None,
     aligned divisors of the chunk / cache lengths).
     """
     b, h, C, d = q.shape
-    L = k.shape[2]
-    if k.shape != (b, h, L, d) or v.shape != k.shape:
+    h_kv, L = k.shape[1], k.shape[2]
+    if k.shape != (b, h_kv, L, d) or v.shape != k.shape:
         raise ValueError(f"prefill_attention: k/v {k.shape}/{v.shape} do "
                          f"not match q {q.shape} + max_len")
+    G = _group_size("prefill_attention", h, h_kv)
     if offsets.shape != (b,):
         raise ValueError(f"prefill_attention: offsets {offsets.shape} "
                          f"must be [{b}]")
-    _check_head_scales("prefill_attention", h, k_scale, v_scale)
+    _check_head_scales("prefill_attention", h_kv, k_scale, v_scale)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     from apex_tpu.kernels.flash_attention import _fit_block, _has_vma
@@ -273,15 +284,17 @@ def prefill_attention(q, k, v, offsets, *, scale: Optional[float] = None,
                                            k_scale=k_scale,
                                            v_scale=v_scale)
     q3 = q.reshape(b * h, C, d)
-    k3 = k.reshape(b * h, L, d)
-    v3 = v.reshape(b * h, L, d)
+    k3 = k.reshape(b * h_kv, L, d)
+    v3 = v.reshape(b * h_kv, L, d)
     off3 = jnp.repeat(jnp.asarray(offsets, jnp.int32), h)
     ks3 = vs3 = None
     if k_scale is not None:
-        ks3 = jnp.tile(jnp.asarray(k_scale, jnp.float32), b)
-        vs3 = jnp.tile(jnp.asarray(v_scale, jnp.float32), b)
+        # one scale per flattened QUERY row: its K/V head's
+        per_q = (lambda t: t) if G == 1 else (lambda t: jnp.repeat(t, G))
+        ks3 = jnp.tile(per_q(jnp.asarray(k_scale, jnp.float32)), b)
+        vs3 = jnp.tile(per_q(jnp.asarray(v_scale, jnp.float32)), b)
     out = _prefill_pallas(q3, k3, v3, off3, scale, bq, bk, interpret,
-                          ks3, vs3)
+                          ks3, vs3, G)
     return out.reshape(b, h, C, d).astype(q.dtype)
 
 
@@ -304,14 +317,16 @@ def paged_prefill_attention_reference(q, k_pool, v_pool, page_table,
 
 
 def _paged_prefill_kernel(pt_ref, off_ref, *refs, scale, block_q,
-                          page_len, quant, kt=False):
+                          page_len, quant, kt=False, G=1):
     """Grid (b, h, nq, max_pages): one batch row x head, q-blocked
     chunk, one pool page per KV step. :func:`_prefill_kernel`'s (m, l)
     recurrence and global-position shifted-causal mask; the page the
     DMA fetched was chosen by the scalar-prefetch index map. ``quant``
     (static) adds two scalar-prefetch scale refs and the fused per-head
     dequant multiplies. ``kt`` (static): the page blocks are
-    ``[d, page_len]``, the stacked pool's form."""
+    ``[d, page_len]``, the stacked pool's form. ``G`` (static): query
+    heads per K/V head; the grid walks QUERY heads and the index map
+    fetched K/V head ``hh // G``'s page."""
     qk_dims, pv_dims = _page_dots(kt)
     if quant:
         ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, \
@@ -320,6 +335,7 @@ def _paged_prefill_kernel(pt_ref, off_ref, *refs, scale, block_q,
         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
     hh = pl.program_id(1)
+    kv = hh if G == 1 else hh // G    # the K/V head of this query head
     qi = pl.program_id(2)
     ji = pl.program_id(3)
     nj = pl.num_programs(3)
@@ -340,7 +356,7 @@ def _paged_prefill_kernel(pt_ref, off_ref, *refs, scale, block_q,
             q, k, qk_dims,
             preferred_element_type=jnp.float32) * scale      # [bq, pl]
         if quant:
-            s = s * ks_ref[hh]
+            s = s * ks_ref[kv]
         rows = offset + qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, page_len), 0)
         cols = ji * page_len + jax.lax.broadcasted_iota(
@@ -356,7 +372,7 @@ def _paged_prefill_kernel(pt_ref, off_ref, *refs, scale, block_q,
             p, v_ref[0, 0].astype(jnp.float32), pv_dims,
             preferred_element_type=jnp.float32)
         if quant:
-            pv = pv * vs_ref[hh]
+            pv = pv * vs_ref[kv]
         acc_ref[:] = acc_ref[:] * alpha + pv
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -375,9 +391,11 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pt, offsets, scale, bq,
     page_len = k_pool.shape[-1 if kt else -2]
     max_pages = pt.shape[1]
     quant = ks is not None
+    G = h // k_pool.shape[2 if kt else 1]    # query heads per K/V head
+    kv_head = (lambda hh: hh) if G == 1 else (lambda hh: hh // G)
     kernel = functools.partial(_paged_prefill_kernel, scale=scale,
                                block_q=bq, page_len=page_len,
-                               quant=quant, kt=kt)
+                               quant=quant, kt=kt, G=G)
 
     # the dequant scales ride as two extra scalar-prefetch operands;
     # the index maps' variadic tails absorb them (only the kernel body
@@ -397,7 +415,7 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pt, offsets, scale, bq,
         # j <= last, so the clamp never changes what the compute reads
         # (outputs stay bitwise identical to the oracle).
         last = (off[b] + (C - 1)) // page_len
-        return (pt[b, jnp.minimum(j, last)], hh, 0, 0)
+        return (pt[b, jnp.minimum(j, last)], kv_head(hh), 0, 0)
 
     kv_spec = _page_block_spec(page_len, d, _kv_page, layer)
     n_prefetch, extra_ops = (4, (ks, vs)) if quant else (2, ())
@@ -442,8 +460,10 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
     ``q`` [batch, heads, C, head_dim] — C consecutive prompt tokens
     whose K/V are already written into the pool at logical positions
     ``[offsets[b], offsets[b] + C)`` of row ``b``'s pages; ``k_pool``/
-    ``v_pool`` [num_pages, heads, page_len, head_dim] (one layer of the
-    serving pool), or the whole stacked pool [layers, num_pages, heads,
+    ``v_pool`` [num_pages, kv_heads, page_len, head_dim] (one layer of
+    the serving pool; ``kv_heads`` divides ``heads``, query head ``i``
+    reading K/V head ``i // (heads // kv_heads)``), or the whole stacked
+    pool [layers, num_pages, kv_heads,
     head_dim, page_len] with the static ``layer`` to attend (the
     serving engine's form, pages transposed; the layer is one more
     block index of the page DMA — no layer is sliced out);
@@ -474,17 +494,18 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
     B, h, C, d = q.shape
     P, hp, page_len, dp = _layer_pool_shape("paged_prefill_attention",
                                             k_pool, v_pool, layer)
-    if hp != h or dp != d:
+    if dp != d:
         raise ValueError(f"paged_prefill_attention: pools "
                          f"{k_pool.shape}/{v_pool.shape} do not match q "
                          f"{q.shape}")
+    _group_size("paged_prefill_attention", h, hp)
     if page_table.ndim != 2 or page_table.shape[0] != B:
         raise ValueError(f"paged_prefill_attention: page_table "
                          f"{page_table.shape} must be [{B}, max_pages]")
     if offsets.shape != (B,):
         raise ValueError(f"paged_prefill_attention: offsets "
                          f"{offsets.shape} must be [{B}]")
-    _check_head_scales("paged_prefill_attention", h, k_scale, v_scale)
+    _check_head_scales("paged_prefill_attention", hp, k_scale, v_scale)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     from apex_tpu.kernels.flash_attention import _fit_block, _has_vma

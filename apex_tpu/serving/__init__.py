@@ -200,7 +200,7 @@ from .faults import (FaultPlan, FaultPolicy, FaultSpec, InjectedFault,
 from .fleet import FleetController, WorkerDied
 from .host_tier import (HostTier, SwapWorker, record_from_wire,
                         record_to_wire)
-from .kv_cache import KVCache, PagedKVCache, PagePool
+from .kv_cache import KVCache, PagedKVCache, PagePool, SlotState
 from .kv_quant import KVQuantConfig
 from .lora import LoRAConfig, LoRAManager
 from .prefix_cache import PrefixCache, PrefixMatch
@@ -217,7 +217,7 @@ __all__ = ["DeadlineUnmeetable", "DraftWorker", "Engine", "FaultPlan",
            "FaultPolicy",
            "FaultSpec", "FleetController", "HostTier", "InjectedFault",
            "KVCache", "KVQuantConfig", "LoRAConfig", "LoRAManager",
-           "PagedKVCache", "PagePool",
+           "PagedKVCache", "PagePool", "SlotState",
            "PendingDecode", "PoolAuditor", "PoolInvariantError",
            "PrefixCache", "PrefixMatch", "QueueFull", "Request",
            "RequestStatus", "Router", "SLOConfig", "Scheduler",

@@ -191,7 +191,7 @@ from apex_tpu.log_util import get_logger
 from apex_tpu.telemetry import tracing
 
 from .host_tier import HostTier, SwapWorker
-from .kv_cache import KVCache, PagedKVCache, PagePool
+from .kv_cache import KVCache, PagedKVCache, PagePool, SlotState
 from .kv_quant import KVQuantConfig, quantize
 from .prefix_cache import PrefixCache
 from .speculative import SpecConfig
@@ -525,7 +525,37 @@ class Engine:
         hidden = int(model.hidden)
         heads = int(model.num_heads)
         layers = int(model.num_layers)
-        head_dim = hidden // heads
+        # the pool's geometry is the MODEL's K/V geometry: its K/V heads
+        # (fewer than its query heads under grouped-query attention) and
+        # its own head size where it states one
+        kv_heads = int(getattr(model, "num_kv_heads", heads))
+        head_dim = int(getattr(model, "head_dim", hidden // heads))
+        # per-slot state beside the pages (kv_cache.SlotState): what is
+        # not built for it is refused by name HERE, never run wrong
+        self.slot_state_width = int(getattr(model, "slot_state_width", 0))
+        self.model_kind = str(getattr(model, "model_kind",
+                                      type(model).__name__))
+        if self.slot_state_width:
+            for on, what in (
+                    (prefix_pool > 0, "prefix_cache retention "
+                     "(prefix_pool > 0)"),
+                    (host_tier is not None, "host_tier swap"),
+                    (spec is not None, "speculative verify (the "
+                     "unaligned window)"),
+                    (lora is not None, "LoRA adapters"),
+                    (kv_quant is not None, "the int8 KV tier (kv_quant)"),
+                    (weight_quant is not None, "the int8 weight tier "
+                     "(weight_quant)"),
+                    (mesh is not None, "tensor parallelism (mesh=)"),
+                    (not paged, "the contiguous cache (paged=False)")):
+                if on:
+                    raise NotImplementedError(
+                        f"serving.Engine: {what} is not built for a model "
+                        f"with per-slot state ({self.model_kind!r}: "
+                        f"{self.slot_state_width} values a slot and "
+                        "layer beside its pages); it re-enters or "
+                        "re-shapes a request from pages alone, and the "
+                        "slot's state would be wrong")
         # quantized-cache storage tier (independent of the COMPUTE half
         # dtype the policy picks): int8 K/V with per-[layer, head] fp32
         # scales, resolved HERE so a degenerate calibration (absmax 0 /
@@ -642,10 +672,17 @@ class Engine:
                     f"the sentinel page")
             self.num_pages = num_pages
             if mesh is None:
+                state = None
+                if self.slot_state_width:
+                    state = SlotState.create(
+                        layers=layers, slots=self.slots,
+                        width=self.slot_state_width, dtype=half,
+                        num_experts=int(getattr(model, "num_experts", 0)))
                 self.cache = PagedKVCache.create(
-                    layers=layers, num_pages=num_pages, heads=heads,
+                    layers=layers, num_pages=num_pages, heads=kv_heads,
                     page_len=page_len, head_dim=head_dim,
-                    dtype=cache_dtype, k_scale=k_scale, v_scale=v_scale)
+                    dtype=cache_dtype, k_scale=k_scale, v_scale=v_scale,
+                    state=state)
             else:
                 # heads-axis pool sharding: each shard holds
                 # [layers, num_pages, heads/tp, head_dim, page_len] —
@@ -657,7 +694,7 @@ class Engine:
                 # scales shard ALONG the pool's heads axis
                 # ([layers, heads/tp] per shard), so each shard
                 # de/quantizes its own heads collective-free.
-                shape = (layers, num_pages, heads, head_dim, page_len)
+                shape = (layers, num_pages, kv_heads, head_dim, page_len)
                 pspec = _sharding.cache_pspec(self._tp_axis)
                 if k_scale is not None:
                     sspec = _sharding.scale_pspec(self._tp_axis)
@@ -693,7 +730,7 @@ class Engine:
             # them back out
             self.cache = KVCache.create(
                 layers=layers, slots=self.slots + self.prefix_pool,
-                heads=heads, max_len=self.max_len, head_dim=head_dim,
+                heads=kv_heads, max_len=self.max_len, head_dim=head_dim,
                 dtype=cache_dtype, k_scale=k_scale, v_scale=v_scale)
             self.prefix_cache = None if self.prefix_pool == 0 else \
                 PrefixCache(
@@ -836,13 +873,19 @@ class Engine:
             # tensor-parallel axis (params split per the rule table, the
             # pool on heads, every host operand replicated); mesh=None
             # wraps nothing — the verbatim single-chip programs
+            # a model with per-slot state runs the same three programs
+            # with the state threaded through (one more operand each)
+            stateful = bool(self.slot_state_width)
             self._jit_prefill = jax.jit(
+                self._state_prefill_impl if stateful else
                 self._tp_wrap(self._paged_prefill_impl, 2),
                 donate_argnums=(1,))
             self._jit_decode = jax.jit(
+                self._state_decode_impl if stateful else
                 self._tp_wrap(self._paged_decode_impl, 2),
                 donate_argnums=(1,))
             self._jit_chunk = jax.jit(
+                self._state_chunk_impl if stateful else
                 self._tp_wrap(self._paged_chunk_impl, 2),
                 donate_argnums=(1,))
             self._jit_verify = jax.jit(
@@ -1025,6 +1068,18 @@ class Engine:
             * np.dtype(c.dtype).itemsize * 2
         self._registry.gauge_set("serving.kv.bytes_per_token",
                                  float(per_token))
+        if getattr(c, "state", None) is not None:
+            # what a slot holds beside its pages, whatever its length
+            self._registry.gauge_set("serving.state.bytes_per_slot",
+                                     float(c.state.bytes_per_slot()))
+            self._registry.gauge_set("serving.kv.state_bytes",
+                                     float(c.state.nbytes()))
+            experts = c.state.expert_tokens.shape[1]
+            if experts:
+                held = getattr(self._model, "experts_held", None)
+                self._registry.gauge_set(
+                    "serving.moe.experts_held",
+                    float(experts if held is None else len(held)))
         if c.k_scale is not None:
             from .kv_quant import QMAX
             absmax = max(float(jnp.max(c.k_scale)),
@@ -1339,8 +1394,19 @@ class Engine:
             kv_scales=self._kv_scales_of(cache),
             **self._lora_kw(lora, adapter_ids))
         k_new, v_new = self._quantize_prefill_kv(cache, k_new, v_new)
-        # scatter the padded [0, prefill_len) window into the slot's
-        # pages: m whole pages, ids from the (traced) page-table row
+        cache = self._scatter_prefill(cache, pt_row, k_new, v_new)
+        last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
+                                            keepdims=False)   # [V(/tp)]
+        last = self._gather_logits(jnp.asarray(last, jnp.float32))
+        finite = jnp.all(jnp.isfinite(last))
+        token = sample_tokens(last[None], temperature[None], key,
+                              self.top_k)[0]
+        return cache, token, finite
+
+    def _scatter_prefill(self, cache, pt_row, k_new, v_new):
+        """A monolithic prefill's K/V ``[layers, 1, h, prefill_len, d]``
+        into the slot's pages: the padded ``[0, prefill_len)`` window as
+        m whole pages, ids from the (traced) page-table row."""
         pl_ = self.page_len
         m = -(-self.prefill_len // pl_)
         pad = m * pl_ - self.prefill_len
@@ -1357,15 +1423,8 @@ class Engine:
                                                               3)
             return pool.at[:, pages].set(new)
 
-        cache = cache.replace(k=_scatter(cache.k, k_new),
-                              v=_scatter(cache.v, v_new))
-        last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
-                                            keepdims=False)   # [V(/tp)]
-        last = self._gather_logits(jnp.asarray(last, jnp.float32))
-        finite = jnp.all(jnp.isfinite(last))
-        token = sample_tokens(last[None], temperature[None], key,
-                              self.top_k)[0]
-        return cache, token, finite
+        return cache.replace(k=_scatter(cache.k, k_new),
+                             v=_scatter(cache.v, v_new))
 
     def _paged_chunk_impl(self, params, cache, tokens, pt_row, offset,
                           n_valid, temperature, fault_bias, key,
@@ -1440,6 +1499,118 @@ class Engine:
         # reads n_accepted — the rejected tail's pages stay allocated
         # to the slot, their K/V unreachable behind the length
         return cache, greedy, n_accepted, finite
+
+    # ------------------------- compiled bodies (paged, per-slot state)
+    # The three heartbeat programs for a model that keeps state per slot
+    # beside its pages (kv_cache.SlotState; models.zaya.ZayaLM). Same
+    # operands as the paged bodies above plus ONE trailing operand: the
+    # slot a chunk / prefill belongs to, or the decode batch's active
+    # mask (a slot that is mid-prefill rides the decode batch with its
+    # real page table, and its state must not move). The state rides in
+    # the donated cache pytree and is written in place like the pool.
+    def _state_apply(self, params, state, tokens, rows, **kw):
+        """One model call with the slot rows ``[layers, B, W]`` in; the
+        pools and expert counters updated, the rows the call leaves
+        out."""
+        logits, (k2, v2, rows2, counts) = self._model.apply(
+            {"params": params}, tokens, train=False, state=rows, **kw)
+        st = state
+        if st.expert_tokens.shape[1]:
+            st = st.replace(expert_tokens=st.expert_tokens + counts)
+        return logits, k2, v2, rows2, st
+
+    def _state_prefill_impl(self, params, cache, tokens, pt_row, length,
+                            temperature, key, slot):
+        self.prefill_traces += 1    # python body runs at trace time only
+        # a monolithic prefill admits the request: its state starts from
+        # zeros (the model's default), never from what the slot held
+        logits, k_new, v_new, rows, st = self._state_apply(
+            params, cache.state, tokens, None, return_kv=True,
+            n_valid=length[None])
+        st = st.replace(rows=jax.lax.dynamic_update_slice_in_dim(
+            st.rows, jnp.asarray(rows, st.rows.dtype), slot, axis=1))
+        cache = self._scatter_prefill(cache, pt_row, k_new,
+                                      v_new).replace(state=st)
+        last = jnp.asarray(logits[0, 0], jnp.float32)             # [V]
+        finite = jnp.all(jnp.isfinite(last))
+        token = sample_tokens(last[None], temperature[None], key,
+                              self.top_k)[0]
+        return cache, token, finite
+
+    def _state_chunk_impl(self, params, cache, tokens, pt_row, offset,
+                          n_valid, temperature, fault_bias, key, slot):
+        self.chunk_traces += 1      # python body runs at trace time only
+        offset = jnp.asarray(offset, jnp.int32)
+        # the chunk reads the state its predecessor left and leaves the
+        # state of its own last VALID position; the chunk at offset 0
+        # admits the request and starts from zeros, whatever the slot's
+        # last tenant left behind
+        rows = jax.lax.dynamic_slice_in_dim(cache.state.rows, slot, 1,
+                                            axis=1)
+        rows = jnp.where(offset == 0, jnp.zeros_like(rows), rows)
+        logits, k2, v2, rows, st = self._state_apply(
+            params, cache.state, tokens, rows,
+            cache=(cache.k, cache.v, pt_row), positions=offset[None],
+            n_valid=n_valid[None])
+        st = st.replace(rows=jax.lax.dynamic_update_slice_in_dim(
+            st.rows, jnp.asarray(rows, st.rows.dtype), slot, axis=1))
+        cache = cache.replace(k=k2, v=v2, state=st)
+        # the model returned the logits of the last VALID row only
+        last = jnp.asarray(logits[0, 0], jnp.float32) + fault_bias
+        finite = jnp.all(jnp.isfinite(last))
+        token = sample_tokens(last[None], temperature[None], key,
+                              self.top_k)[0]
+        return cache, token, finite
+
+    def _state_decode_impl(self, params, cache, last_tokens, page_table,
+                           lengths, temperature, fault_bias, key, active):
+        self.decode_traces += 1     # python body runs at trace time only
+        positions = jnp.minimum(lengths, self.max_len - 1)
+        old = cache.state.rows
+        logits, k2, v2, rows, st = self._state_apply(
+            params, cache.state, last_tokens[:, None], old,
+            cache=(cache.k, cache.v, page_table), positions=positions,
+            valid=active[:, None])
+        # only a slot that decoded moves its state: an idle slot's is
+        # dead until admission zeroes it, a prefilling slot's is live
+        st = st.replace(rows=jnp.where(active[None, :, None],
+                                       jnp.asarray(rows, old.dtype), old))
+        rows_ = jnp.asarray(logits[:, 0, :], jnp.float32) \
+            + fault_bias[:, None]
+        finite = jnp.all(jnp.isfinite(rows_), axis=-1)        # [slots]
+        tokens = sample_tokens(rows_, temperature, key, self.top_k)
+        return cache.replace(k=k2, v=v2, state=st), tokens, finite
+
+    def _slot_arg(self, slot: int):
+        """The trailing operand of a stateful engine's chunk / prefill
+        call (its slot); nothing on a model without slot state, whose
+        programs keep the operands they always had."""
+        return (np.int32(slot),) if self.slot_state_width else ()
+
+    def moe_tokens_per_expert(self) -> Optional[np.ndarray]:
+        """Tokens routed to each expert by every program since the
+        engine was built, ``[layers, num_experts]`` int64 — accumulated
+        on the device inside the programs and read HERE, once, when
+        asked (one small transfer; never in the beat). None for a model
+        with no expert layer. Sets the counters
+        ``serving.moe.tokens_routed`` (a layer's total: every token is
+        routed once a layer) and ``serving.moe.tokens_per_expert.e<i>``
+        (expert ``i``'s sum over the layers)."""
+        st = getattr(self.cache, "state", None)
+        if st is None or not st.expert_tokens.shape[1]:
+            return None
+        counts = self._readback("moe_counts", lambda: np.asarray(
+            st.expert_tokens)).astype(np.int64)
+        if self._registry is not None:
+            # counters only grow: add what came since the last read
+            def _raise_to(name, total):
+                have = self._registry.counters.get(name, 0.0)
+                self._registry.counter_inc(name, max(0.0, total - have))
+
+            _raise_to("serving.moe.tokens_routed", float(counts[0].sum()))
+            for e, n in enumerate(counts.sum(0)):
+                _raise_to(f"serving.moe.tokens_per_expert.e{e}", float(n))
+        return counts
 
     def _swap_out_impl(self, cache, page_ids):
         """The hierarchical-KV tier's OUTBOUND compiled program: gather
@@ -1517,7 +1688,8 @@ class Engine:
                 jnp.asarray(tokens),
                 jnp.asarray(self._page_table[slot:slot + 1].copy()),
                 np.int32(n), np.float32(temperature),
-                self._next_key(), *self._lora_args(slot)))
+                self._next_key(), *self._lora_args(slot),
+                *self._slot_arg(slot)))
         else:
             ops = self._operands(lambda: (
                 jnp.asarray(tokens), np.int32(n), np.int32(slot),
@@ -1625,7 +1797,8 @@ class Engine:
                 jnp.asarray(self._page_table[slot:slot + 1].copy()),
                 np.int32(offset), np.int32(n),
                 np.float32(temperature), np.float32(fault_bias),
-                self._next_key(), *self._lora_args(slot)))
+                self._next_key(), *self._lora_args(slot),
+                *self._slot_arg(slot)))
         else:
             ops = self._operands(lambda: (
                 jnp.asarray(tokens),
@@ -2344,7 +2517,8 @@ class Engine:
                 jnp.asarray(self._host_len.copy()),
                 jnp.asarray(temperatures, jnp.float32),
                 jnp.asarray(fault_bias), self._next_key(),
-                *self._lora_args()))
+                *self._lora_args(),
+                *((jnp.asarray(act),) if self.slot_state_width else ())))
         else:
             ops = self._operands(lambda: (
                 jnp.asarray(last_tokens, jnp.int32),
@@ -2723,7 +2897,11 @@ class Engine:
         pool's size or more mean the compiler is copying the pool
         (a layout the kernels, the writes and the stored array do not
         share), which costs its bytes in time every step and in
-        capacity always. Sets the gauges ``serving.kv.pool_bytes``,
+        capacity always. A model with per-slot state
+        (:class:`~apex_tpu.serving.kv_cache.SlotState`) adds
+        ``state_bytes`` to each program: the state rides in the donated
+        cache pytree, so it is part of ``alias_bytes`` too. Sets the
+        gauges ``serving.kv.pool_bytes``,
         ``serving.kv.decode_temp_bytes`` and
         ``serving.kv.chunk_temp_bytes``.
 
@@ -2739,6 +2917,9 @@ class Engine:
             out[name] = {"argument_bytes": m.argument_bytes,
                          "alias_bytes": m.alias_bytes,
                          "temp_bytes": m.temp_bytes}
+            if self.slot_state_width:
+                # donated and aliased with the pool: part of alias_bytes
+                out[name]["state_bytes"] = self.cache.state.nbytes()
         if self._registry is not None:
             self._registry.gauge_set("serving.kv.pool_bytes",
                                      float(self.cache.nbytes()))
@@ -2769,10 +2950,12 @@ class Engine:
             programs = {
                 "decode": self._jit_decode.lower(
                     self.params, self.cache, *decode_ops, slots_f32,
-                    slots_f32, self._key, *self._lora_args()),
+                    slots_f32, self._key, *self._lora_args(),
+                    *((np.zeros(self.slots, bool),)
+                      if self.slot_state_width else ())),
                 "chunk": self._jit_chunk.lower(
                     self.params, self.cache, *chunk_ops, *scalars,
-                    *self._lora_args(0)),
+                    *self._lora_args(0), *self._slot_arg(0)),
             }
         finally:
             self.decode_traces, self.chunk_traces = traces

@@ -83,7 +83,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["KVCache", "PagedKVCache", "PagePool"]
+__all__ = ["KVCache", "PagedKVCache", "PagePool", "SlotState"]
 
 
 @flax.struct.dataclass
@@ -296,6 +296,51 @@ class KVCache:
 
 
 @flax.struct.dataclass
+class SlotState:
+    """What a model keeps PER SLOT beside its paged K/V — a fixed block
+    of values a layer that the next token's step needs of this one
+    (a convolution's last inputs, a shifted projection: models whose
+    ``slot_state_width`` is non-zero, e.g.
+    :class:`~apex_tpu.models.zaya.ZayaLM`). Unlike a page it belongs to
+    the slot, not to a position: it is overwritten every step, cannot be
+    shared copy-on-write, and means nothing without the exact position
+    it was left at — which is why prefix retention, swap and preemption
+    (all of which re-enter a request mid-stream from PAGES) are refused
+    for such models until this state is snapshotted with them.
+
+    Lives in the :class:`PagedKVCache` pytree, so it is donated with
+    the pool and written in place by the same programs. The program
+    that admits a request (the chunk program at offset 0, or the
+    monolithic prefill) starts the slot from zeros; nothing on the host
+    ever clears it."""
+
+    rows: jnp.ndarray            # [layers, slots, width]
+    # tokens routed to each expert since the engine was built (or
+    # `Engine.moe_reset_counts`), accumulated by the programs on the
+    # device and read once when asked; [layers, 0] for a model with no
+    # expert layer
+    expert_tokens: jnp.ndarray   # [layers, num_experts] int32
+
+    @classmethod
+    def create(cls, *, layers: int, slots: int, width: int,
+               num_experts: int = 0, dtype: Any = jnp.bfloat16):
+        return cls(rows=jnp.zeros((layers, slots, width), dtype),
+                   expert_tokens=jnp.zeros((layers, num_experts),
+                                           jnp.int32))
+
+    @property
+    def width(self) -> int:
+        return self.rows.shape[2]
+
+    def bytes_per_slot(self) -> int:
+        return int(self.rows.shape[0] * self.rows.shape[2]
+                   * self.rows.dtype.itemsize)
+
+    def nbytes(self) -> int:
+        return int(self.rows.size * self.rows.dtype.itemsize)
+
+
+@flax.struct.dataclass
 class PagedKVCache:
     """Paged KV pool pytree: ``[layers, num_pages, heads, head_dim,
     page_len]`` K and V. Pure device storage — lengths and page tables
@@ -326,6 +371,9 @@ class PagedKVCache:
     # copy-on-write share never copies scale state alongside its pages.
     k_scale: Optional[jnp.ndarray] = None   # [layers, heads] fp32
     v_scale: Optional[jnp.ndarray] = None   # [layers, heads] fp32
+    # per-slot state beside the pages (models with slot_state_width > 0);
+    # None — no leaf, the pytree the programs always had — otherwise
+    state: Optional[SlotState] = None
 
     # ------------------------------------------------------------- geometry
     @property
@@ -365,7 +413,7 @@ class PagedKVCache:
     @classmethod
     def create(cls, *, layers: int, num_pages: int, heads: int,
                page_len: int, head_dim: int, dtype: Any = jnp.bfloat16,
-               k_scale=None, v_scale=None) -> "PagedKVCache":
+               k_scale=None, v_scale=None, state=None) -> "PagedKVCache":
         """Allocate a zeroed pool (``dtype`` normally the amp half
         dtype, or int8 with the scale pair under the engine's
         ``kv_quant`` tier). ``num_pages`` INCLUDES the page-0 sentinel,
@@ -376,7 +424,7 @@ class PagedKVCache:
                              "sentinel/garbage page)")
         shape = (layers, num_pages, heads, head_dim, page_len)
         return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                   k_scale=k_scale, v_scale=v_scale)
+                   k_scale=k_scale, v_scale=v_scale, state=state)
 
     def layer_view(self):
         """The ``(k, v)`` pool pair the paged model path consumes."""

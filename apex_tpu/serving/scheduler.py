@@ -537,6 +537,24 @@ class Scheduler:
             raise ValueError("max_queue must be >= 1")
         if chunk_budget < 1:
             raise ValueError("chunk_budget must be >= 1")
+        if getattr(engine, "slot_state_width", 0):
+            # a model with per-slot state beside its pages: what
+            # re-enters a request mid-stream from pages alone is refused
+            # by name, as serving.Engine refuses its own side of it
+            for on, what in (
+                    (retain_prefixes, "prefix_cache retention "
+                     "(retain_prefixes)"),
+                    (slo is not None and slo.preempt,
+                     "slo preemption with resume"),
+                    (speculative, "speculative verify"),
+                    (role != "both", f"disaggregated role={role!r} (the "
+                     "KV handoff)")):
+                if on:
+                    raise NotImplementedError(
+                        f"serving.Scheduler: {what} is not built for a "
+                        f"model with per-slot state "
+                        f"({getattr(engine, 'model_kind', '?')!r}): the "
+                        "slot's state is not snapshotted with its pages")
         if pipeline_depth < 0:
             raise ValueError("pipeline_depth must be >= 0 (0 = the "
                              "synchronous oracle beat)")

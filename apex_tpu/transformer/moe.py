@@ -33,7 +33,8 @@ from jax import lax
 
 from apex_tpu.comm import AXIS_EXPERT
 
-__all__ = ["MoEMLP", "top1_routing", "top2_routing", "router_z_loss"]
+__all__ = ["MoEMLP", "top1_routing", "top2_routing", "router_z_loss",
+           "dropless_top1_experts"]
 
 
 def _scatter_to_slots(mask, pos, gate, capacity):
@@ -235,3 +236,53 @@ class MoEMLP(nn.Module):
                        jnp.asarray(out, jnp.float32),
                        preferred_element_type=jnp.float32)
         return jnp.asarray(y, x.dtype), aux
+
+
+# ------------------------------------------------- serving: drop nothing
+def dropless_top1_experts(u, gate, choice, w_gate_up, w_down, *,
+                          num_experts: int, experts_held=None,
+                          out_dtype=None):
+    """The serving tier's expert layer: top-1, no capacity, no token
+    dropped, one grouped GEMM over the experts HELD here.
+
+    ``u`` ``[T, H]`` the (normed) tokens; ``choice`` ``[T]`` int32 each
+    token's expert among ALL ``num_experts`` (the router runs over the
+    published count whatever is held); ``gate`` ``[T]`` fp32 the chosen
+    expert's probability; ``w_gate_up`` ``[G, H, 2F]`` (gate columns
+    first) and ``w_down`` ``[G, F, H]`` the weights of the ``G`` experts
+    in ``experts_held`` (a static tuple of expert ids in the order the
+    weights are stacked; None = all of them). Returns ``(y [T, H],
+    tokens_per_expert [num_experts] int32)`` with ``y[t] = gate[t] *
+    (silu(u Wg) * (u Wu)) Wd`` under expert ``choice[t]`` where that
+    expert is held and zero where it lives on another chip — the part of
+    the layer's result this chip gives; the parts of all the shares add
+    up to the whole layer's.
+
+    Tokens are sorted by expert (``moe.sort``), run through
+    :func:`~apex_tpu.kernels.grouped_gemm.grouped_gemm` for gate and up
+    as ONE fused ``[G, H, 2F]`` operand and again for down
+    (``moe.gemm``), scaled and unsorted (``moe.combine``). Any
+    imbalance, an expert with no token included, costs nothing but the
+    empty expert's weight stream. The training module above
+    (:class:`MoEMLP`) keeps its capacity-and-drop dispatch."""
+    from apex_tpu.kernels.grouped_gemm import group_ranges, grouped_gemm
+
+    T, H = u.shape
+    F = w_down.shape[1]
+    out_dtype = out_dtype or u.dtype
+    with jax.named_scope("moe.sort"):
+        order = jnp.argsort(choice, stable=True)
+        inverse = jnp.zeros((T,), jnp.int32).at[order].set(
+            jnp.arange(T, dtype=jnp.int32))
+        sizes, starts, ends = group_ranges(choice, num_experts,
+                                           experts_held)
+        xs = u[order]
+    with jax.named_scope("moe.gemm"):
+        gu = grouped_gemm(xs, w_gate_up, starts, ends)
+        h = jax.nn.silu(jnp.asarray(gu[:, :F], jnp.float32)) \
+            * jnp.asarray(gu[:, F:], jnp.float32)
+        ys = grouped_gemm(jnp.asarray(h, xs.dtype), w_down, starts, ends,
+                          out_dtype=jnp.float32)
+    with jax.named_scope("moe.combine"):
+        y = ys[inverse] * jnp.asarray(gate, jnp.float32)[:, None]
+    return jnp.asarray(y, out_dtype), sizes

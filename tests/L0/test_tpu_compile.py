@@ -140,10 +140,10 @@ def _pool(pages, heads, d, stacked):
     return (pages, heads, PAGE, d), None
 
 
-def _paged_decode(heads, d, kv_dtype, stacked=False):
+def _paged_decode(heads, d, kv_dtype, stacked=False, kv_heads=None):
     from apex_tpu.kernels.decode_attention import paged_decode_attention
 
-    pool, layer = _pool(B * MAX_PAGES + 1, heads, d, stacked)
+    pool, layer = _pool(B * MAX_PAGES + 1, kv_heads or heads, d, stacked)
 
     def fn(q, k, v, table, lengths, ks, vs):
         return paged_decode_attention(q, k, v, table, lengths,
@@ -164,10 +164,11 @@ def _prefill(heads, d, kv_dtype, chunk=256):
                 *_scales(heads, kv_dtype)]
 
 
-def _paged_prefill(heads, d, kv_dtype, stacked=False, chunk=256):
+def _paged_prefill(heads, d, kv_dtype, stacked=False, chunk=256,
+                   kv_heads=None):
     from apex_tpu.kernels.prefill_attention import paged_prefill_attention
 
-    pool, layer = _pool(MAX_PAGES + 1, heads, d, stacked)
+    pool, layer = _pool(MAX_PAGES + 1, kv_heads or heads, d, stacked)
 
     def fn(q, k, v, table, offsets, ks, vs):
         return paged_prefill_attention(q, k, v, table, offsets,
@@ -177,7 +178,23 @@ def _paged_prefill(heads, d, kv_dtype, stacked=False, chunk=256):
                 *_scales(heads, kv_dtype)]
 
 
+def _grouped_gemm(tokens):
+    """One call of the expert layer's product at ZAYA1-8B's widths: 16
+    experts, gate and up fused (2048 -> 4096)."""
+    from apex_tpu.kernels.grouped_gemm import grouped_gemm
+
+    return grouped_gemm, [((tokens, 2048), BF16), ((16, 2048, 4096), BF16),
+                          ((16,), I32), ((16,), I32)]
+
+
 CASES = {
+    "paged_decode_grouped_8q2kv_x128": (
+        _paged_decode, (8, 128, BF16, True, 2), ["paged_decode_attention"]),
+    "paged_prefill_grouped_8q2kv_x128": (
+        _paged_prefill, (8, 128, BF16, True, 256, 2),
+        ["paged_prefill_attention"]),
+    "grouped_gemm_decode_96": (_grouped_gemm, (96,), ["moe_grouped_gemm"]),
+    "grouped_gemm_chunk_256": (_grouped_gemm, (256,), ["moe_grouped_gemm"]),
     "flash_fwd_bwd": (_flash, (), ["flash_attention_fwd",
                                    "flash_attention_bwd_dq",
                                    "flash_attention_bwd_dkv"]),
