@@ -34,25 +34,28 @@ than the training sweep).
 
 **Paged variant** (:func:`paged_decode_attention`): the serving tier's
 block-table refactor replaces the per-slot cache row with a dense pool
-of fixed-size pages plus a ``[batch, max_pages]`` page table. The
-kernel is the same online-softmax recurrence with ONE structural
-change: the KV block index is no longer an affine function of the grid
-position — block ``j`` of batch row ``b`` lives wherever
-``page_table[b, j]`` says. Pallas expresses exactly that through
-scalar-prefetch block index maps (``PrefetchScalarGridSpec``): the page
-table rides SMEM ahead of the grid, and each (b, h, j) step DMAs pool
-page ``page_table[b, j]`` instead of row offset ``j``. The length skip
-is unchanged — pages wholly past ``lengths[b]`` are masked to the
-sentinel page and their compute skipped.
+of fixed-size pages plus a ``[batch, max_pages]`` page table: block
+``j`` of batch row ``b`` lives wherever ``page_table[b, j]`` says. The
+kernel is the same online-softmax recurrence over another unit of work:
+the pool stays in HBM, the page table and the lengths ride SMEM
+(scalar prefetch), and ONE invocation walks every row's *live* pages -
+``ceil(lengths[b] / page_len)`` of them, never the rest of the table -
+fetching ``decode.paged_step_bytes`` worth of whole pages a step, every
+K/V head of a page in one DMA (in the stacked pool a page of all heads
+of a layer is one contiguous stretch), double-buffered, the next row's
+first step in flight while this row's last is multiplied. All heads of
+a step are one product against the row's query laid block-diagonally
+(:func:`_paged_decode_kernel`).
 
 **Tensor parallelism** (``serving.Engine(mesh=...)``): the kernels need
-NO sharded variant. The grid iterates ``batch x heads`` (flattened to
-``b*h`` rows here, an explicit heads dimension in the paged grid), so a
-heads-sharded pool — ``[num_pages, heads/tp, page_len, head_dim]`` per
-shard, the serving tier's TP layout — simply hands each shard a grid
-with fewer heads-axis blocks over its own pool slice: the index maps
-never mix heads, every DMA stays shard-local, and the per-shard math is
-bit-identical to the single-chip kernel over that head subset.
+NO sharded variant. A heads-sharded pool - ``[layers, num_pages,
+heads/tp, head_dim, page_len]`` per shard, the serving tier's TP
+layout - hands each shard the same kernels over its own pool slice
+and its own query heads: the contiguous kernels' grid has fewer
+``b*h`` rows, the paged decode kernel's page is the local heads' page
+(and its pages a step follow from that page's bytes); no index map or
+product mixes heads across shards, every DMA stays shard-local, and
+the per-shard math is the single-chip kernel's over that head subset.
 Attention therefore contributes ZERO collectives to the sharded serving
 programs (the psums live in the projection GEMMs; see
 :mod:`apex_tpu.serving.sharding`).
@@ -369,11 +372,13 @@ def _layer_pool_shape(name, k_pool, v_pool, layer):
 
 
 def _page_block_spec(page_len, d, page_idx, layer):
-    """The K/V ``BlockSpec`` of the two paged kernels: one pool page of
-    one head, chosen by the scalar-prefetch index map ``page_idx``. On a
+    """The K/V ``BlockSpec`` of the paged PREFILL kernel: one pool page
+    of one head, chosen by the scalar-prefetch index map ``page_idx``
+    (the paged decode kernel takes no block of the pool: it leaves it in
+    HBM and fetches whole pages of all heads itself). On a
     stacked pool the static ``layer`` is one more (squeezed) block index
     in front and the page arrives as it is stored, ``[d, page_len]``
-    (the kernel bodies' ``kt`` form): it is DMA'd out of the pool where
+    (the kernel body's ``kt`` form): it is DMA'd out of the pool where
     it lives, and no layer of the pool is ever sliced out in HBM."""
     if layer is None:
         return pl.BlockSpec((1, 1, page_len, d), page_idx)
@@ -434,119 +439,285 @@ def paged_decode_attention_reference(q, k_pool, v_pool, page_table,
                                       k_scale=k_scale, v_scale=v_scale)
 
 
-def _paged_decode_kernel(pt_ref, len_ref, *refs, scale, page_len, quant,
-                         kt=False, G=1):
-    """Grid (b, h_kv, max_pages): one batch row x K/V head, one pool page
-    per step; the ``G`` query heads of the head's group are the rows of
-    one ``[G, d] x [d, page_len]`` product against the one fetched page
-    (``G`` = 1: plain multi-head attention, one query row). The (m, l) recurrence is :func:`_decode_kernel`'s; the page
-    the DMA fetched was chosen by the scalar-prefetch index map
-    (``pt_ref[b, j]``), so the kernel body only needs the length skip/
-    mask on GLOBAL positions ``j * page_len + lane``. ``quant``
-    (static) adds two scalar-prefetch scale refs and the same fused
-    per-head dequant multiplies as :func:`_decode_kernel`. ``kt``
-    (static): the page blocks are ``[d, page_len]``, the stacked pool's
-    form — only the contracted axis of the two products moves."""
-    qk_dims, pv_dims = _page_dots(kt)
+DEFAULT_PAGED_STEP_BYTES = 512 * 1024
+_SCOPED_VMEM_ROOM = 8 * 1024 * 1024
+
+
+def _pages_per_step(page_bytes, max_pages):
+    """Pool pages one step of the paged decode kernel fetches: as many
+    as fit the tuned bytes in flight per buffer
+    (``decode.paged_step_bytes``), by the page's own bytes (all K/V
+    heads of one layer, ``heads x head_dim x page_len`` elements) - at
+    least one, at most a row's table."""
+    target = vmem.get_override("decode.paged_step_bytes",
+                               DEFAULT_PAGED_STEP_BYTES)
+    return max(1, min(max_pages, target // page_bytes))
+
+
+def _p_rows(h):
+    """Rows one bfloat16 piece of ``p`` takes in its stacked scratch:
+    the query heads rounded up to the packed tile of 16."""
+    return -(-h // 16) * 16
+
+
+def _paged_decode_vmem(k_pool, q, pages):
+    """``(working set, scoped limit asked for)`` in bytes of the paged
+    decode kernel on the stacked pool ``k_pool`` at ``pages`` pages a
+    step: K and V, two buffers each, of ``pages`` whole pages; the
+    query and the output of every row; the stacked pieces of ``p``; the
+    float32 accumulator, (m, l) and one step's logits. The limit leaves
+    the compiler room for the products' operands beside it."""
+    _, _, h_kv, d, page_len = k_pool.shape
+    B, h, _ = q.shape
+    T, C = pages * page_len, h_kv * d
+    buffers = 2 * 2 * h_kv * d * T * k_pool.dtype.itemsize
+    rows = -(-h // 8) * 8
+    lanes = -(-d // 128) * 128
+    q_and_out = 2 * B * rows * lanes * q.dtype.itemsize
+    state = 3 * _p_rows(h) * T * 2 + rows * (C + 2 * 128 + 2 * T) * 4
+    working = buffers + q_and_out + state
+    return working, 2 * working + _SCOPED_VMEM_ROOM
+
+
+def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
+                         G, quant, widen):
+    """One invocation walks every batch row's LIVE pages, ``pages`` of
+    them a step, every K/V head of a page in one fetch.
+
+    The pool stays in HBM (``k_hbm``/``v_hbm``, the stacked
+    ``[layers, num_pages, h_kv, d, page_len]`` form). A step of row
+    ``b`` is the next ``pages`` entries of its table below
+    ``ceil(lengths[b] / page_len)``: each page ``[h_kv, d, page_len]``
+    of layer ``layer_ref[0]`` (a scalar operand, so every layer of a
+    model runs the SAME kernel: traced, lowered and compiled once, not
+    once a layer) is one contiguous stretch of HBM and one
+    ``make_async_copy`` into its ``page_len`` lanes of a
+    ``[h_kv, d, pages * page_len]`` VMEM buffer; K and V have two such
+    buffers each, and while one step is multiplied the next is in
+    flight - the next row's first step included, so a row's last pages
+    never wait on a cold fetch. A table entry past the length costs
+    neither a DMA nor a step: rows of length 0 (and the sentinel page)
+    are never fetched.
+
+    Arithmetic (the (m, l) recurrence of :func:`_decode_kernel`, float32
+    state): the page buffer read as ``[h_kv * d, T]`` (``T = pages *
+    page_len``) is ONE product for all heads against the row's query
+    laid block-diagonally, ``qbd[i, hh * d + c] = q[i, c]`` where query
+    head ``i`` reads K/V head ``hh = i // G`` and 0 elsewhere: ``s =
+    qbd @ K`` is ``[h, T]``, every query head against its own K/V
+    head's keys, the operands as stored (bfloat16 x bfloat16 is exact in
+    the float32 sum). ``p @ V^T`` gives ``[h, h_kv * d]``, each query
+    head against EVERY K/V head's values; the accumulator keeps that
+    form and the finish reads each head's own ``d`` columns. ``p``
+    stays float32: against a bfloat16 (or int8) page it is handed to
+    the product as its three bfloat16 pieces ``hi + mid + lo`` (24
+    bits of mantissa, exact), stacked on the row axis so the page is
+    loaded once. Table slots of a step past the row's last live page
+    hold stale bytes: K's are masked with the positions past the
+    length, V's are zeroed before the product (0 x NaN is NaN).
+
+    VMEM working set (:func:`_paged_decode_vmem` counts it, and the
+    call asks for a scoped limit of twice that plus 8 MB): the four page
+    buffers, ``4 x pages x`` a page's bytes - 1.3 MB at GPT-2 large's
+    320 KB page, 2.1 MB at eight of ZAYA1-8B's 64 KB pages - plus every
+    row's query and output, ``p``'s pieces, the accumulator and one
+    step's logits: 1.8 MB and 2.7 MB in all.
+
+    ``quant`` (static): int8 pages widen to bfloat16 (exact) and the
+    per-head scales, ``[h, 1]`` float32 columns, multiply after each
+    product as in :func:`_decode_kernel`. ``widen`` (static, the CPU's
+    interpreter: its dot takes no bfloat16 x bfloat16 -> float32):
+    operands are widened to float32 at the product, the same numbers.
+    """
     if quant:
-        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, \
-            l_ref = refs
+        q_ref, ks_ref, vs_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, \
+            p_ref, acc_ref, m_ref, l_ref = refs
     else:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
-    hh = pl.program_id(1)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-    length = len_ref[b]
+        q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, p_ref, acc_ref, \
+            m_ref, l_ref = refs
+    B, h, d = q_ref.shape
+    h_kv, page_len = h // G, kbuf.shape[-1] // pages
+    C, T = h_kv * d, pages * page_len
+    max_pages = pt_ref.shape[1]
+    layer = layer_ref[0]
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    split = kbuf.dtype != f32            # p as three bfloat16 pieces
+    # the K product's operands: as stored (int8 widened) where q is
+    # bfloat16 too, else float32
+    k_dtype = bf16 if q_ref.dtype == bf16 and split else f32
+    Rp = _p_rows(h)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def dot(a, b, dims):
+        if widen:
+            a, b = a.astype(f32), b.astype(f32)
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   preferred_element_type=f32)
 
-    @pl.when(j * page_len < length)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)                   # [G, d]
-        k = k_ref[0, 0].astype(jnp.float32)       # [pl, d] (kt: [d, pl])
-        s = jax.lax.dot_general(
-            q, k, qk_dims,
-            preferred_element_type=jnp.float32) * scale       # [G, pl]
-        if quant:
-            s = s * ks_ref[hh]
-        cols = j * page_len + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_len), 1)
-        s = jnp.where(cols < length, s, _NEG_INF)
-        m_prev = m_ref[:G, :1]                                # [G, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                                # [G, pl]
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:G, :1] = alpha * l_ref[:G, :1] + jnp.sum(
-            p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_ref[0, 0].astype(jnp.float32), pv_dims,
-            preferred_element_type=jnp.float32)
-        if quant:
-            pv = pv * vs_ref[hh]
-        acc_ref[:G, :] = acc_ref[:G, :] * alpha + pv
-        m_ref[:G, :1] = m_new
+    def live_pages(b):
+        return jnp.minimum(
+            jax.lax.div(jnp.maximum(len_ref[b], 0) + (page_len - 1),
+                        page_len), max_pages)
 
-    @pl.when(j == nj - 1)
-    def _finish():
-        l = l_ref[:G, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:G, :] / l_safe).astype(o_ref.dtype)
+    def next_row(b):
+        """The first row after ``b`` with a live page, or ``B``."""
+        return jax.lax.while_loop(
+            lambda r: jnp.logical_and(
+                r < B, len_ref[jnp.minimum(r, B - 1)] <= 0),
+            lambda r: r + 1, b + 1)
+
+    def step_copies(b, i, slot, act):
+        """Start or wait (``act``) the DMAs of step ``i`` of row ``b``
+        on buffer ``slot``: its live pages only."""
+        n = live_pages(b)
+        for jj in range(pages):
+            j = i * pages + jj
+
+            @pl.when(j < n)
+            def _page():
+                page = pt_ref[b, jnp.minimum(j, max_pages - 1)]
+                lanes = pl.ds(jj * page_len, page_len)
+                for x, (hbm, buf) in enumerate(((k_hbm, kbuf),
+                                                (v_hbm, vbuf))):
+                    act(pltpu.make_async_copy(
+                        hbm.at[layer, page], buf.at[slot, :, :, lanes],
+                        sem.at[x, slot]))
+
+    # the block-diagonal query: q tiled h_kv times along the lanes by a
+    # product with [I I .. I] (exact: one term a sum), masked to each
+    # query head's own K/V head
+    tile = (jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (d, C), 1), d)
+            == jax.lax.broadcasted_iota(jnp.int32, (d, C), 0)
+            ).astype(q_ref.dtype)
+    own = (jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (h, C), 1), d)
+           == jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (h, C), 0),
+                          G))
+    if split:
+        p_ref[...] = jnp.zeros_like(p_ref)
+
+    first = next_row(-1)
+
+    @pl.when(first < B)
+    def _warm():
+        step_copies(jnp.minimum(first, B - 1), 0, 0,
+                    lambda c: c.start())
+
+    def row(b, slot):
+        length = len_ref[b]
+        n = live_pages(b)
+        steps = jax.lax.div(n + (pages - 1), pages)
+        after = next_row(b)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        qbd = jnp.where(own, dot(q_ref[b], tile, ((1,), (0,))),
+                        0.0).astype(k_dtype)                  # [h, C]
+
+        def step(i, slot):
+            last = i + 1 >= steps
+            nb = jnp.where(last, after, b)
+
+            @pl.when(nb < B)
+            def _prefetch():
+                step_copies(jnp.minimum(nb, B - 1),
+                            jnp.where(last, 0, i + 1), 1 - slot,
+                            lambda c: c.start())
+
+            step_copies(b, i, slot, lambda c: c.wait())
+            for jj in range(1, pages):
+                @pl.when(i * pages + jj >= n)
+                def _stale():
+                    vbuf[slot, :, :, jj * page_len:(jj + 1) * page_len] = \
+                        jnp.zeros((h_kv, d, page_len), vbuf.dtype)
+            k = kbuf[slot].reshape(C, T).astype(k_dtype)
+            v = vbuf[slot].reshape(C, T)
+            if quant:
+                v = v.astype(bf16)
+            s = dot(qbd, k, ((1,), (0,))) * scale             # [h, T]
+            if quant:
+                s = s * ks_ref[...]
+            cols = i * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+            s = jnp.where(cols < length, s, _NEG_INF)
+            m_prev = m_ref[:, :1]                             # [h, 1]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)                            # [h, T]
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = jnp.broadcast_to(
+                alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
+                l_ref.shape)
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            if split:
+                hi = p.astype(bf16)
+                rest = p - hi.astype(f32)
+                mid = rest.astype(bf16)
+                lo = (rest - mid.astype(f32)).astype(bf16)
+                for x, piece in enumerate((hi, mid, lo)):
+                    p_ref[x * Rp:x * Rp + h, :] = piece
+                pv3 = dot(p_ref[...], v, ((1,), (1,)))        # [3 Rp, C]
+                pv = pv3[:h] + pv3[Rp:Rp + h] + pv3[2 * Rp:2 * Rp + h]
+            else:
+                pv = dot(p, v, ((1,), (1,)))                  # [h, C]
+            if quant:
+                pv = pv * vs_ref[...]
+            acc_ref[...] = acc_ref[...] * alpha + pv
+            return 1 - slot
+
+        slot = jax.lax.fori_loop(0, steps, step, slot)
+        l = l_ref[:, :1]
+        out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)      # [h, C]
+        for hh in range(h_kv):
+            o_ref[b, hh * G:(hh + 1) * G, :] = out[
+                hh * G:(hh + 1) * G, hh * d:(hh + 1) * d].astype(o_ref.dtype)
+        return slot
+
+    jax.lax.fori_loop(0, B, row, 0)
 
 
-def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, scale,
-                         interpret, ks=None, vs=None, layer=None):
+@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
+def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, layer, ks=None,
+                         vs=None, *, scale, pages, interpret):
+    """The kernel's call on the stacked pool, ``layer`` a traced scalar.
+    Jitted: a model's layers differ in ``layer`` alone, so they share
+    one trace and one lowered function (36 traces of this body were
+    seconds of every process's start)."""
     B, h, d = q.shape
-    kt = layer is not None           # stacked pool: pages [d, page_len]
-    page_len = k_pool.shape[-1 if kt else -2]
-    h_kv = k_pool.shape[2 if kt else 1]
-    G = h // h_kv                    # query heads per K/V head
-    R = _scratch_rows(G)
-    max_pages = pt.shape[1]
+    _, _, h_kv, _, page_len = k_pool.shape
+    T, C = pages * page_len, h_kv * d
     quant = ks is not None
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               page_len=page_len, quant=quant, kt=kt, G=G)
-    # the dequant scales ride as two extra scalar-prefetch operands (the
-    # variadic tail absorbs them — only the kernel body reads them).
-    # q/out carry the group's rows as their own axis ([B, h_kv, G, d];
-    # G = 1 is a unit row axis): Mosaic wants a block's last two dims to
-    # tile (8, 128) or EQUAL the array's, and a (G, d) block over
-    # [.., G, d] is the latter where (1, d) over [.., h, d] is neither
-    # (the contiguous kernel's [bh, G, d] does the same).
-    def _q_idx(b, hh, j, pt, ln, *_scales):
-        return (b, hh, 0, 0)
-
-    def _kv_idx(b, hh, j, pt, ln, *_scales):
-        return (pt[b, j], hh, 0, 0)
-
-    kv_spec = _page_block_spec(page_len, d, _kv_idx, layer)
-    n_prefetch, extra_ops = (4, (ks, vs)) if quant else (2, ())
+                               pages=pages, G=h // h_kv, quant=quant,
+                               widen=interpret)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    scale_ops = ()
+    if quant:
+        # one scale a QUERY head, as a column the products' rows take
+        scale_ops = tuple(jnp.repeat(s, h // h_kv)[:, None]
+                          for s in (ks, vs))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,   # page_table, lengths[, ks, vs]
-        grid=(B, h_kv, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, d), _q_idx),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, d), _q_idx),
+        num_scalar_prefetch=3,            # page_table, lengths, layer
+        grid=(1,),
+        in_specs=[whole] * (1 + len(scale_ops)) + [in_hbm, in_hbm],
+        out_specs=whole,
         scratch_shapes=[
-            pltpu.VMEM((R, d), jnp.float32),      # acc (rows :G live)
-            pltpu.VMEM((R, 128), jnp.float32),    # m
-            pltpu.VMEM((R, 128), jnp.float32),    # l
+            pltpu.VMEM((2, h_kv, d, T), k_pool.dtype),    # K, two buffers
+            pltpu.VMEM((2, h_kv, d, T), v_pool.dtype),    # V, two buffers
+            pltpu.SemaphoreType.DMA((2, 2)),              # (K|V, buffer)
+            pltpu.VMEM((3 * _p_rows(h), T), jnp.bfloat16),  # p's pieces
+            pltpu.VMEM((h, C), jnp.float32),              # acc
+            pltpu.VMEM((h, 128), jnp.float32),            # m
+            pltpu.VMEM((h, 128), jnp.float32),            # l
         ],
     )
-    out = pl.pallas_call(
+    _, limit = _paged_decode_vmem(k_pool, q, pages)
+    return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, h_kv, G, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, h, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=limit),
         interpret=interpret, name="paged_decode_attention",
-    )(pt, lengths, *extra_ops, q.reshape(B, h_kv, G, d), k_pool, v_pool)
-    return out.reshape(B, h, d)
+    )(pt, lengths, jnp.reshape(layer, (1,)).astype(jnp.int32), q,
+      *scale_ops, k_pool, v_pool)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
@@ -565,8 +736,10 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     pool [layers, num_pages, kv_heads, head_dim, page_len] with the static
     ``layer`` to attend (the serving engine's form, pages transposed:
     :func:`_layer_pool_shape` says why): the layer is then one more
-    block index of the page DMA, so the serving programs hand over the
-    pool they write in place and never slice a layer out of it;
+    index of the page DMA (handed to the kernel as a scalar, so a
+    model's layers share ONE traced and lowered kernel), and the serving
+    programs hand over the pool they write in place and never slice a
+    layer out of it;
     ``page_table``
     [batch, max_pages] int32 maps row ``b``'s logical block ``j`` to a
     pool page (sentinel ids for unallocated blocks — masked, never
@@ -575,13 +748,19 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     written at logical position ``lengths[b] - 1`` of its row's pages.
     ``scale`` defaults to ``1/sqrt(head_dim)``.
 
-    Inference-only. The Pallas path walks each row's page list through
-    scalar-prefetch index maps — one pool-page DMA per grid step, with
-    pages past ``lengths[b]`` skipping their compute — so a short
-    request in a big pool costs O(length) MXU work exactly like the
-    contiguous kernel, while the pool itself stays dense and shared.
-    Unaligned shapes and non-Mosaic dtypes fall back to the
-    gather-then-reference oracle.
+    Inference-only. The Pallas path leaves the pool in HBM and walks
+    each row's LIVE pages (``ceil(lengths[b] / page_len)``; the table
+    past them costs neither a DMA nor a step), several pages a step and
+    every K/V head of a page in one fetch, double-buffered across steps
+    and rows (:func:`_paged_decode_kernel`) - a short request in a big
+    pool costs O(length), and the pool itself stays dense and shared.
+    Pages a step follow from the page's own bytes and ONE tuned number,
+    ``decode.paged_step_bytes`` (bytes in flight per buffer): no
+    per-model setting. A single layer's 4-D pool is relaid to the
+    stacked form first (a pool-sized copy: the form of tests and smoke
+    runs, not of serving). Unaligned shapes (``page_len`` not a
+    multiple of 128, a head's rows not whole tiles of the page's type)
+    and non-Mosaic dtypes fall back to the gather-then-reference oracle.
     """
     B, h, d = q.shape
     P, hp, page_len, dp = _layer_pool_shape("paged_decode_attention",
@@ -603,7 +782,11 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     from apex_tpu.kernels.flash_attention import _has_vma
     if jax.default_backend() == "cpu":
         interpret = True
-    pallas_ok = (d % 8 == 0 and page_len % 128 == 0)
+    # the kernel reads a page as [heads * head_dim, page_len]: compiled,
+    # a head's rows must fill whole tiles of the stored type (8 rows of
+    # float32, 16 of bfloat16, 32 of int8)
+    rows = 8 if interpret else 32 // k_pool.dtype.itemsize
+    pallas_ok = (d % rows == 0 and page_len % 128 == 0)
     if not pallas_ok or (interpret and _has_vma(q)) \
             or (not interpret and not mosaic_dtype_ok(q, k_pool, v_pool)):
         return paged_decode_attention_reference(
@@ -615,7 +798,19 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     if k_scale is not None:
         ks = jnp.asarray(k_scale, jnp.float32)
         vs = jnp.asarray(v_scale, jnp.float32)
-    out = _paged_decode_pallas(q, k_pool, v_pool, pt, len32, scale,
-                               interpret, ks, vs, layer)
+    if layer is None:
+        # one layer's [num_pages, heads, page_len, d] pool is relaid to
+        # the stacked form the kernel reads: a pool-sized copy, the
+        # price of the form tests and smoke runs use; serving hands over
+        # the stacked pool
+        k_pool, v_pool = (jnp.swapaxes(t, -1, -2)[None]
+                          for t in (k_pool, v_pool))
+    _, _, h_kv, _, _ = k_pool.shape
+    pages = _pages_per_step(h_kv * d * page_len * k_pool.dtype.itemsize,
+                            pt.shape[1])
+    out = _paged_decode_pallas(q, k_pool, v_pool, pt, len32,
+                               jnp.int32(layer or 0), ks, vs,
+                               scale=float(scale), pages=pages,
+                               interpret=interpret)
     live = (lengths > 0)[:, None, None]
     return jnp.where(live, out, 0).astype(q.dtype)

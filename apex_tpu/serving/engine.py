@@ -259,13 +259,17 @@ class PendingDecode:
     the step computed for) and ``t_dispatch`` the dispatch timestamp,
     so reconcile can observe the full dispatch→retire latency as
     ``serving.decode.step_s`` (in sync mode reconcile follows dispatch
-    immediately and the reading degenerates to today's measurement)."""
+    immediately and the reading degenerates to today's measurement).
+    ``attended`` (paged engines) is what each decoding row's length was
+    in this step, kept for reconcile's ``serving.decode.pages_live`` /
+    ``pages_tabled`` counters: dispatch counts nothing itself."""
 
     tokens: Any                 # [slots] int32, ON DEVICE until reconcile
     finite: Any                 # [slots] bool, ON DEVICE until reconcile
     active: np.ndarray          # [slots] bool, host dispatch mask
     t_dispatch: float
     reconciled: bool = False
+    attended: Optional[np.ndarray] = None   # [decoding rows] int lengths
 
 
 @dataclasses.dataclass
@@ -2529,11 +2533,14 @@ class Engine:
         self.cache, tokens, finite = self._runtime_call(
             "decode", lambda: self._jit_decode(self.params, self.cache,
                                                *ops))
+        attended = None
         if self.paged:
+            # write-then-attend: a row at position p attends p + 1
+            attended = np.minimum(self._host_len[act], self.max_len - 1) + 1
             grow = act & (self._host_len < self.max_len)
             self._host_len[grow] += 1
         return PendingDecode(tokens=tokens, finite=finite, active=act,
-                             t_dispatch=t0)
+                             t_dispatch=t0, attended=attended)
 
     def decode_reconcile(self, pending: PendingDecode, valid=None):
         """Read a dispatched decode step back to the host — ONE batched
@@ -2552,7 +2559,14 @@ class Engine:
         ``tokens_generated`` counts only emitted tokens and stays
         comparable with the sync path serving the same stream. The
         block time is charged to :attr:`device_wait_s`; the finiteness
-        verdict lands in :attr:`last_decode_finite`."""
+        verdict lands in :attr:`last_decode_finite`.
+
+        A paged engine also counts how much of the page table the step's
+        decode kernel walked, from the lengths the dispatch recorded:
+        ``serving.decode.pages_live`` (sum over decoding rows of
+        ``ceil(length / page_len)``: the pages the kernel fetches) and
+        ``serving.decode.pages_tabled`` (decoding rows x ``max_pages``:
+        what a walk of the whole table would fetch)."""
         if pending.reconciled:
             raise RuntimeError("PendingDecode already reconciled — each "
                                "dispatched step reads back exactly once")
@@ -2575,6 +2589,15 @@ class Engine:
             self._registry.counter_inc("serving.decode.steps")
             self._registry.counter_inc("serving.tokens_generated",
                                        n_valid)
+            if pending.attended is not None:
+                # how much of the page table the decode kernel walked:
+                # it fetches a row's live pages, not its table
+                self._registry.counter_inc(
+                    "serving.decode.pages_live",
+                    int(np.sum(-(-pending.attended // self.page_len))))
+                self._registry.counter_inc(
+                    "serving.decode.pages_tabled",
+                    pending.attended.size * self.max_pages)
         return out, finite, dt
 
     def sync(self) -> None:

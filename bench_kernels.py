@@ -342,7 +342,71 @@ def bench_group_norm():
         gbytes=gb)
 
 
+# ----------------------------------------------------------- paged decode
+# The benchmark's two serving geometries as their engines call the kernel
+# (rows, query heads, K/V heads, head_dim, table pages, pool pages) and
+# the lengths their traffic keeps live (about 355 and 550 tokens a row).
+PAGED_DECODE_CELLS = {
+    "gpt2_large_24x1024": (24, 20, 20, 64, 8, 193, (64, 650)),
+    "zaya1_8b_96x2048": (96, 8, 2, 128, 16, 1537, (100, 1000)),
+}
+PAGED_DECODE_LAYERS = 4
+
+
+def paged_decode_case(cell, seed=0):
+    """``(q, k_pool, v_pool, page_table, lengths)`` of one cell: a
+    stacked bf16 pool of a few layers, every row its own pages, table
+    entries past a row's live pages the sentinel."""
+    import numpy as np
+
+    rows, h, h_kv, d, table, pool, (lo, hi) = PAGED_DECODE_CELLS[cell]
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shape = (PAGED_DECODE_LAYERS, pool, h_kv, d, 128)
+    kp, vp = (jax.random.normal(k, shape, jnp.bfloat16) for k in ks[:2])
+    q = jax.random.normal(ks[2], (rows, h, d), jnp.bfloat16)
+    lengths = rng.integers(lo, hi, size=rows).astype(np.int32)
+    pt = rng.permutation(np.arange(1, pool))[:rows * table]
+    pt = pt.reshape(rows, table).astype(np.int32)
+    pt[np.arange(table)[None, :] >= -(-lengths[:, None] // 128)] = 0
+    return q, kp, vp, jnp.asarray(pt), jnp.asarray(lengths)
+
+
+def paged_decode_ms(cell, case=None):
+    """Device ms of ONE layer's call of the kernel at ``cell``, the mean
+    over the pool's layers, and the GB of live K/V it has to read."""
+    from apex_tpu.kernels.decode_attention import paged_decode_attention
+
+    q, kp, vp, pt, lengths = case or paged_decode_case(cell)
+
+    def layers(q, kp, vp, pt, lengths):
+        return sum(paged_decode_attention(q, kp, vp, pt, lengths, layer=i)
+                   .astype(jnp.float32) for i in range(PAGED_DECODE_LAYERS))
+    ms = timeit(layers, q, kp, vp, pt, lengths) / PAGED_DECODE_LAYERS
+    _, _, h_kv, d, page_len = kp.shape
+    page_bytes = 2 * h_kv * d * page_len * kp.dtype.itemsize  # K and V
+    live = float(jnp.sum(-(-lengths // 128)))
+    return ms, live * page_bytes / 1e9
+
+
+def bench_paged_decode():
+    """The paged decode kernel against its gather-then-attend oracle
+    (what XLA makes of the same read) at the benchmark's two serving
+    geometries; the roofline counts the LIVE pages' bytes."""
+    from apex_tpu.kernels.decode_attention import \
+        paged_decode_attention_reference
+
+    for cell in PAGED_DECODE_CELLS:
+        case = paged_decode_case(cell)
+        ms, gbytes = paged_decode_ms(cell, case)
+        d = case[0].shape[-1]
+        xla = timeit(lambda *a: paged_decode_attention_reference(
+            *a, scale=1 / d ** 0.5, layer=1), *case)
+        row("paged_decode", cell, ms, xla, gbytes=gbytes)
+
+
 SUITES = {"flash": bench_flash, "ln": bench_ln, "xentropy": bench_xentropy,
+          "paged_decode": bench_paged_decode,
           "lm_head": bench_lm_head,
           "adam": bench_adam, "causal_softmax": bench_causal_softmax,
           "masked_softmax": bench_masked_softmax,
@@ -509,6 +573,15 @@ def sweep(out_path="tuned_blocks.json"):
 
     _sweep_knob(results, "group_norm.bwd_block_spatial",
                 (64, 128, 256, 512), gn_bwd_ms)
+
+    # the paged decode kernel's bytes in flight a buffer: ONE number for
+    # both serving geometries (their pages are 320 KB and 64 KB), so the
+    # measure is the two cells' ms a layer weighted by their layers
+    _sweep_knob(results, "decode.paged_step_bytes",
+                tuple(kb * 1024 for kb in (64, 128, 256, 384, 512, 768,
+                                           1024, 2048)),
+                lambda: 36 * paged_decode_ms("gpt2_large_24x1024")[0]
+                + 20 * paged_decode_ms("zaya1_8b_96x2048")[0])
 
     with open(out_path, "w") as f:
         json.dump(results, f, indent=1, sort_keys=True)

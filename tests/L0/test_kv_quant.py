@@ -243,6 +243,48 @@ def test_quantized_kernels_match_gather_dequant_oracles():
                                v_scale=vs[:2])
 
 
+@pytest.mark.parametrize("plens", [
+    [128, 129], [1, 384], [0, 257], [256, 5],
+], ids=["boundary_and_one_past", "one_token_and_full_table",
+        "empty_and_ragged", "two_pages_and_one"])
+@pytest.mark.parametrize("G,d,stacked", [(1, 64, True), (4, 128, True),
+                                         (1, 16, False)])
+def test_paged_decode_int8_pages_over_live_page_walks(G, d, stacked, plens):
+    """The decode kernel's walk (two pages a step, live pages only) over
+    int8 pages with per-head scales, both pool forms, against the
+    gather-dequant oracle at this file's tolerance. Float32 q: the int8
+    codes widen exactly, so what differs is summation order."""
+    from apex_tpu.kernels import vmem
+    rng = np.random.default_rng(5)
+    B, h_kv, PL, MAXP, NP_ = 2, 2, 128, 3, 7
+    q = jnp.asarray(rng.standard_normal((B, h_kv * G, d)), jnp.float32)
+    shape = (NP_, h_kv, PL, d)
+    kp, vp = (rng.integers(-QMAX, QMAX + 1, size=shape).astype(np.int8)
+              for _ in range(2))
+    ks = jnp.asarray(rng.uniform(0.01, 0.05, size=h_kv), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.01, 0.05, size=h_kv), jnp.float32)
+    pt = np.array([[1, 2, 3], [4, 5, 6]], np.int32)
+    live = -(-np.asarray(plens) // PL)
+    pt[np.arange(MAXP)[None, :] >= live[:, None]] = 0    # the sentinel
+    layer = None
+    if stacked:     # [layers, pages, heads, d, page_len], layer 1 read
+        kp, vp = (np.stack([np.full_like(t, 77), t]).swapaxes(-1, -2)
+                  for t in (kp, vp))
+        layer = 1
+    args = (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt),
+            jnp.asarray(plens, jnp.int32))
+    kw = dict(k_scale=ks, v_scale=vs, layer=layer)
+    vmem.set_override("decode.paged_step_bytes", 2 * h_kv * d * PL)
+    try:
+        out = jax.jit(lambda *a: paged_decode_attention(
+            *a, interpret=True, **kw))(*args)
+    finally:
+        vmem.remove_override("decode.paged_step_bytes")
+    ref = paged_decode_attention_reference(*args, scale=1 / d ** 0.5, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    assert (np.asarray(out)[np.asarray(plens) == 0] == 0).all()
+
+
 # ------------------------------------------------------------- composition
 def test_quantized_token_match_vs_bf16_oracle_over_hit_miss_evict(
         engine_trio):

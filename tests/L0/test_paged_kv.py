@@ -285,6 +285,138 @@ def test_stacked_pool_needs_its_layer_and_a_layer_its_stacked_pool():
                           np.float32)).all()
 
 
+# ------------------------- the decode kernel's walk over a row's live pages
+# (model geometry: G query heads a K/V head x head_dim, page dtype, pool
+# form) x (what a row's length and table can look like). Every case reads
+# table entries past a row's last live page as the SENTINEL page 0, which
+# is filled with NaN: the kernel must never bring it into a result.
+_GEOMETRIES = {
+    "g1_d64_bf16": (1, 64, "bf16", True),
+    "g4_d128_bf16": (4, 128, "bf16", True),
+    "g1_d64_int8": (1, 64, "int8", True),
+    "g4_d64_bf16_one_layer": (4, 64, "bf16", False),
+    "g1_d128_int8_one_layer": (1, 128, "int8", False),
+}
+_MAXP = 5
+# name -> (lengths, pages a step the tuned bytes are set to give)
+_WALKS = {
+    "page_boundary_and_one_past": ([128, 129, 256, 257], 2),
+    "one_token_beside_a_full_table": ([1, _MAXP * 128, 1, 640], 2),
+    "live_pages_not_a_multiple_of_the_step": ([100, 300, 600, 385], 2),
+    "three_pages_a_step": ([129, 385, 640, 512], 3),
+    "one_page_a_step": ([5, 130, 300, 640], 1),
+    "empty_rows_between_live_ones": ([0, 300, 0, 5], 2),
+    "all_rows_empty": ([0, 0, 0, 0], 2),
+}
+
+
+def _walk_case(geometry, lengths, shared):
+    G, d, dtype, stacked = _GEOMETRIES[geometry]
+    rng = np.random.default_rng(11)
+    h_kv, B, PL = 2, len(lengths), 128
+    NP_ = 1 + B * _MAXP
+    shape = (NP_, h_kv, d, PL)
+    scales = {}
+    if dtype == "int8":
+        kp, vp = (rng.integers(-127, 128, size=shape).astype(np.float32)
+                  for _ in range(2))
+        scales = {"k_scale": jnp.asarray(rng.uniform(.01, .03, h_kv),
+                                         jnp.float32),
+                  "v_scale": jnp.asarray(rng.uniform(.01, .03, h_kv),
+                                         jnp.float32)}
+    else:
+        kp, vp = (rng.normal(size=shape).astype(np.float32)
+                  for _ in range(2))
+    # every row its own pages, in a shuffled order; entries past the
+    # live pages are the sentinel
+    pt = rng.permutation(np.arange(1, NP_)).reshape(B, _MAXP)
+    if shared:
+        # the prefix cache's copy-on-write form: rows 1.. read row 0's
+        # first two (full) pages
+        pt[1:, :2] = pt[0, :2]
+    live = -(-np.asarray(lengths) // PL)
+    for b in range(B):
+        pt[b, live[b]:] = 0
+    q = jnp.asarray(rng.normal(size=(B, h_kv * G, d)), jnp.bfloat16)
+    return q, kp, vp, jnp.asarray(pt, jnp.int32), scales, stacked, dtype
+
+
+def _as_pool(pages, dtype, stacked):
+    """``pages`` [num_pages, heads, d, page_len] as the kernel's pool:
+    the middle layer of a stacked one (the other layers NaN, or -128 in
+    int8: never this layer's business), or one layer's 4-D form."""
+    store = jnp.int8 if dtype == "int8" else jnp.bfloat16
+    if not stacked:
+        return jnp.asarray(pages.swapaxes(-1, -2), store)
+    other = np.full_like(pages, -128 if dtype == "int8" else np.nan)
+    return jnp.asarray(np.stack([other, pages, other]), store)
+
+
+@pytest.fixture
+def step_bytes():
+    from apex_tpu.kernels import vmem
+    saved = vmem.overrides().get("decode.paged_step_bytes")
+
+    def set_pages(pages, page_bytes):
+        vmem.set_override("decode.paged_step_bytes", pages * page_bytes)
+    yield set_pages
+    vmem.remove_override("decode.paged_step_bytes")
+    if saved is not None:
+        vmem.set_override("decode.paged_step_bytes", saved)
+
+
+@pytest.mark.parametrize("walk,shared", [
+    (w, sh) for w in sorted(_WALKS) for sh in (False, True)
+    if not (sh and w == "all_rows_empty")])   # nothing read, nothing shared
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+def test_paged_decode_walks_only_a_rows_live_pages(geometry, walk, shared,
+                                                   step_bytes):
+    lengths, pages = _WALKS[walk]
+    if shared:
+        # a shared prefix is whole pages its readers attend in full
+        lengths = [n and max(n, 256 + i) for i, n in enumerate(lengths)]
+    q, kp, vp, pt, scales, stacked, dtype = _walk_case(geometry, lengths,
+                                                      shared)
+    lens = jnp.asarray(lengths, jnp.int32)
+    layer = 1 if stacked else None
+    h_kv, d = kp.shape[1], kp.shape[2]
+    step_bytes(pages, h_kv * d * 128 * (1 if dtype == "int8" else 2))
+    # the oracle reads a pool whose sentinel page is zeros; the kernel
+    # one whose sentinel is NaN (int8 codes have no NaN: theirs is noise)
+    clean = [_as_pool(t, dtype, stacked) for t in (kp, vp)]
+    kp[0], vp[0] = (101, 101) if dtype == "int8" else (np.nan, np.nan)
+    dirty = [_as_pool(t, dtype, stacked) for t in (kp, vp)]
+    want = paged_decode_attention_reference(
+        q, *clean, pt, lens, scale=1 / d ** 0.5, layer=layer, **scales)
+    got = jax.jit(lambda *a: paged_decode_attention(
+        *a, layer=layer, interpret=True, **scales))(q, *dirty, pt, lens)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert got.shape == want.shape and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=3e-2)
+    assert (got[np.asarray(lengths) == 0] == 0).all()
+
+
+def test_a_rows_nan_page_never_reaches_another_rows_result(step_bytes):
+    """Two pages a step: row 0's second page holds NaN and stays behind
+    in the buffer row 2's one live page is fetched into next. Row 0 is
+    NaN (the engine quarantines it); rows 1-3 must read as if it were
+    not there."""
+    lengths = [256, 100, 100, 100]
+    q, kp, vp, pt, _, _, _ = _walk_case("g1_d64_bf16", lengths, False)
+    step_bytes(2, kp.shape[1] * kp.shape[2] * 128 * 2)
+    lens = jnp.asarray(lengths, jnp.int32)
+    clean = [_as_pool(t, "bf16", True) for t in (kp, vp)]
+    kp[pt[0, 1]], vp[pt[0, 1]] = np.nan, np.nan
+    dirty = [_as_pool(t, "bf16", True) for t in (kp, vp)]
+    want = paged_decode_attention_reference(q, *clean, pt, lens,
+                                            scale=0.125, layer=1)
+    got = paged_decode_attention(q, *dirty, pt, lens, layer=1,
+                                 interpret=True)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isnan(got[0]).all()
+    np.testing.assert_allclose(got[1:], want[1:], atol=3e-2)
+
+
 # ------------------------------------------------------------ engines
 def _tiny_lm(max_seq_len=64, **kw):
     return TransformerLM(vocab_size=VOCAB, hidden=32, num_layers=2,
@@ -550,6 +682,55 @@ def test_paged_pool_telemetry_gauges_and_request_records(engine_pair):
             if rec.get("tag") == "serving.request"}
     assert recs[reqs[0].uid]["reused_tokens"] == 0
     assert recs[reqs[1].uid]["reused_tokens"] == 16
+
+
+def test_decode_page_counters_follow_the_rows_lengths(engine_pair):
+    """``serving.decode.pages_live`` / ``pages_tabled``: the share of
+    the page table the decode kernel walks, from the lengths of the rows
+    that decode. A request of prompt ``p`` and ``n`` tokens gets its
+    first token from prefill and decodes ``n - 1`` steps, the step at
+    position ``p + i`` attending ``p + i + 1`` keys."""
+    from apex_tpu.telemetry.summarize import (render_summary,
+                                              summarize_records)
+    ep, _ = engine_pair
+    ep.reset(clear_prefixes=True)
+    reg = telemetry.MetricsRegistry()
+    ep.set_registry(reg)
+    sched = Scheduler(ep, registry=reg)
+    jobs = [(5, 12), (17, 9), (8, 1)]        # (prompt length, new tokens)
+    try:
+        for p, n in jobs:                     # one at a time: rows alone
+            sched.run([Request(prompt=list(range(1, p + 1)),
+                               max_new_tokens=n)])
+    finally:
+        ep.set_registry(None)
+    c = reg.snapshot()["counters"]
+    lengths = [p + i + 1 for p, n in jobs for i in range(n - 1)]
+    assert c["serving.decode.pages_live"] == sum(
+        -(-length // ep.page_len) for length in lengths)
+    assert c["serving.decode.pages_tabled"] == len(lengths) * ep.max_pages
+    assert c["serving.decode.steps"] == len(lengths)
+    share = c["serving.decode.pages_live"] / c["serving.decode.pages_tabled"]
+    assert share == pytest.approx(
+        np.mean([-(-length // ep.page_len) for length in lengths])
+        / ep.max_pages)
+    text = render_summary(summarize_records([reg.snapshot()]))
+    assert "serving.decode.pages_live" in text
+    assert "serving.decode.pages_tabled" in text
+    # several rows a step: every decoding row counts its own table
+    ep.reset(clear_prefixes=True)
+    reg = telemetry.MetricsRegistry()
+    ep.set_registry(reg)
+    try:
+        Scheduler(ep, registry=reg).run(
+            [Request(prompt=list(range(1, p + 1)), max_new_tokens=n)
+             for p, n in jobs])
+    finally:
+        ep.set_registry(None)
+    c = reg.snapshot()["counters"]
+    assert c["serving.decode.pages_tabled"] == len(lengths) * ep.max_pages
+    assert c["serving.decode.pages_live"] == sum(
+        -(-length // ep.page_len) for length in lengths)
 
 
 def test_paged_reset_keeps_warm_prefix_pages_unless_cleared(engine_pair):

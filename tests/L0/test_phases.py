@@ -95,7 +95,7 @@ def test_self_time_is_duration_less_direct_children():
     with tracing.phase("p") as p:
         with tracing.phase("c1") as c1:
             time.sleep(0.01)
-            with tracing.phase("g"):
+            with tracing.phase("g") as g:
                 time.sleep(0.005)
         with tracing.phase("c2") as c2:
             time.sleep(0.02)
@@ -104,8 +104,10 @@ def test_self_time_is_duration_less_direct_children():
     dur = {r.id: r.dur for r in recs}
     assert own[p.id] == pytest.approx(
         dur[p.id] - dur[c1.id] - dur[c2.id], abs=1e-9)
-    # a grandchild is taken from its parent, not from the root
-    assert own[c1.id] == pytest.approx(0.01, abs=5e-3)
+    # a grandchild is taken from its parent, not from the root (by the
+    # records' own durations: a sleep under a loaded test run overshoots)
+    assert own[c1.id] == pytest.approx(dur[c1.id] - dur[g.id], abs=1e-9)
+    assert own[c1.id] >= 0.01
     assert own[c2.id] == pytest.approx(dur[c2.id])
     assert sum(own.values()) == pytest.approx(dur[p.id], abs=1e-9)
     assert own[p.id] < 2e-3

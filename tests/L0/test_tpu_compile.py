@@ -242,6 +242,49 @@ def test_kernel_compiles_for_v5e_and_stays_a_kernel(case, one_chip, as_v5e):
         f"{case}: expected Mosaic kernels {want}, the program holds {have}"
 
 
+# The two serving configurations of the benchmark, as their engines call
+# the kernel: (rows, query heads, K/V heads, head_dim, table pages,
+# layers, pool pages).
+CELL_GEOMETRY = {
+    "gpt2_large_24x1024": (24, 20, 20, 64, 8, 36, 193),
+    "zaya1_8b_96x2048": (96, 8, 2, 128, 16, 20, 1537),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_GEOMETRY))
+def test_paged_decode_at_a_cells_geometry_fits_its_vmem(cell, one_chip,
+                                                        as_v5e):
+    """The whole stacked pool of a benchmark cell, one middle layer
+    read: one kernel, its pages a step read off the page's bytes, and
+    the working set it states under the scoped limit it asks for (which
+    the compile above all accepts)."""
+    import importlib
+    # by module path: the package re-exports the same-named FUNCTION
+    da = importlib.import_module("apex_tpu.kernels.decode_attention")
+    rows, h, h_kv, d, table, layers, pool = CELL_GEOMETRY[cell]
+    shapes = [((rows, h, d), BF16), ((layers, pool, h_kv, d, PAGE), BF16),
+              ((layers, pool, h_kv, d, PAGE), BF16), ((rows, table), I32),
+              ((rows,), I32)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+            for s, t in shapes]
+
+    def fn(q, k, v, pt, lengths):
+        return da.paged_decode_attention(q, k, v, pt, lengths,
+                                         layer=layers // 2)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert kernel_calls(compiled.as_text()) == {"paged_decode_attention": 1}
+    page_bytes = h_kv * d * PAGE * 2
+    pages = da._pages_per_step(page_bytes, table)
+    step_bytes = vmem.overrides()["decode.paged_step_bytes"]
+    assert 1 <= pages <= table
+    assert pages == 1 or pages * page_bytes <= step_bytes
+    working, limit = da._paged_decode_vmem(args[1], args[0], pages)
+    assert 4 * pages * page_bytes < working < limit <= 64 * 2 ** 20, \
+        (working, limit)
+    # nothing pool-sized beside the pool: the kernel reads it where it is
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+
 def test_xentropy_at_unpadded_gpt2_vocab_takes_the_reference(one_chip,
                                                             as_v5e):
     """The gate chip_smoke's per-kernel check exists for: at 50257 (not

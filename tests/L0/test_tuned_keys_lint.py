@@ -286,6 +286,10 @@ def test_sharded_serving_owes_the_tables_no_new_keys():
         "decode.block_k", "decode.chunk_block_q", "decode.chunk_block_k",
         "decode.prefill_block_q", "decode.prefill_block_k",
         "decode.page_block_q", "decode.page_len",
+        # the paged decode kernel's bytes in flight a buffer (PR 31):
+        # per shard it sees the local heads' page and reads its pages a
+        # step off that, so sharding needs no value of its own
+        "decode.paged_step_bytes",
     }, (f"decode.* table surface changed: {sorted(table)} — if a "
         "sharded-attention knob landed, update this pin deliberately")
     stale_tp = {k for k in _table_keys()

@@ -44,7 +44,7 @@ def _pool_case(h, h_kv, d, seed=0):
 
 
 @pytest.mark.parametrize("h,h_kv,d", [(4, 2, 16), (8, 2, 128), (4, 4, 64),
-                                      (6, 1, 64)])
+                                      (6, 1, 64), (8, 2, 64), (2, 2, 128)])
 def test_grouped_heads_match_the_repeated_heads_oracle(h, h_kv, d):
     q, qc, kp, vp, pt = _pool_case(h, h_kv, d)
     G, scale = h // h_kv, 1.0 / np.sqrt(d)
@@ -62,6 +62,37 @@ def test_grouped_heads_match_the_repeated_heads_oracle(h, h_kv, d):
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6
     got = prefill_attention(qc, k, v, offs)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+
+
+@pytest.mark.parametrize("lens", [
+    [128, 129, 384],      # on a page boundary, one past it, a full table
+    [1, 384, 0],          # one token beside a full table, an empty row
+    [256, 257, 1],        # live pages 2, 3, 1 at two pages a step
+], ids=["boundary", "one_and_full_and_empty", "ragged_steps"])
+@pytest.mark.parametrize("h,h_kv,d", [(8, 2, 128), (4, 4, 64)])
+def test_paged_decode_walk_under_grouped_heads(h, h_kv, d, lens):
+    """The decode kernel's steps of several pages, live pages only, at
+    the expert model's head geometry and at plain heads: float32, so the
+    tolerance is summation order. The sentinel page is NaN."""
+    from apex_tpu.kernels import vmem
+    q, _, kp, vp, pt = _pool_case(h, h_kv, d, seed=3)
+    G, scale = h // h_kv, 1.0 / np.sqrt(d)
+    lens = jnp.asarray(lens, jnp.int32)
+    pt = jnp.asarray(np.array([[1, 2, 7], [3, 4, 5], [6, 8, 2]], np.int32))
+    live = -(-np.asarray(lens) // 128)
+    pt = jnp.where(np.arange(3)[None, :] < live[:, None], pt, 0)
+    k, v = gather_pages(kp, pt, 1), gather_pages(vp, pt, 1)
+    want = decode_attention_reference(q, jnp.repeat(k, G, 1),
+                                      jnp.repeat(v, G, 1), lens, scale=scale)
+    kp, vp = kp.at[:, 0].set(jnp.nan), vp.at[:, 0].set(jnp.nan)
+    vmem.set_override("decode.paged_step_bytes", 2 * h_kv * d * 128 * 4)
+    try:
+        got = jax.jit(lambda *a: paged_decode_attention(*a, layer=1))(
+            q, kp, vp, pt, lens)
+    finally:
+        vmem.remove_override("decode.paged_step_bytes")
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+    assert bool(jnp.all(got[np.asarray(lens) == 0] == 0))
 
 
 def test_query_heads_that_do_not_divide_are_refused_by_name():
