@@ -38,6 +38,6 @@ from .layer_norm import (  # noqa: E402,F401
 from .multi_tensor import (  # noqa: E402,F401
     fused_scale, fused_axpby, fused_l2norm, fused_adam_step, fused_sgd_step)
 from .decode_attention import (  # noqa: E402,F401
-    decode_attention, decode_attention_reference)
+    decode_attention_reference)
 from .prefill_attention import (  # noqa: E402,F401
     prefill_attention, prefill_attention_reference)

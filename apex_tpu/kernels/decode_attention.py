@@ -2,21 +2,13 @@
 
 Training attention (:mod:`apex_tpu.kernels.flash_attention`) answers
 "every query attends to every earlier key"; decode answers a different
-question: ONE new query per sequence against a **preallocated KV cache**
-of which only the first ``lengths[b]`` positions are valid. This is the
-same move the flash-attention kernel lineage makes from training kernels
-to cached inference: the blockwise online-softmax inner loop is
-unchanged, but the query block degenerates to a single row and the
-causal-block skip becomes a *length* skip — KV blocks entirely past the
-sequence's valid length are never touched, so a request of length 37 in
-a 1024-slot cache pays for ceil(38/block_k) blocks, not 8.
-
-Layouts (matching the serving cache, one slot per batch row):
-
-- ``q``: ``[batch, heads, head_dim]`` — the current token's query.
-- ``k``/``v``: ``[batch, heads, max_len, head_dim]`` — the cache view.
-- ``lengths``: ``[batch]`` int32 — valid positions per row (the current
-  token's K/V must already be written at ``lengths-1``).
+question: ONE new query per sequence against a **paged KV pool** of
+which only the first ``lengths[b]`` positions of each row are valid.
+This is the same move the flash-attention kernel lineage makes from
+training kernels to cached inference: the blockwise online-softmax
+inner loop is unchanged, but the query block degenerates to a single
+row and the causal-block skip becomes a *length* skip — pages entirely
+past the sequence's valid length are never touched.
 
 Numerics follow the kernel tier's contract: fp32 accumulation regardless
 of I/O dtype (the cache is normally bf16 via the amp cast policies), and
@@ -24,23 +16,19 @@ a pure-jnp reference that doubles as the CPU/unaligned fallback and the
 test oracle. Rows with ``lengths == 0`` return zeros (a defined value for
 inactive serving slots — their output is discarded by the engine).
 
-Block geometry rides the shared tuned-override registry
-(:mod:`apex_tpu.kernels.vmem`) under new ``decode.*`` keys:
-``decode.block_k`` (KV positions per grid step, lane-multiple 128) here,
-and ``decode.prefill_block_q``/``decode.prefill_block_k`` consumed by
-``serving.Engine`` for its prefill flash-attention geometry (prefill
-shapes — short sequences, single-request batch — want different blocks
-than the training sweep).
+:func:`decode_attention_reference` is that oracle over a contiguous
+``[batch, heads, max_len, head_dim]`` view; the paged oracle is the
+same function over the pool gathered through the page table
+(:func:`gather_pages`).
 
-**Paged variant** (:func:`paged_decode_attention`): the serving tier's
-block-table refactor replaces the per-slot cache row with a dense pool
-of fixed-size pages plus a ``[batch, max_pages]`` page table: block
+**The kernel** (:func:`paged_decode_attention`): the cache is a dense
+pool of fixed-size pages plus a ``[batch, max_pages]`` page table: block
 ``j`` of batch row ``b`` lives wherever ``page_table[b, j]`` says. The
-kernel is the same online-softmax recurrence over another unit of work:
-the pool stays in HBM, the page table and the lengths ride SMEM
+pool stays in HBM, the page table and the lengths ride SMEM
 (scalar prefetch), and ONE invocation walks every row's *live* pages -
 ``ceil(lengths[b] / page_len)`` of them, never the rest of the table -
-fetching ``decode.paged_step_bytes`` worth of whole pages a step, every
+fetching ``decode.paged_step_bytes`` (the tuned-override registry,
+:mod:`apex_tpu.kernels.vmem`) worth of whole pages a step, every
 K/V head of a page in one DMA (in the stacked pool a page of all heads
 of a layer is one contiguous stretch), double-buffered, the next row's
 first step in flight while this row's last is multiplied. All heads of
@@ -51,10 +39,9 @@ a step are one product against the row's query laid block-diagonally
 NO sharded variant. A heads-sharded pool - ``[layers, num_pages,
 heads/tp, head_dim, page_len]`` per shard, the serving tier's TP
 layout - hands each shard the same kernels over its own pool slice
-and its own query heads: the contiguous kernels' grid has fewer
-``b*h`` rows, the paged decode kernel's page is the local heads' page
-(and its pages a step follow from that page's bytes); no index map or
-product mixes heads across shards, every DMA stays shard-local, and
+and its own query heads: the paged decode kernel's page is the local
+heads' page (and its pages a step follow from that page's bytes); no
+index map or product mixes heads across shards, every DMA stays shard-local, and
 the per-shard math is the single-chip kernel's over that head subset.
 Attention therefore contributes ZERO collectives to the sharded serving
 programs (the psums live in the projection GEMMs; see
@@ -73,12 +60,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.kernels import mosaic_dtype_ok, vmem
 
-__all__ = ["decode_attention", "decode_attention_reference",
+__all__ = ["decode_attention_reference",
            "paged_decode_attention", "paged_decode_attention_reference",
            "gather_pages"]
 
 _NEG_INF = -1e30
-DEFAULT_BLOCK_K = 256
 
 
 # --------------------------------------------------------------- jnp reference
@@ -132,126 +118,9 @@ def decode_attention_reference(q, k, v, lengths, *, scale: float = 1.0,
     return jnp.asarray(jnp.where(live, out, 0.0), out_dtype)
 
 
-# -------------------------------------------------------------------- kernel
-def _decode_kernel(len_ref, *refs, scale, block_k, quant, G=1):
-    """Grid (bh, nk): one batch·K/V-head row, blockwise over cached KV;
-    the ``G`` query heads of the row's group are the rows of one
-    ``[G, d] x [d, block_k]`` product against the one fetched block
-    (``G`` = 1: plain multi-head attention, one query row).
-
-    Online softmax identical to the training forward kernel's (m, l)
-    recurrence, with the causal tile-skip replaced by a length skip:
-    a block whose first position is already past this row's valid
-    length contributes nothing and is skipped entirely.
-
-    ``quant`` (static) threads the int8-cache tier through: two extra
-    SMEM refs carry the per-row K/V dequantization scales, the K scale
-    folds into the existing logit multiply and the V scale into the
-    accumulator update — dequantization fused with the attend, the
-    int8 block never expanding outside VMEM. The non-quant trace is
-    byte-identical to before the tier existed.
-    """
-    if quant:
-        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, \
-            l_ref = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
-    ki = pl.program_id(1)
-    nk = pl.num_programs(1)
-    length = len_ref[b]
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    @pl.when(ki * block_k < length)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)                      # [G, d]
-        k = k_ref[0].astype(jnp.float32)                      # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [G, bk]
-        if quant:
-            # dequant-in-kernel: the per-head K scale is constant over
-            # the row, so it factors out of the int8 dot product
-            s = s * ks_ref[b]
-        cols = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        s = jnp.where(cols < length, s, _NEG_INF)
-        m_prev = m_ref[:G, :1]                                # [G, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                                # [G, bk]
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:G, :1] = alpha * l_ref[:G, :1] + jnp.sum(
-            p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if quant:
-            pv = pv * vs_ref[b]
-        acc_ref[:G, :] = acc_ref[:G, :] * alpha + pv
-        m_ref[:G, :1] = m_new
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        l = l_ref[:G, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:G, :] / l_safe).astype(o_ref.dtype)
-
-
-def _scratch_rows(G):
-    """Sublane rows of the (acc, m, l) scratch: the group's ``G`` live
-    rows rounded up to a whole tile of 8."""
-    return -(-G // 8) * 8
-
-
-def _decode_pallas(q3, k3, v3, len3, scale, bk, interpret, ks3=None,
-                   vs3=None):
-    bh, G, d = q3.shape              # rows: batch x K/V heads; G per group
-    L = k3.shape[1]
-    quant = ks3 is not None
-    kernel = functools.partial(_decode_kernel, scale=scale, block_k=bk,
-                               quant=quant, G=G)
-    R = _scratch_rows(G)
-    scale_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2 \
-        if quant else []
-    scale_ops = (ks3, vs3) if quant else ()
-    out = pl.pallas_call(
-        kernel,
-        grid=(bh, L // bk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),                # lengths
-            *scale_specs,                         # k/v dequant scales
-            pl.BlockSpec((1, G, d), lambda b, j: (b, 0, 0)),      # q
-            pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),     # k
-            pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),     # v
-        ],
-        out_specs=pl.BlockSpec((1, G, d), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, G, d), q3.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((R, d), jnp.float32),      # acc (rows :G live)
-            pltpu.VMEM((R, 128), jnp.float32),    # m
-            pltpu.VMEM((R, 128), jnp.float32),    # l
-        ],
-        interpret=interpret, name="decode_attention",
-    )(len3, *scale_ops, q3, k3, v3)
-    return out
-
-
 # ------------------------------------------------------------------ dispatch
-def _resolve_block(block_k):
-    if block_k is None:
-        block_k = vmem.get_override("decode.block_k", DEFAULT_BLOCK_K,
-                                    multiple=128)
-    return block_k
-
-
 def _check_head_scales(name, h, k_scale, v_scale):
-    """Quantized-cache scale validation shared by the four dispatchers:
+    """Quantized-cache scale validation shared by the three dispatchers:
     scales come as a pair of [heads] fp32 vectors or not at all."""
     if (k_scale is None) != (v_scale is None):
         raise ValueError(f"{name}: k_scale and v_scale must be given "
@@ -262,76 +131,6 @@ def _check_head_scales(name, h, k_scale, v_scale):
             if s.shape != (h,):
                 raise ValueError(f"{name}: {nm} {s.shape} must be "
                                  f"[{h}] (one scale per head)")
-
-
-def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
-                     block_k: Optional[int] = None,
-                     k_scale=None, v_scale=None,
-                     interpret: bool = False):
-    """Single-token attention against a length-masked KV cache.
-
-    ``q`` [batch, heads, head_dim]; ``k``/``v`` [batch, kv_heads, max_len,
-    head_dim] (the serving cache's per-layer view; ``kv_heads`` divides
-    ``heads`` — grouped-query attention, query head ``i`` reading K/V
-    head ``i // (heads // kv_heads)``, the group's query heads sharing
-    each fetched block); ``lengths`` [batch]
-    int32 — positions ``[0, lengths[b])`` are attended, everything past
-    is masked. The current token's own K/V must already be written at
-    position ``lengths[b] - 1`` (the serving engine's write-then-attend
-    order). ``scale`` defaults to ``1/sqrt(head_dim)``.
-
-    Inference-only (no VJP — decode never backprops). The Pallas path
-    skips KV blocks past ``lengths[b]`` entirely, so short sequences in
-    a long cache cost O(length), not O(max_len); unaligned shapes and
-    non-Mosaic dtypes fall back to the jnp reference, which XLA fuses
-    acceptably at decode's tiny per-step footprint.
-
-    Quantized cache (``k_scale``/``v_scale``, both ``[heads]`` fp32):
-    ``k``/``v`` hold int8 codes dequantized IN-KERNEL — the K scale
-    rides the logit multiply, the V scale the accumulator update — so
-    the half-width cache bytes stream through VMEM and never expand in
-    HBM. The fallback path dequantizes in the jnp oracle instead (same
-    math, materialised).
-
-    Tuned geometry: ``decode.block_k`` in the
-    :mod:`apex_tpu.kernels.vmem` override registry (lane-multiple 128,
-    clamped to the largest aligned divisor of ``max_len``).
-    """
-    b, h, d = q.shape
-    h_kv, L = k.shape[1], k.shape[2]
-    if k.shape != (b, h_kv, L, d) or v.shape != k.shape:
-        raise ValueError(f"decode_attention: k/v {k.shape}/{v.shape} do "
-                         f"not match q {q.shape} + max_len")
-    G = _group_size("decode_attention", h, h_kv)
-    if lengths.shape != (b,):
-        raise ValueError(f"decode_attention: lengths {lengths.shape} must "
-                         f"be [{b}]")
-    _check_head_scales("decode_attention", h_kv, k_scale, v_scale)
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
-    from apex_tpu.kernels.flash_attention import _fit_block, _has_vma
-    bk = _fit_block(_resolve_block(block_k), L, 128)
-    if jax.default_backend() == "cpu":
-        interpret = True
-    pallas_ok = (L % bk == 0 and d % 8 == 0 and bk % 128 == 0)
-    if not pallas_ok or (interpret and _has_vma(q)) \
-            or (not interpret and not mosaic_dtype_ok(q, k, v)):
-        return decode_attention_reference(q, k, v, lengths, scale=scale,
-                                          k_scale=k_scale,
-                                          v_scale=v_scale)
-    q3 = q.reshape(b * h_kv, G, d)
-    k3 = k.reshape(b * h_kv, L, d)
-    v3 = v.reshape(b * h_kv, L, d)
-    len3 = jnp.repeat(jnp.asarray(lengths, jnp.int32), h_kv)
-    ks3 = vs3 = None
-    if k_scale is not None:
-        # flattened bh rows walk heads fastest: row b*h + hh -> head hh
-        ks3 = jnp.tile(jnp.asarray(k_scale, jnp.float32), b)
-        vs3 = jnp.tile(jnp.asarray(v_scale, jnp.float32), b)
-    out = _decode_pallas(q3, k3, v3, len3, scale, bk, interpret, ks3,
-                         vs3)
-    live = (lengths > 0)[:, None, None]
-    return jnp.where(live, out.reshape(b, h, d), 0).astype(q.dtype)
 
 
 # ------------------------------------------------------------ paged variant

@@ -110,39 +110,39 @@ def _pool_write_pages(pool, layer, page_ids, new):
 
 
 class SelfAttention(nn.Module):
-    """Causal MHA with four modes sharing one set of weights:
+    """Causal MHA with these modes sharing one set of weights:
 
     - **train/eval** (default): full-sequence flash attention.
-    - **prefill** (``return_kv=True``): same forward, additionally
-      returning this layer's ``(k, v)`` ``[B, h, S, d]`` for the serving
-      engine to write into its KV cache.
-    - **decode** (``cache=(k_cache, v_cache)`` + ``positions``, S == 1):
-      the token's K/V is scattered into the cache at ``positions[b]``
-      and attention runs against the cached prefix via
-      :func:`apex_tpu.kernels.decode_attention.decode_attention`
-      (length-masked, fp32 accumulation), returning
-      ``(out, (k_cache', v_cache'))``.
-    - **chunked prefill** (``cache`` + ``positions``, S > 1): S
-      consecutive prompt tokens starting at cache position
-      ``positions[b]`` — their K/V is written at ``[positions[b],
-      positions[b] + S)`` and each attends the cached prefix up to and
-      including itself (write-then-attend, shifted-causal) via
-      :func:`apex_tpu.kernels.prefill_attention.prefill_attention`.
-    - **paged decode / chunked prefill** (``cache=(k_pool, v_pool,
-      page_table)`` + the static ``layer``): same two modes over the
+    - ``return_kv=True``: same forward, additionally returning this
+      layer's ``(k, v)`` ``[B, h, S, d]``. Its one use is the int8 KV
+      tier's calibration forward
+      (:meth:`apex_tpu.serving.kv_quant.KVQuantConfig.resolve_scales`).
+    - **decode** (``cache=(k_pool, v_pool, page_table)`` + ``positions``
+      + the static ``layer``, S == 1): the token's K/V is written into
+      the pool at ``positions[b]`` and attention runs against the cached
+      prefix, length-masked with fp32 accumulation
+      (:func:`apex_tpu.kernels.decode_attention.paged_decode_attention`).
+    - **chunked prefill** (same ``cache``, S > 1): S consecutive prompt
+      tokens starting at cache position ``positions[b]`` — their K/V is
+      written at ``[positions[b], positions[b] + S)`` and each attends
+      the cached prefix up to and including itself (write-then-attend,
+      shifted-causal,
+      :func:`apex_tpu.kernels.prefill_attention.paged_prefill_attention`).
+
+      Both run over the
       serving engine's paged pool, which arrives WHOLE — the stacked
       ``[layers, num_pages, h, d, page_len]`` K and V that every layer
       shares — and is WRITTEN IN PLACE: K/V land in the pool itself at
       ``(layer, page_table[b, pos // page_len], :, :, pos % page_len)``
       and attention reads the pool itself through the table via the
-      ``paged_*`` kernel variants, the layer one more block index of
+      ``paged_*`` kernels, the layer one more block index of
       their page DMA. No layer is sliced out of the pool and nothing
       pool-shaped is stacked, transposed or copied, so the buffer the
       engine donates is the buffer it gets back. The returned aux is
       the UPDATED POOL pair (pages are shared across rows), not per-row
       caches; chunk writes must be page-aligned and whole-page (the
       engine enforces ``chunk_len % page_len == 0``).
-    - **unaligned append** (``unaligned_append=True``, paged ``S > 1``):
+    - **unaligned append** (``unaligned_append=True``, ``S > 1``):
       the speculative-verify write shape — a SMALL block of S draft
       tokens landing at an arbitrary (non-page-aligned) cache offset
       mid-generation, where the whole-page chunk write cannot apply.
@@ -151,8 +151,7 @@ class SelfAttention(nn.Module):
       shifted-causal paged prefill attention runs. The pages written
       are always the slot's own: generation positions sit past any
       copy-on-write share, so unaligned writes can never touch a
-      shared page. Contiguous caches ignore the flag (their
-      ``dynamic_update_slice`` chunk write already takes any offset).
+      shared page.
 
     ``inference_dtype`` is the decode path's storage/compute dtype: when
     set, Q/K/V leave the qkv GEMM in that dtype (normally the amp half —
@@ -247,64 +246,51 @@ class SelfAttention(nn.Module):
             return quantize(new, scale, axis=axis)
 
         if cache is not None:
-            paged = len(cache) == 3
-            if paged:
-                # paged layout: (k_pool, v_pool, page_table) — pool
-                # [layers, num_pages, h, d, page_len] shared across rows
-                # AND layers, table [B, max_pages] int32 mapping logical
-                # blocks to pages. Writes land in the pool itself at
-                # (layer, page id); attention reads it through the table
-                # with the layer as one more block index — the pool is
-                # never sliced per layer (the serving engine's block-
-                # table refactor, written in place).
-                k_cache, v_cache, page_table = cache
-                page_len = k_cache.shape[4]
-                L = page_table.shape[1] * page_len
-            else:
-                k_cache, v_cache = cache             # [B, h, L, d]
-                L = k_cache.shape[2]
+            # (k_pool, v_pool, page_table) — pool
+            # [layers, num_pages, h, d, page_len] shared across rows
+            # AND layers, table [B, max_pages] int32 mapping logical
+            # blocks to pages. Writes land in the pool itself at
+            # (layer, page id); attention reads it through the table
+            # with the layer as one more block index — the pool is
+            # never sliced per layer (the serving engine's block-
+            # table refactor, written in place).
+            k_cache, v_cache, page_table = cache
+            page_len = k_cache.shape[4]
+            L = page_table.shape[1] * page_len
             # clip is a traced-value safety net only: an out-of-range
             # offset would RELOCATE the S-wide write over earlier cache
             # rows, so callers must bound positions host-side (the
             # serving engine validates offset + chunk_len <= max_len)
             pos = jnp.clip(jnp.asarray(positions, jnp.int32), 0, L - S)
             if S == 1:
-                from apex_tpu.kernels.decode_attention import (
-                    decode_attention, paged_decode_attention)
-                if paged:
-                    # the write page: logical block pos // page_len of
-                    # each row. Inactive slots' tables point at the
-                    # sentinel page, so their (discarded) write can
-                    # never corrupt a live row; a live slot's write
-                    # page is uniquely owned (shared pages are always
-                    # full — copy-on-write by construction).
-                    page_ids = jnp.take_along_axis(
-                        page_table, (pos // page_len)[:, None],
-                        axis=1)[:, 0]
-                    off = pos % page_len
-                    k_cache = _pool_write_tokens(
-                        k_cache, layer, page_ids, off,
-                        _store(k[:, :, 0], k_cache.dtype, ks, 1))
-                    v_cache = _pool_write_tokens(
-                        v_cache, layer, page_ids, off,
-                        _store(v[:, :, 0], v_cache.dtype, vs, 1))
-                    ctx = paged_decode_attention(
-                        q[:, :, 0], k_cache, v_cache, page_table,
-                        pos + 1, k_scale=ks, v_scale=vs, layer=layer)
-                else:
-                    bidx = jnp.arange(B)
-                    k_cache = k_cache.at[bidx, :, pos].set(
-                        _store(k[:, :, 0], k_cache.dtype, ks, 1))
-                    v_cache = v_cache.at[bidx, :, pos].set(
-                        _store(v[:, :, 0], v_cache.dtype, vs, 1))
-                    # write-then-attend: the token sees its own K/V
-                    ctx = decode_attention(q[:, :, 0], k_cache, v_cache,
-                                           pos + 1, k_scale=ks,
-                                           v_scale=vs)
+                from apex_tpu.kernels.decode_attention import \
+                    paged_decode_attention
+
+                # the write page: logical block pos // page_len of
+                # each row. Inactive slots' tables point at the
+                # sentinel page, so their (discarded) write can
+                # never corrupt a live row; a live slot's write
+                # page is uniquely owned (shared pages are always
+                # full — copy-on-write by construction).
+                page_ids = jnp.take_along_axis(
+                    page_table, (pos // page_len)[:, None],
+                    axis=1)[:, 0]
+                off = pos % page_len
+                k_cache = _pool_write_tokens(
+                    k_cache, layer, page_ids, off,
+                    _store(k[:, :, 0], k_cache.dtype, ks, 1))
+                v_cache = _pool_write_tokens(
+                    v_cache, layer, page_ids, off,
+                    _store(v[:, :, 0], v_cache.dtype, vs, 1))
+                # write-then-attend: the token sees its own K/V
+                ctx = paged_decode_attention(
+                    q[:, :, 0], k_cache, v_cache, page_table,
+                    pos + 1, k_scale=ks, v_scale=vs, layer=layer)
             else:
-                from apex_tpu.kernels.prefill_attention import (
-                    prefill_attention, paged_prefill_attention)
-                if paged and unaligned_append:
+                from apex_tpu.kernels.prefill_attention import \
+                    paged_prefill_attention
+
+                if unaligned_append:
                     # speculative verify: S is small (draft_len + 1)
                     # and the offset is an arbitrary mid-generation
                     # position — write each position individually
@@ -326,7 +312,7 @@ class SelfAttention(nn.Module):
                                                   k_scale=ks,
                                                   v_scale=vs,
                                                   layer=layer)
-                elif paged:
+                else:
                     # chunk writes must cover whole pages: the serving
                     # engine pins chunk_len % page_len == 0 and page-
                     # aligned offsets, so the chunk's S positions are
@@ -351,36 +337,9 @@ class SelfAttention(nn.Module):
                                                   k_scale=ks,
                                                   v_scale=vs,
                                                   layer=layer)
-                else:
-                    # chunked prefill: S tokens land at [pos, pos + S)
-                    # of each row's cache (vmapped per-row offsets)
-                    def _write(row, new, p):
-                        return jax.lax.dynamic_update_slice(row, new,
-                                                            (0, p, 0))
-                    k_cache = jax.vmap(_write)(
-                        k_cache, _store(k, k_cache.dtype, ks, 1), pos)
-                    v_cache = jax.vmap(_write)(
-                        v_cache, _store(v, v_cache.dtype, vs, 1), pos)
-                    ctx = prefill_attention(q, k_cache, v_cache, pos,
-                                            k_scale=ks, v_scale=vs)
             out = jnp.moveaxis(ctx.reshape(B, heads, S, d),
                                1, 2).reshape(B, S, heads * d)
         else:
-            if return_kv and ks is not None:
-                # monolithic prefill on the quantized tier: attend (and
-                # return) K/V through the storage grid — quantize then
-                # dequantize with the per-head scales so this forward
-                # sees exactly the values every later attend reads back
-                # out of the int8 cache (chunked prefill writes codes
-                # and attends them in-kernel; without this round-trip
-                # the two ingest paths would attend different K/V and
-                # store divergent codes for every layer past the
-                # first). fp32 keeps the engine's storage quantize an
-                # exact code recovery: round((c*s)/s) == c.
-                from apex_tpu.serving.kv_quant import dequantize, quantize
-                k = dequantize(quantize(k, ks, axis=1), ks, axis=1)
-                v = dequantize(quantize(v, vs, axis=1), vs, axis=1)
-                q = jnp.asarray(q, jnp.float32)
             out = flash_attention(q, k, v, causal=True)  # [B, h, S, d]
             out = jnp.moveaxis(out, 1, 2).reshape(B, S, heads * d)
         ctx_in = out
@@ -412,10 +371,9 @@ class TransformerBlock(nn.Module):
 
     ``cache``/``positions``/``return_kv``/``layer`` thread straight
     through to :class:`SelfAttention` (see its docstring for the modes);
-    with either inference mode on, the block returns ``(x, aux)`` where
-    aux is the updated layer cache (decode; on the paged layout the
-    whole pool, written in place at ``layer``) or this layer's
-    ``(k, v)`` (prefill).
+    with ``cache`` or ``return_kv`` on, the block returns ``(x, aux)``
+    where aux is the whole pool, written in place at ``layer``
+    (``cache``) or this layer's ``(k, v)`` (``return_kv``).
     """
 
     hidden: int
@@ -510,22 +468,19 @@ class TransformerLM(nn.Module):
     Inference modes (the ``apex_tpu.serving`` engine's compiled
     programs — see :class:`SelfAttention`):
 
-    - **prefill**: ``__call__(tokens[B, S], train=False, return_kv=True)
-      -> (logits, (k, v))`` with ``k``/``v`` stacked per layer
-      ``[layers, B, h, S, d]`` — the engine writes them into its slot
-      cache.
     - **decode**: ``__call__(tokens[B, 1], train=False,
-      cache=(k, v), positions=lengths) -> (logits, (k', v'))`` — the
+      cache=(k_pool, v_pool, page_table), positions=lengths) ->
+      (logits, (k_pool', v_pool'))`` — the
       single new token per batch row is embedded at ``positions[b]``,
-      its K/V scattered into the cache, and attention runs length-masked
+      its K/V written into the pool, and attention runs length-masked
       against the cached prefix.
     - **chunked prefill**: same signature with ``tokens[B, C]`` (C > 1)
       — C consecutive prompt tokens per row, embedded at ``positions[b]
       + s``, K/V written to cache ``[positions[b], positions[b] + C)``,
       shifted-causal attention over the cached prefix (the engine's
       chunk-prefill program; one chunk per decode heartbeat).
-    - **paged decode / chunked prefill**: the same two with
-      ``cache=(k_pool, v_pool, page_table)`` — the stacked pools
+
+      In both the stacked pools
       ``[layers, num_pages, h, d, page_len]`` are ONE value carried
       through the layer loop: each block writes its K/V into them in
       place and its kernel reads them in place (see
@@ -534,9 +489,12 @@ class TransformerLM(nn.Module):
       updated where it lives.
     - **speculative verify**: chunked prefill with
       ``unaligned_append=True`` — a ``[B, K+1]`` draft block landing at
-      an arbitrary mid-generation offset; paged caches switch to
-      per-position scatters (see :class:`SelfAttention`), contiguous
-      caches are offset-agnostic already.
+      an arbitrary mid-generation offset, written by per-position
+      scatters (see :class:`SelfAttention`).
+    - ``return_kv=True`` (no cache): the plain forward, additionally
+      returning ``(k, v)`` stacked per layer ``[layers, B, h, S, d]``.
+      Its one use is the int8 KV tier's calibration forward
+      (:meth:`apex_tpu.serving.kv_quant.KVQuantConfig.resolve_scales`).
 
     ``inference_dtype`` (normally the amp half dtype) pins the
     eval-mode GEMM/cache dtype independently of the training policy, so
@@ -584,8 +542,8 @@ class TransformerLM(nn.Module):
         if self.inference_dtype is not None and not train:
             dense_dtype = self.inference_dtype
         if cache is not None and return_kv:
-            raise ValueError("cache (decode) and return_kv (prefill) are "
-                             "exclusive modes")
+            raise ValueError("cache (decode) and return_kv (calibration) "
+                             "are exclusive modes")
         if self.weight_quant and train:
             raise ValueError(
                 "weight_quant is a serving-only mode: int8 kernels "
@@ -621,8 +579,7 @@ class TransformerLM(nn.Module):
         if self.remat and cache is None and not return_kv:
             block_cls = nn.remat(TransformerBlock, static_argnums=(2,))
         kv_out = ([], [])
-        paged = cache is not None and len(cache) == 3
-        if paged:
+        if cache is not None:
             k_pool, v_pool, page_table = cache
         for i in range(self.num_layers):
             block = block_cls(self.hidden, self.num_heads, self.mlp_ratio,
@@ -632,10 +589,7 @@ class TransformerLM(nn.Module):
                               weight_quant=self.weight_quant,
                               name=f"block_{i}")
             # quantized cache: this layer's per-head scale pair
-            # ([layers, heads] engine arrays sliced at i) — threaded
-            # into BOTH inference modes, so monolithic (return_kv)
-            # prefill attends the same storage grid the cache modes
-            # write and read
+            # ([layers, heads] engine arrays sliced at i)
             layer_scales = None if kv_scales is None else \
                 (kv_scales[0][i], kv_scales[1][i])
             # multi-tenant LoRA: this layer's slice of the stacked
@@ -650,7 +604,7 @@ class TransformerLM(nn.Module):
                             lora["mlp_out_b"][i]),
                 "alpha": lora["alpha"],
             }
-            if paged:
+            if cache is not None:
                 # paged pools [layers, P, h, d, page_len] + one shared
                 # [B, max_pages] page table: the WHOLE pools go in with
                 # the layer to write and read, and come back updated in
@@ -662,23 +616,8 @@ class TransformerLM(nn.Module):
                     unaligned_append=unaligned_append,
                     kv_scales=layer_scales, lora=layer_lora,
                     adapter_ids=adapter_ids, layer=i)
-            elif cache is not None:
-                # per-slot rows [layers, B, h, L, d], sliced per layer
-                # and restacked below (the contiguous layout)
-                layer_cache = (cache[0][i], cache[1][i])
-                x, (lk, lv) = block(x, train, cache=layer_cache,
-                                    positions=positions,
-                                    unaligned_append=unaligned_append,
-                                    kv_scales=layer_scales,
-                                    lora=layer_lora,
-                                    adapter_ids=adapter_ids)
-                kv_out[0].append(lk)
-                kv_out[1].append(lv)
             elif return_kv:
-                x, (lk, lv) = block(x, train, return_kv=True,
-                                    kv_scales=layer_scales,
-                                    lora=layer_lora,
-                                    adapter_ids=adapter_ids)
+                x, (lk, lv) = block(x, train, return_kv=True)
                 kv_out[0].append(lk)
                 kv_out[1].append(lv)
             else:
@@ -713,9 +652,9 @@ class TransformerLM(nn.Module):
                              jnp.asarray(embed.embedding, jnp.float32).T)
             if self.weight_quant:
                 logits = logits * embed.embedding_scale
-        if paged:
+        if cache is not None:
             return logits, (k_pool, v_pool)
-        if cache is not None or return_kv:
+        if return_kv:
             return logits, (jnp.stack(kv_out[0]), jnp.stack(kv_out[1]))
         return logits
 

@@ -43,9 +43,6 @@ Serving modes (``serving.Engine`` drives them; all with ``train=False``):
   written in place as :class:`~apex_tpu.models.transformer_lm
   .SelfAttention` does, ``state'`` what position ``n_valid - 1`` leaves,
   logits of THAT position only (``[B, 1, V]``) when ``n_valid`` is given.
-- **monolithic prefill**: ``return_kv=True`` [+ ``n_valid``] — state
-  starts from zeros; returns ``(logits, (k, v, state',
-  tokens_per_expert))`` with ``k``/``v`` ``[layers, B, kv_heads, S, d]``.
 - plain forward: logits ``[B, S, V]``.
 
 Rotary positions are absolute in every mode: ``positions[b] + s``.
@@ -187,7 +184,7 @@ class ZayaLM(nn.Module):
 
     # ------------------------------------------------------------ sublayers
     def _attention(self, u, lp, cdt, *, layer, cache, positions, prev,
-                   n_valid, return_kv):
+                   n_valid):
         """``u [B, S, H]`` (normed, compute dtype) -> ``(out [B, S, H],
         cache aux, state row [B, W])``."""
         B, S, _ = u.shape
@@ -281,7 +278,7 @@ class ZayaLM(nn.Module):
                 ctx = prefill_attention(q, k, v,
                                         jnp.zeros((B,), jnp.int32),
                                         scale=scale)
-                aux = (k, v) if return_kv else None
+                aux = None
             ctx = jnp.moveaxis(ctx, 1, 2).reshape(B, S, nq * d)
             out = jnp.dot(jnp.asarray(ctx, cdt), jnp.asarray(lp["wo"], cdt))
         return out, aux, row
@@ -349,16 +346,12 @@ class ZayaLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False, cache=None,
-                 positions=None, return_kv: bool = False, state=None,
-                 n_valid=None, valid=None):
+                 positions=None, state=None, n_valid=None, valid=None):
         if train:
             raise NotImplementedError(
                 "ZayaLM is a serving model: the expert layer's training "
                 "path (capacity, balancing loss, expert-parallel exchange) "
                 "is transformer.moe.MoEMLP's and is not wired to it")
-        if cache is not None and return_kv:
-            raise ValueError("cache (decode) and return_kv (prefill) are "
-                             "exclusive modes")
         if cache is not None and len(cache) != 3:
             raise NotImplementedError(
                 "ZayaLM: the paged cache (k_pool, v_pool, page_table) only")
@@ -380,7 +373,7 @@ class ZayaLM(nn.Module):
                 jnp.arange(S, dtype=jnp.int32)[None]
                 < jnp.asarray(n_valid, jnp.int32)[:, None])
         r = jnp.zeros((B, S, self.router_width), jnp.float32)
-        rows, counts, kv = [], [], ([], [])
+        rows, counts = [], []
         pools = None if cache is None else (cache[0], cache[1])
 
         def residual(x, fx, rp):
@@ -397,12 +390,9 @@ class ZayaLM(nn.Module):
                 u, lp["attn"], cdt, layer=i,
                 cache=None if pools is None else pools + (cache[2],),
                 positions=positions, prev=jnp.asarray(state[i], cdt),
-                n_valid=n_valid, return_kv=return_kv)
+                n_valid=n_valid)
             if pools is not None:
                 pools = aux
-            elif return_kv:
-                kv[0].append(aux[0])
-                kv[1].append(aux[1])
             rows.append(row)
             x = residual(x, out, lp["attn_res"])
             u = rms_norm_reference(x, lp["moe_norm"]["scale"], self.rms_eps)
@@ -425,7 +415,4 @@ class ZayaLM(nn.Module):
         counts = jnp.stack(counts)                           # [L, E]
         if pools is not None:
             return logits, pools + (new_state, counts)
-        if return_kv:
-            return logits, (jnp.stack(kv[0]), jnp.stack(kv[1]), new_state,
-                            counts)
         return logits

@@ -6,32 +6,26 @@ workload the north star calls for — serving a stream of variable-length
 generation requests from a fixed set of compiled programs:
 
 - :class:`PagedKVCache` + :class:`PagePool` (:mod:`.kv_cache`) — the
-  DEFAULT cache layout: a dense ``[layers, num_pages, heads, head_dim,
+  cache layout: a dense ``[layers, num_pages, heads, head_dim,
   page_len]`` page pool plus a host-side allocator (free list, page
   refcounts, admission reservations). Requests own page lists, not
   rows: short prompts stop paying ``max_len`` HBM, freed pages return
   to the pool immediately, and prefix hits are copy-on-write page
-  shares (refcount bump — zero data movement). :class:`KVCache` keeps
-  the original contiguous per-slot-row layout as the parity oracle and
-  measurable baseline (``Engine(paged=False)``).
-- :class:`Engine` (:mod:`.engine`) — exactly THREE XLA executables on
-  the paged path (jitted chunk-prefill + decode step + the legacy
-  monolithic prefill baseline, each gathering K/V through a
+  shares (refcount bump — zero data movement).
+- :class:`Engine` (:mod:`.engine`) — exactly TWO XLA executables
+  (jitted chunk-prefill + decode step, each gathering K/V through a
   ``[slots, max_pages]`` page-table operand; traced offset/length/
-  temperature scalars), four on the contiguous path (+ the prefix KV
-  row-copy, retired from the paged hit path); greedy / temperature /
+  temperature scalars); greedy / temperature /
   top-k sampling compiled in; attention through the ``decode.*``-tuned
-  kernels of :mod:`apex_tpu.kernels.decode_attention` /
-  :mod:`apex_tpu.kernels.prefill_attention` and their ``paged_*``
-  page-table variants.
+  ``paged_*`` kernels of :mod:`apex_tpu.kernels.decode_attention` /
+  :mod:`apex_tpu.kernels.prefill_attention`.
 - :class:`PrefixCache` (:mod:`.prefix_cache`) — content-addressed
   prompt-prefix reuse: retained prefixes keyed by a rolling hash over
-  ``chunk_len``-aligned token blocks. Paged: entries record the page
+  ``chunk_len``-aligned token blocks. Entries record the page
   ids already holding the prefix (registration and hits are refcount
-  bumps; LRU eviction under pool pressure only). Contiguous: entries
-  own ``prefix_pool`` cache rows with refcount pinning + LRU eviction,
-  hits restored by one row-copy. Both skip ``matched_len / chunk_len``
-  chunks of prefill compute, token-exact vs. the cold path.
+  bumps; LRU eviction under pool pressure only). A hit skips
+  ``matched_len / chunk_len`` chunks of prefill compute, token-exact
+  vs. the cold path.
 - :class:`Scheduler` (:mod:`.scheduler`) — continuous batching with
   chunked prefill fused into the decode heartbeat: admit-into-free-slots,
   at most ``chunk_budget`` compiled chunk-prefill steps per tick (so
@@ -55,8 +49,8 @@ generation requests from a fixed set of compiled programs:
   (``Scheduler(speculative=True)``; rejected-tail K/V never becomes
   visible — rollback is a host/length decrement).
 
-- :mod:`.sharding` — tensor-parallel serving (``Engine(mesh=...)``,
-  paged only): a ``match_partition_rules``-style rule table over the
+- :mod:`.sharding` — tensor-parallel serving (``Engine(mesh=...)``):
+  a ``match_partition_rules``-style rule table over the
   TransformerLM pytree plus shard_map-wrapped engine programs. The KV
   pool shards along the heads axis so attention never crosses ICI;
   the only collectives are two psums per transformer block plus one
@@ -89,7 +83,7 @@ generation requests from a fixed set of compiled programs:
   run; containment adds ZERO compiled programs.
 
 - :class:`HostTier` / :class:`SwapWorker` (:mod:`.host_tier`) —
-  hierarchical KV (``Engine(host_tier=<bytes>)``, paged +
+  hierarchical KV (``Engine(host_tier=<bytes>)``,
   ``prefix_pool > 0``; composes with ``mesh=``): a bounded host-DRAM
   arena behind the page pool. A prefix entry evicted under pool
   pressure has its page bytes migrated device→host (int8 under
@@ -189,8 +183,8 @@ Quick start::
                                       max_new_tokens=64)])
     generated = done[0].output_tokens
 
-Exercised end-to-end by ``bench_serving.py`` and
-``examples/lm/main_amp.py --generate``.
+Exercised end-to-end by ``benchmarks/run.py`` (the serving cells of
+``BENCHMARK.json``) and ``examples/lm/main_amp.py --generate``.
 """
 
 from . import routing_policy, sharding
@@ -200,7 +194,7 @@ from .faults import (FaultPlan, FaultPolicy, FaultSpec, InjectedFault,
 from .fleet import FleetController, WorkerDied
 from .host_tier import (HostTier, SwapWorker, record_from_wire,
                         record_to_wire)
-from .kv_cache import KVCache, PagedKVCache, PagePool, SlotState
+from .kv_cache import PagedKVCache, PagePool, SlotState
 from .kv_quant import KVQuantConfig
 from .lora import LoRAConfig, LoRAManager
 from .prefix_cache import PrefixCache, PrefixMatch
@@ -216,7 +210,7 @@ from .weight_quant import WeightQuantConfig
 __all__ = ["DeadlineUnmeetable", "DraftWorker", "Engine", "FaultPlan",
            "FaultPolicy",
            "FaultSpec", "FleetController", "HostTier", "InjectedFault",
-           "KVCache", "KVQuantConfig", "LoRAConfig", "LoRAManager",
+           "KVQuantConfig", "LoRAConfig", "LoRAManager",
            "PagedKVCache", "PagePool", "SlotState",
            "PendingDecode", "PoolAuditor", "PoolInvariantError",
            "PrefixCache", "PrefixMatch", "QueueFull", "Request",

@@ -1,55 +1,34 @@
-"""Compiled inference engine: chunk-prefill, decode, prefill (+ KV copy).
+"""Compiled inference engine: chunk prefill and decode over a paged KV pool.
 
-Two cache layouts share this one class:
+The cache is a dense pool of fixed-size pages
+(:class:`~apex_tpu.serving.PagedKVCache`) addressed through per-slot
+page tables (:class:`~apex_tpu.serving.PagePool` host allocator);
+lengths live host-side. The engine owns the XLA executables a serving
+process needs, each traced once at fixed shapes and each taking a
+``[.., max_pages]`` int32 page-table operand next to the tokens:
 
-- **paged** (the default): a dense pool of fixed-size pages
-  (:class:`~apex_tpu.serving.PagedKVCache`) addressed through per-slot
-  page tables (:class:`~apex_tpu.serving.PagePool` host allocator).
-  THREE compiled programs — chunk prefill, decode, monolithic prefill —
-  each taking a ``[.., max_pages]`` int32 page-table operand next to
-  the tokens; lengths live host-side. Prefix reuse is copy-on-write:
-  a hit SHARES the donor's pages (refcount bump, zero data movement),
-  so the fourth program of the contiguous layout — the KV row copy —
-  is retired from the hit path and never compiles here.
-- **contiguous** (``paged=False``): the original per-slot-row layout,
-  kept verbatim as the paged path's parity oracle and the measurable
-  baseline — exactly as the monolithic prefill is kept inside the
-  chunked scheduler. Its program set is the original four.
-
-The contiguous engine owns the four — and exactly four — XLA
-executables a serving process needs, each traced once at fixed shapes:
-
-- **chunk prefill** (the scheduler's ingestion path): ``[1, chunk_len]``
+- **chunk prefill** (the one ingestion path): ``[1, chunk_len]``
   tokens (one chunk of a prompt, right-padded on the final partial
-  chunk) → the model's chunked-prefill forward against ONE cache slot
-  (:meth:`KVCache.slot_view`), K/V written at ``[offset, offset +
+  chunk) → the model's chunked-prefill forward against ONE slot's
+  pages, K/V written as whole pages at ``[offset, offset +
   chunk_len)``, shifted-causal attention over the slot's existing
   prefix, a token sampled from the last *valid* row (the request's
-  first token when the chunk is final; discarded otherwise). Slot,
-  offset, valid-count, temperature and the PRNG key are *traced*
-  scalars — every chunk of every prompt lands in this one executable,
-  and the scheduler runs at most one between decode steps, so in-flight
+  first token when the chunk is final; discarded otherwise). Offset,
+  valid-count, temperature and the PRNG key are *traced* scalars —
+  every chunk of every prompt lands in this one executable, and the
+  scheduler runs at most one between decode steps, so in-flight
   decodes never wait more than one chunk for a new admit.
 - **decode step**: ``[slots, 1]`` tokens (every slot's latest token) →
   single-token cached forward, one new token per slot. Inactive slots
   compute too (their output is discarded and their length frozen) —
   that padding waste is the price of a fixed-shape program, and the
   scheduler reports it.
-- **monolithic prefill** (legacy/baseline): ``[1, prefill_len]`` tokens
-  → full causal forward (``return_kv=True``), whole prompt in one call.
-  Kept as the chunked path's bitwise-parity oracle and the
-  head-of-line-blocking baseline (``Scheduler(chunked=False)``,
-  ``bench_serving.py --mixed-prompts``); it stalls every active decode
-  slot for the full prompt, which is exactly what chunking removes.
-- **KV row copy** (prefix reuse): donor slot → destination slot via
-  dynamic slices (the :meth:`KVCache.slot_view`/:meth:`KVCache
-  .write_slot` pattern), traced source/destination/length scalars. One
-  program serves both directions of content-addressed prompt caching —
-  registering a completed prefix into a pool row and restoring a
-  matched prefix into a freshly admitted slot — after which the
-  remaining suffix flows through the *existing* chunk-prefill program
-  starting at the matched offset, skipping ``matched_len / chunk_len``
-  chunks of attention+MLP compute outright.
+
+Prefix reuse is copy-on-write: a hit SHARES the donor's pages
+(refcount bump, zero data movement) and the remaining suffix flows
+through the chunk program starting at the matched offset, skipping
+``matched_len / chunk_len`` chunks of attention+MLP compute outright —
+no program of its own.
 
 Sampling runs inside the compiled programs: greedy when a slot's
 temperature is 0, else temperature softmax over logits optionally
@@ -67,31 +46,28 @@ operand (all-zero in production — adding +0.0 to an fp32 row is
 value-identical — NaN/Inf under a
 :class:`~apex_tpu.serving.FaultPlan`, which makes the guard fire on
 real non-finite logits). Verdicts land in
-:attr:`Engine.last_decode_finite` / :attr:`Engine.last_chunk_finite` /
-:attr:`Engine.last_prefill_finite` and count
-``serving.faults.nonfinite``.
+:attr:`Engine.last_decode_finite` / :attr:`Engine.last_chunk_finite`
+and count ``serving.faults.nonfinite``.
 
 **Speculative verify** (``spec=SpecConfig(...)``): one more compiled
 program — a BATCHED ``[slots, K+1]`` draft-and-verify step built on the
 chunk-append machinery, the same fixed-shape discipline as decode:
 every verify-eligible slot shares ONE program invocation per heartbeat
 (instead of B sequential single-slot calls), and slots not verifying
-ride along as padding whose cache bytes are provably untouched (paged:
-their table-row operand is zeroed so writes land on the sentinel page;
-contiguous: their rows are masked back to their prior bytes
-in-program). The host drafts K tokens per slot (prompt-lookup n-gram —
-see :mod:`apex_tpu.serving.speculative`), the program embeds each
-row's ``[last_token, d_1 .. d_K]`` at that slot's current offset,
-writes their K/V (paged: per-position scatters — ``unaligned_append``;
-contiguous: the ordinary offset chunk write), runs shifted-causal
-attention, and computes ACCEPT-LONGEST-PREFIX *in-program* per row:
+ride along as padding whose cache bytes are provably untouched (their
+table-row operand is zeroed so writes land on the sentinel page). The
+host drafts K tokens per slot (prompt-lookup n-gram — see
+:mod:`apex_tpu.serving.speculative`), the program embeds each row's
+``[last_token, d_1 .. d_K]`` at that slot's current offset, writes
+their K/V (per-position scatters — ``unaligned_append``), runs
+shifted-causal attention, and computes ACCEPT-LONGEST-PREFIX
+*in-program* per row:
 greedy target ``g_s``, ``n_accepted`` = the longest run with
 ``d_i == g_{i-1}``. The emitted tokens ``g_0 .. g_m`` are the
 program's own greedy targets, so greedy output is token-identical to
 plain decode by construction. The rejected tail's K/V is written but
-NEVER visible: lengths are what gate attention, and the contiguous
-program sets each verifying slot's length to ``offset + n_accepted +
-1`` itself (the paged host does the same to its host-side lengths) —
+NEVER visible: lengths are what gate attention, and the host sets each
+verifying slot's length to ``offset + n_accepted + 1`` —
 rollback is a length decrement, no cache mutation to undo; the stale
 positions are overwritten write-then-attend before anything can attend
 them (the same contract inactive-slot decode writes already live by).
@@ -127,7 +103,7 @@ operands are built and uploaded (:meth:`Engine._operands`) before the
 compiled call that takes them, so that what a launch costs on silicon
 is a number and not an assumption.
 
-**Tensor parallelism** (``mesh=...``, paged only): the same programs,
+**Tensor parallelism** (``mesh=...``): the same programs,
 shard_map'd over a 1-D tensor-parallel mesh axis
 (:mod:`apex_tpu.serving.sharding`). Params split per a
 ``match_partition_rules`` table (qkv/MLP-up column-parallel, proj/
@@ -148,14 +124,12 @@ cache in the same dtype); pass ``policy=amp.resolve_policy("O0")`` for
 an exact-fp32 engine (the decode-parity tests' configuration).
 
 Trace accounting: the python bodies of the programs run only when jax
-traces them, so ``chunk_traces``/``decode_traces``/``prefill_traces``/
-``copy_traces`` count compiles — the serving test tier pins the
-contiguous engine to exactly four compiled programs across a
-multi-request, variable-length, hit/miss/evict run that exercises all
-four paths, and the paged engine to exactly THREE across the same
-stream (copy-on-write sharing is host bookkeeping, not a program).
+traces them, so ``chunk_traces``/``decode_traces`` count compiles — the
+serving test tier pins the engine to exactly TWO compiled programs
+across a multi-request, variable-length, hit/miss/evict run
+(copy-on-write sharing is host bookkeeping, not a program).
 
-Paged-mode host bookkeeping (all numpy, no device work):
+Host bookkeeping (all numpy, no device work):
 
 - ``page_len`` positions per page (``decode.page_len`` tuned key,
   degraded to divide ``chunk_len`` — chunk writes must cover whole
@@ -191,8 +165,8 @@ from apex_tpu.log_util import get_logger
 from apex_tpu.telemetry import tracing
 
 from .host_tier import HostTier, SwapWorker
-from .kv_cache import KVCache, PagedKVCache, PagePool, SlotState
-from .kv_quant import KVQuantConfig, quantize
+from .kv_cache import PagedKVCache, PagePool, SlotState
+from .kv_quant import KVQuantConfig
 from .prefix_cache import PrefixCache
 from .speculative import SpecConfig
 from .weight_quant import WeightQuantConfig
@@ -204,9 +178,9 @@ _logger = get_logger("serving")
 
 
 def resolve_page_len(chunk_len: int, page_len: Optional[int] = None) -> int:
-    """The paged engine's page-size resolution, exposed so external
-    sizers (``bench_serving.paged_capacity_stats``) compute pool
-    geometry with the SAME value the constructor will: an explicit
+    """The engine's page-size resolution, exposed so external sizers
+    compute pool geometry with the SAME value the constructor will: an
+    explicit
     ``page_len`` must divide ``chunk_len`` (chunk writes must cover
     whole pages — the copy-on-write invariant); the default is the
     ``decode.page_len`` tuned key, else ``min(chunk_len, 128)``,
@@ -260,7 +234,7 @@ class PendingDecode:
     so reconcile can observe the full dispatch→retire latency as
     ``serving.decode.step_s`` (in sync mode reconcile follows dispatch
     immediately and the reading degenerates to today's measurement).
-    ``attended`` (paged engines) is what each decoding row's length was
+    ``attended`` is what each decoding row's length was
     in this step, kept for reconcile's ``serving.decode.pages_live`` /
     ``pages_tabled`` counters: dispatch counts nothing itself."""
 
@@ -268,8 +242,8 @@ class PendingDecode:
     finite: Any                 # [slots] bool, ON DEVICE until reconcile
     active: np.ndarray          # [slots] bool, host dispatch mask
     t_dispatch: float
+    attended: np.ndarray        # [decoding rows] int lengths
     reconciled: bool = False
-    attended: Optional[np.ndarray] = None   # [decoding rows] int lengths
 
 
 @dataclasses.dataclass
@@ -303,7 +277,7 @@ class Engine:
     model:
         A flax module with the cache-threading contract of
         :class:`apex_tpu.models.transformer_lm.TransformerLM`
-        (``return_kv`` prefill, ``cache``/``positions`` decode) and the
+        (``cache``/``positions`` chunk prefill and decode) and the
         geometry attributes ``num_layers``/``num_heads``/``hidden``/
         ``max_seq_len``.
     params:
@@ -317,8 +291,8 @@ class Engine:
         Cache positions per slot (prompt + generation budget); must not
         exceed the model's ``max_seq_len``.
     prefill_len:
-        Fixed padded prompt capacity of the prefill programs
-        (``<= max_len``). Longer prompts are rejected at submit time.
+        The longest prompt the chunk program accepts (``<= max_len``).
+        Longer prompts are rejected at submit time.
     chunk_len:
         Tokens per chunk-prefill step (default ``min(prefill_len,
         256)``). Smaller chunks bound the stall a prefill imposes on
@@ -329,31 +303,23 @@ class Engine:
         An :class:`apex_tpu.amp.Policy` governing weight/cache storage;
         default ``resolve_policy("O3", verbose=False)`` (pure bf16).
     prefix_pool:
-        Cache rows reserved past the serving slots for content-addressed
-        prompt-prefix reuse (0 = off). When > 0 the engine allocates
-        ``slots + prefix_pool`` rows, compiles the fourth (KV row-copy)
-        program lazily on first use, and exposes a
+        Full-length requests' worth of pages set aside in the
+        ``num_pages`` default for content-addressed prompt-prefix reuse
+        (0 = off). When > 0 the engine exposes a
         :class:`~apex_tpu.serving.PrefixCache` as ``prefix_cache``
-        (consulted by ``Scheduler(retain_prefixes=True)``). The decode
-        batch stays ``[slots, 1]`` — pool rows are never computed over.
-    paged:
-        True (default) = paged pool + page-table indirection (three
-        compiled programs, copy-on-write prefix sharing); False = the
-        original contiguous per-slot-row layout (four programs, prefix
-        reuse by compiled row copy) — kept as the parity oracle and
-        measurable baseline.
+        (consulted by ``Scheduler(retain_prefixes=True)``); retained
+        prefixes share the one pool copy-on-write.
     page_len:
-        Positions per page (paged only). Default: the ``decode.page_len``
+        Positions per page. Default: the ``decode.page_len``
         tuned key, else ``min(chunk_len, 128)``, degraded to the largest
         common divisor of ``chunk_len`` — a page is the unit of sharing
         and must be covered whole by every chunk write. An explicit
         value that does not divide ``chunk_len`` is rejected.
     num_pages:
-        Physical pool pages INCLUDING the page-0 sentinel (paged only).
+        Physical pool pages INCLUDING the page-0 sentinel.
         Default: ``(slots + prefix_pool) * ceil(max_len / page_len) + 1``
-        — the same HBM the contiguous layout would spend on full-length
-        rows; size it down for denser sharing or up for more retained
-        prefixes.
+        — every slot and retained prefix at full length; size it down
+        for denser sharing or up for more retained prefixes.
     spec:
         A :class:`~apex_tpu.serving.SpecConfig` enabling the
         speculative-verify program (``draft_len`` fixes its
@@ -364,7 +330,7 @@ class Engine:
         :meth:`verify_batch` / :meth:`verify_step`.
     mesh:
         A 1-D :class:`jax.sharding.Mesh` enabling tensor-parallel
-        serving (paged only): every compiled program runs shard_map'd
+        serving: every compiled program runs shard_map'd
         over the mesh axis with params split per the
         :mod:`~apex_tpu.serving.sharding` rule table and the KV pool
         sharded along heads (``heads % tp == 0`` enforced, as are the
@@ -372,7 +338,7 @@ class Engine:
         the verbatim single-chip engine.
     kv_quant:
         A :class:`~apex_tpu.serving.KVQuantConfig` turning on the
-        quantized cache STORAGE tier (works on both layouts, composes
+        quantized cache STORAGE tier (composes
         with prefix sharing, speculative verify and ``mesh=``): K/V are
         stored as int8 with per-``[layer, head]`` fp32 scales carried
         in the cache pytree — halving pool HBM, so the same bytes hold
@@ -380,14 +346,13 @@ class Engine:
         attention kernels dequantize in-kernel. Scales are calibrated
         (or given) at construction; degenerate calibration (absmax 0 /
         non-finite) raises HERE. Greedy output becomes a
-        token-match-rate claim vs the bf16 oracle
-        (``bench_serving.py --quantized-kv``); ``kv_quant=None`` (the
+        token-match-rate claim vs the bf16 oracle; ``kv_quant=None`` (the
         default) is the bitwise bf16 baseline — none of the quant code
         is on its trace path. The program set is unchanged either way
         (dequant is fused, never a new executable).
     weight_quant:
         A :class:`~apex_tpu.serving.WeightQuantConfig` turning on the
-        quantized WEIGHT storage tier (both layouts; composes with
+        quantized WEIGHT storage tier (composes with
         ``kv_quant``, prefix sharing, speculative verify, the async
         heartbeat, ``host_tier`` and ``mesh=``): the big serving GEMM
         kernels — qkv, proj, MLP in/out, and the tied vocab head — are
@@ -401,12 +366,11 @@ class Engine:
         the (policy-cast) weights themselves, resolved HERE with the
         loud degenerate-channel failure; under a mesh the scales shard
         with their kernels per the partition-rule table. Greedy output
-        becomes a token-match-rate claim vs the bf16 oracle
-        (``bench_serving.py --quantized-weights``);
+        becomes a token-match-rate claim vs the bf16 oracle;
         ``weight_quant=None`` (the default) is the bitwise baseline —
         none of the quant code is on its trace path.
     host_tier:
-        Hierarchical-KV host-DRAM prefix tier (paged only, requires
+        Hierarchical-KV host-DRAM prefix tier (requires
         ``prefix_pool > 0``; composes with ``mesh=``): an int capacity
         in BYTES, or a pre-built :class:`~apex_tpu.serving.HostTier`.
         When set, a prefix entry evicted under pool pressure has its
@@ -441,27 +405,23 @@ class Engine:
         behaviour — swap-out forces the gathered bytes to host and
         stores them inline on the admission path (no worker thread).
         The emitted token streams are bitwise identical either way
-        (pinned); the hatch exists for debugging and as the bench's
-        measurable baseline (``serving.swap.admit_stall_s`` sync vs
-        async is the admission-stall claim).
+        (pinned); the hatch exists for debugging and as the measurable
+        baseline (``serving.swap.admit_stall_s`` sync vs async is the
+        admission-stall claim).
     top_k:
         Static top-k truncation for sampled (non-greedy) slots; 0 = off.
     registry:
         Optional :class:`apex_tpu.telemetry.MetricsRegistry`; when set,
         the engine observes ``serving.decode.step_s`` and
-        ``serving.prefill.s`` latencies and counts generated tokens.
-
-    Prefill attention geometry honours the tuned-override registry keys
-    ``decode.prefill_block_q``/``decode.prefill_block_k`` (0/absent →
-    the flash kernel's own ``flash.*`` resolution).
+        ``serving.prefill_chunk_s`` latencies and counts generated
+        tokens.
     """
 
     def __init__(self, model, params, *, slots: int, max_len: int,
                  prefill_len: Optional[int] = None,
                  chunk_len: Optional[int] = None, policy=None,
                  prefix_pool: int = 0, top_k: int = 0, seed: int = 0,
-                 registry=None, paged: bool = True,
-                 page_len: Optional[int] = None,
+                 registry=None, page_len: Optional[int] = None,
                  num_pages: Optional[int] = None,
                  spec: Optional[SpecConfig] = None, mesh=None,
                  kv_quant: Optional[KVQuantConfig] = None,
@@ -550,8 +510,7 @@ class Engine:
                     (kv_quant is not None, "the int8 KV tier (kv_quant)"),
                     (weight_quant is not None, "the int8 weight tier "
                      "(weight_quant)"),
-                    (mesh is not None, "tensor parallelism (mesh=)"),
-                    (not paged, "the contiguous cache (paged=False)")):
+                    (mesh is not None, "tensor parallelism (mesh=)")):
                 if on:
                     raise NotImplementedError(
                         f"serving.Engine: {what} is not built for a model "
@@ -591,12 +550,6 @@ class Engine:
         if mesh is not None:
             from . import sharding as _sharding
 
-            if not paged:
-                raise ValueError(
-                    "Engine(mesh=...) requires paged=True: the sharded "
-                    "programs gather K/V through the heads-sharded page "
-                    "pool; the contiguous layout stays the single-chip "
-                    "parity oracle/baseline")
             self._tp_axis = _sharding.tp_axis_of(mesh)
             self.tp = int(np.prod(mesh.devices.shape))
             _sharding.validate_tp_geometry(
@@ -656,91 +609,71 @@ class Engine:
                 self.params, mesh, num_heads=heads, axis=self._tp_axis)
             self._pspec = _sharding.match_partition_rules(
                 _sharding.partition_rules(self._tp_axis), self.params)
-        self.paged = bool(paged)
-        if self.paged:
-            self.page_len = page_len = resolve_page_len(self.chunk_len,
-                                                        page_len)
-            self.max_pages = -(-self.max_len // page_len)
-            if num_pages is None:
-                # same budget the contiguous layout would spend on
-                # (slots + prefix_pool) full-length rows, plus the
-                # sentinel — the win is that short requests no longer
-                # CONSUME their row's worth
-                num_pages = (self.slots + self.prefix_pool) \
-                    * self.max_pages + 1
-            num_pages = int(num_pages)
-            if num_pages < self.max_pages + 1:
-                raise ValueError(
-                    f"num_pages {num_pages} cannot hold even one "
-                    f"max_len request ({self.max_pages} pages) plus "
-                    f"the sentinel page")
-            self.num_pages = num_pages
-            if mesh is None:
-                state = None
-                if self.slot_state_width:
-                    state = SlotState.create(
-                        layers=layers, slots=self.slots,
-                        width=self.slot_state_width, dtype=half,
-                        num_experts=int(getattr(model, "num_experts", 0)))
-                self.cache = PagedKVCache.create(
-                    layers=layers, num_pages=num_pages, heads=kv_heads,
-                    page_len=page_len, head_dim=head_dim,
-                    dtype=cache_dtype, k_scale=k_scale, v_scale=v_scale,
-                    state=state)
-            else:
-                # heads-axis pool sharding: each shard holds
-                # [layers, num_pages, heads/tp, head_dim, page_len] —
-                # attention never crosses ICI; page tables, lengths and
-                # the allocator stay replicated host state. Allocated
-                # DIRECTLY into the sharded layout (zeros_sharded): a
-                # pool sized to aggregate HBM — the point of sharding
-                # it — must never transit one chip whole. Quantization
-                # scales shard ALONG the pool's heads axis
-                # ([layers, heads/tp] per shard), so each shard
-                # de/quantizes its own heads collective-free.
-                shape = (layers, num_pages, kv_heads, head_dim, page_len)
-                pspec = _sharding.cache_pspec(self._tp_axis)
-                if k_scale is not None:
-                    sspec = _sharding.scale_pspec(self._tp_axis)
-                    from jax.sharding import NamedSharding
-                    k_scale = jax.device_put(
-                        k_scale, NamedSharding(mesh, sspec))
-                    v_scale = jax.device_put(
-                        v_scale, NamedSharding(mesh, sspec))
-                self.cache = PagedKVCache(
-                    k=_sharding.zeros_sharded(shape, cache_dtype, mesh,
-                                              pspec),
-                    v=_sharding.zeros_sharded(shape, cache_dtype, mesh,
-                                              pspec),
-                    k_scale=k_scale, v_scale=v_scale)
-            self.pool = PagePool(num_pages, page_len)
-            self._page_table = np.zeros((self.slots, self.max_pages),
-                                        np.int32)
-            self._n_pages = np.zeros(self.slots, np.int32)
-            self._host_len = np.zeros(self.slots, np.int32)
-            self._slot_reserved = np.zeros(self.slots, np.int32)
-            # paged prefix reuse needs no reserved rows — retained
-            # prefixes share the one pool; prefix_pool sizes the EXTRA
-            # capacity set aside for them in the num_pages default and
-            # gates the feature on, exactly as before
-            self.prefix_cache = None if self.prefix_pool == 0 else \
-                PrefixCache(block_len=self.chunk_len, pool_rows=(),
-                            on_evict=self.pool.release)
+        self.page_len = page_len = resolve_page_len(self.chunk_len,
+                                                    page_len)
+        self.max_pages = -(-self.max_len // page_len)
+        if num_pages is None:
+            # (slots + prefix_pool) full-length requests plus the
+            # sentinel; a short request consumes only its own pages
+            num_pages = (self.slots + self.prefix_pool) \
+                * self.max_pages + 1
+        num_pages = int(num_pages)
+        if num_pages < self.max_pages + 1:
+            raise ValueError(
+                f"num_pages {num_pages} cannot hold even one "
+                f"max_len request ({self.max_pages} pages) plus "
+                f"the sentinel page")
+        self.num_pages = num_pages
+        if mesh is None:
+            state = None
+            if self.slot_state_width:
+                state = SlotState.create(
+                    layers=layers, slots=self.slots,
+                    width=self.slot_state_width, dtype=half,
+                    num_experts=int(getattr(model, "num_experts", 0)))
+            self.cache = PagedKVCache.create(
+                layers=layers, num_pages=num_pages, heads=kv_heads,
+                page_len=page_len, head_dim=head_dim,
+                dtype=cache_dtype, k_scale=k_scale, v_scale=v_scale,
+                state=state)
         else:
-            self.pool = None
-            # pool rows ride the same arrays as the serving slots so
-            # ONE copy program (traced src/dst rows, same shapes)
-            # serves both directions of prefix reuse; decode slices
-            # them back out
-            self.cache = KVCache.create(
-                layers=layers, slots=self.slots + self.prefix_pool,
-                heads=kv_heads, max_len=self.max_len, head_dim=head_dim,
-                dtype=cache_dtype, k_scale=k_scale, v_scale=v_scale)
-            self.prefix_cache = None if self.prefix_pool == 0 else \
-                PrefixCache(
-                    block_len=self.chunk_len,
-                    pool_rows=range(self.slots,
-                                    self.slots + self.prefix_pool))
+            # heads-axis pool sharding: each shard holds
+            # [layers, num_pages, heads/tp, head_dim, page_len] —
+            # attention never crosses ICI; page tables, lengths and
+            # the allocator stay replicated host state. Allocated
+            # DIRECTLY into the sharded layout (zeros_sharded): a
+            # pool sized to aggregate HBM — the point of sharding
+            # it — must never transit one chip whole. Quantization
+            # scales shard ALONG the pool's heads axis
+            # ([layers, heads/tp] per shard), so each shard
+            # de/quantizes its own heads collective-free.
+            shape = (layers, num_pages, kv_heads, head_dim, page_len)
+            pspec = _sharding.cache_pspec(self._tp_axis)
+            if k_scale is not None:
+                sspec = _sharding.scale_pspec(self._tp_axis)
+                from jax.sharding import NamedSharding
+                k_scale = jax.device_put(
+                    k_scale, NamedSharding(mesh, sspec))
+                v_scale = jax.device_put(
+                    v_scale, NamedSharding(mesh, sspec))
+            self.cache = PagedKVCache(
+                k=_sharding.zeros_sharded(shape, cache_dtype, mesh,
+                                          pspec),
+                v=_sharding.zeros_sharded(shape, cache_dtype, mesh,
+                                          pspec),
+                k_scale=k_scale, v_scale=v_scale)
+        self.pool = PagePool(num_pages, page_len)
+        self._page_table = np.zeros((self.slots, self.max_pages),
+                                    np.int32)
+        self._n_pages = np.zeros(self.slots, np.int32)
+        self._host_len = np.zeros(self.slots, np.int32)
+        self._slot_reserved = np.zeros(self.slots, np.int32)
+        # retained prefixes share the one pool; prefix_pool sizes the
+        # EXTRA capacity set aside for them in the num_pages default and
+        # gates the feature on
+        self.prefix_cache = None if self.prefix_pool == 0 else \
+            PrefixCache(block_len=self.chunk_len,
+                        on_evict=self.pool.release)
         # hierarchical KV: the host-DRAM prefix tier behind the paged
         # pool. Wired AFTER the prefix cache exists — eviction becomes
         # swap-out (a dispatched device→host migration; the entry
@@ -755,11 +688,6 @@ class Engine:
         self._swap_worker: Optional[SwapWorker] = None
         self.swap_verify_failed = 0
         if host_tier is not None:
-            if not self.paged:
-                raise ValueError(
-                    "Engine(host_tier=...) requires paged=True: the "
-                    "tier swaps pool pages, and the contiguous layout "
-                    "has none")
             if self.prefix_cache is None:
                 raise ValueError(
                     "Engine(host_tier=...) requires prefix_pool > 0 — "
@@ -831,10 +759,8 @@ class Engine:
         # sees a Request
         self._tracer = None
         self._key = jax.random.PRNGKey(seed)
-        self.prefill_traces = 0
         self.decode_traces = 0
         self.chunk_traces = 0
-        self.copy_traces = 0
         self.verify_traces = 0
         self.swap_in_traces = 0
         self.swap_out_traces = 0
@@ -856,75 +782,45 @@ class Engine:
         self.readback_s = 0.0
         # the non-finite guard's host-side view, refreshed by every
         # sampling call: per-slot flags for the last decode step, one
-        # flag each for the last chunk/monolithic prefill. True means
+        # flag for the last chunk. True means
         # the sampled logits row was entirely finite (the token is
         # trustworthy); False is the quarantine signal the scheduler's
         # fault policy consumes.
         self.last_decode_finite = np.ones(self.slots, bool)
         self.last_chunk_finite = True
-        self.last_prefill_finite = True
         self.last_verify_finite = True
         self.last_verify_finite_slots = np.ones(self.slots, bool)
         self.nonfinite_events = 0
-        # prefill flash-attention geometry: decode.* tuned keys beat the
-        # training sweep's flash.* defaults when present
-        self._pf_bq = vmem.get_override("decode.prefill_block_q", 0,
-                                        multiple=8) or None
-        self._pf_bk = vmem.get_override("decode.prefill_block_k", 0,
-                                        multiple=128) or None
-        if self.paged:
-            # under a mesh each program body runs shard_map'd over the
-            # tensor-parallel axis (params split per the rule table, the
-            # pool on heads, every host operand replicated); mesh=None
-            # wraps nothing — the verbatim single-chip programs
-            # a model with per-slot state runs the same three programs
-            # with the state threaded through (one more operand each)
-            stateful = bool(self.slot_state_width)
-            self._jit_prefill = jax.jit(
-                self._state_prefill_impl if stateful else
-                self._tp_wrap(self._paged_prefill_impl, 2),
-                donate_argnums=(1,))
-            self._jit_decode = jax.jit(
-                self._state_decode_impl if stateful else
-                self._tp_wrap(self._paged_decode_impl, 2),
-                donate_argnums=(1,))
-            self._jit_chunk = jax.jit(
-                self._state_chunk_impl if stateful else
-                self._tp_wrap(self._paged_chunk_impl, 2),
-                donate_argnums=(1,))
-            self._jit_verify = jax.jit(
-                self._tp_wrap(self._paged_verify_impl, 3),
-                donate_argnums=(1,))
-            self._jit_copy = None      # retired: hits share pages
-            _logger.info(
-                "serving engine (paged%s): %d slots x %d positions, "
-                "prefill_len=%d, chunk_len=%d, page_len=%d, %d pages "
-                "(+1 sentinel in count), prefix_pool=%d, cache %s "
-                "(%.1f MiB%s), top_k=%d",
-                f", tp={self.tp}" if mesh is not None else "",
-                self.slots, self.max_len, self.prefill_len,
-                self.chunk_len, self.page_len, self.num_pages,
-                self.prefix_pool, np.dtype(cache_dtype).name,
-                self.cache.nbytes() / 2**20,
-                f", {self.cache.nbytes() / self.tp / 2**20:.1f}/shard"
-                if mesh is not None else "", self.top_k)
-        else:
-            self._jit_prefill = jax.jit(self._prefill_impl,
-                                        donate_argnums=(1,))
-            self._jit_decode = jax.jit(self._decode_impl,
-                                       donate_argnums=(1,))
-            self._jit_chunk = jax.jit(self._chunk_impl,
-                                      donate_argnums=(1,))
-            self._jit_verify = jax.jit(self._verify_impl,
-                                       donate_argnums=(1,))
-            self._jit_copy = jax.jit(self._copy_impl, donate_argnums=(0,))
-            _logger.info(
-                "serving engine: %d slots x %d positions, prefill_len=%d,"
-                " chunk_len=%d, prefix_pool=%d, cache %s (%.1f MiB), "
-                "top_k=%d",
-                self.slots, self.max_len, self.prefill_len,
-                self.chunk_len, self.prefix_pool, np.dtype(cache_dtype).name,
-                self.cache.nbytes() / 2**20, self.top_k)
+        # under a mesh each program body runs shard_map'd over the
+        # tensor-parallel axis (params split per the rule table, the
+        # pool on heads, every host operand replicated); mesh=None
+        # wraps nothing — the verbatim single-chip programs
+        # a model with per-slot state runs the same two programs
+        # with the state threaded through (one more operand each)
+        stateful = bool(self.slot_state_width)
+        self._jit_decode = jax.jit(
+            self._state_decode_impl if stateful else
+            self._tp_wrap(self._paged_decode_impl, 2),
+            donate_argnums=(1,))
+        self._jit_chunk = jax.jit(
+            self._state_chunk_impl if stateful else
+            self._tp_wrap(self._paged_chunk_impl, 2),
+            donate_argnums=(1,))
+        self._jit_verify = jax.jit(
+            self._tp_wrap(self._paged_verify_impl, 3),
+            donate_argnums=(1,))
+        _logger.info(
+            "serving engine (paged%s): %d slots x %d positions, "
+            "prefill_len=%d, chunk_len=%d, page_len=%d, %d pages "
+            "(+1 sentinel in count), prefix_pool=%d, cache %s "
+            "(%.1f MiB%s), top_k=%d",
+            f", tp={self.tp}" if mesh is not None else "",
+            self.slots, self.max_len, self.prefill_len,
+            self.chunk_len, self.page_len, self.num_pages,
+            self.prefix_pool, np.dtype(cache_dtype).name,
+            self.cache.nbytes() / 2**20,
+            f", {self.cache.nbytes() / self.tp / 2**20:.1f}/shard"
+            if mesh is not None else "", self.top_k)
 
         self._emit_tp_gauges()
         self._emit_kv_gauges()
@@ -1200,19 +1096,17 @@ class Engine:
     @property
     def compiled_programs(self) -> int:
         """Distinct XLA executables traced so far (the compile-count
-        discipline the serving tests pin: exactly three across a run
-        that exercises chunk prefill, decode, and the monolithic
-        baseline; exactly four once prefix reuse exercises the KV
-        row-copy too — and one more, on either layout, once speculative
-        decoding exercises the verify program: 4 paged, 5 contiguous.
-        The hierarchical-KV tier adds AT MOST one more PER DIRECTION
-        on the paged path: the fixed-shape ``swap_out`` block gather
+        discipline the serving tests pin: exactly two across a run
+        that exercises chunk prefill and decode, prefix hits included —
+        and one more once speculative decoding exercises the verify
+        program.
+        The hierarchical-KV tier adds AT MOST one more PER DIRECTION:
+        the fixed-shape ``swap_out`` block gather
         (traced lazily on the first pressure eviction) and the
         fixed-shape ``swap_in`` block scatter (traced lazily on the
         first hit-after-swap) — both shape-padded to ``max_pages``, so
         no entry size can ever trace a second copy)."""
         return (self.chunk_traces + self.decode_traces
-                + self.prefill_traces + self.copy_traces
                 + self.verify_traces + self.swap_in_traces
                 + self.swap_out_traces)
 
@@ -1236,20 +1130,6 @@ class Engine:
             return None
         return (cache.k_scale, cache.v_scale)
 
-    def _quantize_prefill_kv(self, cache, k_new, v_new):
-        """Quantize a prefill's stacked ``[layers, B, heads, P, d]``
-        K/V into the cache's int8 codes (identity on the bf16 tier):
-        the one STORAGE cast the model does not perform itself, because
-        ``return_kv`` prefill never sees the cache. The model has
-        already round-tripped these values through the scale grid
-        (``kv_scales`` in the ``return_kv`` forward), so this quantize
-        is an exact code recovery — the bytes stored here are the bytes
-        chunked prefill would have written."""
-        if cache.k_scale is None:
-            return k_new, v_new
-        return (quantize(k_new, cache.k_scale[:, None, :, None, None]),
-                quantize(v_new, cache.v_scale[:, None, :, None, None]))
-
     @staticmethod
     def _lora_kw(lora, adapter_ids):
         """The model-apply kwargs for the two optional trailing LoRA
@@ -1257,72 +1137,6 @@ class Engine:
         traces stay verbatim (the bitwise baseline)."""
         return {} if lora is None else {"lora": lora,
                                         "adapter_ids": adapter_ids}
-
-    def _prefill_impl(self, params, cache, tokens, length, slot,
-                      temperature, key, lora=None, adapter_ids=None):
-        self.prefill_traces += 1    # python body runs at trace time only
-        logits, (k_new, v_new) = self._model.apply(
-            {"params": params}, tokens, train=False, return_kv=True,
-            kv_scales=self._kv_scales_of(cache),
-            **self._lora_kw(lora, adapter_ids))
-        k_new, v_new = self._quantize_prefill_kv(cache, k_new, v_new)
-        cache = cache.insert(slot, k_new, v_new, length)
-        last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
-                                            keepdims=False)        # [V]
-        last = jnp.asarray(last, jnp.float32)
-        finite = jnp.all(jnp.isfinite(last))
-        token = sample_tokens(last[None], temperature[None], key,
-                              self.top_k)[0]
-        return cache, token, finite
-
-    def _chunk_impl(self, params, cache, tokens, slot, offset, n_valid,
-                    temperature, fault_bias, key, lora=None,
-                    adapter_ids=None):
-        self.chunk_traces += 1      # python body runs at trace time only
-        k_slot, v_slot = cache.slot_view(slot)
-        offset = jnp.asarray(offset, jnp.int32)
-        logits, (k2, v2) = self._model.apply(
-            {"params": params}, tokens, train=False,
-            cache=(k_slot, v_slot), positions=offset[None],
-            kv_scales=self._kv_scales_of(cache),
-            **self._lora_kw(lora, adapter_ids))
-        cache = cache.write_slot(slot, k2, v2, offset + n_valid)
-        # sample at the last VALID row: the request's first token when
-        # this is the prompt's final chunk, discarded by the host
-        # otherwise (one program either way — finality is not traced)
-        last = jax.lax.dynamic_index_in_dim(logits[0], n_valid - 1,
-                                            keepdims=False)        # [V]
-        last = jnp.asarray(last, jnp.float32) + fault_bias
-        finite = jnp.all(jnp.isfinite(last))
-        token = sample_tokens(last[None], temperature[None], key,
-                              self.top_k)[0]
-        return cache, token, finite
-
-    def _decode_impl(self, params, cache, last_tokens, active,
-                     temperature, fault_bias, key, lora=None,
-                     adapter_ids=None):
-        self.decode_traces += 1     # python body runs at trace time only
-        # prefix-pool rows sit past the serving slots in the same
-        # arrays: slice them out (static) so the decode batch stays
-        # [slots, 1] — retained prefixes cost storage, not compute.
-        # With prefix_pool == 0 the front IS the whole cache and this
-        # degenerates bitwise to a model_view()/advance decode.
-        positions = jnp.minimum(cache.lengths[:self.slots],
-                                self.max_len - 1)
-        logits, (k2, v2) = self._model.apply(
-            {"params": params}, last_tokens[:, None], train=False,
-            cache=cache.front_view(self.slots), positions=positions,
-            kv_scales=self._kv_scales_of(cache),
-            **self._lora_kw(lora, adapter_ids))
-        rows = jnp.asarray(logits[:, 0, :], jnp.float32) \
-            + fault_bias[:, None]
-        finite = jnp.all(jnp.isfinite(rows), axis=-1)         # [slots]
-        tokens = sample_tokens(rows, temperature, key, self.top_k)
-        return cache.advance_front(k2, v2, active), tokens, finite
-
-    def _copy_impl(self, cache, src, dst, length):
-        self.copy_traces += 1       # python body runs at trace time only
-        return cache.copy_slot(src, dst, length)
 
     @staticmethod
     def _accept_longest_prefix(rows, tokens, n_drafted):
@@ -1344,92 +1158,6 @@ class Engine:
             axis=1).astype(jnp.int32)
         return greedy, n_accepted
 
-    def _verify_impl(self, params, cache, tokens, n_drafted, fault_bias,
-                     lora=None, adapter_ids=None):
-        self.verify_traces += 1     # python body runs at trace time only
-        K = tokens.shape[1] - 1
-        # per-row offsets ARE the committed device lengths on the
-        # contiguous layout (device state, exactly like decode); rows
-        # with n_drafted == 0 ride the fixed-shape batch — their writes
-        # are masked back out below, their outputs discarded by the host
-        offsets = cache.lengths[:self.slots]
-        logits, (k2, v2) = self._model.apply(
-            {"params": params}, tokens, train=False,
-            cache=cache.front_view(self.slots), positions=offsets,
-            kv_scales=self._kv_scales_of(cache),
-            **self._lora_kw(lora, adapter_ids))
-        rows = jnp.asarray(logits, jnp.float32) \
-            + fault_bias[:, None, None]
-        finite = jnp.all(jnp.isfinite(rows), axis=(1, 2))     # [slots]
-        greedy, n_accepted = self._accept_longest_prefix(rows, tokens,
-                                                         n_drafted)
-        # commit ONLY the verifying rows whose padded window fits and
-        # that hold a committed prefix: a passenger row near max_len
-        # would have had its [K+1]-wide write clipped back over live
-        # K/V (the model's position safety net relocates, it does not
-        # drop), so its bytes are restored verbatim. verify_batch
-        # raises host-side before any active row can reach this mask
-        # (same contract as the paged path), so the in-program guard is
-        # defense-in-depth for raw _jit_verify callers only — it keeps
-        # an invalid window from corrupting the cache, never a public
-        # API outcome. For verifying rows the rejected tail's K/V sits
-        # past the committed length — unreachable (attention masks by
-        # length) and overwritten write-then-attend by the slot's next
-        # step; rollback is length arithmetic, no cache mutation to
-        # undo.
-        fits = (offsets > 0) & (offsets + K + 1 <= self.max_len)
-        verifying = (n_drafted > 0) & fits
-        mask = verifying[None, :, None, None, None]
-        k_old, v_old = cache.front_view(self.slots)
-        k2 = jnp.where(mask, jnp.asarray(k2, cache.dtype), k_old)
-        v2 = jnp.where(mask, jnp.asarray(v2, cache.dtype), v_old)
-        n_accepted = jnp.where(verifying, n_accepted, 0)
-        new_len = jnp.where(verifying, offsets + n_accepted + 1, offsets)
-        cache = cache.commit_front(k2, v2, new_len)
-        return cache, greedy, n_accepted, finite
-
-    # -------------------------------------------- compiled bodies (paged)
-    def _paged_prefill_impl(self, params, cache, tokens, pt_row, length,
-                            temperature, key, lora=None,
-                            adapter_ids=None):
-        self.prefill_traces += 1    # python body runs at trace time only
-        logits, (k_new, v_new) = self._model.apply(
-            {"params": params}, tokens, train=False, return_kv=True,
-            kv_scales=self._kv_scales_of(cache),
-            **self._lora_kw(lora, adapter_ids))
-        k_new, v_new = self._quantize_prefill_kv(cache, k_new, v_new)
-        cache = self._scatter_prefill(cache, pt_row, k_new, v_new)
-        last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
-                                            keepdims=False)   # [V(/tp)]
-        last = self._gather_logits(jnp.asarray(last, jnp.float32))
-        finite = jnp.all(jnp.isfinite(last))
-        token = sample_tokens(last[None], temperature[None], key,
-                              self.top_k)[0]
-        return cache, token, finite
-
-    def _scatter_prefill(self, cache, pt_row, k_new, v_new):
-        """A monolithic prefill's K/V ``[layers, 1, h, prefill_len, d]``
-        into the slot's pages: the padded ``[0, prefill_len)`` window as
-        m whole pages, ids from the (traced) page-table row."""
-        pl_ = self.page_len
-        m = -(-self.prefill_len // pl_)
-        pad = m * pl_ - self.prefill_len
-        pages = jax.lax.dynamic_slice_in_dim(pt_row[0], 0, m)    # [m]
-
-        def _scatter(pool, new):
-            new = jnp.asarray(new, pool.dtype)
-            if pad:
-                new = jnp.pad(new, ((0, 0), (0, 0), (0, 0), (0, pad),
-                                    (0, 0)))
-            # [layers, 1, h, m*pl, d] -> [layers, m, h, d, pl]
-            new = new[:, 0].reshape(cache.layers, cache.heads, m, pl_,
-                                    cache.head_dim).transpose(0, 2, 1, 4,
-                                                              3)
-            return pool.at[:, pages].set(new)
-
-        return cache.replace(k=_scatter(cache.k, k_new),
-                             v=_scatter(cache.v, v_new))
-
     def _paged_chunk_impl(self, params, cache, tokens, pt_row, offset,
                           n_valid, temperature, fault_bias, key,
                           lora=None, adapter_ids=None):
@@ -1441,7 +1169,9 @@ class Engine:
             kv_scales=self._kv_scales_of(cache),
             **self._lora_kw(lora, adapter_ids))
         cache = cache.replace(k=k2, v=v2)
-        # sample at the last VALID row (see _chunk_impl)
+        # sample at the last VALID row: the request's first token when
+        # this is the prompt's final chunk, discarded by the host
+        # otherwise (one program either way — finality is not traced)
         last = jax.lax.dynamic_index_in_dim(logits[0], n_valid - 1,
                                             keepdims=False)   # [V(/tp)]
         last = self._gather_logits(jnp.asarray(last, jnp.float32)) \
@@ -1505,10 +1235,10 @@ class Engine:
         return cache, greedy, n_accepted, finite
 
     # ------------------------- compiled bodies (paged, per-slot state)
-    # The three heartbeat programs for a model that keeps state per slot
+    # The two heartbeat programs for a model that keeps state per slot
     # beside its pages (kv_cache.SlotState; models.zaya.ZayaLM). Same
     # operands as the paged bodies above plus ONE trailing operand: the
-    # slot a chunk / prefill belongs to, or the decode batch's active
+    # slot a chunk belongs to, or the decode batch's active
     # mask (a slot that is mid-prefill rides the decode batch with its
     # real page table, and its state must not move). The state rides in
     # the donated cache pytree and is written in place like the pool.
@@ -1522,24 +1252,6 @@ class Engine:
         if st.expert_tokens.shape[1]:
             st = st.replace(expert_tokens=st.expert_tokens + counts)
         return logits, k2, v2, rows2, st
-
-    def _state_prefill_impl(self, params, cache, tokens, pt_row, length,
-                            temperature, key, slot):
-        self.prefill_traces += 1    # python body runs at trace time only
-        # a monolithic prefill admits the request: its state starts from
-        # zeros (the model's default), never from what the slot held
-        logits, k_new, v_new, rows, st = self._state_apply(
-            params, cache.state, tokens, None, return_kv=True,
-            n_valid=length[None])
-        st = st.replace(rows=jax.lax.dynamic_update_slice_in_dim(
-            st.rows, jnp.asarray(rows, st.rows.dtype), slot, axis=1))
-        cache = self._scatter_prefill(cache, pt_row, k_new,
-                                      v_new).replace(state=st)
-        last = jnp.asarray(logits[0, 0], jnp.float32)             # [V]
-        finite = jnp.all(jnp.isfinite(last))
-        token = sample_tokens(last[None], temperature[None], key,
-                              self.top_k)[0]
-        return cache, token, finite
 
     def _state_chunk_impl(self, params, cache, tokens, pt_row, offset,
                           n_valid, temperature, fault_bias, key, slot):
@@ -1586,8 +1298,8 @@ class Engine:
         return cache.replace(k=k2, v=v2, state=st), tokens, finite
 
     def _slot_arg(self, slot: int):
-        """The trailing operand of a stateful engine's chunk / prefill
-        call (its slot); nothing on a model without slot state, whose
+        """The trailing operand of a stateful engine's chunk call (its
+        slot); nothing on a model without slot state, whose
         programs keep the operands they always had."""
         return (np.int32(slot),) if self.slot_state_width else ()
 
@@ -1632,7 +1344,7 @@ class Engine:
         snapshot must be taken here and not at completion time. Under
         a mesh each shard gathers its own heads slice (zero
         collectives — pinned from HLO). Pure data movement: no
-        attention, no sampling, no PRNG — the copy-program precedent,
+        attention, no sampling, no PRNG,
         so it owes the tuned tables no ``decode.*`` key."""
         self.swap_out_traces += 1   # python body runs at trace time only
         page_ids = jnp.asarray(page_ids, jnp.int32)
@@ -1648,7 +1360,7 @@ class Engine:
         exactly as it absorbs inactive-slot decode writes). Under a
         mesh each shard scatters its own heads slice (zero
         collectives — pinned from HLO). Pure data movement: no
-        attention, no sampling, no PRNG — the copy-program precedent,
+        attention, no sampling, no PRNG,
         so it owes the tuned tables no ``decode.*`` key."""
         self.swap_in_traces += 1    # python body runs at trace time only
         page_ids = jnp.asarray(page_ids, jnp.int32)
@@ -1660,62 +1372,6 @@ class Engine:
     def _next_key(self):
         self._key, sub = jax.random.split(self._key)
         return sub
-
-    def prefill(self, slot: int, prompt: Sequence[int],
-                temperature: float = 0.0) -> int:
-        """Monolithic prefill: the whole ``prompt`` into ``slot`` in one
-        compiled call; returns the first sampled token (host int) and
-        blocks until it is on the host. This is the legacy/baseline path
-        — it stalls the caller (and any decode heartbeat) for the full
-        prompt; production serving ingests through :meth:`prefill_chunk`
-        one chunk per scheduler tick instead. Kept compiled because it
-        is the chunked path's bitwise-parity oracle and the
-        head-of-line-blocking baseline (``Scheduler(chunked=False)``)."""
-        n = len(prompt)
-        if not 0 < n <= self.prefill_len:
-            raise ValueError(f"prompt length {n} not in (0, "
-                             f"prefill_len={self.prefill_len}]")
-        if not 0 <= slot < self.slots:
-            raise ValueError(f"slot {slot} not in [0, {self.slots})")
-        tokens = np.zeros((1, self.prefill_len), np.int32)
-        tokens[0, :n] = np.asarray(prompt, np.int32)
-        t0 = time.perf_counter()
-        if self.paged:
-            # monolithic prefill writes the full padded window: the
-            # slot restarts cold (stale pages released, the admission
-            # reservation — if the scheduler made one — kept so the
-            # fresh pages draw it down rather than eating into other
-            # slots' promises) with enough pages to hold it
-            self.release_slot(slot, keep_reservation=True)
-            self._grow_slot(slot, -(-self.prefill_len // self.page_len))
-            ops = self._operands(lambda: (
-                jnp.asarray(tokens),
-                jnp.asarray(self._page_table[slot:slot + 1].copy()),
-                np.int32(n), np.float32(temperature),
-                self._next_key(), *self._lora_args(slot),
-                *self._slot_arg(slot)))
-        else:
-            ops = self._operands(lambda: (
-                jnp.asarray(tokens), np.int32(n), np.int32(slot),
-                np.float32(temperature), self._next_key(),
-                *self._lora_args(slot)))
-        self.cache, token, finite = self._runtime_call(
-            "prefill", lambda: self._with_prefill_blocks(
-                lambda: self._jit_prefill(self.params, self.cache,
-                                          *ops)))
-        if self.paged:
-            self._host_len[slot] = n
-        token, self.last_prefill_finite = self._readback(
-            "prefill", lambda: (int(token), bool(finite)))  # device sync
-        if not self.last_prefill_finite:
-            self._count_nonfinite(1)
-        if self._registry is not None:
-            self._registry.observe("serving.prefill.s",
-                                   time.perf_counter() - t0)
-            self._registry.counter_inc("serving.prefill.calls")
-            self._registry.counter_inc("serving.tokens_generated")
-        self.tokens_generated += 1
-        return token
 
     def prefill_chunk(self, slot: int, chunk: Sequence[int], offset: int,
                       temperature: float = 0.0, *, final: bool = True,
@@ -1782,38 +1438,30 @@ class Engine:
         tokens = np.zeros((1, self.chunk_len), np.int32)
         tokens[0, :n] = chunk       # host list -> int32, no device read
         t0 = time.perf_counter()
-        if self.paged:
-            if offset % self.page_len:
-                raise ValueError(
-                    f"paged chunk offset {offset} must be page-aligned "
-                    f"(page_len={self.page_len})")
-            if offset == 0:
-                # cold start on a (possibly re-used) slot: stale pages
-                # back to the pool, the admission reservation kept (the
-                # fresh pages must draw it down, not eat into other
-                # slots' promises). A prefix hit instead enters through
-                # attach_prefix, which resumes at a non-zero offset.
-                self.release_slot(slot, keep_reservation=True)
-            self._grow_slot(
-                slot, -(-(offset + self.chunk_len) // self.page_len))
-            ops = self._operands(lambda: (
-                jnp.asarray(tokens),
-                jnp.asarray(self._page_table[slot:slot + 1].copy()),
-                np.int32(offset), np.int32(n),
-                np.float32(temperature), np.float32(fault_bias),
-                self._next_key(), *self._lora_args(slot),
-                *self._slot_arg(slot)))
-        else:
-            ops = self._operands(lambda: (
-                jnp.asarray(tokens),
-                np.int32(slot), np.int32(offset), np.int32(n),
-                np.float32(temperature), np.float32(fault_bias),
-                self._next_key(), *self._lora_args(slot)))
+        if offset % self.page_len:
+            raise ValueError(
+                f"paged chunk offset {offset} must be page-aligned "
+                f"(page_len={self.page_len})")
+        if offset == 0:
+            # cold start on a (possibly re-used) slot: stale pages
+            # back to the pool, the admission reservation kept (the
+            # fresh pages must draw it down, not eat into other
+            # slots' promises). A prefix hit instead enters through
+            # attach_prefix, which resumes at a non-zero offset.
+            self.release_slot(slot, keep_reservation=True)
+        self._grow_slot(
+            slot, -(-(offset + self.chunk_len) // self.page_len))
+        ops = self._operands(lambda: (
+            jnp.asarray(tokens),
+            jnp.asarray(self._page_table[slot:slot + 1].copy()),
+            np.int32(offset), np.int32(n),
+            np.float32(temperature), np.float32(fault_bias),
+            self._next_key(), *self._lora_args(slot),
+            *self._slot_arg(slot)))
         self.cache, token, finite = self._runtime_call(
             "chunk", lambda: self._jit_chunk(self.params, self.cache,
                                              *ops))
-        if self.paged:
-            self._host_len[slot] = offset + n
+        self._host_len[slot] = offset + n
         return PendingPrefill(
             token=token, finite=finite, slot=slot, final=final,
             t_dispatch=t0, dispatch_s=time.perf_counter() - t0)
@@ -1848,10 +1496,9 @@ class Engine:
     def prefill_chunked(self, slot: int, prompt: Sequence[int],
                         temperature: float = 0.0) -> int:
         """Drain a whole prompt through the chunk-prefill program
-        back-to-back and return the first sampled token — the chunked
-        counterpart of :meth:`prefill` for callers without a scheduler
-        (warmup, parity tests, ``--generate``). Production serving
-        interleaves the same chunks with decode steps instead
+        back-to-back and return the first sampled token — for callers
+        without a scheduler (warmup, parity tests, ``--generate``).
+        Production serving interleaves the same chunks with decode steps instead
         (:class:`~apex_tpu.serving.Scheduler`)."""
         n = len(prompt)
         if not 0 < n <= self.prefill_len:
@@ -1869,80 +1516,7 @@ class Engine:
         (``ceil(prompt_len / chunk_len)``)."""
         return -(-int(prompt_len) // self.chunk_len)
 
-    def copy_kv(self, src: int, dst: int, length: int) -> None:
-        """The contiguous layout's fourth compiled program: copy row
-        ``src``'s K/V into row ``dst`` and set ``dst``'s length to
-        ``length`` (traced scalars — one executable serves every
-        donor/destination/length triple). Rows address serving slots AND
-        prefix-pool rows, so registration (slot → pool row) and
-        restoration (pool row → admitted slot) are the same program.
-        Cheap by construction: one ``[layers, heads, max_len, head_dim]``
-        device-to-device copy, no attention or MLP compute. RETIRED on
-        the paged path — prefix reuse there is a page-refcount bump
-        (:meth:`attach_prefix` / :meth:`retain_prefix`), zero data
-        movement — so a paged engine refuses to compile it."""
-        if self.paged:
-            raise RuntimeError(
-                "copy_kv is retired on the paged engine: prefix hits "
-                "share pages (copy-on-write) instead of copying rows — "
-                "use attach_prefix/retain_prefix, or build "
-                "Engine(paged=False) for the contiguous baseline")
-        rows = self.slots + self.prefix_pool
-        if not 0 <= src < rows or not 0 <= dst < rows:
-            raise ValueError(f"copy rows ({src} -> {dst}) must be in "
-                             f"[0, {rows})")
-        if src == dst:
-            raise ValueError("copy source and destination must differ")
-        if not 0 < length <= self.max_len:
-            raise ValueError(f"copy length {length} not in (0, "
-                             f"max_len={self.max_len}]")
-        t0 = time.perf_counter()
-        self.cache = self._jit_copy(self.cache, np.int32(src),
-                                    np.int32(dst), np.int32(length))
-        if self._registry is not None:
-            self._registry.observe("serving.prefix.copy_s",
-                                   time.perf_counter() - t0)
-
-    def restore_prefix(self, slot: int, row: int, length: int) -> None:
-        """Admission-time prefix hit: pool row ``row``'s first
-        ``length`` positions become serving ``slot``'s cache prefix; the
-        scheduler then resumes chunk prefill at offset ``length``."""
-        self.copy_kv(row, slot, length)
-
-    def store_prefix(self, row: int, slot: int, length: int) -> None:
-        """Registration: retain serving ``slot``'s first ``length``
-        positions (a completed, block-aligned prompt prefix) in pool row
-        ``row``."""
-        self.copy_kv(slot, row, length)
-
-    def _with_prefill_blocks(self, fn):
-        """Run ``fn`` with the ``decode.prefill_block_q``/``_k`` tuned
-        keys temporarily installed as the flash-attention geometry.
-        Blocks resolve at TRACE time, so this bites exactly once — on
-        the call that traces the prefill program — and the training
-        ``flash.*`` values are restored before anything else traces."""
-        if self._pf_bq is None and self._pf_bk is None:
-            return fn()
-        keys = ("flash.block_q", "flash.block_k")
-        saved = {k: vmem.overrides().get(k) for k in keys}
-        for k, v in zip(keys, (self._pf_bq, self._pf_bk)):
-            if v:
-                vmem.set_override(k, v)
-        try:
-            return fn()
-        finally:
-            for k in keys:
-                if saved[k] is None:
-                    vmem.remove_override(k)
-                else:
-                    vmem.set_override(k, saved[k])
-
-    # ------------------------------------------------- paged host bookkeeping
-    def _require_paged(self, what: str) -> None:
-        if not self.paged:
-            raise RuntimeError(f"{what} is a paged-engine operation; "
-                               "this engine was built with paged=False")
-
+    # ------------------------------------------------------- host bookkeeping
     def _alloc_page(self, slot: int) -> int:
         """One fresh page for ``slot`` (drawing down its admission
         reservation when it has one). Pool pressure first evicts LRU
@@ -1981,7 +1555,6 @@ class Engine:
         reclamation is immediate, not deferred to the next overwrite.
         ``keep_reservation`` preserves the slot's admission reservation
         (the cold-start path inside an admitted request)."""
-        self._require_paged("release_slot")
         n = int(self._n_pages[slot])
         if n:
             self.pool.release(self._page_table[slot, :n].tolist())
@@ -1992,22 +1565,16 @@ class Engine:
             self.pool.unreserve(int(self._slot_reserved[slot]))
             self._slot_reserved[slot] = 0
 
-    def pages_required(self, prompt_len: int, max_new_tokens: int,
-                       monolithic: bool = False) -> int:
+    def pages_required(self, prompt_len: int, max_new_tokens: int) -> int:
         """Worst-case pages a request can touch: the padded prefill
-        extent (whole chunks — or the whole ``prefill_len`` window on
-        the monolithic path) or the decode growth to its token budget,
+        extent (whole chunks) or the decode growth to its token budget,
         whichever reaches further, all capped at ``max_len``. The
         scheduler reserves this at admission so mid-decode allocation
         can never fail. Deliberately ignores any prefix-hit discount —
         conservative admission keeps the hit/miss counters exact (the
         match runs only for requests that actually admitted)."""
-        self._require_paged("pages_required")
-        if monolithic:
-            prefill_extent = self.prefill_len
-        else:
-            prefill_extent = min(self.chunks_for(prompt_len)
-                                 * self.chunk_len, self.max_len)
+        prefill_extent = min(self.chunks_for(prompt_len)
+                             * self.chunk_len, self.max_len)
         occupied = min(int(prompt_len) + int(max_new_tokens),
                        self.max_len)
         return self.pool.pages_for(max(prefill_extent, occupied))
@@ -2018,7 +1585,6 @@ class Engine:
         cover the promise. False (nothing changed) when even a fully
         drained prefix cache leaves the pool short — the request stays
         queued."""
-        self._require_paged("try_reserve_slot")
         n_pages = int(n_pages)
         while self.pool.available < n_pages:
             if self.prefix_cache is None \
@@ -2302,10 +1868,9 @@ class Engine:
         return pages
 
     def attach_prefix(self, slot: int, match) -> bool:
-        """Admission-time prefix hit, paged style: the matched entry's
+        """Admission-time prefix hit: the matched entry's
         pages become the head of ``slot``'s page table by refcount bump
-        — ZERO data movement (the contiguous layout paid a compiled
-        row-copy here). Chunk prefill then resumes at the matched
+        — ZERO data movement. Chunk prefill then resumes at the matched
         offset; the first write past the share lands on a fresh page by
         construction (matches are chunk-aligned, chunks cover whole
         pages). Pages the hit shares are refunded from the slot's
@@ -2317,7 +1882,6 @@ class Engine:
         hit. Returns False — with NOTHING attached (the caller must
         treat the admission as a miss and re-prefill cold) — when the
         swap-in degraded; True on every attached hit."""
-        self._require_paged("attach_prefix")
         if getattr(match, "swapped", False):
             restored = self._swap_in(match.row)
             if restored is None:
@@ -2343,7 +1907,7 @@ class Engine:
 
     def retain_prefix(self, slot: int, prompt: Sequence[int],
                       keys: Optional[Sequence[int]] = None) -> str:
-        """Registration, paged style: retain ``prompt``'s block-aligned
+        """Registration: retain ``prompt``'s block-aligned
         prefix by SHARING the pages that already hold it in ``slot`` —
         no copy, no reserved rows. Returns the
         :meth:`PrefixCache.register` outcome; on ``"registered"`` the
@@ -2351,7 +1915,6 @@ class Engine:
         eviction), so the prefix survives the slot. ``keys`` are the
         prompt's precomputed rolling block keys (the pipelined
         scheduler's hash offload; None hashes inline)."""
-        self._require_paged("retain_prefix")
         if self.prefix_cache is None:
             raise RuntimeError("engine built without a prefix cache "
                                "(prefix_pool=0)")
@@ -2393,7 +1956,6 @@ class Engine:
         which case the importer simply re-prefills cold (an entry the
         arena declined stays RESIDENT here as an ordinary local
         prefix). Counts ``serving.disagg.handoff_bytes``."""
-        self._require_paged("export_handoff")
         if self.prefix_cache is None or self.host_tier is None:
             return 0
         n_blocks = (len(prompt) - 1) // self.chunk_len
@@ -2422,25 +1984,22 @@ class Engine:
 
     @property
     def pages_free(self) -> int:
-        """Free pages in the paged pool right now (0 on the contiguous
-        layout) — the cheap host-only capacity gauge the router's
-        least-loaded admission reads per routed request, without the
+        """Free pages in the pool right now — the cheap host-only
+        capacity gauge the router's least-loaded admission reads per routed request, without the
         fragmentation walk :meth:`pool_stats` pays."""
-        return self.pool.free_pages if self.paged else 0
+        return self.pool.free_pages
 
     def slot_pages(self, slot: int) -> int:
-        """Pages currently held by ``slot`` (0 on the contiguous
-        layout) — host bookkeeping only. The scheduler sums this over
-        low-priority running slots for ``preemptible_pages``, the
+        """Pages currently held by ``slot`` — host bookkeeping only.
+        The scheduler sums this over low-priority running slots for ``preemptible_pages``, the
         "reclaimable by preemption" headroom gauge in
         :meth:`Scheduler.load_snapshot`."""
-        return int(self._n_pages[slot]) if self.paged else 0
+        return int(self._n_pages[slot])
 
     def pool_stats(self) -> dict:
         """Paged-pool telemetry snapshot: allocator counters plus the
         per-slot fragmentation view (allocated-but-invalid positions
         over allocated positions)."""
-        self._require_paged("pool_stats")
         stats = self.pool.stats()
         stats["fragmentation"] = self.pool.fragmentation(
             self._host_len, self._n_pages)
@@ -2505,40 +2064,30 @@ class Engine:
                                  f"be [{self.slots}]")
         act = np.asarray(active, bool)
         t0 = time.perf_counter()
-        if self.paged:
-            # write-then-attend writes at host_len: make sure each
-            # active slot's write page exists BEFORE the program runs
-            # (reservation at admission guarantees the pool can cover
-            # it; a slot at max_len clamps onto its last page)
-            with tracing.phase("engine.grow"):
-                for s in np.flatnonzero(act):
-                    pos = int(self._host_len[s])
-                    if pos < self.max_len:
-                        self._grow_slot(s, self.pool.pages_for(pos + 1))
-            ops = self._operands(lambda: (
-                jnp.asarray(last_tokens, jnp.int32),
-                jnp.asarray(self._page_table.copy()),
-                jnp.asarray(self._host_len.copy()),
-                jnp.asarray(temperatures, jnp.float32),
-                jnp.asarray(fault_bias), self._next_key(),
-                *self._lora_args(),
-                *((jnp.asarray(act),) if self.slot_state_width else ())))
-        else:
-            ops = self._operands(lambda: (
-                jnp.asarray(last_tokens, jnp.int32),
-                jnp.asarray(act),
-                jnp.asarray(temperatures, jnp.float32),
-                jnp.asarray(fault_bias), self._next_key(),
-                *self._lora_args()))
+        # write-then-attend writes at host_len: make sure each
+        # active slot's write page exists BEFORE the program runs
+        # (reservation at admission guarantees the pool can cover
+        # it; a slot at max_len clamps onto its last page)
+        with tracing.phase("engine.grow"):
+            for s in np.flatnonzero(act):
+                pos = int(self._host_len[s])
+                if pos < self.max_len:
+                    self._grow_slot(s, self.pool.pages_for(pos + 1))
+        ops = self._operands(lambda: (
+            jnp.asarray(last_tokens, jnp.int32),
+            jnp.asarray(self._page_table.copy()),
+            jnp.asarray(self._host_len.copy()),
+            jnp.asarray(temperatures, jnp.float32),
+            jnp.asarray(fault_bias), self._next_key(),
+            *self._lora_args(),
+            *((jnp.asarray(act),) if self.slot_state_width else ())))
         self.cache, tokens, finite = self._runtime_call(
             "decode", lambda: self._jit_decode(self.params, self.cache,
                                                *ops))
-        attended = None
-        if self.paged:
-            # write-then-attend: a row at position p attends p + 1
-            attended = np.minimum(self._host_len[act], self.max_len - 1) + 1
-            grow = act & (self._host_len < self.max_len)
-            self._host_len[grow] += 1
+        # write-then-attend: a row at position p attends p + 1
+        attended = np.minimum(self._host_len[act], self.max_len - 1) + 1
+        grow = act & (self._host_len < self.max_len)
+        self._host_len[grow] += 1
         return PendingDecode(tokens=tokens, finite=finite, active=act,
                              t_dispatch=t0, attended=attended)
 
@@ -2561,7 +2110,7 @@ class Engine:
         block time is charged to :attr:`device_wait_s`; the finiteness
         verdict lands in :attr:`last_decode_finite`.
 
-        A paged engine also counts how much of the page table the step's
+        It also counts how much of the page table the step's
         decode kernel walked, from the lengths the dispatch recorded:
         ``serving.decode.pages_live`` (sum over decoding rows of
         ``ceil(length / page_len)``: the pages the kernel fetches) and
@@ -2589,15 +2138,14 @@ class Engine:
             self._registry.counter_inc("serving.decode.steps")
             self._registry.counter_inc("serving.tokens_generated",
                                        n_valid)
-            if pending.attended is not None:
-                # how much of the page table the decode kernel walked:
-                # it fetches a row's live pages, not its table
-                self._registry.counter_inc(
-                    "serving.decode.pages_live",
-                    int(np.sum(-(-pending.attended // self.page_len))))
-                self._registry.counter_inc(
-                    "serving.decode.pages_tabled",
-                    pending.attended.size * self.max_pages)
+            # how much of the page table the decode kernel walked:
+            # it fetches a row's live pages, not its table
+            self._registry.counter_inc(
+                "serving.decode.pages_live",
+                int(np.sum(-(-pending.attended // self.page_len))))
+            self._registry.counter_inc(
+                "serving.decode.pages_tabled",
+                pending.attended.size * self.max_pages)
         return out, finite, dt
 
     def sync(self) -> None:
@@ -2683,10 +2231,8 @@ class Engine:
         drafts per row are padded to the fixed shape and excluded from
         acceptance. Every verifying slot needs ``0 < offset`` and
         ``offset + draft_len + 1 <= max_len`` (the scheduler's endgame
-        gate) — violated windows raise HERE, on both layouts, before
-        anything mutates (the contiguous check reads the device
-        lengths: a sync, priced into the parity-oracle path — a
-        silently-masked row would return ``n_accepted = 0`` with
+        gate) — violated windows raise HERE, before anything mutates
+        (a silently-masked row would return ``n_accepted = 0`` with
         nothing committed, indistinguishable from a real zero-accept
         verify, and the caller would emit a token whose K/V never
         landed).
@@ -2736,21 +2282,13 @@ class Engine:
             if fault_bias.shape != (self.slots,):
                 raise ValueError(f"fault_bias {fault_bias.shape} must "
                                  f"be [{self.slots}]")
-        # validate EVERY verifying slot's window host-side, on BOTH
-        # layouts, before anything mutates: a masked row would return
-        # n_accepted=0 with nothing committed — indistinguishable from
-        # a real zero-accept verify, so the caller would emit a bonus
-        # token whose K/V never landed. The contiguous layout keeps
-        # lengths on device, so this read is a device sync — an
-        # acceptable price on the parity-oracle path for the same
-        # loud-failure contract the paged path has always had.
-        if self.paged:
-            lens = self._host_len
-        else:
-            lens = self._readback("lengths", lambda: np.asarray(
-                self.cache.lengths))[:self.slots]
+        # validate EVERY verifying slot's window host-side before
+        # anything mutates: a masked row would return n_accepted=0 with
+        # nothing committed — indistinguishable from a real zero-accept
+        # verify, so the caller would emit a bonus token whose K/V
+        # never landed
         for s in np.flatnonzero(active):
-            off = int(lens[s])
+            off = int(self._host_len[s])
             if not 0 < off or off + K + 1 > self.max_len:
                 raise ValueError(
                     f"verify window [{off}, {off + K + 1}) of slot "
@@ -2762,28 +2300,22 @@ class Engine:
                     f"verify offset {int(offsets[s])} disagrees with "
                     f"slot {s}'s committed length {off}")
         t0 = time.perf_counter()
-        if self.paged:
-            for s in np.flatnonzero(active):
-                # the write extent must be backed by pages BEFORE the
-                # program runs (reservation at admission guarantees the
-                # pool can cover it when the scheduler gated the call)
-                self._grow_slot(s, self.pool.pages_for(
-                    int(self._host_len[s]) + K + 1))
-            # non-verifying rows: sentinel-only table + offset 0, so
-            # their fixed-shape writes can never land on a live page
-            vt = np.where(active[:, None], self._page_table, 0)
-            vlen = np.where(active, self._host_len, 0)
-            ops = self._operands(lambda: (
-                jnp.asarray(tokens),
-                jnp.asarray(vt.astype(np.int32)),
-                jnp.asarray(vlen.astype(np.int32)),
-                jnp.asarray(n_drafted), jnp.asarray(fault_bias),
-                *self._lora_args()))
-        else:
-            ops = self._operands(lambda: (
-                jnp.asarray(tokens),
-                jnp.asarray(n_drafted), jnp.asarray(fault_bias),
-                *self._lora_args()))
+        for s in np.flatnonzero(active):
+            # the write extent must be backed by pages BEFORE the
+            # program runs (reservation at admission guarantees the
+            # pool can cover it when the scheduler gated the call)
+            self._grow_slot(s, self.pool.pages_for(
+                int(self._host_len[s]) + K + 1))
+        # non-verifying rows: sentinel-only table + offset 0, so
+        # their fixed-shape writes can never land on a live page
+        vt = np.where(active[:, None], self._page_table, 0)
+        vlen = np.where(active, self._host_len, 0)
+        ops = self._operands(lambda: (
+            jnp.asarray(tokens),
+            jnp.asarray(vt.astype(np.int32)),
+            jnp.asarray(vlen.astype(np.int32)),
+            jnp.asarray(n_drafted), jnp.asarray(fault_bias),
+            *self._lora_args()))
         self.cache, dev_out, dev_acc, dev_fin = self._runtime_call(
             "verify", lambda: self._jit_verify(self.params, self.cache,
                                                *ops))
@@ -2792,14 +2324,13 @@ class Engine:
         out, n_accepted, finite = self._readback("verify", lambda: (
             np.asarray(dev_out),            # device sync: step latency
             np.asarray(dev_acc, np.int32), np.asarray(dev_fin, bool)))
-        if self.paged:
-            # rollback IS this assignment, per slot: the rejected tail's
-            # K/V sits at [offset + m + 1, offset + K + 1), past the
-            # committed length — unreachable, and overwritten
-            # write-then-attend by the slot's next decode/verify step
-            for s in np.flatnonzero(active):
-                self._host_len[s] = int(self._host_len[s]) \
-                    + int(n_accepted[s]) + 1
+        # rollback IS this assignment, per slot: the rejected tail's
+        # K/V sits at [offset + m + 1, offset + K + 1), past the
+        # committed length — unreachable, and overwritten
+        # write-then-attend by the slot's next decode/verify step
+        for s in np.flatnonzero(active):
+            self._host_len[s] = int(self._host_len[s]) \
+                + int(n_accepted[s]) + 1
         self.last_verify_finite_slots = np.where(active, finite, True)
         # keep the long-standing scalar attribute live too: a caller
         # written against the pre-batching API must not read a stale
@@ -2876,16 +2407,11 @@ class Engine:
         :meth:`FaultPlan.corrupt_page_table` target and the
         :class:`~apex_tpu.serving.PoolAuditor`'s corruption-detection
         probe). Never hands out the live arrays."""
-        self._require_paged("page_table_snapshot")
         return self._page_table.copy(), self._n_pages.copy()
 
     def lengths(self) -> np.ndarray:
-        """Host view of per-slot cache lengths (host state on the paged
-        path; a device read on the contiguous one)."""
-        if self.paged:
-            return self._host_len[:self.slots].copy()
-        return self._readback("lengths", lambda: np.asarray(
-            self.cache.lengths))            # device sync
+        """Per-slot cache lengths (a copy of the host state)."""
+        return self._host_len[:self.slots].copy()
 
     def program_kernels(self) -> dict:
         """Which Pallas kernels the decode and chunk-prefill programs
@@ -2962,12 +2488,8 @@ class Engine:
         chunk = np.zeros((1, self.chunk_len), np.int32)
         scalars = (np.int32(0), np.int32(1), np.float32(0),
                    np.float32(0), self._key)
-        if self.paged:
-            decode_ops = (last, self._page_table, self._host_len)
-            chunk_ops = (chunk, self._page_table[:1])
-        else:
-            decode_ops = (last, np.zeros(self.slots, bool))
-            chunk_ops = (chunk, np.int32(0))
+        decode_ops = (last, self._page_table, self._host_len)
+        chunk_ops = (chunk, self._page_table[:1])
         traces = (self.decode_traces, self.chunk_traces)
         try:
             programs = {
@@ -3021,7 +2543,7 @@ class Engine:
         prefixes SURVIVE a reset by default (they are warm state, not
         per-request state — a bench window reset must not throw away the
         cache it is measuring); pass ``clear_prefixes=True`` to drop
-        them too. On the paged path the wipe also returns every slot's
+        them too. The wipe also returns every slot's
         pages to the pool (retained prefixes keep theirs via their own
         refcounts)."""
         if self.lora is not None:
@@ -3029,36 +2551,26 @@ class Engine:
             # (the arena rows) survives — warm state, like prefixes
             self._slot_adapter[:] = 0
             self.lora.release_all()
-        if self.paged:
-            for s in range(self.slots):
-                self.release_slot(s)
-            if clear_prefixes and self.prefix_cache is not None:
-                # entry eviction releases each entry's page refs through
-                # the pool (the on_evict hook). Swapped entries hold no
-                # pages — their host-side bytes are dropped with the
-                # arena below (warm resets keep BOTH tiers: a swapped
-                # prefix is warm state exactly like a resident one).
-                # A SHARED arena belongs to the whole fleet: discard
-                # only this engine's own swapped keys, never clear()
-                # the sibling engines' records out from under them.
-                own_swapped = self.prefix_cache.swapped_keys()
-                self.prefix_cache.clear()
-                if self.host_tier is not None:
-                    if self.host_tier_shared:
-                        for k in own_swapped:
-                            self.host_tier.discard(k)
-                    else:
-                        self.host_tier.clear()
-                    if self._registry is not None:
-                        self._registry.gauge_set(
-                            "serving.swap.host_bytes",
-                            float(self.host_tier.bytes_used))
-            return
-        lengths = self.cache.lengths
-        if clear_prefixes:
-            lengths = jnp.zeros_like(lengths)
-            if self.prefix_cache is not None:
-                self.prefix_cache.clear()
-        else:
-            lengths = lengths.at[:self.slots].set(0)
-        self.cache = self.cache.replace(lengths=lengths)
+        for s in range(self.slots):
+            self.release_slot(s)
+        if clear_prefixes and self.prefix_cache is not None:
+            # entry eviction releases each entry's page refs through
+            # the pool (the on_evict hook). Swapped entries hold no
+            # pages — their host-side bytes are dropped with the
+            # arena below (warm resets keep BOTH tiers: a swapped
+            # prefix is warm state exactly like a resident one).
+            # A SHARED arena belongs to the whole fleet: discard
+            # only this engine's own swapped keys, never clear()
+            # the sibling engines' records out from under them.
+            own_swapped = self.prefix_cache.swapped_keys()
+            self.prefix_cache.clear()
+            if self.host_tier is not None:
+                if self.host_tier_shared:
+                    for k in own_swapped:
+                        self.host_tier.discard(k)
+                else:
+                    self.host_tier.clear()
+                if self._registry is not None:
+                    self._registry.gauge_set(
+                        "serving.swap.host_bytes",
+                        float(self.host_tier.bytes_used))

@@ -20,8 +20,8 @@ module is the serving-side counterpart, three pieces:
   (:meth:`FaultPlan.corrupt_page_table` — proving the
   :class:`PoolAuditor` detects corruption; it is never pointed at the
   live tables). Deterministic by construction: explicit specs or
-  :meth:`FaultPlan.random` from a seed — the chaos tests and
-  ``bench_serving.py --chaos`` replay identical schedules.
+  :meth:`FaultPlan.random` from a seed — the chaos tests
+  replay identical schedules.
 
 - :class:`FaultPolicy` — the **per-request containment knobs** the
   scheduler applies when a fault (injected or real) surfaces: requeue
@@ -559,8 +559,6 @@ class PoolAuditor:
         violation; returns a summary dict when everything reconciles.
         ``page_table``/``n_pages`` override the engine's live tables
         with debug copies (the corruption-detection probe)."""
-        if not getattr(engine, "paged", False):
-            raise RuntimeError("PoolAuditor audits paged engines only")
         pool = engine.pool
         if page_table is None:
             page_table = engine._page_table

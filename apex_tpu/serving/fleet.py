@@ -275,7 +275,7 @@ def _kill_procs(procs: List[subprocess.Popen]) -> None:
 #: the shipped config's tenant_weights (per-process fairness scope,
 #: documented in docs/serving.md "Overload & SLO").
 _WIRE_SCHED_KW = ("max_queue", "default_timeout_s", "eos_id",
-                  "chunked", "chunk_budget", "retain_prefixes",
+                  "chunk_budget", "retain_prefixes",
                   "speculative", "pipeline_depth", "slo")
 
 

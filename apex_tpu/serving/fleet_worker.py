@@ -51,7 +51,7 @@ def build_engine_from_spec(spec: dict):
          "engine": {"slots": 2, "max_len": 64, "prefill_len": 24,
                     "chunk_len": 8, "prefix_pool": 4, "seed": 5,
                     "policy": "O0",     # resolved by name per process
-                    # optional: paged, page_len, num_pages, top_k,
+                    # optional: page_len, num_pages, top_k,
                     # "lora": {"rank": 4, ...} → per-worker LoRAConfig,
                     "host_tier_bytes": 1 << 20}}  # → per-worker HostTier
 
@@ -124,7 +124,6 @@ def _geometry(state: _WorkerState) -> dict:
         "max_len": eng.max_len,
         "prefill_len": eng.prefill_len,
         "chunk_len": eng.chunk_len,
-        "paged": bool(getattr(eng, "paged", False)),
         "retain_prefixes": bool(state.sched.retain_prefixes),
         "block_len": pc.block_len if pc is not None else None,
         "role": state.sched.role,

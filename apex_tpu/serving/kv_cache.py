@@ -1,67 +1,43 @@
-"""Preallocated slot KV cache — the serving engine's only mutable state.
+"""Paged KV pool — the serving engine's only mutable device state.
 
-One device-resident pytree holds every request's attention history:
-
-- ``k``/``v``: ``[layers, slots, heads, max_len, head_dim]`` — slot ``s``
-  owns row ``[:, s]``; positions ``[0, lengths[s])`` are valid.
-- ``lengths``: ``[slots]`` int32 — valid positions per slot (0 = free).
-
-Storage dtype comes from the amp cast policies (bf16 by default — the
-same ``half_dtype`` the O2/O3 tables resolve), halving HBM versus fp32
-and feeding the decode kernel the dtype it upcasts per-tile anyway.
+:class:`PagedKVCache` is a dense pool of fixed-size pages ``[layers,
+num_pages, heads, head_dim, page_len]`` (each page held transposed,
+``page_len`` in the lanes: the form the chip stores unpadded and the
+kernels read as it lies — see :class:`PagedKVCache`) plus a host-side
+:class:`PagePool` allocator. Storage dtype comes from the amp cast
+policies (bf16 by default — the same ``half_dtype`` the O2/O3 tables
+resolve). A request owns a *page list*: its logical positions
+``[0, L)`` live on pages ``table[0] .. table[ceil(L/page_len)-1]`` at
+in-page offsets ``pos % page_len``. The engine materialises the
+per-slot lists as a ``[slots, max_pages]`` int32 page-table operand
+each call; the attention kernels gather K/V through it.
 
 Slot semantics (the continuous-batching contract):
 
-- **prefill** writes a request's prompt K/V into ``[0, P)`` of a free
-  slot and sets its length; positions past the true prompt length hold
-  pad garbage that is *never attended* (length masking) and is
-  overwritten position-by-position as decode advances.
 - **chunked prefill** ingests a prompt one chunk per decode heartbeat:
-  :meth:`slot_view` hands the model one slot as a batch-of-one cache,
-  the chunk's K/V lands at ``[offset, offset + C)``, and
-  :meth:`write_slot` commits the view back with the grown length.
-- **decode** writes each slot's new token at ``lengths[s]`` and then
-  attends ``[0, lengths[s]]`` — write-then-attend, so garbage can never
+  the chunk's K/V lands on the slot's pages at ``[offset, offset + C)``
+  as whole pages; positions past the true prompt length hold pad
+  garbage that is *never attended* (length masking) and is overwritten
+  position-by-position as decode advances.
+- **decode** writes each slot's new token at its length and then
+  attends ``[0, length]`` — write-then-attend, so garbage can never
   enter a softmax.
-- **eviction** is free: a finished slot is just marked length-0 on the
-  host; the next prefill overwrites it. No device-side compaction.
-- **prefix pool**: an engine built with ``prefix_pool=N`` allocates N
-  extra rows past its serving slots to retain popular prompt prefixes;
-  :meth:`copy_slot` is the one compiled row-copy both directions share
-  (register: slot → pool row; hit: pool row → fresh slot) and
-  :meth:`front_view`/:meth:`advance_front` keep the decode batch off
-  the pool rows.
 
-Everything is functional: updates return a new :class:`KVCache` whose
-buffers alias the old ones under jit donation (the engine donates the
-cache to both of its compiled programs).
-
-**Paged layout** (the serving engine's default since the block-table
-refactor): :class:`PagedKVCache` replaces the per-slot rows with a
-dense pool of fixed-size pages ``[layers, num_pages, heads, head_dim,
-page_len]`` (each page held transposed, ``page_len`` in the lanes: the
-form the chip stores unpadded and the kernels read as it lies — see
-:class:`PagedKVCache`) plus a host-side :class:`PagePool` allocator. A
-request
-owns a *page list* instead of a row: its logical positions ``[0, L)``
-live on pages ``table[0] .. table[ceil(L/page_len)-1]`` at in-page
-offsets ``pos % page_len``. The engine materialises the per-slot lists
-as a ``[slots, max_pages]`` int32 page-table operand each call; the
-attention kernels gather K/V through it. What the indirection buys:
+Everything is functional: updates return a new :class:`PagedKVCache`
+whose buffers alias the old ones under jit donation (the engine donates
+the cache to its compiled programs). What the indirection buys:
 
 - **no per-slot max_len reservation** — a 40-token request holds
   ``ceil(40/page_len)`` pages, not ``max_len`` positions, so the same
   pool bytes serve far more logical requests;
 - **copy-on-write prefix sharing** — a prefix-cache hit bumps the
   refcount of the donor's pages and writes their ids into the new
-  slot's table: zero data movement (the contiguous layout's compiled
-  ``copy_kv`` program is retired from the hit path). Shares are always
+  slot's table: zero data movement. Shares are always
   whole-page (matches are chunk-aligned and ``chunk_len % page_len ==
   0``), so a shared page is never written: the first write past the
   shared prefix lands on a freshly allocated page by construction;
 - **immediate reclamation** — a finished request's pages return to the
-  free list the moment its slot is released (refcount permitting), not
-  when the next prefill overwrites the row.
+  free list the moment its slot is released (refcount permitting).
 
 Page 0 is the **sentinel/garbage page**: never allocated, it absorbs
 the fixed-shape decode program's writes for inactive slots (their page
@@ -83,216 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["KVCache", "PagedKVCache", "PagePool", "SlotState"]
-
-
-@flax.struct.dataclass
-class KVCache:
-    """Slot-major KV cache pytree (see module docstring for semantics).
-
-    ``k_scale``/``v_scale`` (both None by default) are the quantized
-    storage tier's per-``[layer, head]`` fp32 dequantization scales
-    (:mod:`apex_tpu.serving.kv_quant`): when set, ``k``/``v`` hold int8
-    codes and every reader multiplies through the matching scale. They
-    ride the pytree so the donated cache stays self-describing; an
-    unquantized cache flattens to exactly the same three leaves as
-    before."""
-
-    k: jnp.ndarray        # [layers, slots, heads, max_len, head_dim]
-    v: jnp.ndarray        # [layers, slots, heads, max_len, head_dim]
-    lengths: jnp.ndarray  # [slots] int32
-    k_scale: Optional[jnp.ndarray] = None   # [layers, heads] fp32
-    v_scale: Optional[jnp.ndarray] = None   # [layers, heads] fp32
-
-    # ------------------------------------------------------------- geometry
-    @property
-    def layers(self) -> int:
-        return self.k.shape[0]
-
-    @property
-    def slots(self) -> int:
-        return self.k.shape[1]
-
-    @property
-    def heads(self) -> int:
-        return self.k.shape[2]
-
-    @property
-    def max_len(self) -> int:
-        return self.k.shape[3]
-
-    @property
-    def head_dim(self) -> int:
-        return self.k.shape[4]
-
-    @property
-    def dtype(self):
-        return self.k.dtype
-
-    def nbytes(self) -> int:
-        """Device bytes held by the cache (both K and V)."""
-        return int(self.k.size * self.k.dtype.itemsize * 2)
-
-    # -------------------------------------------------------------- updates
-    @classmethod
-    def create(cls, *, layers: int, slots: int, heads: int, max_len: int,
-               head_dim: int, dtype: Any = jnp.bfloat16,
-               k_scale=None, v_scale=None) -> "KVCache":
-        """Allocate a zeroed cache. ``dtype`` is normally the amp half
-        dtype (``policy.half_dtype`` / ``compute_dtype`` — the serving
-        engine resolves it from its policy), or int8 with the
-        ``k_scale``/``v_scale`` pair when the engine's
-        :class:`~apex_tpu.serving.KVQuantConfig` tier is on."""
-        shape = (layers, slots, heads, max_len, head_dim)
-        return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                   lengths=jnp.zeros((slots,), jnp.int32),
-                   k_scale=k_scale, v_scale=v_scale)
-
-    def insert(self, slot, k_new, v_new, length) -> "KVCache":
-        """Write a prefilled request into ``slot``: ``k_new``/``v_new``
-        are the model's stacked prefill K/V ``[layers, 1, heads, P, d]``
-        (``P <= max_len``); the slot's length becomes ``length`` (the
-        true prompt length — pad positions in ``[length, P)`` are masked
-        by it). ``slot``/``length`` may be traced int32 scalars — the
-        jitted prefill program is slot- and length-agnostic."""
-        if k_new.ndim != 5 or k_new.shape[1] != 1:
-            raise ValueError(f"insert expects [layers, 1, heads, P, d] "
-                             f"prefill K/V, got {k_new.shape}")
-        P = k_new.shape[3]
-        if P > self.max_len:
-            raise ValueError(f"prefill length {P} exceeds cache max_len "
-                             f"{self.max_len}")
-        slot = jnp.asarray(slot, jnp.int32)
-        start = (jnp.int32(0), slot, jnp.int32(0), jnp.int32(0),
-                 jnp.int32(0))
-        k = jax.lax.dynamic_update_slice(
-            self.k, jnp.asarray(k_new, self.k.dtype), start)
-        v = jax.lax.dynamic_update_slice(
-            self.v, jnp.asarray(v_new, self.v.dtype), start)
-        lengths = self.lengths.at[slot].set(jnp.asarray(length, jnp.int32))
-        return self.replace(k=k, v=v, lengths=lengths)
-
-    def slot_view(self, slot):
-        """The one-slot ``(k, v)`` pair (``[layers, 1, heads, max_len,
-        head_dim]``) the model's chunk-prefill path consumes — slot ``s``
-        as a batch-of-one cache. ``slot`` may be a traced int32 scalar
-        (the jitted chunk-prefill program is slot-agnostic)."""
-        slot = jnp.asarray(slot, jnp.int32)
-        return (jax.lax.dynamic_slice_in_dim(self.k, slot, 1, axis=1),
-                jax.lax.dynamic_slice_in_dim(self.v, slot, 1, axis=1))
-
-    def write_slot(self, slot, k_slot, v_slot, length) -> "KVCache":
-        """Write an updated :meth:`slot_view` back (``[layers, 1, heads,
-        max_len, head_dim]``) and set the slot's length — the second half
-        of a chunk-prefill step (``length`` = positions ingested so far;
-        mid-prompt chunks leave it short of the true prompt length, so
-        decode-side garbage writes past it are overwritten by the next
-        chunk before anything can attend them)."""
-        want = (self.layers, 1, self.heads, self.max_len, self.head_dim)
-        if k_slot.shape != want or v_slot.shape != want:
-            raise ValueError(f"write_slot expects full slot views "
-                             f"{want}, got k {k_slot.shape} / "
-                             f"v {v_slot.shape}")
-        slot = jnp.asarray(slot, jnp.int32)
-        start = (jnp.int32(0), slot, jnp.int32(0), jnp.int32(0),
-                 jnp.int32(0))
-        k = jax.lax.dynamic_update_slice(
-            self.k, jnp.asarray(k_slot, self.k.dtype), start)
-        v = jax.lax.dynamic_update_slice(
-            self.v, jnp.asarray(v_slot, self.v.dtype), start)
-        lengths = self.lengths.at[slot].set(jnp.asarray(length, jnp.int32))
-        return self.replace(k=k, v=v, lengths=lengths)
-
-    def copy_slot(self, src, dst, length) -> "KVCache":
-        """Row copy for prefix reuse: slot ``src``'s full K/V row →
-        slot ``dst``, whose length becomes ``length``. ``src``/``dst``/
-        ``length`` may be traced int32 scalars — the engine's one
-        compiled copy program serves every (donor, destination, matched
-        length) triple. The copy is the full ``max_len`` window (slice
-        sizes must be static under jit); positions past ``length`` carry
-        donor garbage that is never attended (length masking) and is
-        overwritten as chunk prefill resumes at ``length`` — the same
-        contract prefill padding already lives by. ``src``'s own length
-        is untouched."""
-        k_row, v_row = self.slot_view(src)
-        dst = jnp.asarray(dst, jnp.int32)
-        start = (jnp.int32(0), dst, jnp.int32(0), jnp.int32(0),
-                 jnp.int32(0))
-        k = jax.lax.dynamic_update_slice(self.k, k_row, start)
-        v = jax.lax.dynamic_update_slice(self.v, v_row, start)
-        lengths = self.lengths.at[dst].set(jnp.asarray(length, jnp.int32))
-        return self.replace(k=k, v=v, lengths=lengths)
-
-    def model_view(self):
-        """The ``(k, v)`` pair the model's decode path consumes
-        (``[layers, slots, heads, max_len, head_dim]`` — already the
-        cache layout; slots are the decode batch)."""
-        return self.k, self.v
-
-    def front_view(self, n: int):
-        """The first ``n`` slot rows as a decode cache (``[layers, n,
-        heads, max_len, head_dim]``; ``n`` static). An engine with a
-        prefix pool reserves rows ``[n, slots)`` for retained prefixes —
-        the decode batch must neither compute over nor advance them."""
-        return self.k[:, :n], self.v[:, :n]
-
-    def advance_front(self, k_front, v_front, active) -> "KVCache":
-        """:meth:`advance` over the first ``k_front.shape[1]`` rows
-        only: commit the model-returned decode stacks back into the full
-        arrays (prefix-pool rows untouched) and grow the active front
-        lengths."""
-        n = k_front.shape[1]
-        start = (jnp.int32(0),) * 5
-        k = jax.lax.dynamic_update_slice(
-            self.k, jnp.asarray(k_front, self.k.dtype), start)
-        v = jax.lax.dynamic_update_slice(
-            self.v, jnp.asarray(v_front, self.v.dtype), start)
-        front = self.lengths[:n]
-        grow = jnp.asarray(active, bool) & (front < self.max_len)
-        lengths = self.lengths.at[:n].set(
-            jnp.where(grow, front + 1, front))
-        return self.replace(k=k, v=v, lengths=lengths)
-
-    def commit_front(self, k_front, v_front, front_lengths) -> "KVCache":
-        """:meth:`advance_front`'s general sibling for the batched
-        speculative verify: commit the model-returned front stacks and
-        SET the front rows' lengths to ``front_lengths`` (``[n]`` int32,
-        already computed in-program as ``offset + n_accepted + 1`` for
-        verifying rows and the unchanged old length for the rest).
-        Prefix-pool rows past the front are untouched."""
-        n = k_front.shape[1]
-        start = (jnp.int32(0),) * 5
-        k = jax.lax.dynamic_update_slice(
-            self.k, jnp.asarray(k_front, self.k.dtype), start)
-        v = jax.lax.dynamic_update_slice(
-            self.v, jnp.asarray(v_front, self.v.dtype), start)
-        lengths = self.lengths.at[:n].set(
-            jnp.asarray(front_lengths, jnp.int32))
-        return self.replace(k=k, v=v, lengths=lengths)
-
-    def advance(self, k, v, active) -> "KVCache":
-        """Absorb a decode step: ``k``/``v`` are the model-returned
-        stacks (each slot's new token written at its old length) and
-        ``active`` [slots] bool marks slots whose length advances —
-        inactive slots keep their length so their (discarded) write is
-        re-overwritten by the next real occupant."""
-        grow = jnp.asarray(active, bool) & (self.lengths < self.max_len)
-        return self.replace(k=k, v=v,
-                            lengths=jnp.where(grow, self.lengths + 1,
-                                              self.lengths))
-
-    # ------------------------------------------------------------ reporting
-    def occupancy(self, active=None) -> float:
-        """Fraction of slots in use (host-side; by active mask when
-        given, else by nonzero length)."""
-        if active is not None:
-            return float(np.mean(np.asarray(active, bool)))
-        return float(np.mean(np.asarray(self.lengths) > 0))
-
-    def padding_waste(self, active=None) -> float:
-        """Fraction of the decode batch spent on empty slots — the
-        continuous-batching inefficiency signal (1 - occupancy)."""
-        return 1.0 - self.occupancy(active)
+__all__ = ["PagedKVCache", "PagePool", "SlotState"]
 
 
 @flax.struct.dataclass
@@ -310,9 +77,8 @@ class SlotState:
 
     Lives in the :class:`PagedKVCache` pytree, so it is donated with
     the pool and written in place by the same programs. The program
-    that admits a request (the chunk program at offset 0, or the
-    monolithic prefill) starts the slot from zeros; nothing on the host
-    ever clears it."""
+    that admits a request (the chunk program at offset 0) starts the
+    slot from zeros; nothing on the host ever clears it."""
 
     rows: jnp.ndarray            # [layers, slots, width]
     # tokens routed to each expert since the engine was built (or

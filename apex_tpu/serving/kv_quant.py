@@ -39,7 +39,7 @@ calibrated range (clipped beyond it — the ``margin`` headroom exists
 because decode-time K/V can modestly exceed a prompt-sample absmax).
 Greedy serving accuracy is therefore a TOLERANCE claim, not a bitwise
 one: the quantized engine is measured as a token-match-rate against
-the bf16 oracle (``bench_serving.py --quantized-kv``), while
+the bf16 oracle, while
 ``kv_quant=None`` remains the default and the bitwise baseline.
 
 Calibration: per-``[layer, head]`` absmax either given explicitly

@@ -3,7 +3,7 @@
 Real traffic is dominated by shared prompt prefixes (system prompts,
 few-shot templates, multi-turn history); without reuse every request
 re-runs chunk prefill over tokens whose K/V already sit byte-identical
-in another row of the cache. This module is the host-side index that
+on other pages of the pool. This module is the host-side index that
 eliminates that recompute:
 
 - **Content addressing**: a retained prefix is keyed by a *rolling hash
@@ -16,47 +16,33 @@ eliminates that recompute:
   the matched offset — reuse composes with chunked prefill and the
   chunk computations that produced the donor K/V are bitwise identical
   to the ones the cold path would run.
-- **Storage**: matched prefixes live in *pool rows* — cache rows the
-  engine reserves past its serving slots (``Engine(prefix_pool=N)``).
-  Registration copies a completed prompt's block-aligned K/V from its
-  serving slot into a pool row through the engine's one compiled
-  row-copy program; a hit copies it back into the admitted slot the
-  same way.
-- **Refcounts + LRU**: every hit pins its donor entry (``acquire``)
-  until the request leaves its slot (``release``); eviction is
-  least-recently-used over entries at refcount 0 only — a prefix in use
-  by a live slot is never evicted. When every entry is pinned and the
-  pool is full, registration degrades gracefully: the request is served
-  cold and a ``pool_full`` tick is counted, nothing crashes.
+- **Storage**: an entry is its pages. Registration records the page
+  ids that already hold a completed prompt's block-aligned K/V (the
+  engine bumps their refcounts on ``"registered"``) and copies
+  nothing; a hit shares them into the admitted slot's page table.
+  Eviction hands them back through ``on_evict`` (the engine wires
+  :meth:`PagePool.release`, so a page still shared with a live slot
+  survives its entry). Sharing costs zero new pages — capacity
+  pressure lives in the engine's admission reservation, which calls
+  :meth:`evict_lru`.
+- **Refcounts + LRU**: ``acquire`` pins an entry until ``release``;
+  eviction is least-recently-used over entries at refcount 0 only.
+  Hits need no pin (the pages protect themselves via the pool's
+  refcounts; evicting a donor entry mid-request is harmless).
 - **Exactness**: hash keys are a lookup accelerator, not the source of
   truth — every match is verified token-for-token against the entry's
   retained tokens before it is trusted, so a hash collision can only
   cost a miss, never a wrong-token hit. Matches are additionally capped
   below the full prompt (``aligned(n - 1)``): at least the final block
-  always runs through chunk prefill, because that program — not the
-  copy — samples the request's first output token.
+  always runs through chunk prefill, because that program samples the
+  request's first output token.
 
-The class is pure host bookkeeping (dicts and counters); all device
-work happens in the engine's copy program, injected per call as
-``copy_fn``. Telemetry is the caller's job (the scheduler mirrors
+The class is pure host bookkeeping (dicts and counters). Telemetry is
+the caller's job (the scheduler mirrors
 :meth:`stats` into ``serving.prefix.*``); the raw counters here keep the
 class importable without a registry.
 
-**Paged entries** (the block-table engine): construct with
-``pool_rows=()`` and an ``on_evict`` hook, and register with
-``pages=(...)`` instead of ``copy_fn``. A paged entry retains no pool
-row and copies nothing — it records the page ids that already hold the
-prefix (the engine bumps their refcounts on ``"registered"``), and
-eviction hands them back through ``on_evict`` (the engine wires
-:meth:`PagePool.release`, so a page still shared with a live slot
-survives its entry). Two consequences replace the contiguous pinning
-story: registration can never be ``pool_full`` (sharing costs zero new
-pages — capacity pressure moves to the engine's admission reservation,
-which calls :meth:`evict_lru` instead), and hits need no
-acquire/release (the pages protect themselves via refcounts; evicting
-a donor entry mid-request is harmless).
-
-**Hierarchical KV** (paged + an engine host tier): eviction under pool
+**Hierarchical KV** (an engine host tier): eviction under pool
 pressure becomes a SWAP — the victim entry's page bytes migrate
 device→host (the engine's ``swap_out`` hook, wired via
 :meth:`PrefixCache.set_swap_hooks`; by default the hook only
@@ -110,14 +96,12 @@ def _roll(h: int, block: Tuple[int, ...]) -> int:
 @dataclasses.dataclass
 class _Entry:
     """One retained prefix: ``tokens`` (the full block-aligned prefix)
-    living in cache row ``row`` (contiguous layout) or on pool pages
-    ``pages`` (paged layout; ``row`` is then a synthetic negative key);
-    ``refcount`` pins a contiguous entry against eviction while a live
-    slot's admission copied from it (paged entries need no pin — their
-    pages carry their own refcounts in the engine's page pool).
+    living on pool pages ``pages`` under key ``row`` (a synthetic
+    negative key, or a handoff's request uid); ``refcount`` pins the
+    entry against eviction between ``acquire`` and ``release``.
 
     ``swapped`` is the hierarchical-KV tier's resident/swapped state:
-    a swapped paged entry holds NO device pages (``pages`` is None,
+    a swapped entry holds NO device pages (``pages`` is None,
     ``swapped_pages`` remembers how many it held) — its page bytes
     live in the engine's host-DRAM :class:`~apex_tpu.serving
     .HostTier` under key ``row``, and a hit migrates them back before
@@ -135,11 +119,9 @@ class _Entry:
 
 @dataclasses.dataclass(frozen=True)
 class PrefixMatch:
-    """A verified admission-time hit: copy ``length`` positions from
-    cache row ``row`` (then :meth:`PrefixCache.acquire` it for the
-    request's slot lifetime) — or, for a paged entry, share ``pages``
-    into the admitted slot's page table (``row`` is the entry's
-    synthetic key; no acquire needed). ``swapped=True`` marks a hit
+    """A verified admission-time hit: share ``pages`` (covering
+    ``length`` positions) into the admitted slot's page table (``row``
+    is the entry's key). ``swapped=True`` marks a hit
     whose page bytes sit in the host tier (``pages`` is None until
     the engine swaps them back in)."""
 
@@ -152,26 +134,20 @@ class PrefixMatch:
 class PrefixCache:
     """Host-side index of retained prompt prefixes (see module
     docstring). ``block_len`` must equal the engine's ``chunk_len``;
-    ``pool_rows`` are the cache row ids reserved for retained prefixes
-    (the engine hands over ``[slots, slots + prefix_pool)``)."""
+    ``on_evict`` receives an evicted entry's pages."""
 
-    def __init__(self, *, block_len: int, pool_rows: Sequence[int] = (),
+    def __init__(self, *, block_len: int,
                  on_evict: Optional[Callable[[Tuple[int, ...]],
                                              None]] = None):
         if block_len < 1:
             raise ValueError("block_len must be >= 1")
         self.block_len = int(block_len)
-        self.pool_rows: List[int] = list(pool_rows)
-        if len(set(self.pool_rows)) != len(self.pool_rows):
-            raise ValueError("pool_rows must be distinct")
-        self._free: List[int] = list(self.pool_rows)
-        self._entries: Dict[int, _Entry] = {}        # row/key -> entry
+        self._entries: Dict[int, _Entry] = {}        # key -> entry
         self._index: Dict[int, Tuple[int, int]] = {}  # key -> (row, blocks)
         self._clock = itertools.count(1)
-        # paged entries: synthetic negative keys (never collide with
-        # cache row ids, nor — being process-unique — with sibling
-        # caches sharing one host arena) + the page-release hook
-        # eviction fires
+        # synthetic negative keys (never collide with handoff uids,
+        # nor — being process-unique — with sibling caches sharing one
+        # host arena) + the page-release hook eviction fires
         self._paged_key = _paged_key
         self._on_evict = on_evict
         # hierarchical-KV hooks (engine-wired via set_swap_hooks; both
@@ -183,17 +159,12 @@ class PrefixCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
-        self.pool_full = 0
         self.tokens_reused = 0
         self.registrations = 0
         self.swap_outs = 0
         self.swap_ins = 0
 
     # ------------------------------------------------------------- geometry
-    @property
-    def capacity(self) -> int:
-        return len(self.pool_rows)
-
     @property
     def size(self) -> int:
         return len(self._entries)
@@ -294,21 +265,18 @@ class PrefixCache:
                 best = PrefixMatch(row=row, length=length, pages=None,
                                    swapped=True)
                 continue
-            if entry.pages is None:
-                pages = None
-            else:
-                # the entry's page_len: its tokens spread evenly over
-                # its pages (both block- and page-aligned by the
-                # engine's registration contract)
-                page_len = len(entry.tokens) // len(entry.pages)
-                pages = entry.pages[:length // page_len]
+            # the entry's page_len: its tokens spread evenly over
+            # its pages (both block- and page-aligned by the
+            # engine's registration contract)
+            page_len = len(entry.tokens) // len(entry.pages)
+            pages = entry.pages[:length // page_len]
             best = PrefixMatch(row=row, length=length, pages=pages)
         return best
 
     # ------------------------------------------------------------ refcounts
     def acquire(self, match: PrefixMatch) -> None:
-        """Pin the matched entry while the admitted request occupies its
-        slot (the scheduler releases on request finish/eviction)."""
+        """Pin the matched entry against eviction until
+        :meth:`release`."""
         self._entries[match.row].refcount += 1
 
     def release(self, match: PrefixMatch) -> None:
@@ -327,36 +295,23 @@ class PrefixCache:
         self.tokens_reused -= match.length
 
     # ---------------------------------------------------------- registration
-    def register(self, prompt: Sequence[int],
-                 copy_fn: Optional[Callable[[int, int], None]] = None,
-                 *, pages: Optional[Sequence[int]] = None,
+    def register(self, prompt: Sequence[int], *, pages: Sequence[int],
                  keys: Optional[Sequence[int]] = None) -> str:
-        """Retain ``prompt``'s block-aligned prefix. Contiguous layout:
-        ``copy_fn(row, length)`` runs the engine's row-copy program
-        (serving slot → pool row ``row``) and is called at most once,
-        only after a row is secured. Paged layout: pass ``pages``
-        instead — the page ids already holding the prefix; no copy, no
-        row, and the CALLER bumps the pages' refcounts iff the outcome
+        """Retain ``prompt``'s block-aligned prefix: ``pages`` are the
+        page ids already holding it; nothing is copied, and the CALLER
+        bumps the pages' refcounts iff the outcome
         is ``"registered"`` (eviction releases them through
         ``on_evict``). Returns the outcome:
 
-        - ``"registered"`` — a pool row was (re)filled with the prefix
-          (contiguous) / the prefix's pages were recorded (paged);
+        - ``"registered"`` — the prefix's pages were recorded;
         - ``"duplicate"`` — the exact prefix is already retained (LRU
-          refreshed, no copy, no extra refcounts);
-        - ``"too_short"`` — the prompt spans no full block;
-        - ``"pool_full"`` — contiguous only: every row is held by a
-          pinned (refcount > 0) entry — graceful degradation, nothing
-          evicted. Paged registration never hits this (sharing costs
-          zero new pages).
+          refreshed, no extra refcounts);
+        - ``"too_short"`` — the prompt spans no full block.
 
         ``keys`` (optional) are the prompt's precomputed rolling block
         keys (at least ``n_blocks`` of them) — same contract as
         :meth:`match`.
         """
-        if (copy_fn is None) == (pages is None):
-            raise ValueError("register takes exactly one of copy_fn "
-                             "(contiguous) or pages (paged)")
         n_blocks = len(prompt) // self.block_len
         if n_blocks == 0:
             return "too_short"
@@ -372,29 +327,15 @@ class PrefixCache:
                     int(t) for t in prompt[:length]):
                 entry.last_used = next(self._clock)
                 return "duplicate"
-        if pages is not None:
-            if length % len(pages):
-                raise ValueError(
-                    f"{len(pages)} pages cannot evenly hold a "
-                    f"{length}-token prefix")
-            row = next(self._paged_key)
-            entry = _Entry(row=row,
-                           tokens=tuple(int(t) for t in prompt[:length]),
-                           n_blocks=n_blocks, last_used=next(self._clock),
-                           pages=tuple(int(p) for p in pages))
-        else:
-            row = self._take_row()
-            if row is None:
-                self.pool_full += 1
-                return "pool_full"
-            try:
-                copy_fn(row, length)
-            except BaseException:
-                self._free.append(row)   # don't leak the row on a failed copy
-                raise
-            entry = _Entry(row=row,
-                           tokens=tuple(int(t) for t in prompt[:length]),
-                           n_blocks=n_blocks, last_used=next(self._clock))
+        if not len(pages) or length % len(pages):
+            raise ValueError(
+                f"{len(pages)} pages cannot evenly hold a "
+                f"{length}-token prefix")
+        row = next(self._paged_key)
+        entry = _Entry(row=row,
+                       tokens=tuple(int(t) for t in prompt[:length]),
+                       n_blocks=n_blocks, last_used=next(self._clock),
+                       pages=tuple(int(p) for p in pages))
         self._entries[row] = entry
         for i, key in enumerate(keys):
             # shorter-prefix keys already owned by another entry keep
@@ -483,21 +424,9 @@ class PrefixCache:
             return False
         return self._swap_out(entry)
 
-    def _take_row(self) -> Optional[int]:
-        """A free pool row, evicting the least-recently-used refcount-0
-        entry when none is free; None when every entry is pinned."""
-        if self._free:
-            return self._free.pop()
-        victims = [e for e in self._entries.values() if e.refcount == 0]
-        if not victims:
-            return None
-        victim = min(victims, key=lambda e: e.last_used)
-        self._evict(victim)
-        return victim.row
-
     def evict_lru(self) -> bool:
         """Evict the least-recently-used refcount-0 entry (pool-pressure
-        valve: the paged engine calls this when an admission reservation
+        valve: the engine calls this when an admission reservation
         cannot be covered — retained prefixes are a cache, the admitted
         request is not). False when nothing is evictable.
 
@@ -541,9 +470,9 @@ class PrefixCache:
         (via the engine hook, which must SNAPSHOT the bytes — copy, or
         dispatch the compiled gather that program-orders the copy —
         BEFORE this releases the device pages), page refcounts back to
-        the pool. False — and no state change — when no tier is wired,
-        the entry is not paged, or the tier declined the bytes."""
-        if self._swap_out_fn is None or entry.pages is None:
+        the pool. False — and no state change — when no tier is wired
+        or the tier declined the bytes."""
+        if self._swap_out_fn is None:
             return False
         if not self._swap_out_fn(entry.row, entry.pages):
             return False
@@ -612,13 +541,13 @@ class PrefixCache:
             # hand the entry's page refcounts back (a page still shared
             # with a live slot survives — the pool frees it at zero)
             self._on_evict(entry.pages)
-        _logger.debug("prefix cache evicted %d-block prefix from row %d",
+        _logger.debug("prefix cache evicted %d-block prefix (key %d)",
                       entry.n_blocks, entry.row)
 
     # ------------------------------------------------------------- lifecycle
     def clear(self) -> None:
         """Drop every entry and index key (counters survive — they are
-        run-scoped, not cache-scoped). Paged entries hand their page
+        run-scoped, not cache-scoped). Entries hand their page
         refcounts back through ``on_evict`` so the pool reclaims them."""
         if self._on_evict is not None:
             for entry in self._entries.values():
@@ -626,15 +555,12 @@ class PrefixCache:
                     self._on_evict(entry.pages)
         self._entries.clear()
         self._index.clear()
-        self._free = list(self.pool_rows)
 
     def page_holds(self) -> List[Tuple[int, ...]]:
-        """Every paged entry's retained page-id tuple — the refcounts
+        """Every resident entry's retained page-id tuple — the refcounts
         the cache legitimately holds in the engine's
         :class:`~apex_tpu.serving.PagePool`, exposed for the
-        :class:`~apex_tpu.serving.PoolAuditor`'s reconciliation walk.
-        Empty for a contiguous-layout cache (row entries hold no
-        pages)."""
+        :class:`~apex_tpu.serving.PoolAuditor`'s reconciliation walk."""
         return [entry.pages for entry in self._entries.values()
                 if entry.pages is not None]
 
@@ -647,18 +573,15 @@ class PrefixCache:
             "hit_rate": self.hit_rate,
             "tokens_reused": self.tokens_reused,
             "evictions": self.evictions,
-            "pool_full": self.pool_full,
             "registrations": self.registrations,
             "swap_outs": self.swap_outs,
             "swap_ins": self.swap_ins,
             "entries": self.size,
             "swapped_entries": len(self.swapped_keys()),
-            "capacity": self.capacity,
         }
 
     _DELTA_KEYS = ("hits", "misses", "tokens_reused", "evictions",
-                   "pool_full", "registrations", "swap_outs",
-                   "swap_ins")
+                   "registrations", "swap_outs", "swap_ins")
 
     def stats_since(self, baseline: dict) -> dict:
         """The counter DELTAS since ``baseline`` (a prior :meth:`stats`
@@ -669,14 +592,12 @@ class PrefixCache:
         so any per-window reading (the router's per-replica affinity
         accounting, the bench's measured-window hit rate) must be a
         delta: reading :attr:`hit_rate` directly after a warm reset
-        silently blends the warmup's hits in. Occupancy (``entries`` /
-        ``capacity``) is reported as-of-now — it is state, not a
-        counter."""
+        silently blends the warmup's hits in. Occupancy (``entries``)
+        is reported as-of-now — it is state, not a counter."""
         now = self.stats()
         out = {k: now[k] - baseline.get(k, 0) for k in self._DELTA_KEYS}
         consulted = out["hits"] + out["misses"]
         out["hit_rate"] = out["hits"] / consulted if consulted else 0.0
         out["entries"] = self.size
         out["swapped_entries"] = len(self.swapped_keys())
-        out["capacity"] = self.capacity
         return out

@@ -103,8 +103,7 @@ box's CPU backend share cores, so N-replica tokens/s is NOT a scaling
 measurement here — the CPU-honest columns are prefix-affinity hit rate
 vs the random-routing control, bitwise parity across replica counts,
 and leak-free drains; the aggregate-throughput scaling claim is
-silicon's (``bench_serving.py --replica-router`` prints both with the
-caveat attached). For ``roles`` fleets the CPU-honest columns are
+silicon's. For ``roles`` fleets the CPU-honest columns are
 decode-beat isolation (``serving.disagg.decode_isolation``) and the
 handoff byte/latency histograms — not tokens/s.
 """
@@ -182,7 +181,7 @@ class Router:
         unchanged.
     **scheduler_kw:
         Everything else a :class:`~apex_tpu.serving.Scheduler` takes
-        (``max_queue`` — PER REPLICA — ``eos_id``, ``chunked``,
+        (``max_queue`` — PER REPLICA — ``eos_id``,
         ``retain_prefixes``, ``speculative``, ``pipeline_depth``,
         ``fault_policy``, ...), applied uniformly to every replica.
     """

@@ -60,7 +60,7 @@ def rank_replicas(candidates: Sequence[int],
     ``snapshots[i]`` is a :meth:`Scheduler.load_snapshot` dict — or its
     wire form: the key set is part of the snapshot's versioned wire
     contract, so both fronts rank on identical fields. ``pages_free``
-    / ``host_bytes_free`` may be None (unpaged / no host tier) and
+    / ``host_bytes_free`` may be None (no host tier) and
     rank as 0 — absent capacity is not headroom.
 
     ``priority`` is the routed request's STATIC base priority

@@ -10,8 +10,8 @@ beats:
    with status ``"timeout"`` (their slot frees immediately);
 2. **admit** — while a slot is free and the queue is non-empty, pop the
    oldest request into the slot as *prefilling* (its queue wait ends
-   here — the first half of the TTFT decomposition). On a PAGED engine
-   admission is gated on the page pool first: the head request's
+   here — the first half of the TTFT decomposition).
+   Admission is gated on the page pool first: the head request's
    worst-case page demand (padded prefill extent or prompt + token
    budget, whichever is larger) must be reservable —
    :meth:`Engine.try_reserve_slot` evicts LRU prefix entries under
@@ -21,13 +21,11 @@ beats:
    reservation is what makes mid-decode allocation infallible. With
    ``retain_prefixes=True`` admission then consults the engine's
    :class:`~apex_tpu.serving.PrefixCache`: the longest cached
-   block-aligned prefix of the prompt is attached to the slot — on the
-   paged path by refcount-bumping the donor's pages into the slot's
-   page table (copy-on-write: ZERO data movement, and the matched
-   pages are refunded from the reservation), on the contiguous path by
-   one compiled KV row-copy with the donor entry refcount-pinned for
-   the slot's lifetime — and chunk prefill resumes at the matched
-   offset — every matched chunk is attention+MLP compute that never
+   block-aligned prefix of the prompt is attached to the slot — by
+   refcount-bumping the donor's pages into the slot's page table
+   (copy-on-write: ZERO data movement, and the matched pages are
+   refunded from the reservation) — and chunk prefill resumes at the
+   matched offset — every matched chunk is attention+MLP compute that never
    runs;
 3. **chunk prefill** — at most ``chunk_budget`` (default 1) compiled
    chunk-prefill steps across the prefilling slots, round-robin. A
@@ -87,9 +85,7 @@ Sarathi-style chunked prefill): the monolithic alternative — pause the
 heartbeat and run a whole ``[1, prefill_len]`` prefill at admit time —
 stalls every in-flight decode for the full prompt length. Chunking
 bounds that stall at one chunk, and short prompts stop paying full
-``prefill_len`` padding compute. The monolithic path is kept behind
-``chunked=False`` as the measurable baseline
-(``bench_serving.py --mixed-prompts`` prints the two side by side).
+``prefill_len`` padding compute.
 
 Backpressure instead of OOM: the queue is bounded (``max_queue``);
 :meth:`submit` raises :class:`QueueFull` when it is at capacity, so a
@@ -126,12 +122,10 @@ Terminal request states are one typed enum (:class:`RequestStatus`):
 scheduler, the request records, and telemetry.
 
 Prefix registration is the write half: when a retained-prefix run's
-prompt finishes chunk prefill, its block-aligned K/V is copied into a
-pool row (capacity-bounded; LRU eviction only at refcount 0; a full,
-fully-pinned pool degrades gracefully to the cold path — the request is
-served, just without retention). Both halves are chunked-path only:
-``retain_prefixes=True`` requires ``chunked=True`` (monolithic prefill
-cannot resume mid-prompt) and an engine built with ``prefix_pool > 0``.
+prompt finishes chunk prefill, the pages holding its block-aligned K/V
+are recorded as a cache entry (zero copies; LRU eviction under pool
+pressure). ``retain_prefixes=True`` requires an engine built with
+``prefix_pool > 0``.
 
 Telemetry (through the shared :class:`~apex_tpu.telemetry
 .MetricsRegistry`): ``serving.ttft_s`` decomposed into
@@ -145,7 +139,7 @@ completion record per request (with ``chunks_per_prompt`` and
 :meth:`run`, and the prefix-reuse layer: ``serving.prefix.hits`` /
 ``.misses`` / ``.hit_rate`` (gauge), ``serving.prefix.tokens_reused``,
 ``serving.prefix.chunks_skipped``, ``serving.prefix.evictions``,
-``serving.prefix.registrations`` and ``serving.prefix.pool_full``.
+and ``serving.prefix.registrations``.
 Speculative runs add ``serving.spec.drafted`` / ``serving.spec
 .accepted`` counters, the per-verify ``serving.spec.acceptance_rate``
 histogram, the per-heartbeat ``serving.spec.tokens_per_step`` gauge
@@ -378,9 +372,8 @@ SNAPSHOT_WIRE_VERSION = 3   # v2: oldest_deadline_s/preemptible_pages;
 #: process boundary — None when nothing carries one) and
 #: ``preemptible_pages`` (pages held by running requests strictly
 #: below the SLO config's top class — the headroom a top-priority
-#: arrival could reclaim; None when SLO scheduling is off or the
-#: engine is not paged). v3 adds ``resident_adapters`` (the adapter
-#: names currently resident in the engine's LoRA arena — the
+#: arrival could reclaim; None when SLO scheduling is off). v3 adds
+#: ``resident_adapters`` (the adapter names currently resident in the engine's LoRA arena — the
 #: adapter-affinity signal, ranked by routing_policy right after the
 #: prefix-affinity match; None when LoRA serving is off).
 _SNAPSHOT_KEYS = ("queue_depth", "queue_free", "slots", "slots_busy",
@@ -521,7 +514,7 @@ class Scheduler:
     def __init__(self, engine, *, max_queue: int = 64,
                  default_timeout_s: Optional[float] = None,
                  eos_id: Optional[int] = None, registry=None,
-                 chunked: bool = True, chunk_budget: int = 1,
+                 chunk_budget: int = 1,
                  retain_prefixes: bool = False,
                  speculative: bool = False,
                  pipeline_depth: int = 0,
@@ -564,30 +557,18 @@ class Scheduler:
                 "spec=SpecConfig(...) — the verify program's shape is "
                 "fixed at engine construction")
         if retain_prefixes:
-            if not chunked:
-                raise ValueError(
-                    "retain_prefixes requires chunked=True: prefix reuse"
-                    " resumes prefill mid-prompt, which the monolithic "
-                    "program cannot do")
             if getattr(engine, "prefix_cache", None) is None:
                 raise ValueError(
                     "retain_prefixes requires an engine built with "
                     "prefix_pool > 0 (no pool rows to retain into)")
         if slo is not None:
-            if not chunked:
+            if slo.preempt and not retain_prefixes:
                 raise ValueError(
-                    "slo scheduling requires chunked=True: resume "
-                    "re-ingests mid-stream at the committed offset, "
-                    "which the monolithic program cannot do")
-            if slo.preempt:
-                if not retain_prefixes \
-                        or not getattr(engine, "paged", False):
-                    raise ValueError(
-                        "slo.preempt requires a paged engine with "
-                        "retain_prefixes=True: a preempted request's "
-                        "committed K/V survives as a prefix-cache "
-                        "entry (host-tier swap or resident COW share) "
-                        "and resume is an ordinary prefix attach")
+                    "slo.preempt requires "
+                    "retain_prefixes=True: a preempted request's "
+                    "committed K/V survives as a prefix-cache "
+                    "entry (host-tier swap or resident COW share) "
+                    "and resume is an ordinary prefix attach")
         if role not in ("prefill", "decode", "both"):
             raise ValueError(
                 f"role must be 'prefill', 'decode' or 'both', got "
@@ -598,17 +579,15 @@ class Scheduler:
                     f"role={role!r} requires retain_prefixes=True: the "
                     "KV handoff travels as an ordinary swapped prefix, "
                     "so both sides need the prefix-cache machinery")
-            if not getattr(engine, "paged", False) \
-                    or getattr(engine, "host_tier", None) is None:
+            if getattr(engine, "host_tier", None) is None:
                 raise ValueError(
-                    f"role={role!r} requires a paged engine with a "
+                    f"role={role!r} requires an engine with a "
                     "host_tier: the handoff's KV travels through the "
                     "(shared) host arena's swap programs")
         self.engine = engine
         self.max_queue = int(max_queue)
         self.default_timeout_s = default_timeout_s
         self.eos_id = eos_id
-        self.chunked = bool(chunked)
         self.chunk_budget = int(chunk_budget)
         self.retain_prefixes = bool(retain_prefixes)
         self.speculative = bool(speculative)
@@ -677,25 +656,21 @@ class Scheduler:
         self._last_tokens = np.zeros(engine.slots, np.int32)
         self._temps = np.zeros(engine.slots, np.float32)
         self._pf_rr = 0           # round-robin start for chunk budgeting
-        # per-slot pinned prefix match (released when the slot frees)
-        self._slot_prefix: List[Optional[object]] = [None] * engine.slots
         self.completed: List[Request] = []
         # fault isolation: containment is ALWAYS on (the policy has
         # production defaults); the plan is the chaos harness's
         # injection schedule (None in production); the auditor
-        # reconciles page refcounts after finish/eviction events on
-        # paged engines, sampled by the policy's audit_every_n
+        # reconciles page refcounts after finish/eviction events,
+        # sampled by the policy's audit_every_n
         self.fault_policy = fault_policy if fault_policy is not None \
             else FaultPolicy()
         self.fault_plan = fault_plan
         if auditor is not None:
             self.auditor = auditor
-        elif getattr(engine, "paged", False):
+        else:
             self.auditor = PoolAuditor(
                 every_n=self.fault_policy.audit_every_n,
                 registry=self.registry)
-        else:
-            self.auditor = None
         self._tick = 0            # heartbeat index (the FaultPlan clock)
         self._step_s_ema: Optional[float] = None   # decode-step seconds
         # ---- async pipelined heartbeat state (pipeline_depth >= 1):
@@ -873,12 +848,9 @@ class Scheduler:
         return round(steps * self._step_s_ema, 6)
 
     def _free_slot(self, slot: int) -> None:
-        """Detach whatever occupies ``slot``: clear the running entry,
-        unpin its prefix donor, and (paged) return its pages plus any
-        unused admission reservation to the pool NOW — on the
-        contiguous layout the row is only reclaimed by the next prefill
-        overwriting it. Shared by normal finishes and fault
-        quarantines."""
+        """Detach whatever occupies ``slot``: clear the running entry
+        and return its pages plus any unused admission reservation to
+        the pool NOW. Shared by normal finishes and fault quarantines."""
         self._running[slot] = None
         self._temps[slot] = 0.0
         self._slot_hash_keys[slot] = None
@@ -901,18 +873,13 @@ class Scheduler:
             if dropped and self.registry is not None:
                 self.registry.counter_inc("serving.heartbeat.discarded",
                                           dropped)
-        if self._slot_prefix[slot] is not None:
-            # the slot no longer reads from its donor prefix: unpin
-            self.engine.prefix_cache.release(self._slot_prefix[slot])
-            self._slot_prefix[slot] = None
         if getattr(self.engine, "lora", None) is not None:
             # the single LoRA unbind point: drops the slot's adapter
             # refcount (the adapter STAYS resident for affinity — only
             # arena pressure evicts it). Not in Engine.release_slot,
             # which cold-start prefill calls mid-request
             self.engine.lora_unbind(slot)
-        if getattr(self.engine, "paged", False):
-            self.engine.release_slot(slot)
+        self.engine.release_slot(slot)
 
     def _finish(self, request: Request, reason: str,
                 slot: Optional[int] = None,
@@ -1017,10 +984,9 @@ class Scheduler:
                     self.registry.counter_inc(
                         f"serving.slo.tenant.{request.tenant}.tokens",
                         len(request.output_tokens))
-        if self.auditor is not None:
-            # finish events move refcounts (page release, reservation
-            # return): reconcile on the policy's sampling cadence
-            self.auditor.maybe_audit(self.engine)
+        # finish events move refcounts (page release, reservation
+        # return): reconcile on the policy's sampling cadence
+        self.auditor.maybe_audit(self.engine)
 
     def _quarantine(self, request: Request, slot: Optional[int],
                     error: str) -> None:
@@ -1125,8 +1091,6 @@ class Scheduler:
         return None
 
     def _admit(self) -> None:
-        if not self.chunked:
-            return self._admit_monolithic()
         if self.slo is not None:
             return self._admit_slo()
         for slot in range(self.engine.slots):
@@ -1206,10 +1170,8 @@ class Scheduler:
                      t0=t_adm - r.queue_wait_s, dur=r.queue_wait_s)
             tr.event(r.uid, "admit", t0=t_adm, slot=slot,
                      reused_tokens=r.reused_tokens,
-                     pages=(self.engine.pages_required(
-                         len(r.prompt), r.max_new_tokens)
-                         if getattr(self.engine, "paged", False)
-                         else 0))
+                     pages=self.engine.pages_required(
+                         len(r.prompt), r.max_new_tokens))
         self._running[slot] = r
         self._temps[slot] = r.temperature
 
@@ -1409,8 +1371,7 @@ class Scheduler:
         self._queue.append(r)
         if self.registry is not None:
             self.registry.counter_inc("serving.preempt.preemptions")
-        if self.auditor is not None:
-            self.auditor.maybe_audit(self.engine)
+        self.auditor.maybe_audit(self.engine)
 
     def _preempt_export(self, slot: int, r: Request, seq, cap: int,
                         tier) -> int:
@@ -1429,17 +1390,12 @@ class Scheduler:
         # already retained (refreshed), so the match will find it
         return cap if outcome in ("registered", "duplicate") else 0
 
-    def _reserve_pages(self, slot: int, r: Request,
-                       monolithic: bool = False) -> bool:
-        """Paged admission gate: reserve ``r``'s worst-case page demand
-        for ``slot`` (True on a contiguous engine — rows are
-        preallocated there). Counts ``serving.pool.admit_blocked`` when
+    def _reserve_pages(self, slot: int, r: Request) -> bool:
+        """Admission gate: reserve ``r``'s worst-case page demand
+        for ``slot``. Counts ``serving.pool.admit_blocked`` when
         the pool turns an admission away."""
-        if not getattr(self.engine, "paged", False):
-            return True
         need = self.engine.pages_required(len(r.prompt),
-                                          r.max_new_tokens,
-                                          monolithic=monolithic)
+                                          r.max_new_tokens)
         ok = self.engine.try_reserve_slot(slot, need)
         if not ok and self.registry is not None:
             self.registry.counter_inc("serving.pool.admit_blocked")
@@ -1457,10 +1413,9 @@ class Scheduler:
     def _consult_prefix_cache(self, r: Request, slot: int) -> None:
         """Admission-time read path: attach the longest cached
         block-aligned prefix of ``r``'s ingest stream to ``slot`` —
-        paged: share the donor's pages into the slot's table
+        share the donor's pages into the slot's table
         (copy-on-write, zero data movement, no pin needed: page
-        refcounts outlive the entry); contiguous: one compiled
-        row-copy with the donor entry pinned for the slot's lifetime.
+        refcounts outlive the entry).
         Chunk prefill then resumes at the matched offset. A miss
         changes nothing — the request prefills cold from offset 0.
         For a PREEMPTED request the stream is prompt + committed
@@ -1488,22 +1443,16 @@ class Scheduler:
             self._slot_hash_keys[slot] = keys
         seq = self._ingest(r)
         m = pcache.match(seq, keys=keys)
-        if m is not None:
-            if getattr(self.engine, "paged", False):
-                if not self.engine.attach_prefix(slot, m):
-                    # hierarchical KV: the hit's host-tier bytes were
-                    # missing/corrupt (the engine dropped the entry and
-                    # counted serving.swap.verify_failed) or the pool
-                    # was too tight to restore them — degrade to a
-                    # VERIFIED MISS: nothing attached, the request
-                    # prefills cold from offset 0, and the hit/miss
-                    # accounting is reversed so hit_rate stays honest
-                    pcache.unrecord_hit(m)
-                    m = None
-            else:
-                self.engine.restore_prefix(slot, m.row, m.length)
-                pcache.acquire(m)
-                self._slot_prefix[slot] = m
+        if m is not None and not self.engine.attach_prefix(slot, m):
+            # hierarchical KV: the hit's host-tier bytes were
+            # missing/corrupt (the engine dropped the entry and
+            # counted serving.swap.verify_failed) or the pool
+            # was too tight to restore them — degrade to a
+            # VERIFIED MISS: nothing attached, the request
+            # prefills cold from offset 0, and the hit/miss
+            # accounting is reversed so hit_rate stays honest
+            pcache.unrecord_hit(m)
+            m = None
         if m is not None:
             r._prefill_pos = m.length
             r.reused_tokens = m.length
@@ -1560,95 +1509,6 @@ class Scheduler:
                 self.tracer.event(r.uid, "resume", slot=slot,
                                   resumed_tokens=0 if m is None
                                   else m.length, cold=m is None)
-
-    def _admit_monolithic(self) -> None:
-        """Legacy admit (``chunked=False``): whole-prompt prefill at
-        admission — the head-of-line-blocking baseline the chunked path
-        is benchmarked against."""
-        for slot in range(self.engine.slots):
-            if self._running[slot] is not None:
-                continue
-            # keep filling THIS slot: a request that finishes right at
-            # prefill (instant EOS / budget 1) leaves it free for the next
-            while self._queue and self._running[slot] is None:
-                idx = self._eligible_index(time.perf_counter())
-                if idx is None:
-                    return          # everything queued is backing off
-                gate = self._lora_gate(slot, idx)
-                if gate == "failed":
-                    continue        # the queue changed: re-scan
-                if gate == "blocked":
-                    return          # arena rows all pinned: keep FIFO
-                if not self._reserve_pages(slot, self._queue[idx],
-                                           monolithic=True):
-                    if getattr(self.engine, "lora", None) is not None:
-                        self.engine.lora_unbind(slot)
-                    return          # pool exhausted: keep FIFO, retry later
-                r = self._queue[idx]
-                del self._queue[idx]
-                r.queue_wait_s = time.perf_counter() - r._t_queued
-                if self.registry is not None:
-                    self.registry.observe("serving.queue_wait_s",
-                                          r.queue_wait_s)
-                if self.tracer is not None:
-                    tr = self.tracer
-                    t_adm = tr.now()
-                    tr.event(r.uid, "queue_wait",
-                             t0=t_adm - r.queue_wait_s,
-                             dur=r.queue_wait_s)
-                    tr.event(r.uid, "admit", t0=t_adm, slot=slot,
-                             reused_tokens=0,
-                             pages=(self.engine.pages_required(
-                                 len(r.prompt), r.max_new_tokens,
-                                 monolithic=True)
-                                 if getattr(self.engine, "paged",
-                                            False) else 0))
-                t0 = time.perf_counter()
-                try:
-                    token = self.engine.prefill(
-                        slot, list(r.prompt), temperature=r.temperature)
-                except Exception as e:  # noqa: BLE001 — containment edge
-                    r.prefill_s += time.perf_counter() - t0
-                    self._count_transient()
-                    self._quarantine(r, slot,
-                                     f"{type(e).__name__}: {e}")
-                    continue
-                r.prefill_s += time.perf_counter() - t0
-                r.chunks += 1
-                if self.tracer is not None:
-                    self.tracer.event(r.uid, "prefill_chunk", t0=t0,
-                                      dur=time.perf_counter() - t0,
-                                      lo=0, hi=len(r.prompt),
-                                      final=True)
-                if not self.engine.last_prefill_finite:
-                    # non-finite prompt logits: the sampled token is
-                    # garbage — quarantine instead of emitting it
-                    self._quarantine(r, slot,
-                                     "non-finite prefill logits")
-                    continue
-                r.ttft_s = time.perf_counter() - r._t_submit
-                if self.registry is not None:
-                    self.registry.observe("serving.ttft_s", r.ttft_s)
-                r.output_tokens.append(token)
-                r.status = RequestStatus.RUNNING
-                if self.eos_id is not None and token == self.eos_id:
-                    self._finish(r, "eos")
-                elif r.max_new_tokens <= 1:
-                    self._finish(r, "max_new_tokens")
-                elif len(r.prompt) >= self.engine.max_len:
-                    # cache already full: a decode step would overwrite
-                    # the last prompt position's K/V (the engine clamps
-                    # its write to max_len-1) and emit a corrupted token
-                    self._finish(r, "max_len")
-                else:
-                    self._running[slot] = r
-                    self._last_tokens[slot] = token
-                    self._temps[slot] = r.temperature
-                if self._running[slot] is None \
-                        and getattr(self.engine, "paged", False):
-                    # finished right at prefill (_finish saw no slot):
-                    # free the pages + leftover reservation now
-                    self.engine.release_slot(slot)
 
     def _count_transient(self) -> None:
         if self.registry is not None:
@@ -1915,12 +1775,9 @@ class Scheduler:
     def _register_prefix(self, r: Request, slot: int) -> None:
         """Write path, at prompt-ingestion completion: retain the
         prompt's block-aligned K/V prefix (now fully resident in
-        ``slot``). Paged: share the slot's pages into a cache entry —
+        ``slot``): share the slot's pages into a cache entry —
         zero copies, zero new pages (capacity pressure is the admission
-        gate's job). Contiguous: one compiled row-copy into a pool row;
-        a full pool evicts its LRU refcount-0 entry and a fully-pinned
-        pool skips retention (graceful degradation — the request is
-        unaffected)."""
+        gate's job)."""
         pcache = self.engine.prefix_cache
         before = pcache.evictions
         keys = self._slot_hash_keys[slot]
@@ -1929,15 +1786,7 @@ class Scheduler:
         # in the slot (keys are None on resume — stored hashes covered
         # the prompt only — so the cache re-hashes inline)
         seq = self._ingest(r)
-        if getattr(self.engine, "paged", False):
-            outcome = self.engine.retain_prefix(slot, seq,
-                                                keys=keys)
-        else:
-            outcome = pcache.register(
-                seq,
-                lambda row, length: self.engine.store_prefix(row, slot,
-                                                             length),
-                keys=keys)
+        outcome = self.engine.retain_prefix(slot, seq, keys=keys)
         if self.registry is not None:
             evicted = pcache.evictions - before
             if evicted:
@@ -1945,9 +1794,7 @@ class Scheduler:
                                           evicted)
             if outcome == "registered":
                 self.registry.counter_inc("serving.prefix.registrations")
-            elif outcome == "pool_full":
-                self.registry.counter_inc("serving.prefix.pool_full")
-        if self.auditor is not None and pcache.evictions != before:
+        if pcache.evictions != before:
             # evictions release entry page refcounts: reconcile on the
             # policy's sampling cadence
             self.auditor.maybe_audit(self.engine)
@@ -2306,7 +2153,7 @@ class Scheduler:
         with tracing.phase("serve.admit"):
             self._admit()
         with tracing.phase("serve.chunk") as ph:
-            chunks = self._prefill_tick(tick) if self.chunked else 0
+            chunks = self._prefill_tick(tick)
             # the chunk budget bounds the stall imposed ON in-flight
             # decodes; while nothing is decoding there is nothing to
             # stall, so keep ingesting back-to-back (cold-start/queue-
@@ -2415,7 +2262,7 @@ class Scheduler:
         return True
 
     def _emit_beat_gauges(self, active: np.ndarray) -> None:
-        """Per-beat occupancy / padding-waste / paged-pool gauges over
+        """Per-beat occupancy / padding-waste / page-pool gauges over
         the decode batch's dispatch mask (shared by the sync and
         pipelined beats)."""
         if self.registry is None:
@@ -2424,20 +2271,19 @@ class Scheduler:
         self.registry.gauge_set("serving.slot_occupancy", occ)
         self.registry.observe("serving.slot_occupancy", occ)
         self.registry.observe("serving.padding_waste", 1.0 - occ)
-        if getattr(self.engine, "paged", False):
-            # the paged pool's per-step health: HBM pressure
-            # (pages_in_use/free), sharing efficiency (cow_shares —
-            # pages serving >1 reader for one page of HBM) and
-            # internal fragmentation (allocated-but-invalid slack)
-            ps = self.engine.pool_stats()
-            self.registry.gauge_set("serving.pool.pages_in_use",
-                                    float(ps["pages_in_use"]))
-            self.registry.gauge_set("serving.pool.pages_free",
-                                    float(ps["pages_free"]))
-            self.registry.gauge_set("serving.pool.cow_shares",
-                                    float(ps["cow_shares"]))
-            self.registry.gauge_set("serving.pool.fragmentation",
-                                    float(ps["fragmentation"]))
+        # the paged pool's per-step health: HBM pressure
+        # (pages_in_use/free), sharing efficiency (cow_shares —
+        # pages serving >1 reader for one page of HBM) and
+        # internal fragmentation (allocated-but-invalid slack)
+        ps = self.engine.pool_stats()
+        self.registry.gauge_set("serving.pool.pages_in_use",
+                                float(ps["pages_in_use"]))
+        self.registry.gauge_set("serving.pool.pages_free",
+                                float(ps["pages_free"]))
+        self.registry.gauge_set("serving.pool.cow_shares",
+                                float(ps["cow_shares"]))
+        self.registry.gauge_set("serving.pool.fragmentation",
+                                float(ps["fragmentation"]))
 
     # ------------------------------------------- the pipelined heartbeat
     def _step_body_pipelined(self, tick: int) -> bool:
@@ -2456,7 +2302,7 @@ class Scheduler:
         with tracing.phase("serve.admit"):
             self._admit()
         with tracing.phase("serve.chunk") as ph:
-            chunks = self._prefill_tick(tick) if self.chunked else 0
+            chunks = self._prefill_tick(tick)
             # cold-queue burst (same contract as the sync beat): only
             # while nothing is decoding AND nothing is in flight
             while chunks and not self._pipeline \
@@ -2722,9 +2568,7 @@ class Scheduler:
         bookkeeping (queue/slot walks, the paged allocator's free
         count, the host arena's byte ledger); nothing forces a device
         value, so probing N replicas per submit costs microseconds,
-        not syncs. ``pages_free`` is None on a contiguous engine (rows
-        are preallocated — slot occupancy is the whole capacity story
-        there); ``host_bytes_free`` is None without a hierarchical-KV
+        not syncs. ``host_bytes_free`` is None without a hierarchical-KV
         host tier — when present it is the swap arena's remaining
         headroom, so the router's least-loaded tie-break sees arena
         pressure (a replica about to shed swapped prefixes), not just
@@ -2743,8 +2587,7 @@ class Scheduler:
           AND whose committed stream still fits the prefill re-ingest
           window (a decode past ``prefill_len`` is no longer exactly
           resumable, so it is never a victim) — the headroom a
-          top-class arrival could reclaim by preemption. None on a
-          contiguous engine (no pages to count).
+          top-class arrival could reclaim by preemption.
         """
         busy = sum(r is not None for r in self._running)
         tier = getattr(self.engine, "host_tier", None)
@@ -2760,21 +2603,20 @@ class Scheduler:
                 rem = r._t_submit + r.deadline_s - now
                 if oldest is None or rem < oldest:
                     oldest = rem
-            if getattr(self.engine, "paged", False):
-                top = self.slo.top_priority
-                preemptible = 0
-                for slot, r in enumerate(self._running):
-                    if r is None or r.status != RequestStatus.RUNNING:
-                        continue
-                    if len(r.prompt) + len(r.output_tokens) \
-                            > self.engine.prefill_len:
-                        # mirrors _try_preempt: past the re-ingest
-                        # window the slot is not exactly resumable
-                        continue
-                    pri = r._eff_priority if r._eff_priority is not None \
-                        else self.slo.base_priority(r)
-                    if pri < top:
-                        preemptible += self.engine.slot_pages(slot)
+            top = self.slo.top_priority
+            preemptible = 0
+            for slot, r in enumerate(self._running):
+                if r is None or r.status != RequestStatus.RUNNING:
+                    continue
+                if len(r.prompt) + len(r.output_tokens) \
+                        > self.engine.prefill_len:
+                    # mirrors _try_preempt: past the re-ingest
+                    # window the slot is not exactly resumable
+                    continue
+                pri = r._eff_priority if r._eff_priority is not None \
+                    else self.slo.base_priority(r)
+                if pri < top:
+                    preemptible += self.engine.slot_pages(slot)
         return {
             "queue_depth": len(self._queue),
             "queue_free": self.max_queue - len(self._queue),
@@ -2782,8 +2624,7 @@ class Scheduler:
             "slots_busy": busy,
             "slots_free": self.engine.slots - busy,
             "inflight_steps": len(self._pipeline),
-            "pages_free": self.engine.pages_free
-            if getattr(self.engine, "paged", False) else None,
+            "pages_free": self.engine.pages_free,
             "host_bytes_free": None if tier is None
             else tier.capacity_bytes - tier.bytes_used,
             "oldest_deadline_s": oldest,

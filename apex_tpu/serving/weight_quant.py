@@ -53,7 +53,7 @@ parameter path and channel named, never surfacing later as NaN logits.
 
 Accuracy is the PR 10 contract one tier over: greedy serving under
 ``Engine(weight_quant=WeightQuantConfig())`` is a token-match-rate
-claim vs the bf16 oracle (``bench_serving.py --quantized-weights``),
+claim vs the bf16 oracle,
 while ``weight_quant=None`` stays the default and the bitwise baseline
 — none of this module is on its trace path.
 """

@@ -1028,7 +1028,7 @@ def _maybe_generate(args, model, params, tele):
     completed requests, the model and ``Engine`` geometry keywords they
     were served with, the Pallas kernels each serving program holds and
     the bytes it keeps beside its operands (for callers inspecting the
-    outputs or serving the same stream on another engine), or None
+    outputs or checking them against the model's plain forward), or None
     without --generate."""
     if not args.generate:
         return None

@@ -286,8 +286,7 @@ def test_chaos_stream_unfaulted_bitwise_and_zero_leaks(engine):
                                                audit_every_n=1)).run(
         clean_reqs)
     clean = [list(r.output_tokens) for r in clean_reqs]
-    traces0 = (engine.chunk_traces, engine.decode_traces,
-               engine.prefill_traces)
+    traces0 = (engine.chunk_traces, engine.decode_traces)
 
     engine.reset()
     plan = FaultPlan([
@@ -319,8 +318,7 @@ def test_chaos_stream_unfaulted_bitwise_and_zero_leaks(engine):
             # whether or not they absorbed a fault
             assert list(r.output_tokens) == clean[i], \
                 f"request {i} diverged under pipelined chaos"
-    assert (engine.chunk_traces, engine.decode_traces,
-            engine.prefill_traces) == traces0
+    assert (engine.chunk_traces, engine.decode_traces) == traces0
     assert sched.auditor.audit(engine)["pages_in_use"] == 0
     assert reg.snapshot()["counters"]["serving.faults.transient"] >= 1
     engine.reset()

@@ -92,21 +92,6 @@ def test_bench_exit_code_sees_every_failed_leg():
     assert bench._failed_legs({"value": 1.0, "serving": {"value": 2}}) == []
 
 
-def test_bench_child_process_legs_do_not_run_next_to_a_chip(monkeypatch,
-                                                            capsys):
-    import bench
-
-    assert bench._child_leg_refused("process_fleet") is None    # the CPU
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    for leg, fn in (("process_fleet", bench._serving_process_fleet_leg),
-                    ("host_tier", bench._serving_host_tier_leg),
-                    ("tensor_parallel", bench._serving_tp_leg)):
-        out = fn()
-        assert out["skipped"] is True and "tpu backend" in out["reason"]
-        assert f"{leg} leg not run on the tpu backend" \
-            in capsys.readouterr().err
-
-
 def test_fleet_refuses_workers_that_could_not_open_a_chip(monkeypatch):
     from jax._src import xla_bridge
 
@@ -116,6 +101,7 @@ def test_fleet_refuses_workers_that_could_not_open_a_chip(monkeypatch):
     fleet.check_worker_backend(8)               # CPU workers always start
 
     monkeypatch.delenv("JAX_PLATFORMS")
+    jax.devices()           # the state under test: a backend is live
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert xla_bridge.backends_are_initialized()
     with pytest.raises(RuntimeError, match="already live on tpu"):
@@ -136,17 +122,53 @@ def test_fleet_refuses_workers_that_could_not_open_a_chip(monkeypatch):
 
 
 # ------------------------------------------ chip_smoke.py, rehearsed tiny
+@pytest.mark.parametrize("tamper", [False, True],
+                         ids=["untouched", "one_token_replaced"])
+def test_chip_smoke_holds_served_tokens_to_the_plain_forward(tamper):
+    """The serve check's rule at tiny size: every served token's logit
+    within ``SERVED_LOGIT_GAP`` of its position's best in the model's
+    plain forward over prompt + output. Served tokens pass; one of them
+    replaced by that position's WORST token fails, and the failure names
+    the request."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    import chip_smoke
+    from apex_tpu import serving
+    from apex_tpu.amp.policy import resolve_policy
+    from apex_tpu.models.transformer_lm import TransformerLM
+
+    m = TransformerLM(vocab_size=101, hidden=32, num_layers=2, num_heads=4,
+                      max_seq_len=64)
+    params = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+                    train=False)["params"]
+    eng = serving.Engine(m, params, slots=2, max_len=64, prefill_len=24,
+                         chunk_len=8,
+                         policy=resolve_policy("O0", verbose=False))
+    rng = np.random.default_rng(0)
+    reqs = serving.Scheduler(eng).run(
+        [serving.Request(prompt=rng.integers(1, 101, n).tolist(),
+                         max_new_tokens=6) for n in (5, 8, 19)])
+    assert chip_smoke.served_token_failures(m, params, reqs) == []
+    if tamper:
+        victim = reqs[1]
+        seq = jnp.asarray([list(victim.prompt) + victim.output_tokens[:3]])
+        worst = int(jnp.argmin(m.apply({"params": params}, seq,
+                                       train=False)[0, -1]))
+        victim.output_tokens[3] = worst
+        (failure,) = chip_smoke.served_token_failures(m, params, reqs)
+        assert f"request {victim.uid} token 3" in failure
+        assert "below its position's best logit" in failure
+
+
 TINY_LM = ["--size", "tiny", "--vocab-size", "512", "--opt-level", "O2"]
 
 
-@pytest.mark.slow       # ~1 min: two engines + a train step at seq 640
+@pytest.mark.slow       # ~1 min: an engine + a train step at seq 640
 def test_chip_smoke_lm_phase_runs_end_to_end_at_tiny_size():
     """Everything but the kernels' presence holds on the CPU; and the
     kernel gate fires, which is what it is for. The serve geometry is
-    chip_smoke's own (512 + 128 = 5 pages of 128): only there do the
-    paged and contiguous kernels walk the cache in the same blocks, and
-    greedy tokens of a barely-trained model survive nothing less than
-    identical arithmetic."""
+    chip_smoke's own (512 + 128 = 5 pages of 128)."""
     import chip_smoke
 
     failures = chip_smoke.lm_phase(TINY_LM + [

@@ -69,7 +69,7 @@ def _mk_engine(lm_and_params, *, tier=None, slots=2, pool=4, seed=5,
                **kw):
     m, params = lm_and_params
     return Engine(m, params, slots=slots, max_len=64, prefill_len=24,
-                  chunk_len=CHUNK, prefix_pool=pool, paged=True,
+                  chunk_len=CHUNK, prefix_pool=pool,
                   policy=resolve_policy("O0", verbose=False), seed=seed,
                   host_tier=tier, **kw)
 
@@ -387,13 +387,12 @@ def test_program_counts_pin_exact_per_role(lm_and_params):
                     retain_prefixes=True, max_queue=16)
     router.run(_stream())
     assert (pe.chunk_traces, pe.swap_out_traces) == (1, 1)
-    assert (pe.decode_traces, pe.swap_in_traces, pe.copy_traces,
-            pe.verify_traces, pe.prefill_traces) == (0, 0, 0, 0, 0), \
+    assert (pe.decode_traces, pe.swap_in_traces,
+            pe.verify_traces) == (0, 0, 0), \
         "a prefill-role engine traced a decode-side program"
     assert (de.chunk_traces, de.decode_traces,
             de.swap_in_traces) == (1, 1, 1)
-    assert (de.swap_out_traces, de.copy_traces, de.verify_traces,
-            de.prefill_traces) == (0, 0, 0, 0), \
+    assert (de.swap_out_traces, de.verify_traces) == (0, 0), \
         "a decode-role engine traced an ingest-side program"
     assert pe.compiled_programs == 2 and de.compiled_programs == 3
     router.close()

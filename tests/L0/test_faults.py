@@ -13,8 +13,8 @@ The acceptance bar from the fault-isolation issue, as tests:
 - containment adds ZERO compiled programs: the chaos run's trace
   counters match the fault-free run's (the guard is fused into the
   existing programs; injection rides a zero-in-production operand);
-- the non-finite guard is per-slot (decode) / per-call (chunk,
-  monolithic prefill) and fires on REAL NaN logits (a NaN-poisoned
+- the non-finite guard is per-slot (decode) / per-call (chunk)
+  and fires on REAL NaN logits (a NaN-poisoned
   engine fails every request typed-``FAILED`` without crashing);
 - the fault policy requeues with capped exponential backoff up to
   ``max_retries`` then lands the typed ``FAILED`` terminal status,
@@ -67,11 +67,10 @@ def lm_and_params():
     return m, params
 
 
-def _mk_engine(lm_and_params, *, paged=True, pool=0, slots=2, seed=5,
-               **kw):
+def _mk_engine(lm_and_params, *, pool=0, slots=2, seed=5, **kw):
     m, params = lm_and_params
     return Engine(m, params, slots=slots, max_len=64, prefill_len=24,
-                  chunk_len=CHUNK, prefix_pool=pool, paged=paged,
+                  chunk_len=CHUNK, prefix_pool=pool,
                   policy=resolve_policy("O0", verbose=False), seed=seed,
                   **kw)
 
@@ -164,8 +163,6 @@ def test_auditor_passes_on_healthy_pool_and_samples(engine, lm_and_params):
     assert aud.audits == 1
     off = PoolAuditor(every_n=0)             # disabled
     assert off.maybe_audit(engine) is None
-    with pytest.raises(RuntimeError, match="paged engines only"):
-        PoolAuditor().audit(_mk_engine(lm_and_params, paged=False))
 
 
 def test_auditor_detects_leak_and_double_free(engine):
@@ -214,8 +211,7 @@ def test_decode_nonfinite_guard_is_per_slot(lm_and_params):
     value-identical — healthy batchmates never see the fault). Two
     engines built identically (same params/seed/geometry) run the same
     step, one clean and one injected — the comparison crosses two
-    traces of the same program, the discipline the chunked-vs-
-    monolithic parity test already relies on."""
+    traces of the same program."""
     e1 = _mk_engine(lm_and_params)
     e2 = _mk_engine(lm_and_params)
     for e in (e1, e2):
@@ -276,8 +272,7 @@ def test_chaos_pin_unfaulted_requests_bitwise_and_zero_leaks(engine):
     clean_reqs = _stream()
     sched0.run(clean_reqs)
     clean = [list(r.output_tokens) for r in clean_reqs]
-    traces0 = (engine.chunk_traces, engine.decode_traces,
-               engine.prefill_traces)
+    traces0 = (engine.chunk_traces, engine.decode_traces)
 
     engine.reset()
     stalls = []
@@ -319,8 +314,7 @@ def test_chaos_pin_unfaulted_requests_bitwise_and_zero_leaks(engine):
         if r.retries and r.status is RequestStatus.FINISHED:
             assert list(r.output_tokens) == clean[i]
     # containment added ZERO compiled programs
-    assert (engine.chunk_traces, engine.decode_traces,
-            engine.prefill_traces) == traces0
+    assert (engine.chunk_traces, engine.decode_traces) == traces0
     # watchdog saw the injected stall; auditor sees zero leaks at drain
     assert plan.stats()["injected_stalls"] == 1
     assert len(stalls) >= 1
@@ -331,21 +325,6 @@ def test_chaos_pin_unfaulted_requests_bitwise_and_zero_leaks(engine):
     assert snap["counters"]["serving.faults.nonfinite"] >= 1
     assert sched.auditor.audit(engine)["pages_in_use"] == 0
     engine.reset()
-
-
-def test_contiguous_engine_containment(lm_and_params):
-    """The fault policy is layout-agnostic: the contiguous (paged=False)
-    engine quarantines and requeues the same way — no auditor (nothing
-    paged to audit), same typed terminals."""
-    eng = _mk_engine(lm_and_params, paged=False)
-    plan = FaultPlan([FaultSpec(kind="exception", tick=2, site="chunk")])
-    sched = Scheduler(eng, fault_policy=_fast_policy(max_retries=2),
-                      fault_plan=plan)
-    assert sched.auditor is None
-    reqs = _stream()
-    sched.run(reqs)
-    assert all(r.status is RequestStatus.FINISHED for r in reqs)
-    assert sum(r.retries for r in reqs) == 1
 
 
 # ------------------------------------------------- policy + scheduler
@@ -642,7 +621,7 @@ def test_sharded_engine_chaos_quarantine_frees_pages_on_every_shard(
     clean_reqs = _stream100()
     Scheduler(eng, fault_policy=_fast_policy()).run(clean_reqs)
     clean = [list(r.output_tokens) for r in clean_reqs]
-    traces0 = (eng.chunk_traces, eng.decode_traces, eng.prefill_traces)
+    traces0 = (eng.chunk_traces, eng.decode_traces)
 
     eng.reset()
     plan = FaultPlan([
@@ -672,8 +651,7 @@ def test_sharded_engine_chaos_quarantine_frees_pages_on_every_shard(
             assert list(r.output_tokens) == clean[i], \
                 f"request {i} diverged under chaos on the sharded engine"
     # containment added ZERO compiled programs on the sharded engine
-    assert (eng.chunk_traces, eng.decode_traces,
-            eng.prefill_traces) == traces0
+    assert (eng.chunk_traces, eng.decode_traces) == traces0
     snap = reg.snapshot()
     assert snap["counters"]["serving.faults.nonfinite"] >= 1
     # the tp gauges rode the same registry

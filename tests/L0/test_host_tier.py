@@ -85,11 +85,10 @@ def lm_and_params():
     return m, params
 
 
-def _mk_engine(lm_and_params, *, pool=2, slots=3, seed=5, paged=True,
-               **kw):
+def _mk_engine(lm_and_params, *, pool=2, slots=3, seed=5, **kw):
     m, params = lm_and_params
     return Engine(m, params, slots=slots, max_len=64, prefill_len=24,
-                  chunk_len=CHUNK, prefix_pool=pool, paged=paged,
+                  chunk_len=CHUNK, prefix_pool=pool,
                   policy=resolve_policy("O0", verbose=False), seed=seed,
                   **kw)
 
@@ -249,7 +248,7 @@ def test_at_most_one_new_program_per_direction_and_zero_leaks(
     assert et.chunk_traces == 1 and et.decode_traces == 1
     assert et.swap_in_traces == 1          # every page shares ONE program
     assert et.swap_out_traces == 1         # ... in each direction
-    assert et.copy_traces == et.verify_traces == et.prefill_traces == 0
+    assert et.verify_traces == 0
     assert et.compiled_programs == 4
     assert ec.compiled_programs == 2
     assert ec.swap_in_traces == ec.swap_out_traces == 0
@@ -262,8 +261,6 @@ def test_at_most_one_new_program_per_direction_and_zero_leaks(
 
 
 def test_engine_host_tier_validation(lm_and_params):
-    with pytest.raises(ValueError, match="paged=True"):
-        _mk_engine(lm_and_params, host_tier=1 << 20, paged=False)
     with pytest.raises(ValueError, match="prefix_pool"):
         _mk_engine(lm_and_params, host_tier=1 << 20, pool=0)
     # a pre-built arena is accepted as-is (capacity honoured)

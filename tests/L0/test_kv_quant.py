@@ -6,14 +6,12 @@ The acceptance bar from the quantized-cache issue, as tests:
   LOUDLY at engine construction (degenerate scales must never surface
   later as NaN output), and the quantize/dequant round-trip error is
   bounded by ``scale / 2`` at representative absmax ranges;
-- **dequant-in-kernel**: the four attention kernels' int8 paths match
+- **dequant-in-kernel**: the three attention kernels' int8 paths match
   the jnp gather-dequant oracles (the PR 6 oracle pattern, lifted to
   the quantized tier);
 - **composition** is the point: greedy token-match-rate >= threshold
-  vs the bf16 oracle across a prefix hit/miss/evict stream, the paged
-  and contiguous quantized engines token-exact against EACH OTHER
-  (same quantization, indirected storage), COW prefix sharing over
-  quantized pages with no scale copies, speculative verify token-exact
+  vs the bf16 oracle across a prefix hit/miss/evict stream, COW prefix
+  sharing over quantized pages with no scale copies, speculative verify token-exact
   plain-vs-spec ON the quantized engine (accept-longest-prefix emits
   the program's own greedy targets — quantization moves both sides
   identically), and a tp=1 mesh bitwise vs the unsharded quantized
@@ -38,8 +36,7 @@ import pytest
 from apex_tpu import telemetry
 from apex_tpu.amp.policy import resolve_policy
 from apex_tpu.kernels.decode_attention import (
-    decode_attention, decode_attention_reference, paged_decode_attention,
-    paged_decode_attention_reference)
+    paged_decode_attention, paged_decode_attention_reference)
 from apex_tpu.kernels.prefill_attention import (
     paged_prefill_attention, paged_prefill_attention_reference,
     prefill_attention, prefill_attention_reference)
@@ -71,24 +68,21 @@ def lm_and_params():
     return m, params
 
 
-def _mk_engine(lm_and_params, *, kv_quant=None, paged=True, pool=2,
-               slots=3, seed=5, **kw):
+def _mk_engine(lm_and_params, *, kv_quant=None, pool=2, slots=3, seed=5,
+               **kw):
     m, params = lm_and_params
     return Engine(m, params, slots=slots, max_len=64, prefill_len=24,
-                  chunk_len=CHUNK, prefix_pool=pool, paged=paged,
+                  chunk_len=CHUNK, prefix_pool=pool,
                   policy=resolve_policy("O0", verbose=False), seed=seed,
                   kv_quant=kv_quant, **kw)
 
 
 @pytest.fixture(scope="module")
-def engine_trio(lm_and_params):
-    """bf16(O0) oracle + paged-int8 + contiguous-int8, identical
-    geometry — the match-rate triple (jit caches warm across the
-    module)."""
+def engine_pair(lm_and_params):
+    """bf16(O0) oracle + int8, identical geometry — the match-rate
+    pair (jit caches warm across the module)."""
     return (_mk_engine(lm_and_params),
-            _mk_engine(lm_and_params, kv_quant=KVQuantConfig()),
-            _mk_engine(lm_and_params, kv_quant=KVQuantConfig(),
-                       paged=False))
+            _mk_engine(lm_and_params, kv_quant=KVQuantConfig()))
 
 
 def _shared_prefix_stream(seed, n=8, new_tokens=8):
@@ -209,14 +203,10 @@ def test_quantized_kernels_match_gather_dequant_oracles():
     pt = jnp.asarray(rng.integers(0, NP_, size=(B, MAXP)), jnp.int32)
     ks = jnp.asarray(rng.uniform(0.01, 0.05, size=h), jnp.float32)
     vs = jnp.asarray(rng.uniform(0.01, 0.05, size=h), jnp.float32)
-    lens = jnp.asarray([37, 256], jnp.int32)
     offs = jnp.asarray([0, 200], jnp.int32)
     plens = jnp.asarray([5, 130], jnp.int32)
     poffs = jnp.asarray([0, 100], jnp.int32)
     cases = [
-        (decode_attention(q1, k8, v8, lens, k_scale=ks, v_scale=vs),
-         decode_attention_reference(q1, k8, v8, lens, scale=1 / d ** 0.5,
-                                    k_scale=ks, v_scale=vs)),
         (prefill_attention(qc, k8, v8, offs, k_scale=ks, v_scale=vs),
          prefill_attention_reference(qc, k8, v8, offs,
                                      scale=1 / d ** 0.5, k_scale=ks,
@@ -237,7 +227,7 @@ def test_quantized_kernels_match_gather_dequant_oracles():
                                    atol=2e-5)
     # a lone scale is a caller bug, named loudly
     with pytest.raises(ValueError, match="together"):
-        decode_attention(q1, k8, v8, lens, k_scale=ks)
+        paged_decode_attention(q1, kp, vp, pt, plens, k_scale=ks)
     with pytest.raises(ValueError, match="per head"):
         paged_decode_attention(q1, kp, vp, pt, plens, k_scale=ks[:2],
                                v_scale=vs[:2])
@@ -287,33 +277,27 @@ def test_paged_decode_int8_pages_over_live_page_walks(G, d, stacked, plens):
 
 # ------------------------------------------------------------- composition
 def test_quantized_token_match_vs_bf16_oracle_over_hit_miss_evict(
-        engine_trio):
-    """THE composition pin: the quantized engines serve the prefix
+        engine_pair):
+    """THE composition pin: the quantized engine serves the prefix
     hit/miss/evict stream at greedy token-match-rate >= threshold vs
-    the bf16 oracle, and paged-int8 is token-EXACT vs contiguous-int8
-    (same quantization, indirected storage — the PR 6 parity argument,
-    one tier down)."""
-    oracle, quant_paged, quant_contig = engine_trio
+    the bf16 oracle."""
+    oracle, quant_paged = engine_pair
     out_o = _serve(oracle, seed=42)
     out_p = _serve(quant_paged, seed=42)
-    out_c = _serve(quant_contig, seed=42)
     rate = _match_rate(out_o, out_p)
     assert rate >= MATCH_THRESHOLD, \
         f"quantized token-match-rate {rate:.3f} vs bf16 oracle"
-    assert out_p == out_c, \
-        "paged and contiguous int8 engines diverged — quantization " \
-        "must be a storage property, not a layout property"
     # halved storage at identical geometry
     assert quant_paged.cache.nbytes() * 2 <= oracle.cache.nbytes()
 
 
-def test_cow_prefix_sharing_shares_quantized_pages(engine_trio):
+def test_cow_prefix_sharing_shares_quantized_pages(engine_pair):
     """COW composition: a prefix hit on the quantized engine shares
     int8 pages by refcount bump (zero data movement, zero scale
     copies — scales are per-head engine state, not per-page), and the
     hit request's tokens match the cold miss path token-for-token
     (shared bytes are byte-identical to freshly written bytes)."""
-    _, eq, _ = engine_trio
+    _, eq = engine_pair
     eq.reset(clear_prefixes=True)
     sched = Scheduler(eq, retain_prefixes=True)
     rng = np.random.default_rng(9)
@@ -363,7 +347,7 @@ def test_speculative_verify_is_token_exact_on_the_quantized_engine(
     assert outs["spec"] == outs["plain"]
     assert accepted["spec"] > 0, "drafter never fired — the exactness " \
         "pin proved nothing"
-    # quantization adds no program: 3 paged + 1 lazy verify
+    # quantization adds no program: chunk + decode + 1 lazy verify
     assert eng.compiled_programs == eng.chunk_traces \
         + eng.decode_traces + eng.verify_traces
     assert eng.verify_traces == 1
@@ -405,58 +389,12 @@ def test_tp2_mesh_is_token_exact_vs_unsharded_quantized_engine(
     assert shard_shapes == {(2, 2)}   # [layers, heads/tp] per shard
 
 
-def test_monolithic_prefill_attends_the_quant_grid(lm_and_params):
-    """Ingest-path consistency: the monolithic (``return_kv``) prefill
-    on a quantized engine attends K/V through the SAME storage grid
-    chunked prefill writes and reads. Pinned at the model level — with
-    ``kv_scales`` the returned K/V are fixed points of quantize∘
-    dequantize (so the engine's storage cast is exact code recovery)
-    and the logits move off the raw-precision forward — and at the
-    engine level: the monolithic scheduler path token-matches the
-    chunked path on one quantized engine (different executables, so
-    the tolerance contract, not bitwise — same bar as the oracle
-    comparison)."""
-    m, params = lm_and_params
-    eng = _mk_engine(lm_and_params, kv_quant=KVQuantConfig(), seed=13)
-    ks, vs = eng.cache.k_scale, eng.cache.v_scale
-    toks = jnp.asarray([list(range(1, 13))], jnp.int32)
-    logits_q, (k_q, v_q) = m.apply({"params": params}, toks,
-                                   train=False, return_kv=True,
-                                   kv_scales=(ks, vs))
-    sk = ks[:, None, :, None, None]
-    sv = vs[:, None, :, None, None]
-    for got, scale in ((k_q, sk), (v_q, sv)):
-        np.testing.assert_array_equal(
-            np.asarray(dequantize(quantize(got, scale), scale)),
-            np.asarray(got, np.float32),
-            err_msg="return_kv K/V are not on the quantization grid")
-    logits_raw = m.apply({"params": params}, toks, train=False)
-    assert not np.array_equal(np.asarray(logits_q),
-                              np.asarray(logits_raw)), \
-        "kv_scales did not engage the grid in the return_kv forward"
-    # engine level: chunked vs monolithic ingestion, one quantized
-    # engine, chunk-boundary prompt lengths (below/at/straddling)
-    rng = np.random.default_rng(17)
-    prompts = [list(rng.integers(1, VOCAB, size=n))
-               for n in (5, CHUNK, 13, 21)]
-    outs = {}
-    for label, chunked in (("chunk", True), ("mono", False)):
-        eng.reset(clear_prefixes=True)
-        reqs = [Request(prompt=p, max_new_tokens=6) for p in prompts]
-        Scheduler(eng, chunked=chunked).run(reqs)
-        outs[label] = [list(r.output_tokens) for r in reqs]
-    rate = _match_rate(outs["chunk"], outs["mono"])
-    assert rate >= MATCH_THRESHOLD, \
-        f"quantized chunked-vs-monolithic token-match-rate {rate:.3f}"
-
-
 # ----------------------------------------------------- the bf16 default pin
 def test_kv_quant_none_stays_the_bitwise_baseline_with_pinned_programs(
         lm_and_params):
     """The contract the ROADMAP states: kv_quant=None is the DEFAULT
     and the bitwise baseline. Two default engines serve the stream
-    token-identically through the pinned paged program set (3 + the
-    monolithic baseline = 3 total distinct executables, copy retired),
+    token-identically through the pinned program set (chunk + decode),
     their caches carry NO scale state, and the quantized engine
     compiles the same set — zero new programs either way."""
     a = _mk_engine(lm_and_params, seed=11)
@@ -464,16 +402,14 @@ def test_kv_quant_none_stays_the_bitwise_baseline_with_pinned_programs(
     assert a.kv_quant is None and a.cache.k_scale is None \
         and a.cache.v_scale is None
     assert _serve(a, seed=31) == _serve(b, seed=31)
-    a.prefill(0, [5, 9, 2])           # the monolithic baseline compiles
-    assert (a.chunk_traces, a.decode_traces, a.prefill_traces,
-            a.copy_traces) == (1, 1, 1, 0)
-    assert a.compiled_programs == 3
+    a.prefill_chunked(0, [5, 9, 2])
+    assert (a.chunk_traces, a.decode_traces) == (1, 1)
+    assert a.compiled_programs == 2
     q = _mk_engine(lm_and_params, kv_quant=KVQuantConfig(), seed=11)
     _serve(q, seed=31)
-    q.prefill(0, [5, 9, 2])
-    assert (q.chunk_traces, q.decode_traces, q.prefill_traces,
-            q.copy_traces) == (1, 1, 1, 0)
-    assert q.compiled_programs == 3
+    q.prefill_chunked(0, [5, 9, 2])
+    assert (q.chunk_traces, q.decode_traces) == (1, 1)
+    assert q.compiled_programs == 2
 
 
 def test_kv_gauges_report_the_capacity_claim(lm_and_params):

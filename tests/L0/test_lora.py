@@ -82,7 +82,7 @@ def _mk_engine(lm_and_params, *, lora=_CFG, slots=3, mesh=None,
                register=("a1", "a2"), **kw):
     m, params = lm_and_params
     eng = Engine(m, params, slots=slots, max_len=64, prefill_len=24,
-                 chunk_len=CHUNK, prefix_pool=0, seed=5, paged=True,
+                 chunk_len=CHUNK, prefix_pool=0, seed=5,
                  page_len=CHUNK, num_pages=64, lora=lora, mesh=mesh,
                  **kw)
     if lora is not None:

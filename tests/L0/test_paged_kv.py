@@ -6,14 +6,16 @@ The acceptance bar from the block-table issue, as tests:
   ``paged_prefill_attention``) match their jnp oracles, and the oracles
   are BITWISE identical to the contiguous references over the gathered
   page view (same math, indirected storage);
-- the paged engine is token-exact against the contiguous baseline
-  (greedy, identical geometry) over a mixed hit/miss/evict request
+- through an identity page table the paged decode kernel holds the
+  contract of a contiguous cache row (zero-length rows are zero,
+  nothing past a row's length is read, int8 dequantised in the kernel,
+  bf16 in and out, under ``jit``);
+- the engine is token-exact against a teacher-forcing recompute
+  (greedy) over a mixed hit/miss/evict request
   stream with prompt lengths below / at / straddling page boundaries;
-- a prefix-cache hit on the paged path performs ZERO KV data movement:
-  the engine compiles exactly THREE programs (chunk prefill + decode +
-  monolithic prefill) across a stream that includes hits — the
-  contiguous layout's fourth (row-copy) program never traces, pinned by
-  trace counters and by ``copy_kv`` refusing to run at all;
+- a prefix-cache hit performs ZERO KV data movement:
+  the engine compiles exactly TWO programs (chunk prefill + decode)
+  across a stream that includes hits, pinned by trace counters;
 - copy-on-write refcount pinning: a shared page is never freed while
   any slot or prefix entry references it, and the first write past a
   shared prefix lands on a freshly allocated page (never the donor's);
@@ -204,6 +206,79 @@ def test_paged_prefill_kernel_matches_oracle_across_offsets():
                                atol=5e-6)
     with pytest.raises(ValueError, match="offsets"):
         paged_prefill_attention(q, kp, vp, pt, off[:1])
+
+
+def _identity_pool(x, page_len):
+    """A contiguous cache ``[B, h, L, d]`` as pool pages ``[B * L /
+    page_len, h, page_len, d]`` and the identity table ``[B, L /
+    page_len]`` that reads it back row by row."""
+    B, h, L, d = x.shape
+    n = L // page_len
+    pool = x.reshape(B, h, n, page_len, d).transpose(0, 2, 1, 3, 4)
+    return pool.reshape(B * n, h, page_len, d), jnp.arange(
+        B * n, dtype=jnp.int32).reshape(B, n)
+
+
+_ROW_CASES = {
+    # name: (B, h, L, d, lengths, dtype, tolerance)
+    "short_and_full": (3, 4, 256, 64, [1, 5, 256], "f32", 2e-5),
+    "dead_and_page_edge": (3, 4, 256, 64, [0, 37, 128], "f32", 2e-5),
+    "all_full": (3, 4, 256, 64, [256, 256, 256], "f32", 2e-5),
+    "zero_length_rows_are_zero": (2, 2, 128, 8, [0, 4], "f32", 2e-5),
+    "nothing_past_length_is_read": (2, 4, 256, 16, [9, 200], "f32", 1e-6),
+    "bf16_in_and_out": (2, 4, 256, 32, [17, 256], "bf16", 0.05),
+    "under_jit": (1, 2, 256, 8, [129], "f32", 2e-5),
+    "int8_dequantised_in_kernel": (3, 4, 256, 16, [1, 37, 256], "int8",
+                                   2e-5),
+    "int8_codes_past_length": (3, 4, 256, 16, [1, 37, 40], "int8", 2e-5),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ROW_CASES))
+def test_paged_decode_through_an_identity_table_is_a_contiguous_row(case):
+    """What the contiguous decode kernel's tests held, held of the paged
+    kernel: a cache row laid out as consecutive pages and read through
+    an identity table gives ``decode_attention_reference`` over the row
+    (float32 oracle), whatever lies past the row's length."""
+    B, h, L, d, lengths, dtype, tol = _ROW_CASES[case]
+    rng = np.random.default_rng(sorted(_ROW_CASES).index(case))
+    lens = jnp.asarray(lengths, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((B, h, d)), jnp.float32)
+    scales = {}
+    if dtype == "int8":
+        k = jnp.asarray(rng.integers(-127, 128, (B, h, L, d)), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, (B, h, L, d)), jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.01, 0.06, h), jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.01, 0.06, h), jnp.float32)
+        scales = {"k_scale": ks, "v_scale": vs}
+        k32 = jnp.asarray(k, jnp.float32) * ks[None, :, None, None]
+        v32 = jnp.asarray(v, jnp.float32) * vs[None, :, None, None]
+    else:
+        k32 = jnp.asarray(rng.standard_normal((B, h, L, d)), jnp.float32)
+        v32 = jnp.asarray(rng.standard_normal((B, h, L, d)), jnp.float32)
+        k, v = k32, v32
+        if dtype == "bf16":
+            q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
+    want = np.asarray(decode_attention_reference(
+        jnp.asarray(q, jnp.float32), k32, v32, lens, scale=1.0 / d ** 0.5))
+    # garbage past each row's length: the result may not move
+    past = jnp.arange(L)[None, None, :, None] >= lens[:, None, None, None]
+    k = jnp.where(past, jnp.asarray(127 if dtype == "int8" else 1e4,
+                                    k.dtype), k)
+    v = jnp.where(past, jnp.asarray(-127 if dtype == "int8" else -1e4,
+                                    v.dtype), v)
+    kp, pt = _identity_pool(k, 128)
+    vp, _ = _identity_pool(v, 128)
+    fn = lambda q, kp, vp, pt, lens: paged_decode_attention(   # noqa: E731
+        q, kp, vp, pt, lens, interpret=True, **scales)
+    if case == "under_jit":
+        fn = jax.jit(fn)
+    out = fn(q, kp, vp, pt, lens)
+    assert out.dtype == q.dtype
+    got = np.asarray(out, np.float32)
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    dead = np.asarray(lens) == 0
+    assert (got[dead] == 0).all()
 
 
 # ------------------------------------ the stacked pool, read where it lies
@@ -431,58 +506,46 @@ def lm_and_params():
     return m, params
 
 
-def _mk_engine(lm_and_params, *, paged, pool=2, slots=3, seed=5,
-               **kw):
+def _mk_engine(lm_and_params, *, pool=2, slots=3, seed=5, **kw):
     m, params = lm_and_params
     return Engine(m, params, slots=slots, max_len=64, prefill_len=24,
-                  chunk_len=CHUNK, prefix_pool=pool, paged=paged,
+                  chunk_len=CHUNK, prefix_pool=pool,
                   policy=resolve_policy("O0", verbose=False), seed=seed,
                   **kw)
 
 
 @pytest.fixture(scope="module")
-def engine_pair(lm_and_params):
-    """One paged engine + one contiguous engine, identical geometry —
-    the parity pair (jit caches warm across the module)."""
-    return (_mk_engine(lm_and_params, paged=True),
-            _mk_engine(lm_and_params, paged=False))
+def engine(lm_and_params):
+    """The module's shared engine (jit caches warm across it)."""
+    return _mk_engine(lm_and_params)
 
 
-def test_paged_engine_geometry_and_defaults(engine_pair):
-    ep, ec = engine_pair
-    assert ep.paged and not ec.paged
+def test_paged_engine_geometry_and_defaults(engine):
+    ep = engine
     assert ep.page_len == CHUNK           # min(chunk, 128) -> chunk
     assert ep.max_pages == 64 // CHUNK
-    # default pool budget == the contiguous layout's rows (+ sentinel)
+    # default pool budget: every slot and prefix at full length (+ sentinel)
     assert ep.num_pages == (3 + 2) * ep.max_pages + 1
     assert ep.pool.free_pages == ep.num_pages - 1
 
 
 def test_paged_engine_validation(lm_and_params):
     with pytest.raises(ValueError, match="divide chunk_len"):
-        _mk_engine(lm_and_params, paged=True, page_len=5)
+        _mk_engine(lm_and_params, page_len=5)
     with pytest.raises(ValueError, match="cannot hold even one"):
-        _mk_engine(lm_and_params, paged=True, num_pages=4)
-    eng = _mk_engine(lm_and_params, paged=True, pool=0)
+        _mk_engine(lm_and_params, num_pages=4)
+    eng = _mk_engine(lm_and_params, pool=0)
     assert eng.prefix_cache is None
-    with pytest.raises(RuntimeError, match="retired"):
-        eng.copy_kv(0, 1, 8)
     with pytest.raises(RuntimeError, match="prefix cache"):
         eng.retain_prefix(0, [1] * 8)
     with pytest.raises(ValueError, match="page-aligned"):
         eng.prefill_chunk(0, [1, 2], 3)
-    ec = _mk_engine(lm_and_params, paged=False, pool=0)
-    with pytest.raises(RuntimeError, match="paged=False"):
-        ec.release_slot(0)
-    with pytest.raises(RuntimeError, match="paged=False"):
-        ec.pages_required(8, 4)
 
 
 def _boundary_cases():
     """(prompt_a, prompt_b, expected_reuse) with shared-prefix lengths
     below / at / straddling page boundaries (page_len == CHUNK == 8) and
-    spanning two pages — the same sweep test_prefix_cache runs on the
-    contiguous layout."""
+    spanning two pages — the same sweep test_prefix_cache runs."""
     rng = np.random.default_rng(42)
     out = []
     for pre_len, want in [(5, 0), (8, 8), (13, 8), (16, 16)]:
@@ -492,48 +555,41 @@ def _boundary_cases():
     return out
 
 
-def test_paged_token_exact_vs_contiguous_over_hit_miss_evict_stream(
-        engine_pair, lm_and_params):
-    """THE acceptance pin: greedy tokens from the paged engine (with
-    copy-on-write prefix retention on) match the contiguous baseline
-    (same geometry, retention on) request-for-request across a stream
-    that drives misses, hits, boundary-length prompts and (on the
-    1-row contiguous pool of test_prefix_cache's sibling sweep)
-    evictions — and both match one teacher-forcing recompute."""
+def test_paged_token_exact_vs_recompute_over_hit_miss_evict_stream(
+        engine, lm_and_params):
+    """THE acceptance pin: greedy tokens from the engine (with
+    copy-on-write prefix retention on) match one teacher-forcing
+    recompute request-for-request across a stream that drives misses,
+    hits and boundary-length prompts — the hit served from shared pages
+    as exactly as the miss that filled them."""
     m, params = lm_and_params
-    ep, ec = engine_pair
+    ep = engine
     ep.reset(clear_prefixes=True)
-    ec.reset(clear_prefixes=True)
     sp = Scheduler(ep, retain_prefixes=True)
-    sc = Scheduler(ec, retain_prefixes=True)
     for prompt_a, prompt_b, want_reuse in _boundary_cases():
         for prompt in (prompt_a, prompt_b):
             (rp,) = sp.run([Request(prompt=list(prompt),
                                     max_new_tokens=5)])
-            (rc,) = sc.run([Request(prompt=list(prompt),
-                                    max_new_tokens=5)])
-            assert rp.output_tokens == rc.output_tokens, \
-                f"paged diverged from contiguous (prompt len {len(prompt)})"
-            assert rp.reused_tokens == rc.reused_tokens
-            assert rp.chunks == rc.chunks
+            assert rp.chunks == ep.chunks_for(len(prompt)) \
+                - rp.reused_tokens // CHUNK
+            # teacher-forcing recompute re-derives every greedy step
+            seq = jnp.asarray([list(prompt) + rp.output_tokens],
+                              jnp.int32)
+            full = m.apply({"params": params}, seq, train=False)
+            want = np.asarray(jnp.argmax(full[0], axis=-1))
+            for i, tok in enumerate(rp.output_tokens):
+                assert tok == int(want[len(prompt) - 1 + i]), \
+                    f"recompute divergence at token {i} (prompt len " \
+                    f"{len(prompt)}, reused {rp.reused_tokens})"
         assert rp.reused_tokens == want_reuse
-        # teacher-forcing recompute re-derives every greedy step
-        seq = jnp.asarray([list(prompt_b) + rp.output_tokens], jnp.int32)
-        full = m.apply({"params": params}, seq, train=False)
-        want = np.asarray(jnp.argmax(full[0], axis=-1))
-        for i, tok in enumerate(rp.output_tokens):
-            assert tok == int(want[len(prompt_b) - 1 + i]), \
-                f"recompute divergence at token {i}"
 
 
-def test_exactly_three_compiled_programs_with_zero_copy_hits(
-        engine_pair):
-    """The re-derived program pin: the same hit/miss stream that pins
-    FOUR programs on the contiguous engine (chunk + decode + monolithic
-    + row-copy) pins THREE here — a prefix hit is host bookkeeping plus
-    the existing programs, never a copy dispatch. copy_traces stays 0
-    across the whole module (every earlier test rode these engines)."""
-    ep, _ = engine_pair
+def test_exactly_two_compiled_programs_with_zero_copy_hits(engine):
+    """The program pin: a hit/miss stream compiles chunk + decode and
+    nothing else — a prefix hit is host bookkeeping plus
+    the existing programs, never a copy dispatch — across the whole
+    module (every earlier test rode this engine)."""
+    ep = engine
     ep.reset(clear_prefixes=True)
     sched = Scheduler(ep, retain_prefixes=True)
     rng = np.random.default_rng(1)
@@ -541,20 +597,18 @@ def test_exactly_three_compiled_programs_with_zero_copy_hits(
     sched.run([Request(prompt=pre + [7, 8], max_new_tokens=3)])   # miss
     (hit,) = sched.run([Request(prompt=pre + [9], max_new_tokens=3)])
     assert hit.reused_tokens == 16
-    ep.prefill(0, [5, 9, 2])          # the monolithic baseline compiles
-    assert (ep.chunk_traces, ep.decode_traces, ep.prefill_traces,
-            ep.copy_traces) == (1, 1, 1, 0)
-    assert ep.compiled_programs == 3
-    assert ep._jit_copy is None       # the program object never exists
+    ep.prefill_chunked(0, [5, 9, 2])  # scheduler-less callers: same program
+    assert (ep.chunk_traces, ep.decode_traces) == (1, 1)
+    assert ep.compiled_programs == 2
 
 
-def test_cow_shared_page_never_freed_while_referenced(engine_pair):
+def test_cow_shared_page_never_freed_while_referenced(engine):
     """Copy-on-write refcount pinning, observed at the page level: the
     donor entry's pages are shared into the hitting slot's table (one
     page, >= 2 readers, ZERO copies); releasing either reader alone
     keeps the page resident; write-after-share lands on a FRESH page —
     the donor's pages are never written by the borrower."""
-    ep, _ = engine_pair
+    ep = engine
     ep.reset(clear_prefixes=True)
     sched = Scheduler(ep, retain_prefixes=True)
     rng = np.random.default_rng(9)
@@ -599,7 +653,7 @@ def test_pool_exhaustion_queues_admissions_and_degrades_gracefully(
     never a mid-decode failure. Prefix entries give way under pressure
     (LRU eviction at reservation time)."""
     # max_len 64, page 8 -> 8 pages/request worst case; 9 usable pages
-    eng = _mk_engine(lm_and_params, paged=True, pool=2, slots=3,
+    eng = _mk_engine(lm_and_params, pool=2, slots=3,
                      num_pages=10)
     reg = telemetry.MetricsRegistry()
     sched = Scheduler(eng, retain_prefixes=True, registry=reg)
@@ -631,12 +685,13 @@ def test_pool_exhaustion_queues_admissions_and_degrades_gracefully(
 
 def test_cold_start_paths_keep_the_admission_reservation(lm_and_params):
     """Regression (review finding): every cold-start release inside an
-    admitted request — the first chunk's offset-0 branch AND the
-    monolithic prefill — must pass keep_reservation, or the admission
+    admitted request — the first chunk's offset-0 branch, through
+    ``prefill_chunk`` or ``prefill_chunked`` — must pass
+    keep_reservation, or the admission
     promise silently evaporates and a later admission can steal the
     pages, resurrecting the mid-decode exhaustion the reservation
     design exists to prevent."""
-    eng = _mk_engine(lm_and_params, paged=True, pool=0, slots=2)
+    eng = _mk_engine(lm_and_params, pool=0, slots=2)
     assert eng.try_reserve_slot(0, 5)
     assert eng.pool.reserved_total == 5
     eng.prefill_chunk(0, [1, 2, 3], 0)            # offset-0 cold start
@@ -646,15 +701,14 @@ def test_cold_start_paths_keep_the_admission_reservation(lm_and_params):
     eng.release_slot(0)
     assert eng.pool.reserved_total == 0
     assert eng.try_reserve_slot(1, 5)
-    eng.prefill(1, [1, 2, 3])                     # monolithic cold start
-    assert int(eng._slot_reserved[1]) == 5 - eng.pool.pages_for(
-        eng.prefill_len)
+    eng.prefill_chunked(1, list(range(1, 12)))    # two chunks, cold start
+    assert int(eng._slot_reserved[1]) == 5 - 2
     assert eng.pool.reserved_total == int(eng._slot_reserved[1])
     eng.release_slot(1)
 
 
-def test_paged_pool_telemetry_gauges_and_request_records(engine_pair):
-    ep, _ = engine_pair
+def test_paged_pool_telemetry_gauges_and_request_records(engine):
+    ep = engine
     ep.reset(clear_prefixes=True)
     reg = telemetry.MetricsRegistry()
     ep.set_registry(reg)
@@ -684,7 +738,7 @@ def test_paged_pool_telemetry_gauges_and_request_records(engine_pair):
     assert recs[reqs[1].uid]["reused_tokens"] == 16
 
 
-def test_decode_page_counters_follow_the_rows_lengths(engine_pair):
+def test_decode_page_counters_follow_the_rows_lengths(engine):
     """``serving.decode.pages_live`` / ``pages_tabled``: the share of
     the page table the decode kernel walks, from the lengths of the rows
     that decode. A request of prompt ``p`` and ``n`` tokens gets its
@@ -692,7 +746,7 @@ def test_decode_page_counters_follow_the_rows_lengths(engine_pair):
     position ``p + i`` attending ``p + i + 1`` keys."""
     from apex_tpu.telemetry.summarize import (render_summary,
                                               summarize_records)
-    ep, _ = engine_pair
+    ep = engine
     ep.reset(clear_prefixes=True)
     reg = telemetry.MetricsRegistry()
     ep.set_registry(reg)
@@ -733,8 +787,8 @@ def test_decode_page_counters_follow_the_rows_lengths(engine_pair):
         -(-length // ep.page_len) for length in lengths)
 
 
-def test_paged_reset_keeps_warm_prefix_pages_unless_cleared(engine_pair):
-    ep, _ = engine_pair
+def test_paged_reset_keeps_warm_prefix_pages_unless_cleared(engine):
+    ep = engine
     ep.reset(clear_prefixes=True)
     sched = Scheduler(ep, retain_prefixes=True)
     pre = list(np.random.default_rng(13).integers(1, VOCAB, size=8))
@@ -755,7 +809,7 @@ def test_logical_requests_outlive_physical_rows(lm_and_params):
     through 3 slots with room to spare, because each request only ever
     holds the pages it uses and frees them at completion — the
     contiguous layout would spend 3 full rows regardless of length."""
-    eng = _mk_engine(lm_and_params, paged=True, pool=0, slots=3,
+    eng = _mk_engine(lm_and_params, pool=0, slots=3,
                      num_pages=3 * 8 + 1)
     reg = telemetry.MetricsRegistry()
     sched = Scheduler(eng, registry=reg)

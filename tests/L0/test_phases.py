@@ -234,7 +234,7 @@ def lm_and_params():
 def engine(lm_and_params):
     m, params = lm_and_params
     return Engine(m, params, slots=2, max_len=64, prefill_len=24,
-                  chunk_len=8, paged=True,
+                  chunk_len=8,
                   policy=resolve_policy("O0", verbose=False), seed=5)
 
 

@@ -6,29 +6,22 @@ The acceptance bar from the issue, as tests:
   chunked path and one teacher-forcing full recompute, for shared
   prefixes below / at / straddling a block boundary (and for a prompt
   that is entirely cached, where the final block must still prefill —
-  the copy program produces no logits to sample from);
-- a request stream exercising hit, miss, eviction AND the monolithic
-  baseline is served by exactly FOUR compiled programs (chunk prefill +
-  decode + monolithic prefill + the KV row-copy), pinned by trace
-  counters;
-- LRU eviction with refcount pinning: a prefix in use by a live slot is
-  never evicted, and a full, fully-pinned pool degrades gracefully to
-  the cold path (request served, retention skipped, ``pool_full``
-  counted);
+  sharing pages produces no logits to sample from);
+- a request stream exercising hit, miss and eviction is served by
+  exactly TWO compiled programs (chunk prefill + decode), pinned by
+  trace counters;
+- LRU eviction with refcount pinning: a pinned entry is never evicted,
+  and evicting the donor of a live hit is harmless (its pages carry
+  their own refcounts);
 - telemetry carries ``serving.prefix.*`` and the per-request completion
   record carries ``reused_tokens``.
 
 Everything runs on CPU with a tiny model at policy O0 (exact fp32), the
 same shared-program discipline as test_serving.py: the hit path and the
 cold path literally execute the same XLA programs, so exactness is
-bitwise, not approximately.
-
-These engines are built ``paged=False`` on purpose: this file pins the
-CONTIGUOUS layout's prefix machinery (pool rows, the compiled row-copy,
-refcount pinning, the exactly-FOUR-programs discipline), which the
-paged default keeps as its parity oracle. The paged layout's prefix
-story — copy-on-write page sharing, zero-copy hits, the THREE-program
-pin — lives in tests/L0/test_paged_kv.py.
+bitwise, not approximately. The page-level half of the story —
+copy-on-write refcounts, pool exhaustion — lives in
+tests/L0/test_paged_kv.py.
 """
 
 import jax
@@ -61,11 +54,12 @@ def lm_and_params():
     return m, params
 
 
-def _mk_engine(lm_and_params, *, pool=2, slots=3, seed=5):
+def _mk_engine(lm_and_params, *, pool=2, slots=3, seed=5, **kw):
     m, params = lm_and_params
     return Engine(m, params, slots=slots, max_len=128, prefill_len=24,
-                  chunk_len=CHUNK, prefix_pool=pool, paged=False,
-                  policy=resolve_policy("O0", verbose=False), seed=seed)
+                  chunk_len=CHUNK, prefix_pool=pool,
+                  policy=resolve_policy("O0", verbose=False), seed=seed,
+                  **kw)
 
 
 @pytest.fixture(scope="module")
@@ -78,30 +72,34 @@ def pool2_pair(lm_and_params):
 
 
 @pytest.fixture(scope="module")
-def pool1_engine(lm_and_params):
-    """Shared 1-row-pool engine (eviction/pool-full pressure tests)."""
-    return _mk_engine(lm_and_params, pool=1)
+def tight_engine(lm_and_params):
+    """Shared engine whose pool holds ONE max_len request and nothing
+    more (16 pages + the sentinel): a long request's admission evicts
+    the retained prefixes (eviction-pressure tests)."""
+    return _mk_engine(lm_and_params, pool=1, num_pages=17)
 
 
 # --------------------------------------------------- host-side PrefixCache
-def _pc(pool=2):
-    return PrefixCache(block_len=4, pool_rows=range(8, 8 + pool))
+def _pc():
+    return PrefixCache(block_len=4)
+
+
+def _pages(prompt, first=1):
+    """Page ids for ``prompt``'s block-aligned prefix at page_len 4."""
+    return list(range(first, first + len(prompt) // 4))
 
 
 def test_match_is_block_aligned_and_capped_below_the_prompt():
     pc = _pc()
-    copies = []
-    assert pc.register(list(range(1, 11)),
-                       lambda row, n: copies.append((row, n))) \
-        == "registered"
-    assert copies == [(9, 8)]       # 10 tokens -> 2 full blocks retained
+    # 10 tokens -> 2 full blocks retained, on two pages
+    assert pc.register(list(range(1, 11)), pages=[5, 6]) == "registered"
     # identical 8-token prefix, longer prompt: match the 2 blocks
     m = pc.match(list(range(1, 9)) + [77, 78, 79])
-    assert (m.row, m.length) == (9, 8)
+    assert (m.length, m.pages) == (8, (5, 6))
     # the whole prompt cached (exact 8 tokens): cap at aligned(7) = 4 —
     # the final block must prefill to produce the first token's logits
     m = pc.match(list(range(1, 9)))
-    assert m.length == 4
+    assert (m.length, m.pages) == (4, (5,))
     # shares only one block
     assert pc.match([1, 2, 3, 4] + [9, 9, 9, 9, 9]).length == 4
     # diverges inside the first block: miss
@@ -118,17 +116,18 @@ def test_probe_reads_like_match_but_mutates_nothing():
     LRU refresh, no refcounts. A probe that counted would poison every
     non-chosen replica's hit_rate N-1 times per routed request."""
     pc = _pc()
-    pc.register(list(range(1, 11)), lambda row, n: None)
+    pc.register(list(range(1, 11)), pages=[5, 6])
     prompt = list(range(1, 9)) + [77, 78, 79]
-    assert pc.probe(prompt) == pc.match(prompt).length == 8
+    m = pc.match(prompt)
+    assert pc.probe(prompt) == m.length == 8
     stats0 = pc.stats()
-    clock0 = pc._entries[9].last_used
+    clock0 = pc._entries[m.row].last_used
     # hits, misses and LRU order are all untouched by any probe outcome
     assert pc.probe(prompt) == 8
     assert pc.probe([5, 5, 5, 5, 5]) == 0          # a miss probes as 0
     assert pc.probe(prompt, keys=pc.block_keys(prompt, 2)) == 8
     assert pc.stats() == stats0
-    assert pc._entries[9].last_used == clock0
+    assert pc._entries[m.row].last_used == clock0
     # and the verified-tokens guarantee holds: a would-be hash hit over
     # different tokens probes as 0, never a wrong length
     assert pc.probe([1, 2, 3, 99] + list(range(5, 12))) == 0
@@ -141,14 +140,14 @@ def test_stats_since_reads_window_deltas_across_warm_resets():
     a delta: stats_since(baseline) isolates the window, where reading
     hit_rate directly would blend every prior window in."""
     pc = _pc()
-    pc.register([1] * 8, lambda row, n: None)
+    pc.register([1] * 8, pages=[1, 2])
     assert pc.match([1] * 9) is not None            # warmup hit
     assert pc.match([7] * 9) is None                # warmup miss
     base = pc.stats()
     # a warm reset drops entries but NOT counters — the PR 11 quirk
     pc.clear()
     assert pc.hits == 1 and pc.misses == 1
-    pc.register([2] * 8, lambda row, n: None)
+    pc.register([2] * 8, pages=[3, 4])
     assert pc.match([2] * 12) is not None
     assert pc.match([2] * 12) is not None
     assert pc.match([9] * 9) is None
@@ -169,46 +168,47 @@ def test_stats_since_reads_window_deltas_across_warm_resets():
 
 def test_register_dedupes_and_rejects_too_short():
     pc = _pc()
-    calls = []
-    fn = lambda row, n: calls.append((row, n))
-    assert pc.register([1, 2, 3], fn) == "too_short"
-    assert pc.register(list(range(1, 10)), fn) == "registered"
-    # same aligned prefix again (different tail): no second copy
-    assert pc.register(list(range(1, 9)) + [55], fn) == "duplicate"
-    assert len(calls) == 1 and pc.registrations == 1
+    assert pc.register([1, 2, 3], pages=[1]) == "too_short"
+    assert pc.register(list(range(1, 10)), pages=[1, 2]) == "registered"
+    # same aligned prefix again (different tail): no second entry
+    assert pc.register(list(range(1, 9)) + [55], pages=[3, 4]) \
+        == "duplicate"
+    assert pc.size == 1 and pc.registrations == 1
+    assert pc.page_holds() == [(1, 2)]
 
 
 def test_lru_eviction_prefers_least_recently_used():
-    pc = _pc(pool=2)
-    fn = lambda row, n: None
+    freed = []
+    pc = PrefixCache(block_len=4, on_evict=freed.append)
     a, b, c = ([1] * 8), ([2] * 8), ([3] * 8)
-    assert pc.register(a, fn) == "registered"
-    assert pc.register(b, fn) == "registered"
+    assert pc.register(a, pages=[1, 2]) == "registered"
+    assert pc.register(b, pages=[3, 4]) == "registered"
     assert pc.match(a + [7]) is not None       # refresh A
-    assert pc.register(c, fn) == "registered"  # pool full -> evict LRU: B
-    assert pc.evictions == 1
+    assert pc.evict_lru()                      # pool pressure -> LRU: B
+    assert pc.register(c, pages=[5, 6]) == "registered"
+    assert pc.evictions == 1 and freed == [(3, 4)]
     assert pc.match(b + [7]) is None           # B gone
     assert pc.match(a + [7]) is not None       # A survived (recently used)
     assert pc.match(c + [7]) is not None
 
 
 def test_refcount_pins_against_eviction_and_degrades_when_all_pinned():
-    pc = _pc(pool=2)
-    fn = lambda row, n: None
-    a, b, c, d = ([1] * 8), ([2] * 8), ([3] * 8), ([4] * 8)
-    pc.register(a, fn)
-    pc.register(b, fn)
+    pc = _pc()
+    a, b, c = ([1] * 8), ([2] * 8), ([3] * 8)
+    pc.register(a, pages=[1, 2])
+    pc.register(b, pages=[3, 4])
     ma = pc.match(a + [7])
     mb = pc.match(b + [7])
-    pc.acquire(ma)                  # A pinned by a live slot
-    assert pc.register(c, fn) == "registered"   # evicts B (refcount 0)
+    pc.acquire(ma)                  # A pinned
+    assert pc.evict_lru()           # evicts B (refcount 0)
     assert pc.match(a + [7]) is not None, "pinned entry was evicted"
+    pc.register(c, pages=[5, 6])
     mc = pc.match(c + [7])
     pc.acquire(mc)                  # now A and C both pinned
-    assert pc.register(d, fn) == "pool_full"    # graceful degradation
-    assert pc.pool_full == 1 and pc.evictions == 1
+    assert not pc.evict_lru()       # nothing evictable: the valve says so
+    assert pc.evictions == 1 and pc.size == 2
     pc.release(ma)
-    assert pc.register(d, fn) == "registered"   # A evictable again
+    assert pc.evict_lru()           # A evictable again
     assert pc.match(a + [7]) is None
     pc.release(mb)                  # releasing an evicted match: no-op
 
@@ -217,14 +217,13 @@ def test_eviction_rebinds_shared_shorter_prefix_keys():
     """A shorter shared prefix addressed by an evicted entry is still
     resident inside a surviving longer entry — eviction must rebind the
     key, not orphan the depth."""
-    pc = _pc(pool=2)
-    fn = lambda row, n: None
+    pc = _pc()
     base = [5, 5, 5, 5]
-    pc.register(base + [1, 1, 1, 1], fn)        # owns H_1 (base)
-    pc.register(base + [2, 2, 2, 2], fn)        # same H_1 kept by first
-    pc.register([9] * 8, fn)                    # evicts the LRU (first)
+    pc.register(base + [1, 1, 1, 1], pages=[1, 2])  # owns H_1 (base)
+    pc.register(base + [2, 2, 2, 2], pages=[1, 3])  # same H_1 kept by first
+    assert pc.evict_lru()                           # evicts the LRU (first)
     m = pc.match(base + [7, 7, 7, 7, 7])
-    assert m is not None and m.length == 4, \
+    assert m is not None and (m.length, m.pages) == (4, (1,)), \
         "depth-1 key orphaned by eviction despite a surviving cover"
 
 
@@ -232,52 +231,30 @@ def test_hash_collision_cannot_fake_a_hit(monkeypatch):
     import apex_tpu.serving.prefix_cache as mod
 
     monkeypatch.setattr(mod, "_roll", lambda h, block: 42)  # all collide
-    pc = _pc(pool=2)
-    pc.register([1] * 8, lambda row, n: None)
+    pc = _pc()
+    pc.register([1] * 8, pages=[1, 2])
     assert pc.match([2] * 9) is None    # same key, different tokens
     m = pc.match([1] * 9)
     assert m is not None                # real content still matches
 
 
-def test_copy_failure_does_not_leak_the_pool_row():
-    pc = _pc(pool=1)
-
-    def boom(row, n):
-        raise RuntimeError("device fell over")
-
-    with pytest.raises(RuntimeError):
-        pc.register([1] * 8, boom)
-    assert pc.register([1] * 8, lambda row, n: None) == "registered"
-
-
 def test_prefix_cache_validates():
     with pytest.raises(ValueError, match="block_len"):
-        PrefixCache(block_len=0, pool_rows=[1])
-    with pytest.raises(ValueError, match="distinct"):
-        PrefixCache(block_len=4, pool_rows=[1, 1])
-
-
-# -------------------------------------------------------- engine + copy
-def test_engine_copy_kv_validation(lm_and_params, pool2_pair):
-    eng, _ = pool2_pair                 # 3 slots + 2 pool rows
-    with pytest.raises(ValueError, match="copy rows"):
-        eng.copy_kv(0, 5, 4)
-    with pytest.raises(ValueError, match="must differ"):
-        eng.copy_kv(2, 2, 4)
-    with pytest.raises(ValueError, match="copy length"):
-        eng.copy_kv(0, 3, 0)
-    with pytest.raises(ValueError, match="copy length"):
-        eng.copy_kv(0, 3, 129)
-    with pytest.raises(ValueError, match="prefix_pool"):
-        _mk_engine(lm_and_params, pool=-1)
+        PrefixCache(block_len=0)
+    pc = _pc()
+    for pages in ([], [1, 2, 3]):       # 8 tokens on 0 or on 3 pages
+        with pytest.raises(ValueError, match="cannot evenly hold"):
+            pc.register([1] * 8, pages=pages)
+    with pytest.raises(TypeError):
+        pc.register([1] * 8)            # an entry is its pages
 
 
 def test_scheduler_retain_prefixes_validation(lm_and_params, pool2_pair):
     eng_no_pool = _mk_engine(lm_and_params, pool=0)   # never traced: cheap
     with pytest.raises(ValueError, match="prefix_pool"):
         Scheduler(eng_no_pool, retain_prefixes=True)
-    with pytest.raises(ValueError, match="chunked"):
-        Scheduler(pool2_pair[0], retain_prefixes=True, chunked=False)
+    with pytest.raises(ValueError, match="prefix_pool"):
+        _mk_engine(lm_and_params, pool=-1)
 
 
 # --------------------------------------------------- end-to-end exactness
@@ -333,7 +310,7 @@ def test_prefix_hit_bitwise_exact_vs_cold_and_recompute(lm_and_params,
 
 def test_fully_cached_prompt_still_prefills_its_final_block(pool2_pair):
     """A prompt whose every token is cached must still run >= 1 chunk:
-    the copy program moves K/V but samples nothing — the first output
+    sharing pages moves no K/V and samples nothing — the first output
     token's logits only exist if the last block goes through chunk
     prefill. The cap (aligned(n-1)) enforces exactly that."""
     eng, eng_cold = pool2_pair
@@ -350,73 +327,65 @@ def test_fully_cached_prompt_still_prefills_its_final_block(pool2_pair):
     assert r2.output_tokens == cold.output_tokens
 
 
-def test_exactly_four_compiled_programs_over_hit_miss_evict(pool1_engine):
-    """The compiled-program pin, one up from PR 4's three: a stream
-    driving hits, misses, registrations and LRU evictions through a
-    1-row pool, plus the monolithic baseline, traces exactly one chunk-
-    prefill + one decode + one monolithic prefill + one KV row-copy
-    program — the copy is slot-, direction- and length-agnostic."""
-    eng = pool1_engine
+def test_exactly_two_compiled_programs_over_hit_miss_evict(tight_engine):
+    """The compiled-program pin: a stream driving hits, misses,
+    registrations and LRU evictions (a long request's admission needs
+    the whole pool) traces exactly one chunk-prefill + one decode
+    program — a hit is host bookkeeping."""
+    eng = tight_engine
     eng.reset(clear_prefixes=True)
     pc = eng.prefix_cache
     hits0, miss0, evic0 = pc.hits, pc.misses, pc.evictions
     sched = Scheduler(eng, retain_prefixes=True)
     rng = np.random.default_rng(1)
     pre1 = list(rng.integers(1, VOCAB, size=8))
-    pre2 = list(rng.integers(1, VOCAB, size=16))
+    long = list(rng.integers(1, VOCAB, size=17))
     stream = [
-        pre1 + [7, 8],            # miss, registers pre1
-        pre1 + [9],               # hit (copy pool->slot)
-        pre2 + [3],               # miss, registers pre2 (evicts pre1)
-        pre1[:5] + [5, 6],        # miss (evicted; too short to register)
-        pre2 + [1, 2, 3],         # hit at 16
+        (pre1 + [7, 8], 3),       # miss, registers pre1
+        (pre1 + [9], 3),          # hit (shares pre1's page)
+        (long, 111),              # miss; reserving 16 pages evicts pre1
+        (pre1 + [5, 6], 3),       # miss (evicted), registers pre1 again
+        (pre1 + [1, 2, 3], 3),    # hit at 8
     ]
-    for p in stream:
-        sched.run([Request(prompt=p, max_new_tokens=3)])
+    for p, budget in stream:
+        sched.run([Request(prompt=p, max_new_tokens=budget)])
     assert (pc.hits - hits0, pc.misses - miss0) == (2, 3)
     assert pc.evictions - evic0 >= 1
-    eng.prefill(0, [5, 9, 2])     # the monolithic baseline still compiles
-    assert (eng.chunk_traces, eng.decode_traces, eng.prefill_traces,
-            eng.copy_traces) == (1, 1, 1, 1)
-    assert eng.compiled_programs == 4
+    assert (eng.chunk_traces, eng.decode_traces) == (1, 1)
+    assert eng.compiled_programs == 2
 
 
-def test_pool_full_with_live_pins_degrades_to_cold_path(pool1_engine):
-    """Every pool row pinned by a live slot: a new registration is
-    skipped (pool_full), nothing is evicted, and the request itself is
-    served normally — graceful degradation, not an error."""
-    eng = pool1_engine
+def test_evicting_a_live_hits_donor_entry_is_harmless(tight_engine,
+                                                      pool2_pair):
+    """A hit needs no pin: its shared pages carry their own refcounts,
+    so evicting the donor entry while the hit still decodes changes
+    nothing it reads — same tokens as a retention-off engine, and the
+    pool drains clean."""
+    eng = tight_engine
     eng.reset(clear_prefixes=True)
-    pool_full0, evic0 = eng.prefix_cache.pool_full, \
-        eng.prefix_cache.evictions
-    reg = telemetry.MetricsRegistry()
-    sched = Scheduler(eng, retain_prefixes=True, registry=reg)
+    sched = Scheduler(eng, retain_prefixes=True)
     rng = np.random.default_rng(9)
     pre = list(rng.integers(1, VOCAB, size=8))
     sched.run([Request(prompt=pre + [1], max_new_tokens=2)])
-    # b hits pre and stays live (big budget, stepped manually): its pin
-    # holds the only pool row
-    b = Request(prompt=pre + [2], max_new_tokens=50)
+    b = Request(prompt=pre + [2], max_new_tokens=12)
     sched.submit(b)
     while b.status != "running":
         sched.step()
     assert b.reused_tokens == 8
-    other = list(rng.integers(1, VOCAB, size=9))
-    c = Request(prompt=other, max_new_tokens=2)
-    sched.submit(c)
-    while c.status not in ("finished", "expired"):   # b (budget 50) outlives c
-        sched.step()
-    assert b.status == "running", "pin holder must still be live"
-    assert c.status == "finished" and len(c.output_tokens) == 2
-    pc = eng.prefix_cache
-    assert pc.pool_full - pool_full0 >= 1 and pc.evictions == evic0
-    assert pc.match(pre + [3]) is not None, "pinned entry evicted"
-    assert reg.snapshot()["counters"]["serving.prefix.pool_full"] >= 1
-    # draining b releases the pin; the next registration may now evict
+    evic0 = eng.prefix_cache.evictions
+    while eng.prefix_cache.evict_lru():     # every entry, b's donor among them
+        pass
+    assert eng.prefix_cache.evictions > evic0
+    assert eng.prefix_cache.probe(pre + [3]) == 0
     while sched.pending:
         sched.step()
-    (d,) = sched.run([Request(prompt=other, max_new_tokens=2)])
-    assert eng.prefix_cache.evictions == evic0 + 1
+    cold = pool2_pair[1]
+    cold.reset()
+    (want,) = Scheduler(cold).run([Request(prompt=pre + [2],
+                                           max_new_tokens=12)])
+    assert b.output_tokens == want.output_tokens
+    eng.reset(clear_prefixes=True)
+    assert sched.auditor.audit(eng)["pages_in_use"] == 0
 
 
 def test_prefix_telemetry_and_request_records(pool2_pair):
@@ -445,7 +414,7 @@ def test_prefix_telemetry_and_request_records(pool2_pair):
     # pcache's counters span the module, the registry's are this test's)
     assert snap["gauges"]["serving.prefix.hit_rate"] \
         == pytest.approx(eng.prefix_cache.hit_rate)
-    assert snap["histograms"]["serving.prefix.copy_s"]["count"] >= 2
+    assert "serving.prefix.copy_s" not in snap["histograms"]   # no copies
     recs = {rec["uid"]: rec for rec in reg.records
             if rec.get("tag") == "serving.request"}
     assert recs[reqs[0].uid]["reused_tokens"] == 0

@@ -9,13 +9,14 @@ prefill), as tests:
   argmax, so both paths are compared through identical programs — the
   shared-program discipline of test_amp_train_step.py, avoiding 64
   separately-fused eager forwards);
-- chunked prefill is token-exact (bitwise argmax) against BOTH the
-  monolithic-prefill path and full recompute, for prompt lengths
-  shorter than / equal to / straddling a chunk boundary;
-- a variable-length request stream exercising chunked serving plus the
-  monolithic baseline is served by exactly 3 compiled programs (chunk
-  prefill + decode step + legacy monolithic prefill), pinned by trace
-  counters;
+- chunked prefill is token-exact (bitwise argmax) against full
+  recompute, for prompt lengths shorter than / equal to / straddling a
+  chunk boundary;
+- a variable-length request stream is served by exactly 2 compiled
+  programs (chunk prefill + decode step), pinned by trace counters;
+- the KV layout and the prefill strategy are constants: ``paged`` and
+  ``chunked`` are no keywords, and the constructors' keyword counts
+  are pinned;
 - chunk-prefill steps interleave with the decode heartbeat: an
   in-flight decode gains a token on EVERY tick of a long admit (the
   head-of-line-blocking fix);
@@ -37,7 +38,7 @@ import pytest
 from apex_tpu import serving, telemetry
 from apex_tpu.amp.policy import resolve_policy
 from apex_tpu.models.transformer_lm import TransformerLM
-from apex_tpu.serving import Engine, KVCache, QueueFull, Request, Scheduler
+from apex_tpu.serving import Engine, QueueFull, Request, Scheduler
 
 pytestmark = pytest.mark.serving
 
@@ -63,41 +64,6 @@ def fp32_engine(lm_and_params):
     m, params = lm_and_params
     return Engine(m, params, slots=3, max_len=128, prefill_len=16,
                   policy=resolve_policy("O0", verbose=False), seed=7)
-
-
-# ------------------------------------------------------------------ kv cache
-def test_kv_cache_create_and_geometry():
-    c = KVCache.create(layers=2, slots=4, heads=3, max_len=32, head_dim=8,
-                       dtype=jnp.bfloat16)
-    assert (c.layers, c.slots, c.heads, c.max_len, c.head_dim) \
-        == (2, 4, 3, 32, 8)
-    assert c.dtype == jnp.bfloat16
-    assert c.nbytes() == 2 * 4 * 3 * 32 * 8 * 2 * 2
-    assert c.occupancy() == 0.0 and c.padding_waste() == 1.0
-
-
-def test_kv_cache_insert_and_advance():
-    c = KVCache.create(layers=2, slots=2, heads=1, max_len=8, head_dim=4,
-                       dtype=jnp.float32)
-    k_new = jnp.ones((2, 1, 1, 4, 4))
-    c = c.insert(1, k_new, 2 * k_new, 3)
-    assert int(c.lengths[1]) == 3 and int(c.lengths[0]) == 0
-    np.testing.assert_array_equal(np.asarray(c.k[:, 1, :, :4]),
-                                  np.ones((2, 1, 4, 4)))
-    # advance grows only active slots, clamped at max_len
-    c = c.advance(c.k, c.v, jnp.asarray([False, True]))
-    assert int(c.lengths[1]) == 4 and int(c.lengths[0]) == 0
-    assert c.occupancy(active=[False, True]) == 0.5
-
-
-def test_kv_cache_insert_validates():
-    c = KVCache.create(layers=1, slots=1, heads=1, max_len=4, head_dim=4)
-    with pytest.raises(ValueError, match="exceeds cache max_len"):
-        c.insert(0, jnp.zeros((1, 1, 1, 8, 4)), jnp.zeros((1, 1, 1, 8, 4)),
-                 8)
-    with pytest.raises(ValueError, match="prefill K/V"):
-        c.insert(0, jnp.zeros((1, 2, 1, 4, 4)), jnp.zeros((1, 2, 1, 4, 4)),
-                 4)
 
 
 # ------------------------------------------------------------------ sampling
@@ -132,7 +98,7 @@ def test_greedy_decode_token_exact_vs_full_recompute(fp32_engine,
     also the PR 4 acceptance pin: the chunked path is token-exact for
     >= 64 generated tokens against full-recompute argmax (the
     chunk-boundary sweep lives in
-    test_chunked_prefill_token_exact_vs_monolithic_and_recompute)."""
+    test_chunked_prefill_token_exact_vs_recompute)."""
     m, params = lm_and_params
     eng = fp32_engine
     sched = Scheduler(eng)
@@ -152,15 +118,12 @@ def test_greedy_decode_token_exact_vs_full_recompute(fp32_engine,
 
 # --------------------------------------------------------- chunked prefill
 @pytest.fixture(scope="module")
-def chunk_engines(lm_and_params):
-    """Two identical O0 engines (chunk_len=8) — one serves the chunked
-    path, one the monolithic baseline, for output comparisons."""
+def chunk_engine(lm_and_params):
+    """An O0 engine with chunk_len=8 shared by the chunk tests."""
     m, params = lm_and_params
-    mk = lambda: Engine(m, params, slots=3, max_len=128, prefill_len=24,
-                        chunk_len=8,
-                        policy=resolve_policy("O0", verbose=False),
-                        seed=5)
-    return mk(), mk()
+    return Engine(m, params, slots=3, max_len=128, prefill_len=24,
+                  chunk_len=8, policy=resolve_policy("O0", verbose=False),
+                  seed=5)
 
 
 def _greedy_reqs():
@@ -174,15 +137,15 @@ def _greedy_reqs():
             for n, b in [(5, 12), (8, 4), (13, 4), (21, 4)]]
 
 
-def test_exactly_three_compiled_programs(chunk_engines):
+def test_exactly_two_compiled_programs(chunk_engine):
     """Variable-length, variable-budget, variable-chunk-count request
-    stream through the chunked scheduler PLUS the monolithic-baseline
-    prefill → exactly one chunk-prefill trace, one decode-step trace and
-    one monolithic-prefill trace (the fixed-shape contract: no
+    stream through the scheduler, then direct ``prefill_chunked`` calls
+    → exactly one chunk-prefill trace and one decode-step trace
+    (the fixed-shape contract: no
     per-token, per-request, per-offset or per-chunk-count recompiles).
     Runs first on the module's shared engine, so the pin covers every
     later test on it too."""
-    eng, _ = chunk_engines
+    eng = chunk_engine
     sched = Scheduler(eng)
     rng = np.random.default_rng(0)
     # prompt lengths span 1-3 chunks, including exact chunk multiples
@@ -194,33 +157,26 @@ def test_exactly_three_compiled_programs(chunk_engines):
     assert len(done) == 6
     assert [r.chunks for r in reqs] == [eng.chunks_for(len(r.prompt))
                                         for r in reqs]
-    # the monolithic baseline path still compiles (and only once)
+    # callers without a scheduler land in the same chunk program
     eng.reset()
-    eng.prefill(0, [5, 9, 2])
-    eng.prefill(1, list(range(1, 20)))
-    assert (eng.chunk_traces, eng.decode_traces, eng.prefill_traces) \
-        == (1, 1, 1)
-    assert eng.compiled_programs == 3
+    eng.prefill_chunked(0, [5, 9, 2])
+    eng.prefill_chunked(1, list(range(1, 20)))
+    assert (eng.chunk_traces, eng.decode_traces) == (1, 1)
+    assert eng.compiled_programs == 2
 
 
-def test_chunked_prefill_token_exact_vs_monolithic_and_recompute(
-        chunk_engines, lm_and_params):
+def test_chunked_prefill_token_exact_vs_recompute(chunk_engine,
+                                                  lm_and_params):
     """The PR 4 acceptance bar: greedy decode after chunked prefill is
-    bitwise-argmax identical to the monolithic-prefill path AND to one
-    teacher-forcing full recompute, across chunk-boundary prompt
-    lengths."""
+    bitwise-argmax identical to one teacher-forcing full recompute,
+    across chunk-boundary prompt lengths."""
     m, params = lm_and_params
-    eng_c, eng_m = chunk_engines
+    eng_c = chunk_engine
     eng_c.reset()
-    eng_m.reset()
-    reqs_c, reqs_m = _greedy_reqs(), _greedy_reqs()
-    Scheduler(eng_c, chunked=True).run(reqs_c)
-    Scheduler(eng_m, chunked=False).run(reqs_m)
-    for rc, rm in zip(reqs_c, reqs_m):
-        assert rc.output_tokens == rm.output_tokens, \
-            f"chunked vs monolithic diverged (prompt len {len(rc.prompt)})"
+    reqs_c = _greedy_reqs()
+    Scheduler(eng_c).run(reqs_c)
+    for rc in reqs_c:
         assert rc.chunks == eng_c.chunks_for(len(rc.prompt))
-        assert rm.chunks == 1
         # teacher-forcing: one full forward re-derives every greedy step
         seq = jnp.asarray([list(rc.prompt) + rc.output_tokens], jnp.int32)
         full = m.apply({"params": params}, seq, train=False)
@@ -230,12 +186,12 @@ def test_chunked_prefill_token_exact_vs_monolithic_and_recompute(
                 f"prompt len {len(rc.prompt)}: divergence at token {i}"
 
 
-def test_chunked_prefill_interleaves_with_decode(chunk_engines):
+def test_chunked_prefill_interleaves_with_decode(chunk_engine):
     """The head-of-line fix, observed at token granularity: while a
     3-chunk prompt ingests (one chunk per heartbeat), the in-flight
     decode gains a token on EVERY tick — the monolithic path would
     stall it for the whole prefill."""
-    eng, _ = chunk_engines
+    eng = chunk_engine
     eng.reset()
     sched = Scheduler(eng)
     a = Request(prompt=[3, 1, 4], max_new_tokens=50)
@@ -258,12 +214,12 @@ def test_chunked_prefill_interleaves_with_decode(chunk_engines):
     assert eng.chunks_for(len(b.prompt)) == 3
 
 
-def test_chunked_ttft_decomposition_and_request_records(chunk_engines):
+def test_chunked_ttft_decomposition_and_request_records(chunk_engine):
     """serving.queue_wait_s and serving.prefill_chunk_s land as separate
     histograms from serving.ttft_s, and every completion emits a
     serving.request record carrying chunks_per_prompt."""
     reg = telemetry.MetricsRegistry()
-    eng, _ = chunk_engines
+    eng = chunk_engine
     eng.reset()
     eng.set_registry(reg)
     sched = Scheduler(eng, registry=reg)
@@ -297,12 +253,12 @@ def test_chunked_ttft_decomposition_and_request_records(chunk_engines):
         assert rec["ttft_s"] is not None
 
 
-def test_prefill_chunk_validation(lm_and_params, chunk_engines):
+def test_prefill_chunk_validation(lm_and_params, chunk_engine):
     m, params = lm_and_params
     with pytest.raises(ValueError, match="chunk_len"):
         Engine(m, params, slots=1, max_len=32, prefill_len=8,
                chunk_len=16)
-    eng, _ = chunk_engines                     # chunk_len=8, prefill 24
+    eng = chunk_engine                     # chunk_len=8, prefill 24
     with pytest.raises(ValueError, match="chunk length"):
         eng.prefill_chunk(0, list(range(1, 10)), 0)
     with pytest.raises(ValueError, match="slot"):
@@ -328,12 +284,12 @@ def test_prefill_chunk_validation(lm_and_params, chunk_engines):
         eng24.prefill_chunk(0, [1, 2], 18)
 
 
-def test_chunk_budget_caps_ingestion_only_while_decoding(chunk_engines):
+def test_chunk_budget_caps_ingestion_only_while_decoding(chunk_engine):
     """The budget bounds the stall imposed ON in-flight decodes: with a
     decode active, at most chunk_budget chunks run per tick; with
     nothing decoding there is nothing to stall, so a cold queue bursts
     straight to full ingestion instead of idling between heartbeats."""
-    eng, _ = chunk_engines
+    eng = chunk_engine
     eng.reset()
     sched = Scheduler(eng, chunk_budget=2)
     c = Request(prompt=[1, 2], max_new_tokens=50)
@@ -351,8 +307,8 @@ def test_chunk_budget_caps_ingestion_only_while_decoding(chunk_engines):
     assert a.status == "running" and b.status == "running"
 
 
-def test_cold_queue_bursts_to_full_ingestion(chunk_engines):
-    eng, _ = chunk_engines
+def test_cold_queue_bursts_to_full_ingestion(chunk_engine):
+    eng = chunk_engine
     eng.reset()
     sched = Scheduler(eng)                     # chunk_budget=1
     a = Request(prompt=list(range(1, 24)), max_new_tokens=4)   # 3 chunks
@@ -375,7 +331,7 @@ def test_engine_default_policy_is_pure_half(lm_and_params):
     for leaf in jax.tree_util.tree_leaves(eng.params):
         if jnp.issubdtype(leaf.dtype, jnp.floating):
             assert leaf.dtype == jnp.bfloat16
-    tok = eng.prefill(0, [5, 9, 2])
+    tok = eng.prefill_chunked(0, [5, 9, 2])
     assert 0 <= tok < VOCAB
     out = eng.decode_step([tok, 0], [True, False], [0.0, 0.0])
     assert out.shape == (2,) and 0 <= int(out[0]) < VOCAB
@@ -390,9 +346,43 @@ def test_engine_validation(lm_and_params):
         Engine(m, params, slots=1, max_len=32, prefill_len=64)
     eng = Engine(m, params, slots=1, max_len=16, prefill_len=8)
     with pytest.raises(ValueError, match="prompt length"):
-        eng.prefill(0, list(range(9)))
+        eng.prefill_chunked(0, list(range(9)))
     with pytest.raises(ValueError, match="slot"):
-        eng.prefill(3, [1, 2])
+        eng.prefill_chunked(3, [1, 2])
+
+
+def test_engine_has_one_kv_layout(lm_and_params):
+    """The paged pool is the engine's one layout: ``paged`` is no
+    keyword (a TypeError, not a deprecation shim)."""
+    m, params = lm_and_params
+    for value in (True, False):
+        with pytest.raises(TypeError, match="paged"):
+            Engine(m, params, slots=1, max_len=16, **{"paged": value})
+
+
+def test_scheduler_has_one_prefill_strategy(fp32_engine):
+    """Chunked ingest is the scheduler's one strategy: ``chunked`` is no
+    keyword."""
+    for value in (True, False):
+        with pytest.raises(TypeError, match="chunked"):
+            Scheduler(fp32_engine, **{"chunked": value})
+
+
+def test_constructor_keyword_counts_are_pinned():
+    """18 and 16 keyword-only options: every independent option doubles
+    the configurations tests and cells must cover, so the next one has
+    to be argued for (and this count changed with it)."""
+    import inspect
+
+    def keywords(cls):
+        return [p.name for p in
+                inspect.signature(cls.__init__).parameters.values()
+                if p.kind is p.KEYWORD_ONLY]
+
+    eng, sched = keywords(Engine), keywords(Scheduler)
+    assert len(eng) == 18, eng
+    assert len(sched) == 16, sched
+    assert not {"paged", "chunked"} & set(eng + sched)
 
 
 # -------------------------------------------------------------- scheduler
@@ -433,7 +423,7 @@ def test_scheduler_eos_and_max_len_eviction(lm_and_params):
                  policy=resolve_policy("O0", verbose=False))
     # find the greedy first token, then declare it EOS: request must
     # finish at prefill without ever occupying a slot
-    probe = eng.prefill(0, [7, 7, 7])
+    probe = eng.prefill_chunked(0, [7, 7, 7])
     eng.reset()
     sched = Scheduler(eng, eos_id=probe)
     (r,) = sched.run([Request(prompt=[7, 7, 7], max_new_tokens=50)])
@@ -488,31 +478,6 @@ def test_full_prompt_finishes_at_prefill_without_cache_corruption(
     full = m.apply({"params": params}, jnp.asarray([prompt], jnp.int32),
                    train=False)
     assert r.output_tokens[0] == int(jnp.argmax(full[0, -1]))
-
-
-def test_prefill_block_overrides_are_applied_and_restored(lm_and_params):
-    """decode.prefill_block_q/_k bite the prefill trace (numerics
-    unchanged) and the training flash.* geometry is restored after."""
-    from apex_tpu.kernels import vmem
-
-    m, params = lm_and_params
-    pol = resolve_policy("O0", verbose=False)
-    base = Engine(m, params, slots=1, max_len=32, prefill_len=16,
-                  policy=pol, seed=3).prefill(0, [7, 8, 9])
-    vmem.set_override("decode.prefill_block_q", 8)
-    vmem.set_override("decode.prefill_block_k", 128)
-    vmem.set_override("flash.block_q", 64)      # training-time value
-    try:
-        eng = Engine(m, params, slots=1, max_len=32, prefill_len=16,
-                     policy=pol, seed=3)
-        tok = eng.prefill(0, [7, 8, 9])
-        assert tok == base                      # geometry never changes math
-        assert vmem.overrides().get("flash.block_q") == 64  # restored
-        assert "flash.block_k" not in vmem.overrides()
-    finally:
-        for k in ("decode.prefill_block_q", "decode.prefill_block_k",
-                  "flash.block_q"):
-            vmem.remove_override(k)
 
 
 def test_prefill_and_decode_agree_on_tokens_generated_counter(

@@ -201,9 +201,6 @@ def test_engine_mesh_validation(lm_and_params):
     # heads not divisible by tp (4 heads over 8 shards)
     with pytest.raises(ValueError, match="not divisible"):
         Engine(m, params, mesh=_mesh(8), **kw)
-    # contiguous layout cannot shard
-    with pytest.raises(ValueError, match="paged=True"):
-        Engine(m, params, mesh=_mesh(2), paged=False, **kw)
     # 2-D meshes are a configuration error
     devs = jax.devices()
     mesh2d = Mesh(np.array(devs[:4]).reshape(2, 2), ("tp", "dp"))
@@ -254,8 +251,7 @@ def test_tp1_mesh_bitwise_vs_unsharded(lm_and_params):
     assert eng.chunk_traces == 1
     assert eng.decode_traces == 1
     assert eng.verify_traces == 1
-    assert eng.prefill_traces == 0      # scheduler streams never use it
-    assert eng.copy_traces == 0
+    assert eng.compiled_programs == 3
 
 
 def test_sharded_warm_reset_keeps_prefixes_valid(lm_and_params):
@@ -378,12 +374,6 @@ def test_tp2_collective_counts_from_hlo(lm_and_params):
         jnp.zeros(3, jnp.int32),
         jnp.zeros(3, jnp.float32)).compile().as_text()
     assert counts(verify) == want, "verify collectives drifted"
-    prefill = eng._jit_prefill.lower(
-        eng.params, eng.cache, jnp.zeros((1, 24), jnp.int32),
-        jnp.zeros((1, mp), jnp.int32), np.int32(4), np.float32(0),
-        key).compile().as_text()
-    assert counts(prefill) == want, "monolithic prefill collectives " \
-        "drifted"
 
 
 @pytest.mark.slow

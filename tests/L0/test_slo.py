@@ -73,11 +73,10 @@ def lm_and_params():
     return m, params
 
 
-def _mk_engine(lm_and_params, *, pool=4, slots=2, seed=5, paged=True,
-               **kw):
+def _mk_engine(lm_and_params, *, pool=4, slots=2, seed=5, **kw):
     m, params = lm_and_params
     return Engine(m, params, slots=slots, max_len=64, prefill_len=24,
-                  chunk_len=CHUNK, prefix_pool=pool, paged=paged,
+                  chunk_len=CHUNK, prefix_pool=pool,
                   policy=resolve_policy("O0", verbose=False), seed=seed,
                   **kw)
 
@@ -148,8 +147,6 @@ def test_tenant_ledger_wfq_and_pickle_refusal():
 
 def test_scheduler_slo_validation(engine_pair):
     _, ep = engine_pair
-    with pytest.raises(ValueError, match="chunked"):
-        Scheduler(ep, chunked=False, slo=SLO)
     with pytest.raises(ValueError, match="retain_prefixes"):
         Scheduler(ep, retain_prefixes=False, slo=SLO)
     # priority-only scheduling works without preemption machinery
@@ -158,12 +155,6 @@ def test_scheduler_slo_validation(engine_pair):
     with pytest.raises(ValueError, match="unknown slo_class"):
         sched.submit(Request(prompt=[1, 2], max_new_tokens=1,
                              slo_class="platinum"))
-
-
-def test_preempt_requires_paged(lm_and_params):
-    flat = _mk_engine(lm_and_params, paged=False, pool=2)
-    with pytest.raises(ValueError, match="paged"):
-        Scheduler(flat, retain_prefixes=True, slo=SLO)
 
 
 # ------------------------------------------------ bitwise preempt/resume

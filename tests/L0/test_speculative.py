@@ -4,7 +4,7 @@ The acceptance bar from the speculative-decoding issue, as tests:
 
 - **the parity pin**: greedy speculative output is bitwise identical to
   plain decode across a request stream with prompt lengths below / at /
-  straddling chunk boundaries, on BOTH cache layouts, and matches one
+  straddling chunk boundaries, and matches one
   teacher-forcing full recompute (every emitted token is the verify
   program's own greedy target — the structural argument — and the
   verify/decode programs agree token-for-token — the pinned one);
@@ -15,7 +15,7 @@ The acceptance bar from the speculative-decoding issue, as tests:
   rollback pin: rejected-tail K/V written by the verify step never
   becomes visible;
 - **compiled-programs pin**: the verify program is exactly ONE new
-  executable — 4 paged (5 contiguous) across a stream that varies
+  executable — 3 in all — across a stream that varies
   drafts, offsets, draft lengths and slots (drafting never retraces);
 - **chaos composition**: a seeded FaultPlan (verify-site exceptions +
   non-finite injection into a verifying slot) over a speculative run —
@@ -68,24 +68,22 @@ def lm_and_params():
     return m, params
 
 
-def _mk_engine(lm_and_params, *, paged=True, slots=3, seed=5, spec=True,
-               **kw):
+def _mk_engine(lm_and_params, *, slots=3, seed=5, spec=True, **kw):
     m, params = lm_and_params
     return Engine(m, params, slots=slots, max_len=128, prefill_len=24,
-                  chunk_len=CHUNK, paged=paged,
+                  chunk_len=CHUNK,
                   policy=resolve_policy("O0", verbose=False), seed=seed,
                   spec=SpecConfig(draft_len=K, ngram=2) if spec else None,
                   **kw)
 
 
 @pytest.fixture(scope="module")
-def engines(lm_and_params):
-    """One spec-enabled engine per layout, shared module-wide: parity
+def engine(lm_and_params):
+    """One spec-enabled engine shared module-wide: parity
     comparisons run plain and speculative passes through the SAME
     compiled programs, and the trace pin at the end of the module
     covers every test in between."""
-    return {"paged": _mk_engine(lm_and_params, paged=True),
-            "contiguous": _mk_engine(lm_and_params, paged=False)}
+    return _mk_engine(lm_and_params)
 
 
 def _boundary_reqs():
@@ -168,15 +166,14 @@ def _plain_greedy(engine, prompt, n):
     return out
 
 
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_verify_accepts_correct_draft_and_rejects_wrong(engines, layout):
+def test_verify_accepts_correct_draft_and_rejects_wrong(engine):
     """A draft equal to the plain continuation accepts fully and the
     returned tokens are the next K+1 plain tokens; a draft wrong at
     position i accepts exactly i tokens; plain decode AFTER the
     rejection reproduces the reference stream — the rejected tail's
     K/V (written into the cache by the verify program) never became
     visible."""
-    eng = engines[layout]
+    eng = engine
     prompt = [3, 17, 91, 42, 8]
     ref = _plain_greedy(eng, prompt, 10)
     offset = len(prompt)
@@ -215,9 +212,8 @@ def test_verify_accepts_correct_draft_and_rejects_wrong(engines, layout):
     assert m == 1 and toks.tolist()[:2] == ref[1:3]
 
 
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_verify_step_validation(engines, layout, lm_and_params):
-    eng = engines[layout]
+def test_verify_step_validation(engine, lm_and_params):
+    eng = engine
     eng.reset()
     eng.prefill_chunked(0, [1, 2, 3])
     with pytest.raises(ValueError, match="draft length"):
@@ -228,11 +224,9 @@ def test_verify_step_validation(engines, layout, lm_and_params):
         eng.verify_step(eng.slots, 1, [1], 3)
     with pytest.raises(ValueError, match="verify window"):
         eng.verify_step(0, 1, [1], eng.max_len - K)   # window spills
-    if layout == "paged":
-        with pytest.raises(ValueError, match="disagrees"):
-            eng.verify_step(0, 1, [1], 7)             # committed len is 3
-    no_spec = _mk_engine(lm_and_params, paged=(layout == "paged"),
-                         spec=False)
+    with pytest.raises(ValueError, match="disagrees"):
+        eng.verify_step(0, 1, [1], 7)                 # committed len is 3
+    no_spec = _mk_engine(lm_and_params, spec=False)
     with pytest.raises(RuntimeError, match="SpecConfig"):
         no_spec.verify_step(0, 1, [1], 3)
     with pytest.raises(ValueError, match="speculative=True requires"):
@@ -249,15 +243,13 @@ def test_engine_spec_validation(lm_and_params):
 
 
 # --------------------------------------------------------- the parity pin
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_speculative_bitwise_parity_and_recompute(engines, layout,
-                                                  lm_and_params):
+def test_speculative_bitwise_parity_and_recompute(engine, lm_and_params):
     """THE acceptance pin: a greedy stream with prompt lengths below /
     at / straddling chunk boundaries served speculative vs plain on the
     same engine — bitwise-identical token streams, real acceptances,
     and agreement with one teacher-forcing full recompute."""
     m, params = lm_and_params
-    eng = engines[layout]
+    eng = engine
     eng.reset()
     plain = _boundary_reqs()
     Scheduler(eng, speculative=False).run(plain)
@@ -291,10 +283,10 @@ def test_speculative_bitwise_parity_and_recompute(engines, layout,
                 f"prompt len {len(r.prompt)}: divergence at token {i}"
 
 
-def test_speculative_with_eos_matches_plain(engines):
+def test_speculative_with_eos_matches_plain(engine):
     """EOS inside an accepted run truncates exactly where plain decode
     stops (emitted tokens past the EOS are discarded)."""
-    eng = engines["paged"]
+    eng = engine
     eng.reset()
     prompt = [3, 17, 91, 42, 8]
     ref = _plain_greedy(eng, prompt, 8)
@@ -311,15 +303,14 @@ def test_speculative_with_eos_matches_plain(engines):
 
 
 # --------------------------------------------------------- batched verify
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_verify_batch_matches_sequential_per_slot(engines, layout):
+def test_verify_batch_matches_sequential_per_slot(engine):
     """The batched-verify satellite's parity pin: B verify-eligible
     slots through ONE [slots, K+1] call emit bitwise the same tokens
     and acceptance counts as B sequential single-slot verify_step calls
     — the wrapper routes through the SAME executable, so this is the
     per-row-independence guarantee (a slot's verify never reads or
-    writes a batchmate's rows), on both layouts."""
-    eng = engines[layout]
+    writes a batchmate's rows)."""
+    eng = engine
     prompts = {0: [3, 17, 91, 42, 8], 1: [7, 7, 9, 7, 7, 9, 2],
                2: [11, 4, 11, 4, 11]}
     drafts = {0: [5, 9, 1], 1: [7, 9], 2: [11]}   # varied draft lengths
@@ -344,14 +335,14 @@ def test_verify_batch_matches_sequential_per_slot(engines, layout):
     eng.reset()
 
 
-def test_verify_batch_leaves_nonverifying_slots_untouched(engines):
+def test_verify_batch_leaves_nonverifying_slots_untouched(engine):
     """Fixed-shape safety: a decoding slot NOT in the verify batch must
     keep its exact cache bytes — its subsequent plain-decode stream is
     bitwise the reference stream even though a batched verify ran on a
     batchmate in between (paged: the passenger's table-row operand is
     zeroed so writes land on the sentinel; this is the guarantee that
     lets the scheduler verify some slots while others decode)."""
-    eng = engines["paged"]
+    eng = engine
     prompt = [3, 17, 91, 42, 8]
     ref = _plain_greedy(eng, prompt, 8)
 
@@ -372,8 +363,8 @@ def test_verify_batch_leaves_nonverifying_slots_untouched(engines):
     eng.reset()
 
 
-def test_verify_batch_validation(engines):
-    eng = engines["paged"]
+def test_verify_batch_validation(engine):
+    eng = engine
     eng.reset()
     eng.prefill_chunked(0, [1, 2, 3])
     with pytest.raises(ValueError, match="at least one"):
@@ -387,20 +378,16 @@ def test_verify_batch_validation(engines):
     eng.reset()
 
 
-@pytest.mark.parametrize("paged", [True, False])
-def test_verify_batch_window_and_offset_raise_on_both_layouts(
-        lm_and_params, paged):
-    """Loud-failure contract, BOTH layouts (review finding: the
-    contiguous path used to mask a spilling window in-program and
+def test_verify_batch_window_and_offset_raise(lm_and_params):
+    """Loud-failure contract (a window masked in-program would
     return n_accepted=0 — indistinguishable from a real zero-accept,
     so the caller would emit a token whose K/V never landed): a
     verifying slot whose committed length leaves no room for the
-    padded [K+1] window raises BEFORE anything mutates, and a caller
-    offset that disagrees with the committed length raises on the
-    contiguous layout too (the old per-slot path only checked paged)."""
+    padded [K+1] window raises BEFORE anything mutates, and so does a
+    caller offset that disagrees with the committed length."""
     m, params = lm_and_params
     eng = Engine(m, params, slots=2, max_len=8, prefill_len=8,
-                 chunk_len=8, paged=paged,
+                 chunk_len=8,
                  policy=resolve_policy("O0", verbose=False),
                  spec=SpecConfig(draft_len=K, ngram=2))
     t = eng.prefill_chunked(0, [1, 2, 3, 4, 5])   # committed length 5
@@ -416,48 +403,36 @@ def test_verify_batch_window_and_offset_raise_on_both_layouts(
 
 
 # ------------------------------------------------- compiled-programs pin
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_exactly_one_new_executable(engines, layout):
-    """The compiled-programs pin, updated: across everything this
-    module ran on the shared engines — streams varying drafts, offsets,
-    slots, draft lengths, plus the monolithic baseline — the verify
+def test_exactly_one_new_executable(engine):
+    """The compiled-programs pin: across everything this
+    module ran on the shared engine — streams varying drafts, offsets,
+    slots, draft lengths — the verify
     program traced EXACTLY once (drafting never retraces), moving the
-    pin 3 -> 4 paged and 4 -> 5 contiguous."""
-    eng = engines[layout]
+    pin 2 -> 3."""
+    eng = engine
     eng.reset()
-    # make sure every program family has actually run at least once
-    eng.prefill(0, [5, 9, 2])
-    if layout == "contiguous":
-        eng.copy_kv(0, 1, 3)
     sched = Scheduler(eng, speculative=True)
     sched.run(_boundary_reqs())
     assert eng.verify_traces == 1, "the verify program retraced"
-    assert (eng.chunk_traces, eng.decode_traces, eng.prefill_traces) \
-        == (1, 1, 1)
-    if layout == "paged":
-        assert eng.copy_traces == 0
-        assert eng.compiled_programs == 4
-    else:
-        assert eng.copy_traces == 1
-        assert eng.compiled_programs == 5
+    assert (eng.chunk_traces, eng.decode_traces) == (1, 1)
+    assert eng.compiled_programs == 3
 
 
 # ------------------------------------------------------ chaos composition
 @pytest.mark.chaos
-def test_chaos_composition_speculative(engines):
+def test_chaos_composition_speculative(engine):
     """Satellite pin: a seeded FaultPlan — a verify-site exception plus
     non-finite logits routed into a verifying slot — over a speculative
     run. Un-faulted requests are bitwise identical to the fault-free
     SPECULATIVE run, faulted requests reach typed terminals, zero new
     programs traced, zero pages leaked at drain."""
-    eng = engines["paged"]
+    eng = engine
     eng.reset()
     policy = FaultPolicy(backoff_base_s=0.0, audit_every_n=1)
     clean_reqs = _boundary_reqs()
     Scheduler(eng, speculative=True, fault_policy=policy).run(clean_reqs)
     clean = [list(r.output_tokens) for r in clean_reqs]
-    traces0 = (eng.chunk_traces, eng.decode_traces, eng.prefill_traces,
-               eng.verify_traces)
+    traces0 = (eng.chunk_traces, eng.decode_traces, eng.verify_traces)
 
     eng.reset()
     # tick 1 is DETERMINISTIC: the chaos schedule is identical to the
@@ -494,7 +469,7 @@ def test_chaos_composition_speculative(engines):
             assert list(r.output_tokens) == clean[i], \
                 f"request {i} diverged under chaos"
     # containment + injection added ZERO compiled programs
-    assert (eng.chunk_traces, eng.decode_traces, eng.prefill_traces,
+    assert (eng.chunk_traces, eng.decode_traces,
             eng.verify_traces) == traces0
     assert reg.snapshot()["counters"]["serving.faults.nonfinite"] >= 1
     assert sched.auditor.audit(eng)["pages_in_use"] == 0

@@ -299,7 +299,7 @@ def test_every_bench_driver_routes_through_guard_bench_main():
 
     root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
     drivers = sorted(glob.glob(os.path.join(root, "bench*.py")))
-    assert len(drivers) >= 5        # bench, kernels, memory, schedule, serving
+    assert len(drivers) >= 4        # bench, kernels, memory, schedule
     for path in drivers:
         with open(path) as f:
             src = f.read()

@@ -121,17 +121,6 @@ def _scales(heads, kv_dtype):
     return [((heads,), F32)] * 2 if kv_dtype == I8 else [None, None]
 
 
-def _decode(heads, d, kv_dtype):
-    from apex_tpu.kernels.decode_attention import decode_attention
-
-    def fn(q, k, v, lengths, ks, vs):
-        return decode_attention(q, k, v, lengths, k_scale=ks, v_scale=vs)
-    L = PAGE * MAX_PAGES
-    return fn, [((B, heads, d), BF16), ((B, heads, L, d), kv_dtype),
-                ((B, heads, L, d), kv_dtype), ((B,), I32),
-                *_scales(heads, kv_dtype)]
-
-
 def _pool(pages, heads, d, stacked):
     """One layer's pool, or (``stacked``) three layers of it as the
     serving engine holds them, the middle one read."""
@@ -203,8 +192,6 @@ CASES = {
     "xentropy_padded_vocab": (_xentropy, (), ["xentropy_fwd",
                                               "xentropy_bwd"]),
     "flat_fused_adam": (_adam, (), ["multi_tensor_adam"]),
-    "decode_bf16": (_decode, (12, 64, BF16), ["decode_attention"]),
-    "decode_int8": (_decode, (12, 64, I8), ["decode_attention"]),
     "paged_decode_bf16_12x64": (_paged_decode, (12, 64, BF16),
                                 ["paged_decode_attention"]),
     "paged_decode_int8_12x64": (_paged_decode, (12, 64, I8),
@@ -258,9 +245,7 @@ def test_paged_decode_at_a_cells_geometry_fits_its_vmem(cell, one_chip,
     read: one kernel, its pages a step read off the page's bytes, and
     the working set it states under the scoped limit it asks for (which
     the compile above all accepts)."""
-    import importlib
-    # by module path: the package re-exports the same-named FUNCTION
-    da = importlib.import_module("apex_tpu.kernels.decode_attention")
+    from apex_tpu.kernels import decode_attention as da
     rows, h, h_kv, d, table, layers, pool = CELL_GEOMETRY[cell]
     shapes = [((rows, h, d), BF16), ((layers, pool, h_kv, d, PAGE), BF16),
               ((layers, pool, h_kv, d, PAGE), BF16), ((rows, table), I32),

@@ -75,7 +75,7 @@ def lm_and_params():
 def _mk_engine(lm_and_params, *, pool=4, slots=2, seed=5, **kw):
     m, params = lm_and_params
     return Engine(m, params, slots=slots, max_len=64, prefill_len=24,
-                  chunk_len=CHUNK, prefix_pool=pool, paged=True,
+                  chunk_len=CHUNK, prefix_pool=pool,
                   policy=resolve_policy("O0", verbose=False), seed=seed,
                   **kw)
 

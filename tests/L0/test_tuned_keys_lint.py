@@ -90,9 +90,7 @@ def test_chunk_prefill_keys_are_tuned():
     satellite): a fresh engine on v5e silicon must not fall back to
     emulator-era defaults for its hottest new program."""
     table = _table_keys()
-    for key in ("decode.chunk_block_q", "decode.chunk_block_k",
-                "decode.block_k", "decode.prefill_block_q",
-                "decode.prefill_block_k"):
+    for key in ("decode.chunk_block_q", "decode.chunk_block_k"):
         assert key in table, f"{key} missing from the tuned tables"
 
 
@@ -114,10 +112,8 @@ def test_paged_kernel_keys_are_tuned():
 
 def test_prefix_copy_sources_are_linted_and_carry_no_tuned_keys():
     """The PR 5 prefix-reuse satellite, tightened by the paged-pool
-    refactor that RETIRED the copy from the hit path: the contiguous
-    KV row-copy program is pure data movement (one dynamic-slice pair,
-    no Pallas kernel) and the paged path replaces it with host-side
-    page sharing (no program at all) — so neither owes the tables any
+    refactor that RETIRED the copy: a prefix hit is host-side
+    page sharing (no program at all) — so it owes the tables no
     key, and NO ``decode.copy_*`` row may remain (a stale row would be
     a dead sweep, caught here by name rather than only via the generic
     stale check). Also pins that the lint's scan covers the sources the
@@ -127,9 +123,8 @@ def test_prefix_copy_sources_are_linted_and_carry_no_tuned_keys():
     table = _table_keys()
     stale_copy = {k for k in table if k.startswith("decode.copy_")}
     assert not stale_copy, (
-        f"tuned tables carry decode.copy_* keys but neither the "
-        f"contiguous KV row-copy nor the paged zero-copy hit path "
-        f"consumes tuned knobs: {stale_copy}")
+        f"tuned tables carry decode.copy_* keys but the zero-copy "
+        f"hit path consumes no tuned knobs: {stale_copy}")
     scanned = {os.path.relpath(p, ROOT)
                for d in SCAN_DIRS
                for p in glob.glob(os.path.join(d, "**", "*.py"),
@@ -283,8 +278,7 @@ def test_sharded_serving_owes_the_tables_no_new_keys():
     the existence/staleness treatment automatically."""
     table = {k for k in _table_keys() if k.startswith("decode.")}
     assert table == {
-        "decode.block_k", "decode.chunk_block_q", "decode.chunk_block_k",
-        "decode.prefill_block_q", "decode.prefill_block_k",
+        "decode.chunk_block_q", "decode.chunk_block_k",
         "decode.page_block_q", "decode.page_len",
         # the paged decode kernel's bytes in flight a buffer (PR 31):
         # per shard it sees the local heads' page and reads its pages a

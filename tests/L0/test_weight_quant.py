@@ -290,13 +290,11 @@ def test_chunk_boundary_prompt_lengths_match(engine_pair):
 
 def test_zero_new_programs(engine_pair):
     """Quantization is a params property: the wq engine compiles the
-    SAME pinned paged program set (chunk + decode + the monolithic
-    baseline; copy retired) — zero new executables."""
+    SAME pinned program set (chunk + decode) — zero new executables."""
     _, wq = engine_pair
-    wq.prefill(0, [5, 9, 2])          # the monolithic baseline compiles
-    assert (wq.chunk_traces, wq.decode_traces, wq.prefill_traces,
-            wq.copy_traces) == (1, 1, 1, 0)
-    assert wq.compiled_programs == 3
+    wq.prefill_chunked(0, [5, 9, 2])  # scheduler-less callers: same program
+    assert (wq.chunk_traces, wq.decode_traces) == (1, 1)
+    assert wq.compiled_programs == 2
 
 
 def test_wq_composes_with_kv_quant(lm_and_params):
@@ -432,9 +430,9 @@ def test_weight_quant_none_stays_the_bitwise_baseline(lm_and_params):
     assert "embedding_scale" not in a.params["wte"]
     assert qkv["kernel"].dtype == jnp.float32         # O0 cast, not int8
     assert _serve(a, seed=31) == _serve(b, seed=31)
-    a.prefill(0, [5, 9, 2])           # the monolithic baseline compiles
-    assert (a.chunk_traces, a.decode_traces, a.prefill_traces,
-            a.copy_traces) == (1, 1, 1, 0)
+    a.prefill_chunked(0, [5, 9, 2])
+    assert (a.chunk_traces, a.decode_traces) == (1, 1)
+    assert a.compiled_programs == 2
 
 
 def test_wq_gauges_report_the_capacity_claim(lm_and_params):

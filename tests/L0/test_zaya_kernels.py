@@ -1,4 +1,4 @@
-"""Grouped-query heads in the four serving attention kernels against
+"""Grouped-query heads in the three serving attention kernels against
 their repeat-the-heads oracles, the grouped GEMM against the one-hot dense
 form (uneven and empty groups, a held subset), and the drop-nothing expert
 layer's shares against the uncut reference's expert sublayer - small sizes
@@ -10,8 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from apex_tpu.kernels.decode_attention import (decode_attention,
-                                               decode_attention_reference,
+from apex_tpu.kernels.decode_attention import (decode_attention_reference,
                                                gather_pages,
                                                paged_decode_attention)
 from apex_tpu.kernels.grouped_gemm import (group_ranges, grouped_gemm,
@@ -53,8 +52,6 @@ def test_grouped_heads_match_the_repeated_heads_oracle(h, h_kv, d):
     lens = jnp.asarray([200, 300, 7], jnp.int32)
     want = decode_attention_reference(q, kr, vr, lens, scale=scale)
     got = paged_decode_attention(q, kp, vp, pt, lens, layer=1)
-    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
-    got = decode_attention(q, k, v, lens)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6
     offs = jnp.asarray([0, 128, 0], jnp.int32)
     want = prefill_attention_reference(qc, kr, vr, offs, scale=scale)

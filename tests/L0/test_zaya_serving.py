@@ -89,16 +89,6 @@ def test_prefill_then_decode_serves_the_references_tokens(engine, weights, n):
     assert _gaps(weights, prompt, out).max() < LOGIT_TOL
 
 
-def test_monolithic_prefill_agrees_with_the_chunked(engine, weights):
-    prompt = _prompt(7, 200)
-    a = engine.prefill(0, prompt)
-    engine.release_slot(0)
-    b = engine.prefill_chunked(0, prompt)
-    engine.release_slot(0)
-    assert a == b
-    assert _gaps(weights, prompt, [a]).max() < LOGIT_TOL
-
-
 def test_neighbouring_slots_never_see_each_others_state(engine, weights):
     pa, pb = _prompt(11, 140), _prompt(12, 90)
     alone = _serve(engine, 1, pa, 6)
@@ -138,11 +128,6 @@ def test_a_reused_slot_starts_from_zeros(engine, weights):
     reused = _serve(engine, 2, py, 4)
     engine.release_slot(2)
     assert _gaps(weights, py, reused).max() < LOGIT_TOL
-    # and by the monolithic prefill, which admits from zeros too
-    _serve(engine, 2, px, 2)
-    engine.release_slot(2)
-    assert engine.prefill(2, py) == reused[0]
-    engine.release_slot(2)
 
 
 def test_scheduler_serves_more_requests_than_slots(weights):
@@ -201,7 +186,6 @@ REFUSED_BY_ENGINE = {
     "int8 KV tier": dict(kv_quant=serving.KVQuantConfig()),
     "int8 weight tier": dict(weight_quant=serving.WeightQuantConfig()),
     "tensor parallelism (mesh=)": dict(mesh="any"),
-    "contiguous cache": dict(paged=False),
 }
 
 

@@ -352,10 +352,10 @@ def _serving_case(kind, kv, seed=0):
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-@pytest.mark.parametrize("kind", ["decode", "paged_decode", "prefill",
+@pytest.mark.parametrize("kind", ["paged_decode", "prefill",
                                   "paged_prefill"])
 def test_serving_attention_matches_reference(tpu_backend, kind, kv):
-    """The four serving attention kernels, compiled by Mosaic and RUN,
+    """The three serving attention kernels, compiled by Mosaic and RUN,
     against their gather/jnp oracles — and really the kernel: the
     compiled program must hold the ``tpu_custom_call`` (at these aligned
     shapes a silent give-way to the reference would compare the oracle
