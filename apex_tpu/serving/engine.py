@@ -791,6 +791,9 @@ class Engine:
         self.last_verify_finite = True
         self.last_verify_finite_slots = np.ones(self.slots, bool)
         self.nonfinite_events = 0
+        # the decode program's second token operand while no step is in
+        # flight (decode_dispatch's ``after``): read by no row then
+        self._no_prev_tokens = jnp.zeros(self.slots, jnp.int32)
         # under a mesh each program body runs shard_map'd over the
         # tensor-parallel axis (params split per the rule table, the
         # pool on heads, every host operand replicated); mesh=None
@@ -1181,10 +1184,20 @@ class Engine:
                               self.top_k)[0]
         return cache, token, finite
 
-    def _paged_decode_impl(self, params, cache, last_tokens, page_table,
-                           lengths, temperature, fault_bias, key,
-                           lora=None, adapter_ids=None):
+    @staticmethod
+    def _chain_tokens(last_tokens, prev_tokens):
+        """A decode step's input tokens: the host's ``last_tokens``,
+        except where one is negative - that row's newest token has not
+        been read yet and is ``prev_tokens``' (the step before's
+        un-read result, still on the device). A token id is never
+        negative, so the mark costs no operand of its own."""
+        return jnp.where(last_tokens < 0, prev_tokens, last_tokens)
+
+    def _paged_decode_impl(self, params, cache, last_tokens, prev_tokens,
+                           page_table, lengths, temperature, fault_bias,
+                           key, lora=None, adapter_ids=None):
         self.decode_traces += 1     # python body runs at trace time only
+        last_tokens = self._chain_tokens(last_tokens, prev_tokens)
         # lengths are HOST state in the paged layout (the allocator owns
         # them); the program is a pure function of the operands. Length
         # growth happens host-side after the call — inactive slots'
@@ -1278,9 +1291,11 @@ class Engine:
                               self.top_k)[0]
         return cache, token, finite
 
-    def _state_decode_impl(self, params, cache, last_tokens, page_table,
-                           lengths, temperature, fault_bias, key, active):
+    def _state_decode_impl(self, params, cache, last_tokens, prev_tokens,
+                           page_table, lengths, temperature, fault_bias,
+                           key, active):
         self.decode_traces += 1     # python body runs at trace time only
+        last_tokens = self._chain_tokens(last_tokens, prev_tokens)
         positions = jnp.minimum(lengths, self.max_len - 1)
         old = cache.state.rows
         logits, k2, v2, rows, st = self._state_apply(
@@ -2032,7 +2047,9 @@ class Engine:
         return out
 
     def decode_dispatch(self, last_tokens, active, temperatures,
-                        fault_bias=None) -> PendingDecode:
+                        fault_bias=None, *,
+                        after: Optional[PendingDecode] = None
+                        ) -> PendingDecode:
         """DISPATCH one decode step and return without waiting for it:
         the compiled call is enqueued on the device (JAX async
         dispatch), host bookkeeping advances speculatively (paged
@@ -2042,13 +2059,18 @@ class Engine:
         :class:`PendingDecode` until :meth:`decode_reconcile` reads
         them back in one batched transfer.
 
-        ``last_tokens`` may be a HOST int array or a DEVICE array — in
-        particular the previous pending step's un-forced ``tokens`` —
-        which is what lets the pipelined heartbeat chain decode step
-        t+1 onto step t's output without the host ever touching the
-        token values: the data dependency stays on the device, and the
-        host think-time (drafting, admission, telemetry) overlaps the
-        device's execution of the steps in flight.
+        ``after`` is the step dispatched before this one and not read
+        yet: a row whose ``last_tokens`` entry is NEGATIVE takes its
+        token from ``after``'s un-forced device ``tokens``, selected
+        inside the decode program itself (the same launch and the same
+        transfers as a step with nothing in flight - no eager op
+        beside it). That is how the dispatch-ahead heartbeat chains
+        step t+1 onto step t's output without the host ever touching
+        the token values: the data dependency stays on the device, and
+        the host think-time (admission, telemetry, the next step's
+        operands) overlaps the device's execution of the step in
+        flight. ``last_tokens`` may also be a DEVICE array as a whole
+        (a pending step's ``tokens``).
 
         Nothing here counts tokens or observes latency — a dispatched
         token is not an emitted token until the reconcile decides it
@@ -2063,6 +2085,16 @@ class Engine:
                 raise ValueError(f"fault_bias {fault_bias.shape} must "
                                  f"be [{self.slots}]")
         act = np.asarray(active, bool)
+        if after is None and isinstance(last_tokens, np.ndarray) \
+                and (last_tokens[act] < 0).any():
+            raise ValueError("a negative last_tokens entry marks a row "
+                             "that takes its token from `after`, and no "
+                             "step was given")
+        # nothing in flight: every row's token is the host's, and the
+        # program's other token operand is a constant that stays on the
+        # device (no transfer)
+        prev_tokens = self._no_prev_tokens if after is None \
+            else after.tokens
         t0 = time.perf_counter()
         # write-then-attend writes at host_len: make sure each
         # active slot's write page exists BEFORE the program runs
@@ -2074,7 +2106,7 @@ class Engine:
                 if pos < self.max_len:
                     self._grow_slot(s, self.pool.pages_for(pos + 1))
         ops = self._operands(lambda: (
-            jnp.asarray(last_tokens, jnp.int32),
+            jnp.asarray(last_tokens, jnp.int32), prev_tokens,
             jnp.asarray(self._page_table.copy()),
             jnp.asarray(self._host_len.copy()),
             jnp.asarray(temperatures, jnp.float32),
@@ -2488,7 +2520,7 @@ class Engine:
         chunk = np.zeros((1, self.chunk_len), np.int32)
         scalars = (np.int32(0), np.int32(1), np.float32(0),
                    np.float32(0), self._key)
-        decode_ops = (last, self._page_table, self._host_len)
+        decode_ops = (last, last, self._page_table, self._host_len)
         chunk_ops = (chunk, self._page_table[:1])
         traces = (self.decode_traces, self.chunk_traces)
         try:

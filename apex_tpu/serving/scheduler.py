@@ -50,16 +50,19 @@ beats:
    ``speculative=False`` (the default) skips the draft phase entirely
    and keeps today's path as the measurable baseline.
 
-**Async pipelined heartbeat** (``pipeline_depth >= 1``): the sync beat
-forces every sampled token to the host (``np.asarray``) before the
-next step is dispatched, so the device idles through all the host
-think-time in between — drafting, admission, hashing, telemetry.
-Dispatch-ahead execution inverts that: decode step t+1 is DISPATCHED
-against the speculated schedule (every in-flight slot presumed to
-continue — EOS is the only finality the host cannot know in advance;
-token-budget and ``max_len`` exhaustion are pure host arithmetic and
-are never speculated past) with step t's un-forced device tokens as
-its ``last_tokens``, and step t is only then RECONCILED: one batched
+**Async pipelined heartbeat** (``pipeline_depth >= 1``; the default
+is 1, the beat every server runs): the sync beat forces every sampled
+token to the host (``np.asarray``) before the next step is dispatched,
+so the device idles through all the host's time in between — the next
+step's operands and launch, the read's tail, drafting, admission,
+hashing, telemetry. Dispatch-ahead execution inverts that: decode step
+t+1 is DISPATCHED against the speculated schedule (every in-flight
+slot presumed to continue — EOS is the only finality the host cannot
+know in advance; token-budget and ``max_len`` exhaustion are pure host
+arithmetic and are never speculated past) with step t's un-forced
+device tokens standing in, INSIDE the decode program, for every row
+whose newest token the host has not read (one launch a beat, as in the
+sync beat), and step t is only then RECONCILED: one batched
 readback, per-slot emission through the same finish checks as the
 sync path, and rollback of any mispredict — a slot that turned out to
 finish (or quarantine, or expire) mid-pipeline simply discards its
@@ -69,10 +72,17 @@ speculated step's K/V write lands past every reader exactly like
 PR 8's rejected verify tail — lengths gate attention, dispatch order
 is program order (the cache threads through every call), and the next
 occupant's chunk prefill overwrites whole pages before attending them
-(write-then-attend). Host bookkeeping rollback is pure length
-arithmetic, already performed by ``release_slot``. ``pipeline_depth=0``
-(the default) keeps today's fully synchronous beat as the bitwise
-oracle; depth ``d`` keeps at most ``d`` decode steps in flight.
+(write-then-attend; a model with per-slot state beside its pages has
+that state reset by the occupant's chunk at offset 0 the same way).
+Host bookkeeping rollback is pure length arithmetic, already performed
+by ``release_slot``. Chunk prefill is dispatched ahead too: a chunk's
+token is read at the top of the next beat, so a final chunk's first
+token is emitted, and its slot decodes, one beat after its dispatch.
+``pipeline_depth=0`` keeps the fully synchronous beat as the bitwise
+oracle the tests pin the default against; depth ``d`` keeps at most
+``d`` decode steps in flight. ``serving.heartbeat.dispatched_ahead``
+over ``serving.decode.steps`` says how often a step really was
+dispatched behind an un-read one.
 A :class:`~apex_tpu.serving.DraftWorker` thread overlaps n-gram
 drafting and prefix block-hashing with device execution (pure
 closures over snapshots — timing can reorder host work, never change
@@ -158,9 +168,8 @@ import enum
 import itertools
 import time
 import weakref
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
-import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.log_util import get_logger
@@ -504,12 +513,28 @@ class _InflightStep:
     # pre-quarantine step for the retried occupant's.
 
 
+class _InflightChunk(NamedTuple):
+    """One dispatch-ahead prefill chunk ``[lo, hi)`` of request ``uid``:
+    the engine's :class:`~apex_tpu.serving.PendingPrefill` handle, when
+    it was dispatched (``t0``) and in which beat (``tick``: a later
+    beat retires it before anything else)."""
+
+    pending: object
+    uid: int
+    lo: int
+    hi: int
+    t0: float
+    tick: int
+
+
 class Scheduler:
     """Continuous-batching front of an :class:`~apex_tpu.serving.Engine`
-    (see module docstring for the step anatomy). ``pipeline_depth=0``
-    (default) is the fully synchronous beat; ``>= 1`` enables
-    dispatch-ahead decode with deferred token readback (bitwise-greedy
-    identical, see the module docstring's async-heartbeat section)."""
+    (see module docstring for the step anatomy). ``pipeline_depth=1``
+    (default) is the dispatch-ahead beat: the next decode step is on
+    the device before the last one's tokens are read (bitwise-greedy
+    identical to the synchronous beat, see the module docstring's
+    async-heartbeat section); ``0`` is that synchronous beat, the
+    oracle; deeper keeps more steps in flight."""
 
     def __init__(self, engine, *, max_queue: int = 64,
                  default_timeout_s: Optional[float] = None,
@@ -517,7 +542,7 @@ class Scheduler:
                  chunk_budget: int = 1,
                  retain_prefixes: bool = False,
                  speculative: bool = False,
-                 pipeline_depth: int = 0,
+                 pipeline_depth: int = 1,
                  role: str = "both",
                  on_requeue=None,
                  fault_policy: Optional[FaultPolicy] = None,
@@ -677,7 +702,9 @@ class Scheduler:
         # dispatched-but-unreconciled decode steps, oldest first, and
         # the worker thread that overlaps drafting + prefix hashing
         # with device execution. Depth 0 never touches any of it — the
-        # sync beat stays the bitwise oracle path.
+        # sync beat stays the bitwise oracle path. Depth 1 is the
+        # default: one step in flight hides the host's side of a beat
+        # under the device's.
         self.pipeline_depth = int(pipeline_depth)
         self._pipeline: collections.deque = collections.deque()
         self._worker: Optional[DraftWorker] = None
@@ -702,9 +729,8 @@ class Scheduler:
         # by _consult_prefix_cache)
         self._handoff_uids: Dict[int, int] = {}
         # dispatch-ahead chunk prefill (pipeline_depth >= 1): per-slot
-        # dispatched-but-unreconciled PendingPrefill handle as
-        # (pending, uid, lo, hi, t_dispatch); depth 0 never populates it
-        self._pending_prefill: List[Optional[tuple]] = \
+        # dispatched-but-unreconciled chunk; depth 0 never populates it
+        self._pending_prefill: List[Optional[_InflightChunk]] = \
             [None] * engine.slots
         # decode-beat isolation accounting: beats taken vs beats that
         # ran any chunk-prefill work (the router aggregates these into
@@ -1525,19 +1551,32 @@ class Scheduler:
         same clock every injection site reads)."""
         if tick is None:
             tick = self._tick
-        ran = 0
         slots = self.engine.slots
+        if self.role != "prefill":
+            # dispatch-ahead prefill: retire every chunk an EARLIER
+            # beat dispatched, wherever the round-robin stands and
+            # before the budget is spent - a final chunk's first token
+            # is emitted, and its slot decodes, at the beat after its
+            # dispatch, as in the synchronous beat. The wait lies under
+            # the decode step that was dispatched behind the chunk and
+            # is still running, so the device is not kept waiting by
+            # it. (A prefill replica dispatches no decode step: there
+            # a chunk is retired at its slot's next visit, below, so
+            # that another slot's chunk keeps the device busy
+            # meanwhile.)
+            for slot, entry in enumerate(self._pending_prefill):
+                if entry is not None and entry.tick < tick:
+                    self._reconcile_prefill(slot)
+        ran = 0
         start = self._pf_rr
         for i in range(slots):
             if ran >= self.chunk_budget:
                 break
             slot = (start + i) % slots
             if self._pending_prefill[slot] is not None:
-                # dispatch-ahead prefill: retire the slot's in-flight
-                # chunk FIRST (its readback was deferred one visit so
-                # the device executed it under this beat's host work),
-                # then dispatch the next — reconcile-then-dispatch
-                # keeps at most one chunk per slot in flight
+                # a chunk of this beat's own cold-queue burst (or a
+                # prefill replica's): reconcile-then-dispatch keeps at
+                # most one chunk per slot in flight
                 self._reconcile_prefill(slot)
             r = self._running[slot]
             if r is None or r.status != "prefilling":
@@ -1662,7 +1701,8 @@ class Scheduler:
             return
         r.prefill_s += time.perf_counter() - t0
         r._prefill_pos = hi
-        self._pending_prefill[slot] = (pending, r.uid, lo, hi, t0)
+        self._pending_prefill[slot] = _InflightChunk(pending, r.uid, lo,
+                                                     hi, t0, tick)
 
     def _reconcile_prefill(self, slot: int) -> None:
         """Retire ``slot``'s dispatched-ahead prefill chunk: force its
@@ -1675,7 +1715,7 @@ class Scheduler:
         if entry is None:
             return
         self._pending_prefill[slot] = None
-        pending, uid, lo, hi, t0 = entry
+        pending, uid, lo, hi, t0, _ = entry
         r = self._running[slot]
         if r is None or r.uid != uid or r.status != "prefilling":
             if self.registry is not None:
@@ -2304,10 +2344,15 @@ class Scheduler:
         with tracing.phase("serve.chunk") as ph:
             chunks = self._prefill_tick(tick)
             # cold-queue burst (same contract as the sync beat): only
-            # while nothing is decoding AND nothing is in flight
+            # while nothing is decoding AND nothing is in flight - a
+            # dispatched FINAL chunk counts as decoding (its slot flips
+            # the moment the chunk is read, at the next beat's top;
+            # the sync beat stops bursting at that same chunk)
             while chunks and not self._pipeline \
                     and not any(r is not None and r.status == "running"
-                                for r in self._running):
+                                for r in self._running) \
+                    and not any(e is not None and e.pending.final
+                                for e in self._pending_prefill):
                 more = self._prefill_tick(tick)
                 if not more:
                     break
@@ -2333,7 +2378,8 @@ class Scheduler:
             with tracing.phase("serve.spec"):
                 spec_slots, spec_calls, spec_emitted = \
                     self._spec_tick(tick)
-        with tracing.phase("serve.decode"):
+        with tracing.phase("serve.decode") as ph:
+            ph.note(inflight=len(self._pipeline))
             active = self._dispatch_decode(tick, spec_slots)
         self._emit_beat_gauges(active if active is not None
                                else np.zeros(self.engine.slots, bool))
@@ -2391,12 +2437,13 @@ class Scheduler:
         bias = None
         if self.fault_plan is not None:
             bias = self.fault_plan.decode_bias(tick, eng.slots)
+        last_tokens, after = self._pipeline_last_tokens(active)
         try:
             if self.fault_plan is not None:
                 self.fault_plan.maybe_raise("decode", tick)
             pending = eng.decode_dispatch(
-                self._pipeline_last_tokens(active), active, self._temps,
-                fault_bias=bias)
+                last_tokens, active, self._temps, fault_bias=bias,
+                after=after)
         except Exception as e:  # noqa: BLE001 — containment edge
             # the dispatch produced no step (injected faults raise
             # INSTEAD of the call): same blast radius as the sync
@@ -2414,30 +2461,37 @@ class Scheduler:
                     if r is not None and r.uid == uids[slot]:
                         self._quarantine(r, slot, desc)
             return active
+        if self._pipeline and self.registry is not None:
+            # the engagement share's numerator (over
+            # serving.decode.steps): this step went to the device while
+            # an earlier one was still un-read
+            self.registry.counter_inc("serving.heartbeat.dispatched_ahead")
         self._pipeline.append(_InflightStep(pending=pending, uids=uids,
                                             tick=tick))
         return active
 
     def _pipeline_last_tokens(self, active: np.ndarray):
-        """The dispatch's ``last_tokens`` operand: host values for
-        settled slots, the NEWEST in-flight step's un-forced device
-        tokens for slots whose latest token is still on the device —
-        merged by one tiny device ``where`` so the data dependency
-        chains decode t+1 onto t without the host ever reading a token
-        (dispatch-ahead region: linted force-free)."""
-        host = self._last_tokens
+        """The dispatch's ``(last_tokens, after)`` operands: host values
+        for settled slots, and -1 for every slot whose latest token is
+        still on the device in the NEWEST in-flight step ``after`` —
+        the decode program itself takes those rows from ``after``'s
+        un-forced tokens (:meth:`Engine.decode_dispatch`), so the data
+        dependency chains decode t+1 onto t without the host ever
+        reading a token and without a launch of its own
+        (dispatch-ahead region: linted force-free). A copy: reconcile
+        writes ``_last_tokens`` while the step may not have read its
+        operand yet."""
+        host = self._last_tokens.copy()
         if not self._pipeline:
-            return host
+            return host, None
         newest = self._pipeline[-1]
-        mask = np.zeros(host.shape[0], bool)
-        for slot, uid in newest.uids.items():
-            r = self._running[slot]
-            if r is not None and r.uid == uid and active[slot]:
-                mask[slot] = True
-        if not mask.any():
-            return host
-        return jnp.where(jnp.asarray(mask), newest.pending.tokens,
-                         jnp.asarray(host))
+        chained = [slot for slot, uid in newest.uids.items()
+                   if active[slot] and self._running[slot] is not None
+                   and self._running[slot].uid == uid]
+        if not chained:
+            return host, None
+        host[chained] = -1
+        return host, newest.pending
 
     def _reconcile_oldest(self) -> int:
         """RECONCILE the oldest in-flight decode step: ONE batched

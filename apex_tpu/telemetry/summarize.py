@@ -26,6 +26,12 @@ __all__ = ["load_records", "summarize_records", "render_summary",
            "render_phase_idle",
            "summarize_trace", "render_trace_summary"]
 
+#: counters read as a share of another: (name, part, whole)
+COUNTER_SHARES = (
+    ("dispatch-ahead engagement", "serving.heartbeat.dispatched_ahead",
+     "serving.decode.steps"),
+)
+
 #: hlo_category substrings that identify collective/communication ops
 COMM_CATEGORIES = ("all-reduce", "all-gather", "all-to-all",
                    "reduce-scatter", "collective", "copy", "send", "recv")
@@ -114,6 +120,11 @@ def render_summary(summary: Dict[str, Any]) -> str:
         for name in sorted(summary["counters"]):
             v = summary["counters"][name]
             lines.append(f"{name[:48]:<48} {v:>14,.0f}")
+        for title, part, whole in COUNTER_SHARES:
+            c = summary["counters"]
+            if c.get(whole):
+                lines.append(f"{title + ' (' + part + ' / ' + whole + ')'}"
+                             f" {c.get(part, 0.0) / c[whole]:.4f}")
     return "\n".join(lines)
 
 

@@ -36,6 +36,7 @@ interpret/reference paths); wall-clock wins are the bench's claim, not
 this file's — here the contract is exactness and accounting.
 """
 
+import collections
 import time
 
 import jax
@@ -96,6 +97,10 @@ def _mixed_stream():
                          (16, 5), (7, 1), (11, 7)]]
 
 
+#: the synchronous beat the dispatch-ahead default is pinned against
+ORACLE = {"pipeline_depth": 0}
+
+
 def _serve(engine, stream, **sched_kw):
     """Run ``stream`` to completion; returns the per-request token
     lists in SUBMISSION order (completion order differs across
@@ -110,11 +115,12 @@ def test_pipeline_depth_validation_and_worker_lifecycle(engine):
     engine.reset()
     with pytest.raises(ValueError, match="pipeline_depth"):
         Scheduler(engine, pipeline_depth=-1)
-    # depth 0 (the default) never spins the worker thread — the sync
-    # oracle path carries zero threading machinery
-    assert Scheduler(engine)._worker is None
-    sched = Scheduler(engine, pipeline_depth=2)
-    assert sched._worker is not None
+    # depth 0 never spins the worker thread — the sync oracle path
+    # carries zero threading machinery
+    assert Scheduler(engine, pipeline_depth=0)._worker is None
+    # the default is the dispatch-ahead beat, one step in flight
+    sched = Scheduler(engine)
+    assert sched.pipeline_depth == 1 and sched._worker is not None
     sched._worker.stop()            # idempotent; finalizer runs it again
 
 
@@ -125,7 +131,7 @@ def test_depth_parity_zero_new_programs_zero_leaks(engine):
     executables (zero new compiled programs), with zero pages leaked
     at drain and an empty pipeline left behind."""
     engine.reset()
-    oracle, sync_sched = _serve(engine, _mixed_stream())
+    oracle, sync_sched = _serve(engine, _mixed_stream(), **ORACLE)
     programs0 = engine.compiled_programs
     for depth in (1, 3):
         engine.reset()
@@ -136,6 +142,154 @@ def test_depth_parity_zero_new_programs_zero_leaks(engine):
             f"depth {depth} traced new programs"
         assert not sched._pipeline, "run() left steps in flight"
         assert sched.auditor.audit(engine)["pages_in_use"] == 0
+    engine.reset()
+
+
+def _backlog_stream(n=10, seed=7):
+    """More requests than slots, prompts of one to three chunks: the
+    backlog cells' traffic at the tests' geometry."""
+    rng = np.random.default_rng(seed)
+    return [Request(prompt=list(rng.integers(1, VOCAB, size=int(ln))),
+                    max_new_tokens=int(b))
+            for ln, b in zip(rng.integers(3, 25, size=n),
+                             rng.integers(4, 14, size=n))]
+
+
+def test_default_beat_is_the_sync_oracle_on_a_chunked_backlog(engine):
+    """What every server runs: ``Scheduler(engine)`` with nothing said
+    is the dispatch-ahead beat, and over a chunked backlog - with an
+    ``eos_id`` that ends requests mid-pipeline, their slots re-occupied
+    by the queue - its greedy streams are bitwise the synchronous
+    beat's, through the same two programs."""
+    engine.reset()
+    probe, _ = _serve(engine, _backlog_stream(), **ORACLE)
+    # an id that some request first emits mid-generation: declared EOS,
+    # it is discovered at a reconcile with a successor step in flight
+    eos_id = next(t for out in probe for i, t in enumerate(out)
+                  if i >= 2 and t not in out[:i])
+    engine.reset()
+    oracle, _ = _serve(engine, _backlog_stream(), eos_id=eos_id, **ORACLE)
+    programs0 = engine.compiled_programs
+    engine.reset()
+    reg = telemetry.MetricsRegistry()
+    reqs = _backlog_stream()
+    try:
+        got, sched = _serve(engine, reqs, eos_id=eos_id, registry=reg)
+    finally:
+        engine.set_registry(None)
+    assert sched.pipeline_depth == 1
+    assert got == oracle, "the default beat diverged from the sync oracle"
+    assert engine.compiled_programs == programs0
+    ended = [i for i, r in enumerate(reqs) if r.finish_reason == "eos"
+             and len(r.output_tokens) >= 3]
+    assert ended and ended[0] < len(reqs) - engine.slots, \
+        "no EOS mid-pipeline with the queue still holding requests"
+    assert reg.snapshot()["counters"].get(
+        "serving.heartbeat.discarded", 0) >= 1
+    assert not sched._pipeline
+    assert sched.auditor.audit(engine)["pages_in_use"] == 0
+    engine.reset()
+
+
+def test_one_decode_launch_a_beat_and_no_op_beside_it(engine):
+    """The chained step costs the host what a synchronous one does: one
+    ``engine.upload`` and one ``engine.launch`` of the decode program a
+    beat (the select of device tokens against host tokens runs INSIDE
+    the program), and nothing the synchronous beat had not compiled is
+    compiled when the dispatch-ahead beat first runs."""
+    from apex_tpu.telemetry import tracing
+    from benchmarks.lib.common import CompileCounter
+
+    engine.reset()
+    _serve(engine, _backlog_stream(4), **ORACLE)        # warm, depth 0
+    engine.reset()
+    compiles = CompileCounter()
+    t0 = time.perf_counter()
+    _serve(engine, _backlog_stream())
+    assert compiles.n == 0, "the dispatch-ahead beat compiled something"
+    recs = tracing.phases.records(since=t0)
+    beats = {r.id for r in recs if r.name == "serve.beat"}
+    launches = collections.Counter(
+        r.root for r in recs if r.name == "engine.launch"
+        and r.args["program"] == "decode")
+    assert launches and set(launches) <= beats
+    assert max(launches.values()) == 1
+    n_programs = sum(1 for r in recs if r.name == "engine.launch")
+    assert sum(1 for r in recs if r.name == "engine.upload") == n_programs
+    # the dispatch's phase says how many steps were in flight behind it
+    noted = [r.args["inflight"] for r in recs if r.name == "serve.decode"
+             and "inflight" in (r.args or {})]
+    assert noted and set(noted) <= {0, 1} and 1 in noted
+    engine.reset()
+
+
+def test_final_chunk_is_retired_at_the_beat_after_its_dispatch(
+        lm_and_params):
+    """Three slots prefilling under ``chunk_budget=1`` while a fourth
+    decodes: the round-robin gives each a chunk every third beat, but a
+    FINAL chunk's first token is read at the top of the very next beat,
+    whichever slot that beat's visit reaches - its request decodes from
+    then on, as in the synchronous beat."""
+    eng = _mk_engine(lm_and_params, slots=4, seed=3)
+    sched = Scheduler(eng, chunk_budget=1)
+    c = Request(prompt=[1, 2], max_new_tokens=40)
+    sched.submit(c)
+    sched.step()
+    sched.step()
+    assert c.status == "running"
+    a = Request(prompt=[7, 8, 9], max_new_tokens=6)            # 1 chunk
+    b = Request(prompt=list(range(1, 24)), max_new_tokens=3)   # 3 chunks
+    d = Request(prompt=list(range(2, 25)), max_new_tokens=3)   # 3 chunks
+    for r in (a, b, d):
+        sched.submit(r)
+    sched.step()            # admits all three; the visit dispatches a's
+    assert a._prefill_pos == 3 and a.output_tokens == []
+    assert sched._pending_prefill[sched._running.index(a)] is not None
+    sched.step()            # the visit is b's; a's token is read first
+    assert len(a.output_tokens) == 1 and a.status == "running"
+    assert a.ttft_s is not None and b._prefill_pos == CHUNK
+    sched.step()            # a decodes beside c while d ingests
+    assert len(a.output_tokens) == 2 and d._prefill_pos == CHUNK
+    sched.run([])
+    assert all(r.status is RequestStatus.FINISHED for r in (a, b, c, d))
+    assert sched.auditor.audit(eng)["pages_in_use"] == 0
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_engagement_counter_over_a_backlog(engine, depth):
+    """``serving.heartbeat.dispatched_ahead`` over
+    ``serving.decode.steps`` is the share of decode steps that went to
+    the device while an earlier one was un-read: nearly all of them
+    with the queue kept full, none in the synchronous beat."""
+    engine.reset()
+    reg = telemetry.MetricsRegistry()
+    engine.set_registry(reg)
+    sched = Scheduler(engine, registry=reg, pipeline_depth=depth,
+                      max_queue=4)
+    stream = iter(_backlog_stream(64, seed=13))
+    try:
+        for _ in range(50):
+            while len(sched._queue) < sched.max_queue:
+                sched.submit(next(stream))
+            sched.step()
+        while sched.pending:
+            sched.step()
+    finally:
+        engine.set_registry(None)
+    counters = reg.snapshot()["counters"]
+    ahead = counters.get("serving.heartbeat.dispatched_ahead", 0)
+    assert counters["serving.decode.steps"] >= 50
+    if depth == 0:
+        assert ahead == 0
+    else:
+        assert ahead / counters["serving.decode.steps"] > 0.9
+    # ``python -m apex_tpu.telemetry summarize`` prints the share
+    from apex_tpu.telemetry.summarize import (render_summary,
+                                              summarize_records)
+    text = render_summary(summarize_records([reg.snapshot()]))
+    share = ahead / counters["serving.decode.steps"]
+    assert ("(serving.heartbeat.dispatched_ahead / serving.decode.steps) "
+            f"{share:.4f}") in text
     engine.reset()
 
 
@@ -161,7 +315,7 @@ def test_eos_mid_pipeline_discards_and_slot_reuse(lm_and_params):
                   Request(prompt=[9, 4, 2, 8], max_new_tokens=6)]
 
     eng.reset()
-    oracle, _ = _serve(eng, mk(), eos_id=eos_id)
+    oracle, _ = _serve(eng, mk(), eos_id=eos_id, **ORACLE)
 
     eng.reset()
     reg = telemetry.MetricsRegistry()
@@ -203,7 +357,7 @@ def test_queue_full_backpressure_parity(engine):
     a stream pushed through run()'s backpressure absorption emits the
     sync path's exact tokens."""
     engine.reset()
-    oracle, _ = _serve(engine, _mixed_stream(), max_queue=2)
+    oracle, _ = _serve(engine, _mixed_stream(), max_queue=2, **ORACLE)
     engine.reset()
     sched = Scheduler(engine, max_queue=2, pipeline_depth=2)
     sched.submit(Request(prompt=[1], max_new_tokens=2))
@@ -241,7 +395,8 @@ def test_speculative_parity_with_threaded_drafter(spec_engine):
     engaged (accepted tokens > 0) and no new programs."""
     eng = spec_engine
     eng.reset()
-    oracle, _ = _serve(eng, _repetitive_stream(), speculative=True)
+    oracle, _ = _serve(eng, _repetitive_stream(), speculative=True,
+                       **ORACLE)
     programs0 = eng.compiled_programs
     eng.reset()
     reqs = _repetitive_stream()
@@ -263,7 +418,7 @@ def test_prefix_hit_stream_parity_with_hash_offload(lm_and_params):
     shared = list(range(1, 17))
     mk = lambda: [Request(prompt=shared + [30 + i], max_new_tokens=6)
                   for i in range(4)]
-    oracle, s0 = _serve(eng, mk(), retain_prefixes=True)
+    oracle, s0 = _serve(eng, mk(), retain_prefixes=True, **ORACLE)
     hits0 = eng.prefix_cache.hits          # cumulative across resets
     eng.reset(clear_prefixes=True)
     got, s1 = _serve(eng, mk(), retain_prefixes=True, pipeline_depth=2)
@@ -283,8 +438,8 @@ def test_chaos_stream_unfaulted_bitwise_and_zero_leaks(engine):
     engine.reset()
     clean_reqs = _mixed_stream()
     Scheduler(engine, fault_policy=FaultPolicy(backoff_base_s=0.0,
-                                               audit_every_n=1)).run(
-        clean_reqs)
+                                               audit_every_n=1),
+              **ORACLE).run(clean_reqs)
     clean = [list(r.output_tokens) for r in clean_reqs]
     traces0 = (engine.chunk_traces, engine.decode_traces)
 
@@ -338,7 +493,7 @@ def test_requeued_request_never_consumes_stale_inflight_tokens(
     flips to running the same beat it admits."""
     eng = _mk_engine(lm_and_params, slots=1, seed=23)
     clean = Request(prompt=[4, 9, 1], max_new_tokens=8)
-    Scheduler(eng).run([clean])
+    Scheduler(eng, **ORACLE).run([clean])
 
     eng.reset()
     reg = telemetry.MetricsRegistry()
@@ -379,7 +534,7 @@ def test_deferred_reconcile_failure_is_contained(lm_and_params):
     produce it for real)."""
     eng = _mk_engine(lm_and_params, slots=1, seed=31)
     clean = Request(prompt=[6, 2, 7], max_new_tokens=6)
-    Scheduler(eng).run([clean])
+    Scheduler(eng, **ORACLE).run([clean])
 
     eng.reset()
     orig = eng.decode_reconcile

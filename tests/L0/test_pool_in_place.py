@@ -55,7 +55,7 @@ def _program(eng, name):
     f32 = np.zeros(SLOTS, np.float32)
     i32 = np.zeros(SLOTS, np.int32)
     if name == "decode":
-        return eng._paged_decode_impl, (i32, eng._page_table,
+        return eng._paged_decode_impl, (i32, i32, eng._page_table,
                                         eng._host_len, f32, f32, eng._key)
     if name == "chunk":
         return eng._paged_chunk_impl, (

@@ -193,7 +193,7 @@ def test_chunked_prefill_interleaves_with_decode(chunk_engine):
     stall it for the whole prefill."""
     eng = chunk_engine
     eng.reset()
-    sched = Scheduler(eng)
+    sched = Scheduler(eng, pipeline_depth=0)    # the synchronous order
     a = Request(prompt=[3, 1, 4], max_new_tokens=50)
     sched.submit(a)
     sched.step()                      # admit + single final chunk + decode
@@ -212,6 +212,47 @@ def test_chunked_prefill_interleaves_with_decode(chunk_engine):
     assert b.ttft_s is not None and b.chunks == 3
     # the budget caps chunk work per heartbeat at one chunk
     assert eng.chunks_for(len(b.prompt)) == 3
+
+
+def test_chunked_prefill_interleaves_with_decode_dispatch_ahead(
+        chunk_engine):
+    """The same head-of-line fix under the default dispatch-ahead beat:
+    the in-flight decode gains a token on EVERY tick of b's ingestion;
+    what differs from the synchronous order is only WHEN the host reads
+    - a chunk is counted, and a final chunk's token emitted, at the top
+    of the beat after the one that dispatched it."""
+    eng = chunk_engine
+    eng.reset()
+    sched = Scheduler(eng)
+    assert sched.pipeline_depth == 1
+    a = Request(prompt=[3, 1, 4], max_new_tokens=50)
+    sched.submit(a)
+    sched.step()                      # admit + final chunk dispatched
+    assert a.status == "prefilling" and a.output_tokens == []
+    sched.step()                      # token read; first decode dispatched
+    assert a.status == "running" and len(a.output_tokens) == 1
+    sched.step()                      # second dispatched, first read
+    assert len(a.output_tokens) == 2
+    b = Request(prompt=list(range(1, 25)), max_new_tokens=4)  # 3 chunks
+    sched.submit(b)
+    for tick in range(1, 4):
+        n_before = len(a.output_tokens)
+        sched.step()
+        assert len(a.output_tokens) == n_before + 1, \
+            f"decode stalled at tick {tick} during b's prefill"
+        # one chunk DISPATCHED a beat, the one before it read
+        assert b._prefill_pos == 8 * tick and b.chunks == tick - 1
+    assert b.status == "prefilling" and b.output_tokens == []
+    n_before = len(a.output_tokens)
+    sched.step()                      # b's first token, read at the top
+    assert b.status == "running" and len(b.output_tokens) == 1
+    assert b.ttft_s is not None and b.chunks == 3
+    assert len(a.output_tokens) == n_before + 1
+    sched.step()                      # b decodes beside a from here on
+    assert len(b.output_tokens) == 2
+    assert len(a.output_tokens) == n_before + 2
+    sched.run([])
+    eng.reset()
 
 
 def test_chunked_ttft_decomposition_and_request_records(chunk_engine):
@@ -291,7 +332,7 @@ def test_chunk_budget_caps_ingestion_only_while_decoding(chunk_engine):
     straight to full ingestion instead of idling between heartbeats."""
     eng = chunk_engine
     eng.reset()
-    sched = Scheduler(eng, chunk_budget=2)
+    sched = Scheduler(eng, chunk_budget=2, pipeline_depth=0)
     c = Request(prompt=[1, 2], max_new_tokens=50)
     sched.submit(c)
     sched.step()                               # c: 1 chunk → decoding
@@ -307,10 +348,41 @@ def test_chunk_budget_caps_ingestion_only_while_decoding(chunk_engine):
     assert a.status == "running" and b.status == "running"
 
 
+def test_chunk_budget_caps_ingestion_while_decoding_dispatch_ahead(
+        chunk_engine):
+    """The budget under the default beat: at most ``chunk_budget``
+    chunks are DISPATCHED a tick beside a decode in flight, each read
+    at the top of the next."""
+    eng = chunk_engine
+    eng.reset()
+    sched = Scheduler(eng, chunk_budget=2)
+    c = Request(prompt=[1, 2], max_new_tokens=50)
+    sched.submit(c)
+    sched.step()
+    sched.step()                               # c's token read → decoding
+    assert c.status == "running"
+    a = Request(prompt=list(range(1, 17)), max_new_tokens=3)   # 2 chunks
+    b = Request(prompt=list(range(2, 18)), max_new_tokens=3)   # 2 chunks
+    sched.submit(a)
+    sched.submit(b)
+    sched.step()
+    assert a._prefill_pos == 8 and b._prefill_pos == 8   # one chunk EACH
+    assert a.chunks == 0 and b.chunks == 0
+    sched.step()
+    assert a._prefill_pos == 16 and b._prefill_pos == 16
+    assert a.chunks == 1 and b.chunks == 1
+    sched.step()                               # both final chunks read
+    assert a.chunks == 2 and b.chunks == 2
+    assert a.status == "running" and b.status == "running"
+    assert len(a.output_tokens) == 1 and len(b.output_tokens) == 1
+    sched.run([])
+    eng.reset()
+
+
 def test_cold_queue_bursts_to_full_ingestion(chunk_engine):
     eng = chunk_engine
     eng.reset()
-    sched = Scheduler(eng)                     # chunk_budget=1
+    sched = Scheduler(eng, pipeline_depth=0)   # chunk_budget=1
     a = Request(prompt=list(range(1, 24)), max_new_tokens=4)   # 3 chunks
     sched.submit(a)
     sched.step()
@@ -320,6 +392,32 @@ def test_cold_queue_bursts_to_full_ingestion(chunk_engine):
     # bound on in-flight stalls is never violated
     assert a.chunks == 3
     assert a.status == "running" and len(a.output_tokens) == 2
+
+
+def test_cold_queue_bursts_to_full_ingestion_dispatch_ahead(chunk_engine):
+    """The burst under the default beat: one tick puts ALL 3 chunks on
+    the device; it stops at the first FINAL chunk dispatched (that slot
+    decodes as soon as the chunk is read), so a second cold prompt
+    waits for the budget like any chunk beside a decode."""
+    eng = chunk_engine
+    eng.reset()
+    sched = Scheduler(eng)                     # chunk_budget=1
+    a = Request(prompt=list(range(1, 24)), max_new_tokens=4)   # 3 chunks
+    b = Request(prompt=list(range(2, 20)), max_new_tokens=2)   # 3 chunks
+    sched.submit(a)
+    sched.submit(b)
+    sched.step()
+    assert a._prefill_pos == 23 and a.chunks == 2
+    assert a.status == "prefilling" and a.output_tokens == []
+    assert b._prefill_pos == 16                # its turns between a's
+    sched.step()                               # a's first token is read
+    assert a.chunks == 3
+    assert a.status == "running" and len(a.output_tokens) == 1
+    assert b._prefill_pos == 18                # one chunk a beat now
+    sched.step()
+    assert len(a.output_tokens) == 2
+    sched.run([])
+    eng.reset()
 
 
 # ----------------------------------------------------------------- engine

@@ -359,6 +359,7 @@ def test_tp2_collective_counts_from_hlo(lm_and_params):
     mp = eng.max_pages
     decode = eng._jit_decode.lower(
         eng.params, eng.cache, jnp.zeros(3, jnp.int32),
+        jnp.zeros(3, jnp.int32),
         jnp.zeros((3, mp), jnp.int32), jnp.zeros(3, jnp.int32),
         jnp.zeros(3, jnp.float32), jnp.zeros(3, jnp.float32),
         key).compile().as_text()

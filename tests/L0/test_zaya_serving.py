@@ -169,6 +169,52 @@ def test_scheduler_serves_more_requests_than_slots(weights):
     assert eng.pool_stats()["pages_in_use"] == 0
 
 
+def test_default_beat_is_the_sync_oracle_with_slot_state(engine, weights):
+    """The dispatch-ahead default against ``pipeline_depth=0`` for a
+    model with per-slot state, over a chunked backlog in which an
+    ``eos_id`` ends requests with a speculated successor step in flight:
+    that step wrote K/V and convolution state for a slot since freed,
+    and the next occupant's chunk at offset 0 must reset the state
+    before anything reads it - so the streams are bitwise equal and the
+    re-occupied slot's tokens are still the reference's."""
+    def stream():
+        return [serving.Request(prompt=_prompt(60 + i, n), max_new_tokens=6,
+                                temperature=0.0)
+                for i, n in enumerate((40, 129, 70, 200, 33, 131))]
+
+    def serve(**kw):
+        for s in range(SLOTS):
+            engine.release_slot(s)
+        reqs = stream()
+        sched = serving.Scheduler(engine, max_queue=8, **kw)
+        sched.run(reqs)
+        assert [r.status.value for r in reqs] == ["finished"] * len(reqs)
+        return reqs, sched
+
+    probe, _ = serve(pipeline_depth=0)
+    # an id one of the FIRST occupants first emits mid-generation, so the
+    # slot it frees is taken by a request still queued
+    eos_id = next(t for r in probe[:SLOTS]
+                  for i, t in enumerate(r.output_tokens)
+                  if i >= 2 and t not in r.output_tokens[:i])
+    oracle, _ = serve(pipeline_depth=0, eos_id=eos_id)
+    discarded0 = engine._registry.counters.get(
+        "serving.heartbeat.discarded", 0)
+    got, sched = serve(eos_id=eos_id)
+    assert sched.pipeline_depth == 1
+    assert [r.output_tokens for r in got] == \
+        [r.output_tokens for r in oracle]
+    assert any(r.finish_reason == "eos" and len(r.output_tokens) >= 3
+               for r in got[:SLOTS])
+    assert engine._registry.counters["serving.heartbeat.discarded"] \
+        > discarded0, "no speculated step was in flight at an EOS"
+    for r in got[SLOTS:]:
+        assert _gaps(weights, list(r.prompt),
+                     list(r.output_tokens)).max() < LOGIT_TOL
+    assert (engine.chunk_traces, engine.decode_traces) == (1, 1)
+    assert engine.pool_stats()["pages_in_use"] == 0
+
+
 def test_the_pool_and_the_state_take_their_geometry_from_the_model(engine):
     c = engine.cache
     assert c.k.shape == (3, engine.num_pages, 2, 16, CHUNK)   # 2 K/V heads
