@@ -13,6 +13,7 @@ from .resnet import (  # noqa: F401
     ResNet152, create_model)
 from .transformer_lm import (  # noqa: F401
     TransformerBlock, TransformerLM, create_lm)
+from .qwen3_next import Qwen3NextLM  # noqa: F401
 from .zaya import ZayaLM  # noqa: F401
 
 
@@ -24,7 +25,15 @@ def build_lm(config: dict, **overrides):
     ``num_attention_heads``, ``num_key_value_heads``, ``head_dim``,
     ``num_experts``, ``moe_intermediate_size``, ``router_hidden_size``,
     ``cca_time0/1``, ``rope_parameters``, ``partial_rotary_factor``,
-    ``rms_norm_eps``, ``max_position_embeddings``, ``vocab_size``).
+    ``rms_norm_eps``, ``max_position_embeddings``, ``vocab_size``),
+    ``qwen3_next`` -> :class:`Qwen3NextLM` (the full-attention keys as
+    ``zaya``'s plus ``full_attention_interval``, ``linear_num_key_heads``,
+    ``linear_num_value_heads``, ``linear_key_head_dim``,
+    ``linear_value_head_dim``, ``linear_conv_kernel_dim``,
+    ``num_experts_per_tok``, ``shared_expert_intermediate_size``,
+    ``rope_theta``; ``num_experts`` is the count this chip HOLDS where the
+    file states ``published.num_experts``, the router's width: the first
+    ``num_experts`` ids are held unless ``experts_held`` is given).
     ``overrides`` are fields of the model class (``dtype``,
     ``experts_held``, ...). An unknown ``model_type`` raises."""
     kind = config.get("model_type")
@@ -58,12 +67,49 @@ def build_lm(config: dict, **overrides):
             rms_eps=float(config["rms_norm_eps"]),
             max_seq_len=int(config["max_position_embeddings"]),
             **overrides)
+    if kind == "qwen3_next":
+        if not config.get("norm_topk_prob", True) \
+                or config.get("mlp_only_layers") \
+                or int(config.get("decoder_sparse_step", 1)) != 1 \
+                or config.get("rope_scaling") \
+                or config.get("tie_word_embeddings"):
+            raise NotImplementedError(
+                "build_lm: qwen3_next as published only - every layer "
+                "sparse, renormalised top-k weights, default rotary "
+                "scaling, an untied head")
+        held = int(config["num_experts"])
+        routed = int(config.get("published", {}).get("num_experts", held))
+        if held != routed:
+            overrides.setdefault("experts_held", tuple(range(held)))
+        return Qwen3NextLM(
+            vocab_size=int(config["vocab_size"]),
+            hidden=int(config["hidden_size"]),
+            num_layers=int(config["num_hidden_layers"]),
+            full_attention_interval=int(config["full_attention_interval"]),
+            num_heads=int(config["num_attention_heads"]),
+            num_kv_heads=int(config["num_key_value_heads"]),
+            head_dim=int(config["head_dim"]),
+            partial_rotary_factor=float(config["partial_rotary_factor"]),
+            rope_theta=float(config["rope_theta"]),
+            lin_key_heads=int(config["linear_num_key_heads"]),
+            lin_value_heads=int(config["linear_num_value_heads"]),
+            lin_key_dim=int(config["linear_key_head_dim"]),
+            lin_value_dim=int(config["linear_value_head_dim"]),
+            conv_kernel=int(config["linear_conv_kernel_dim"]),
+            num_experts=routed,
+            experts_per_token=int(config["num_experts_per_tok"]),
+            expert_width=int(config["moe_intermediate_size"]),
+            shared_width=int(config["shared_expert_intermediate_size"]),
+            rms_eps=float(config["rms_norm_eps"]),
+            max_seq_len=int(config["max_position_embeddings"]),
+            **overrides)
     raise ValueError(f"build_lm: no model for model_type {kind!r} "
-                     "(have: 'gpt2', 'zaya')")
+                     "(have: 'gpt2', 'zaya', 'qwen3_next')")
 
 __all__ = [
     "BasicBlock", "Bottleneck", "ResNet", "ResNet18", "ResNet34", "ResNet50",
     "ResNet101", "ResNet152", "create_model",
-    "TransformerLM", "TransformerBlock", "create_lm", "ZayaLM", "build_lm",
+    "TransformerLM", "TransformerBlock", "create_lm", "ZayaLM",
+    "Qwen3NextLM", "build_lm",
     "BertConfig", "BertModel", "BertForPreTraining", "create_bert",
 ]

@@ -532,6 +532,13 @@ class TransformerLM(nn.Module):
     # Serving-only: int8 kernels cannot train.
     weight_quant: bool = False
 
+    def cache_spec(self) -> dict:
+        """What the serving engine holds for this model
+        (:class:`~apex_tpu.serving.kv_cache.CacheSpec`): every layer
+        pages of all its heads, and no per-slot state."""
+        return {"page_layers": self.num_layers, "kv_heads": self.num_heads,
+                "head_dim": self.hidden // self.num_heads}
+
     @nn.compact
     def __call__(self, tokens, *, train: bool = True,
                  features_only: bool = False, cache=None, positions=None,

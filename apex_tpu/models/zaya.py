@@ -101,6 +101,63 @@ def _last_valid(x, n_valid):
         row, i, keepdims=False))(x, idx)
 
 
+def _attend(q, k, v, cache, positions, layer, scale):
+    """Causal attention of ``q [B, nq, S, d]`` over ``k, v [B, nk, S, d]``
+    (``nq // nk`` query heads a K/V head). With the paged ``cache =
+    (k_pool, v_pool, page_table)`` the new K/V are written IN PLACE into
+    pool layer ``layer`` at ``positions [B]`` (one token: its write page,
+    read-modify-write; a chunk: whole pages) and attention reads the pool
+    through the table; without, the sequence attends itself. Returns
+    ``(ctx [B, nq, S, d], (k_pool, v_pool) | None)``."""
+    B, _, S, _ = q.shape
+    if cache is not None:
+        from apex_tpu.kernels.decode_attention import \
+            paged_decode_attention
+        from apex_tpu.kernels.prefill_attention import \
+            paged_prefill_attention
+        k_pool, v_pool, page_table = cache
+        page_len = k_pool.shape[4]
+        L = page_table.shape[1] * page_len
+        p0 = jnp.clip(jnp.asarray(positions, jnp.int32), 0, L - S)
+        if S == 1:
+            page_ids = jnp.take_along_axis(
+                page_table, (p0 // page_len)[:, None], axis=1)[:, 0]
+            off = p0 % page_len
+            k_pool = _pool_write_tokens(
+                k_pool, layer, page_ids, off,
+                jnp.asarray(k[:, :, 0], k_pool.dtype))
+            v_pool = _pool_write_tokens(
+                v_pool, layer, page_ids, off,
+                jnp.asarray(v[:, :, 0], v_pool.dtype))
+            ctx = paged_decode_attention(
+                q[:, :, 0], k_pool, v_pool, page_table, p0 + 1,
+                scale=scale, layer=layer)[:, :, None]
+        else:
+            if S % page_len:
+                raise ValueError(
+                    f"paged chunk prefill needs S ({S}) to be a "
+                    f"multiple of page_len ({page_len})")
+            idx = (p0 // page_len)[:, None] + jnp.arange(
+                S // page_len, dtype=jnp.int32)[None, :]
+            pages = jnp.take_along_axis(page_table, idx, axis=1)
+            k_pool = _pool_write_pages(
+                k_pool, layer, pages, jnp.asarray(k, k_pool.dtype))
+            v_pool = _pool_write_pages(
+                v_pool, layer, pages, jnp.asarray(v, v_pool.dtype))
+            ctx = paged_prefill_attention(
+                q, k_pool, v_pool, page_table, p0, scale=scale,
+                layer=layer)
+        aux = (k_pool, v_pool)
+    else:
+        from apex_tpu.kernels.prefill_attention import \
+            prefill_attention
+        ctx = prefill_attention(q, k, v,
+                                jnp.zeros((B,), jnp.int32),
+                                scale=scale)
+        aux = None
+    return ctx, aux
+
+
 _INITS = {"ones": nn.initializers.ones, "zeros": nn.initializers.zeros,
           "lecun": nn.initializers.lecun_normal(),
           "normal02": nn.initializers.normal(0.02)}
@@ -182,6 +239,18 @@ class ZayaLM(nn.Module):
         ``u Wv2`` (``head_dim``)."""
         return 2 * self.latent_heads * self.head_dim + self.head_dim
 
+    def cache_spec(self) -> dict:
+        """What the serving engine holds for this model
+        (:class:`~apex_tpu.serving.kv_cache.CacheSpec`): every layer
+        pages of ``num_kv_heads`` x ``head_dim`` and one ``rows`` block
+        of :attr:`slot_state_width` values in the engine's half dtype."""
+        return {"page_layers": self.num_layers,
+                "kv_heads": self.num_kv_heads, "head_dim": self.head_dim,
+                "state": [("rows", self.num_layers,
+                           (self.slot_state_width,), None)],
+                "counter_layers": self.num_layers,
+                "num_experts": self.num_experts}
+
     # ------------------------------------------------------------ sublayers
     def _attention(self, u, lp, cdt, *, layer, cache, positions, prev,
                    n_valid):
@@ -234,51 +303,7 @@ class ZayaLM(nn.Module):
                                    _last_valid(v2, n_valid)], -1)
         scale = 1.0 / np.sqrt(d)
         with jax.named_scope("cca.attn"):
-            if cache is not None:
-                from apex_tpu.kernels.decode_attention import \
-                    paged_decode_attention
-                from apex_tpu.kernels.prefill_attention import \
-                    paged_prefill_attention
-                k_pool, v_pool, page_table = cache
-                page_len = k_pool.shape[4]
-                L = page_table.shape[1] * page_len
-                p0 = jnp.clip(jnp.asarray(positions, jnp.int32), 0, L - S)
-                if S == 1:
-                    page_ids = jnp.take_along_axis(
-                        page_table, (p0 // page_len)[:, None], axis=1)[:, 0]
-                    off = p0 % page_len
-                    k_pool = _pool_write_tokens(
-                        k_pool, layer, page_ids, off,
-                        jnp.asarray(k[:, :, 0], k_pool.dtype))
-                    v_pool = _pool_write_tokens(
-                        v_pool, layer, page_ids, off,
-                        jnp.asarray(v[:, :, 0], v_pool.dtype))
-                    ctx = paged_decode_attention(
-                        q[:, :, 0], k_pool, v_pool, page_table, p0 + 1,
-                        scale=scale, layer=layer)[:, :, None]
-                else:
-                    if S % page_len:
-                        raise ValueError(
-                            f"paged chunk prefill needs S ({S}) to be a "
-                            f"multiple of page_len ({page_len})")
-                    idx = (p0 // page_len)[:, None] + jnp.arange(
-                        S // page_len, dtype=jnp.int32)[None, :]
-                    pages = jnp.take_along_axis(page_table, idx, axis=1)
-                    k_pool = _pool_write_pages(
-                        k_pool, layer, pages, jnp.asarray(k, k_pool.dtype))
-                    v_pool = _pool_write_pages(
-                        v_pool, layer, pages, jnp.asarray(v, v_pool.dtype))
-                    ctx = paged_prefill_attention(
-                        q, k_pool, v_pool, page_table, p0, scale=scale,
-                        layer=layer)
-                aux = (k_pool, v_pool)
-            else:
-                from apex_tpu.kernels.prefill_attention import \
-                    prefill_attention
-                ctx = prefill_attention(q, k, v,
-                                        jnp.zeros((B,), jnp.int32),
-                                        scale=scale)
-                aux = None
+            ctx, aux = _attend(q, k, v, cache, positions, layer, scale)
             ctx = jnp.moveaxis(ctx, 1, 2).reshape(B, S, nq * d)
             out = jnp.dot(jnp.asarray(ctx, cdt), jnp.asarray(lp["wo"], cdt))
         return out, aux, row
@@ -346,7 +371,8 @@ class ZayaLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False, cache=None,
-                 positions=None, state=None, n_valid=None, valid=None):
+                 positions=None, state=None, addr=None, n_valid=None,
+                 valid=None):
         if train:
             raise NotImplementedError(
                 "ZayaLM is a serving model: the expert layer's training "
@@ -366,7 +392,13 @@ class ZayaLM(nn.Module):
                        name="wte")()["embedding"]
         layer_spec = self._layer_spec()
         x = jnp.asarray(emb[tokens], cdt)
-        if state is None:
+        # the serving engine hands the state blocks whole with their
+        # addressing (kv_cache.SlotAddr); a caller without slots hands
+        # the batch's own rows, or nothing (a sequence starts from zeros)
+        blocks = state if isinstance(state, dict) else None
+        if blocks is not None:
+            state = addr.read(blocks["rows"])
+        elif state is None:
             state = jnp.zeros((self.num_layers, B, W), cdt)
         if valid is None:
             valid = jnp.ones((B, S), bool) if n_valid is None else (
@@ -412,6 +444,8 @@ class ZayaLM(nn.Module):
         # float32 (the embedding is never widened whole)
         logits = _einsum32("bsh,vh->bsv", x, jnp.asarray(emb, cdt))
         new_state = jnp.stack(rows).astype(state.dtype)      # [L, B, W]
+        if blocks is not None:
+            new_state = {"rows": addr.write(blocks["rows"], new_state)}
         counts = jnp.stack(counts)                           # [L, E]
         if pools is not None:
             return logits, pools + (new_state, counts)

@@ -194,7 +194,8 @@ from .faults import (FaultPlan, FaultPolicy, FaultSpec, InjectedFault,
 from .fleet import FleetController, WorkerDied
 from .host_tier import (HostTier, SwapWorker, record_from_wire,
                         record_to_wire)
-from .kv_cache import PagedKVCache, PagePool, SlotState
+from .kv_cache import (CacheSpec, PagedKVCache, PagePool, SlotAddr,
+                       SlotState, StateBlock)
 from .kv_quant import KVQuantConfig
 from .lora import LoRAConfig, LoRAManager
 from .prefix_cache import PrefixCache, PrefixMatch
@@ -211,7 +212,8 @@ __all__ = ["DeadlineUnmeetable", "DraftWorker", "Engine", "FaultPlan",
            "FaultPolicy",
            "FaultSpec", "FleetController", "HostTier", "InjectedFault",
            "KVQuantConfig", "LoRAConfig", "LoRAManager",
-           "PagedKVCache", "PagePool", "SlotState",
+           "PagedKVCache", "PagePool", "SlotState", "SlotAddr", "CacheSpec",
+           "StateBlock",
            "PendingDecode", "PoolAuditor", "PoolInvariantError",
            "PrefixCache", "PrefixMatch", "QueueFull", "Request",
            "RequestStatus", "Router", "SLOConfig", "Scheduler",

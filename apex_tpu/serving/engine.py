@@ -165,7 +165,8 @@ from apex_tpu.log_util import get_logger
 from apex_tpu.telemetry import tracing
 
 from .host_tier import HostTier, SwapWorker
-from .kv_cache import PagedKVCache, PagePool, SlotState
+from .kv_cache import (CacheSpec, PagedKVCache, PagePool, SlotAddr,
+                       SlotState)
 from .kv_quant import KVQuantConfig
 from .prefix_cache import PrefixCache
 from .speculative import SpecConfig
@@ -278,8 +279,14 @@ class Engine:
         A flax module with the cache-threading contract of
         :class:`apex_tpu.models.transformer_lm.TransformerLM`
         (``cache``/``positions`` chunk prefill and decode) and the
-        geometry attributes ``num_layers``/``num_heads``/``hidden``/
-        ``max_seq_len``.
+        geometry attributes ``num_heads``/``hidden``/``max_seq_len``.
+        What it needs held is its ``cache_spec()``
+        (:class:`~apex_tpu.serving.kv_cache.CacheSpec`: the layers that
+        hold pages, of what K/V heads, and the blocks of per-slot state
+        some layers keep; without one: pages of all its heads on each
+        of ``num_layers`` layers). A model whose spec has state takes
+        ``state``/``addr`` besides and returns the updated blocks and
+        its tokens-per-expert counts with the pools.
     params:
         The model's parameter pytree (e.g. a train state's params).
         Cast once through ``policy.cast_params`` — by default to the
@@ -488,18 +495,20 @@ class Engine:
         self.top_k = int(top_k)
         hidden = int(model.hidden)
         heads = int(model.num_heads)
-        layers = int(model.num_layers)
-        # the pool's geometry is the MODEL's K/V geometry: its K/V heads
-        # (fewer than its query heads under grouped-query attention) and
-        # its own head size where it states one
-        kv_heads = int(getattr(model, "num_kv_heads", heads))
-        head_dim = int(getattr(model, "head_dim", hidden // heads))
+        # the pool's and the state's geometry are the MODEL's, stated per
+        # kind of layer (kv_cache.CacheSpec): which of its layers hold
+        # pages, of how many K/V heads (fewer than its query heads under
+        # grouped-query attention) of what size, and which hold blocks of
+        # per-slot state, of what shape and dtype
+        self.cache_spec = spec_ = CacheSpec.of(model)
+        layers = spec_.page_layers
+        kv_heads, head_dim = spec_.kv_heads, spec_.head_dim
         # per-slot state beside the pages (kv_cache.SlotState): what is
         # not built for it is refused by name HERE, never run wrong
-        self.slot_state_width = int(getattr(model, "slot_state_width", 0))
+        self.slot_state = bool(spec_.state)
         self.model_kind = str(getattr(model, "model_kind",
                                       type(model).__name__))
-        if self.slot_state_width:
+        if self.slot_state:
             for on, what in (
                     (prefix_pool > 0, "prefix_cache retention "
                      "(prefix_pool > 0)"),
@@ -515,8 +524,8 @@ class Engine:
                     raise NotImplementedError(
                         f"serving.Engine: {what} is not built for a model "
                         f"with per-slot state ({self.model_kind!r}: "
-                        f"{self.slot_state_width} values a slot and "
-                        "layer beside its pages); it re-enters or "
+                        f"{', '.join(b.name for b in spec_.state)} beside "
+                        "its pages); it re-enters or "
                         "re-shapes a request from pages alone, and the "
                         "slot's state would be wrong")
         # quantized-cache storage tier (independent of the COMPUTE half
@@ -626,11 +635,9 @@ class Engine:
         self.num_pages = num_pages
         if mesh is None:
             state = None
-            if self.slot_state_width:
-                state = SlotState.create(
-                    layers=layers, slots=self.slots,
-                    width=self.slot_state_width, dtype=half,
-                    num_experts=int(getattr(model, "num_experts", 0)))
+            if self.slot_state:
+                state = SlotState.create(spec_, slots=self.slots,
+                                         dtype=half)
             self.cache = PagedKVCache.create(
                 layers=layers, num_pages=num_pages, heads=kv_heads,
                 page_len=page_len, head_dim=head_dim,
@@ -800,7 +807,7 @@ class Engine:
         # wraps nothing — the verbatim single-chip programs
         # a model with per-slot state runs the same two programs
         # with the state threaded through (one more operand each)
-        stateful = bool(self.slot_state_width)
+        stateful = self.slot_state
         self._jit_decode = jax.jit(
             self._state_decode_impl if stateful else
             self._tp_wrap(self._paged_decode_impl, 2),
@@ -971,11 +978,12 @@ class Engine:
             * np.dtype(c.dtype).itemsize * 2
         self._registry.gauge_set("serving.kv.bytes_per_token",
                                  float(per_token))
+        self._registry.gauge_set("serving.kv.page_layers", float(c.layers))
         if getattr(c, "state", None) is not None:
             # what a slot holds beside its pages, whatever its length
             self._registry.gauge_set("serving.state.bytes_per_slot",
                                      float(c.state.bytes_per_slot()))
-            self._registry.gauge_set("serving.kv.state_bytes",
+            self._registry.gauge_set("serving.state.bytes",
                                      float(c.state.nbytes()))
             experts = c.state.expert_tokens.shape[1]
             if experts:
@@ -983,6 +991,9 @@ class Engine:
                 self._registry.gauge_set(
                     "serving.moe.experts_held",
                     float(experts if held is None else len(held)))
+                self._registry.gauge_set(
+                    "serving.moe.experts_per_token",
+                    float(getattr(self._model, "experts_per_token", 1)))
         if c.k_scale is not None:
             from .kv_quant import QMAX
             absmax = max(float(jnp.max(c.k_scale)),
@@ -1249,22 +1260,26 @@ class Engine:
 
     # ------------------------- compiled bodies (paged, per-slot state)
     # The two heartbeat programs for a model that keeps state per slot
-    # beside its pages (kv_cache.SlotState; models.zaya.ZayaLM). Same
-    # operands as the paged bodies above plus ONE trailing operand: the
-    # slot a chunk belongs to, or the decode batch's active
-    # mask (a slot that is mid-prefill rides the decode batch with its
-    # real page table, and its state must not move). The state rides in
-    # the donated cache pytree and is written in place like the pool.
-    def _state_apply(self, params, state, tokens, rows, **kw):
-        """One model call with the slot rows ``[layers, B, W]`` in; the
-        pools and expert counters updated, the rows the call leaves
-        out."""
-        logits, (k2, v2, rows2, counts) = self._model.apply(
-            {"params": params}, tokens, train=False, state=rows, **kw)
-        st = state
+    # beside its pages (kv_cache.SlotState: models.zaya.ZayaLM,
+    # models.qwen3_next.Qwen3NextLM). Same operands as the paged bodies
+    # above plus ONE trailing operand: the slot a chunk belongs to, or
+    # the decode batch's active mask (a slot that is mid-prefill rides
+    # the decode batch with its real page table, and its state must not
+    # move). The state rides in the donated cache pytree; the MODEL reads
+    # and writes its blocks where they lie, addressed by a SlotAddr - a
+    # small block through a select, a large one by its own kernel - and
+    # hands the same buffers back, like the pool.
+    def _state_apply(self, params, cache, tokens, addr, page_table, **kw):
+        """One model call with the state blocks and their addressing in;
+        the cache with pools, blocks and expert counters updated out."""
+        st = cache.state
+        logits, (k2, v2, blocks, counts) = self._model.apply(
+            {"params": params}, tokens, train=False, state=st.blocks,
+            addr=addr, cache=(cache.k, cache.v, page_table), **kw)
+        st = st.replace(blocks=blocks)
         if st.expert_tokens.shape[1]:
             st = st.replace(expert_tokens=st.expert_tokens + counts)
-        return logits, k2, v2, rows2, st
+        return logits, cache.replace(k=k2, v=v2, state=st)
 
     def _state_chunk_impl(self, params, cache, tokens, pt_row, offset,
                           n_valid, temperature, fault_bias, key, slot):
@@ -1274,16 +1289,10 @@ class Engine:
         # state of its own last VALID position; the chunk at offset 0
         # admits the request and starts from zeros, whatever the slot's
         # last tenant left behind
-        rows = jax.lax.dynamic_slice_in_dim(cache.state.rows, slot, 1,
-                                            axis=1)
-        rows = jnp.where(offset == 0, jnp.zeros_like(rows), rows)
-        logits, k2, v2, rows, st = self._state_apply(
-            params, cache.state, tokens, rows,
-            cache=(cache.k, cache.v, pt_row), positions=offset[None],
-            n_valid=n_valid[None])
-        st = st.replace(rows=jax.lax.dynamic_update_slice_in_dim(
-            st.rows, jnp.asarray(rows, st.rows.dtype), slot, axis=1))
-        cache = cache.replace(k=k2, v=v2, state=st)
+        logits, cache = self._state_apply(
+            params, cache, tokens,
+            SlotAddr(slot=jnp.asarray(slot, jnp.int32), fresh=offset == 0),
+            pt_row, positions=offset[None], n_valid=n_valid[None])
         # the model returned the logits of the last VALID row only
         last = jnp.asarray(logits[0, 0], jnp.float32) + fault_bias
         finite = jnp.all(jnp.isfinite(last))
@@ -1297,26 +1306,21 @@ class Engine:
         self.decode_traces += 1     # python body runs at trace time only
         last_tokens = self._chain_tokens(last_tokens, prev_tokens)
         positions = jnp.minimum(lengths, self.max_len - 1)
-        old = cache.state.rows
-        logits, k2, v2, rows, st = self._state_apply(
-            params, cache.state, last_tokens[:, None], old,
-            cache=(cache.k, cache.v, page_table), positions=positions,
-            valid=active[:, None])
-        # only a slot that decoded moves its state: an idle slot's is
-        # dead until admission zeroes it, a prefilling slot's is live
-        st = st.replace(rows=jnp.where(active[None, :, None],
-                                       jnp.asarray(rows, old.dtype), old))
+        # only a slot that decoded moves its state (SlotAddr.active)
+        logits, cache = self._state_apply(
+            params, cache, last_tokens[:, None], SlotAddr(active=active),
+            page_table, positions=positions, valid=active[:, None])
         rows_ = jnp.asarray(logits[:, 0, :], jnp.float32) \
             + fault_bias[:, None]
         finite = jnp.all(jnp.isfinite(rows_), axis=-1)        # [slots]
         tokens = sample_tokens(rows_, temperature, key, self.top_k)
-        return cache.replace(k=k2, v=v2, state=st), tokens, finite
+        return cache, tokens, finite
 
     def _slot_arg(self, slot: int):
         """The trailing operand of a stateful engine's chunk call (its
         slot); nothing on a model without slot state, whose
         programs keep the operands they always had."""
-        return (np.int32(slot),) if self.slot_state_width else ()
+        return (np.int32(slot),) if self.slot_state else ()
 
     def moe_tokens_per_expert(self) -> Optional[np.ndarray]:
         """Tokens routed to each expert by every program since the
@@ -1325,8 +1329,12 @@ class Engine:
         asked (one small transfer; never in the beat). None for a model
         with no expert layer. Sets the counters
         ``serving.moe.tokens_routed`` (a layer's total: every token is
-        routed once a layer) and ``serving.moe.tokens_per_expert.e<i>``
-        (expert ``i``'s sum over the layers)."""
+        routed ``experts_per_token`` times a layer),
+        ``serving.moe.tokens_routed_held`` (the same for the experts this
+        chip holds, averaged over the layers and rounded down: the
+        routed work it really does) and
+        ``serving.moe.tokens_per_expert.e<i>`` (expert ``i``'s sum over
+        the layers)."""
         st = getattr(self.cache, "state", None)
         if st is None or not st.expert_tokens.shape[1]:
             return None
@@ -1339,6 +1347,10 @@ class Engine:
                 self._registry.counter_inc(name, max(0.0, total - have))
 
             _raise_to("serving.moe.tokens_routed", float(counts[0].sum()))
+            held = getattr(self._model, "experts_held", None)
+            on_chip = counts if held is None else counts[:, list(held)]
+            _raise_to("serving.moe.tokens_routed_held",
+                      float(on_chip.sum() // len(counts)))
             for e, n in enumerate(counts.sum(0)):
                 _raise_to(f"serving.moe.tokens_per_expert.e{e}", float(n))
         return counts
@@ -2112,7 +2124,7 @@ class Engine:
             jnp.asarray(temperatures, jnp.float32),
             jnp.asarray(fault_bias), self._next_key(),
             *self._lora_args(),
-            *((jnp.asarray(act),) if self.slot_state_width else ())))
+            *((jnp.asarray(act),) if self.slot_state else ())))
         self.cache, tokens, finite = self._runtime_call(
             "decode", lambda: self._jit_decode(self.params, self.cache,
                                                *ops))
@@ -2498,7 +2510,7 @@ class Engine:
             out[name] = {"argument_bytes": m.argument_bytes,
                          "alias_bytes": m.alias_bytes,
                          "temp_bytes": m.temp_bytes}
-            if self.slot_state_width:
+            if self.slot_state:
                 # donated and aliased with the pool: part of alias_bytes
                 out[name]["state_bytes"] = self.cache.state.nbytes()
         if self._registry is not None:
@@ -2529,7 +2541,7 @@ class Engine:
                     self.params, self.cache, *decode_ops, slots_f32,
                     slots_f32, self._key, *self._lora_args(),
                     *((np.zeros(self.slots, bool),)
-                      if self.slot_state_width else ())),
+                      if self.slot_state else ())),
                 "chunk": self._jit_chunk.lower(
                     self.params, self.cache, *chunk_ops, *scalars,
                     *self._lora_args(0), *self._slot_arg(0)),

@@ -1,7 +1,10 @@
-"""Paged KV pool — the serving engine's only mutable device state.
+"""Paged KV pool and per-slot state — the serving engine's only mutable
+device state, built from the model's :class:`CacheSpec` (which of its
+layers hold pages, which hold blocks of per-slot state).
 
 :class:`PagedKVCache` is a dense pool of fixed-size pages ``[layers,
-num_pages, heads, head_dim, page_len]`` (each page held transposed,
+num_pages, heads, head_dim, page_len]`` (``layers`` the model's layers
+that hold pages; each page held transposed,
 ``page_len`` in the lanes: the form the chip stores unpadded and the
 kernels read as it lies — see :class:`PagedKVCache`) plus a host-side
 :class:`PagePool` allocator. Storage dtype comes from the amp cast
@@ -52,35 +55,129 @@ mid-decode.
 
 from __future__ import annotations
 
-from typing import Any, Iterable, List, Optional, Sequence, Tuple
+import dataclasses
+from typing import (Any, Dict, Iterable, List, Optional, Sequence,
+                    Tuple)
 
 import flax.struct
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagedKVCache", "PagePool", "SlotState"]
+__all__ = ["PagedKVCache", "PagePool", "SlotState", "SlotAddr",
+           "CacheSpec", "StateBlock"]
+
+
+@dataclasses.dataclass(frozen=True)
+class StateBlock:
+    """One named block of per-slot state: ``layers`` of the model's
+    layers keep ``shape`` values a slot in ``dtype`` (None: the engine's
+    half dtype), stored ``[layers, slots, *shape]``."""
+
+    name: str
+    layers: int
+    shape: Tuple[int, ...]
+    dtype: Any = None
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What a model asks the serving engine to hold for it, PER KIND OF
+    LAYER: ``page_layers`` of its layers keep paged K/V (``kv_heads``
+    heads of ``head_dim``; the model maps a layer to its index among
+    them), and each :class:`StateBlock` is a fixed block per slot that
+    some of its layers keep beside or instead of pages (a convolution's
+    last inputs, a recurrent matrix). ``counter_layers`` x
+    ``num_experts`` is the shape of the device-side counter of tokens
+    routed (0 experts: no expert layer).
+
+    A model states it as ``model.cache_spec()`` -> a plain dict with
+    these keys (``state`` a list of ``(name, layers, shape, dtype)``);
+    the engine builds the pool and the state from it alone, so a new
+    kind of layer is a new spec, not a branch in the engine."""
+
+    page_layers: int
+    kv_heads: int
+    head_dim: int
+    state: Tuple[StateBlock, ...] = ()
+    counter_layers: int = 0
+    num_experts: int = 0
+
+    @classmethod
+    def of(cls, model) -> "CacheSpec":
+        if not hasattr(model, "cache_spec"):
+            # a dense stand-in that states geometry attributes only: pages
+            # of all its heads on every layer, no per-slot state
+            heads = int(model.num_heads)
+            return cls(page_layers=int(model.num_layers), kv_heads=heads,
+                       head_dim=int(model.hidden) // heads)
+        d = dict(model.cache_spec())
+        blocks = tuple(StateBlock(str(n), int(l), tuple(int(x) for x in sh),
+                                  dt)
+                       for n, l, sh, dt in d.pop("state", ()))
+        if len({b.name for b in blocks}) != len(blocks):
+            raise ValueError("cache_spec: state block names must differ")
+        return cls(state=blocks, **{k: int(v) for k, v in d.items()})
+
+
+@flax.struct.dataclass
+class SlotAddr:
+    """Which slots a program's batch rows are, for the models that read
+    and write :class:`SlotState` blocks. The chunk program runs ONE row:
+    ``slot`` is its slot and ``fresh`` says the chunk is at offset 0 (the
+    request is admitted here and starts from zeros, whatever the slot's
+    last tenant left). The decode program runs every slot, row ``b``
+    being slot ``b`` (``slot`` None), and ``active`` marks the rows that
+    decode: an idle slot's state is dead until admission zeroes it, a
+    slot mid-prefill rides the batch with its state live, so neither may
+    move."""
+
+    slot: Optional[jnp.ndarray] = None      # int32 scalar (chunk)
+    fresh: Optional[jnp.ndarray] = None     # bool scalar (chunk)
+    active: Optional[jnp.ndarray] = None    # [slots] bool (decode)
+
+    def read(self, block):
+        """The rows of ``block [layers, slots, ...]`` this program's
+        batch reads: ``[layers, B, ...]``."""
+        if self.slot is None:
+            return block
+        rows = jax.lax.dynamic_slice_in_dim(block, self.slot, 1, axis=1)
+        return jnp.where(self.fresh, jnp.zeros_like(rows), rows)
+
+    def write(self, block, rows):
+        """``block`` with the batch's ``rows [layers, B, ...]`` in: the
+        one slot's, or the active rows' (a select over the block: for
+        the SMALL blocks; a large one is updated by its kernel)."""
+        rows = jnp.asarray(rows, block.dtype)
+        if self.slot is not None:
+            return jax.lax.dynamic_update_slice_in_dim(block, rows,
+                                                       self.slot, axis=1)
+        mask = self.active.reshape((1, -1) + (1,) * (block.ndim - 2))
+        return jnp.where(mask, rows, block)
 
 
 @flax.struct.dataclass
 class SlotState:
-    """What a model keeps PER SLOT beside its paged K/V — a fixed block
-    of values a layer that the next token's step needs of this one
-    (a convolution's last inputs, a shifted projection: models whose
-    ``slot_state_width`` is non-zero, e.g.
-    :class:`~apex_tpu.models.zaya.ZayaLM`). Unlike a page it belongs to
-    the slot, not to a position: it is overwritten every step, cannot be
-    shared copy-on-write, and means nothing without the exact position
-    it was left at — which is why prefix retention, swap and preemption
-    (all of which re-enter a request mid-stream from PAGES) are refused
-    for such models until this state is snapshotted with them.
+    """What a model keeps PER SLOT beside (or instead of) its paged K/V:
+    named blocks ``[layers_of_that_kind, slots, *shape]`` of values that
+    the next token's step needs of this one - a convolution's last
+    inputs, a shifted projection, a recurrent matrix
+    (:class:`CacheSpec`; e.g. :class:`~apex_tpu.models.zaya.ZayaLM`,
+    :class:`~apex_tpu.models.qwen3_next.Qwen3NextLM`). Unlike a page it
+    belongs to the slot, not to a position: it is overwritten every
+    step, cannot be shared copy-on-write, and means nothing without the
+    exact position it was left at - which is why prefix retention, swap
+    and preemption (all of which re-enter a request mid-stream from
+    PAGES) are refused for such models until this state is snapshotted
+    with them.
 
     Lives in the :class:`PagedKVCache` pytree, so it is donated with
-    the pool and written in place by the same programs. The program
-    that admits a request (the chunk program at offset 0) starts the
-    slot from zeros; nothing on the host ever clears it."""
+    the pool and written in place by the same programs (a small block
+    through :meth:`SlotAddr.write`, a large one by its kernel, aliased).
+    The program that admits a request (the chunk program at offset 0)
+    starts the slot from zeros; nothing on the host ever clears it."""
 
-    rows: jnp.ndarray            # [layers, slots, width]
+    blocks: Dict[str, jnp.ndarray]   # name -> [layers_k, slots, *shape]
     # tokens routed to each expert since the engine was built (or
     # `Engine.moe_reset_counts`), accumulated by the programs on the
     # device and read once when asked; [layers, 0] for a model with no
@@ -88,22 +185,22 @@ class SlotState:
     expert_tokens: jnp.ndarray   # [layers, num_experts] int32
 
     @classmethod
-    def create(cls, *, layers: int, slots: int, width: int,
-               num_experts: int = 0, dtype: Any = jnp.bfloat16):
-        return cls(rows=jnp.zeros((layers, slots, width), dtype),
-                   expert_tokens=jnp.zeros((layers, num_experts),
-                                           jnp.int32))
-
-    @property
-    def width(self) -> int:
-        return self.rows.shape[2]
+    def create(cls, spec: CacheSpec, *, slots: int,
+               dtype: Any = jnp.bfloat16):
+        return cls(
+            blocks={b.name: jnp.zeros((b.layers, slots) + b.shape,
+                                      b.dtype or dtype)
+                    for b in spec.state},
+            expert_tokens=jnp.zeros((spec.counter_layers, spec.num_experts),
+                                    jnp.int32))
 
     def bytes_per_slot(self) -> int:
-        return int(self.rows.shape[0] * self.rows.shape[2]
-                   * self.rows.dtype.itemsize)
+        return sum(int(b.size // b.shape[1] * b.dtype.itemsize)
+                   for b in self.blocks.values())
 
     def nbytes(self) -> int:
-        return int(self.rows.size * self.rows.dtype.itemsize)
+        return sum(int(b.size * b.dtype.itemsize)
+                   for b in self.blocks.values())
 
 
 @flax.struct.dataclass
@@ -137,7 +234,7 @@ class PagedKVCache:
     # copy-on-write share never copies scale state alongside its pages.
     k_scale: Optional[jnp.ndarray] = None   # [layers, heads] fp32
     v_scale: Optional[jnp.ndarray] = None   # [layers, heads] fp32
-    # per-slot state beside the pages (models with slot_state_width > 0);
+    # per-slot state beside the pages (a model whose CacheSpec has state);
     # None — no leaf, the pytree the programs always had — otherwise
     state: Optional[SlotState] = None
 

@@ -555,7 +555,7 @@ class Scheduler:
             raise ValueError("max_queue must be >= 1")
         if chunk_budget < 1:
             raise ValueError("chunk_budget must be >= 1")
-        if getattr(engine, "slot_state_width", 0):
+        if getattr(engine, "slot_state", False):
             # a model with per-slot state beside its pages: what
             # re-enters a request mid-stream from pages alone is refused
             # by name, as serving.Engine refuses its own side of it

@@ -29,12 +29,13 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from apex_tpu.comm import AXIS_EXPERT
 
 __all__ = ["MoEMLP", "top1_routing", "top2_routing", "router_z_loss",
-           "dropless_top1_experts"]
+           "dropless_top1_experts", "dropless_topk_experts"]
 
 
 def _scatter_to_slots(mask, pos, gate, capacity):
@@ -286,3 +287,85 @@ def dropless_top1_experts(u, gate, choice, w_gate_up, w_down, *,
     with jax.named_scope("moe.combine"):
         y = ys[inverse] * jnp.asarray(gate, jnp.float32)[:, None]
     return jnp.asarray(y, out_dtype), sizes
+
+
+TOPK_BLOCK_ROWS = 512
+
+
+def dropless_topk_experts(u, weights, choices, w_gate_up, w_down, *,
+                          num_experts: int, experts_held=None,
+                          out_dtype=None, block_rows: int = TOPK_BLOCK_ROWS):
+    """The drop-nothing layer for ``k`` experts a token, over the experts
+    HELD here: ``choices`` ``[T, k]`` int32 each token's experts among ALL
+    ``num_experts`` (distinct), ``weights`` ``[T, k]`` fp32 what each
+    contributes (normalised by the caller over all ``k``, held or not);
+    the other operands as :func:`dropless_top1_experts`. Returns ``y [T,
+    H]``: the sum over a token's HELD experts of ``weights * (silu(u Wg)
+    * (u Wu)) Wd`` - this chip's part of the layer; what the experts on
+    other chips add is theirs to give, and the parts of all the shares
+    add up to the whole layer's.
+
+    The ``T x k`` (token, expert) rows are sorted with the held experts
+    first, in the order their weights are stacked (``moe.sort``), so the
+    rows this chip works on are the first ``n_held`` of the order and
+    nothing is gathered for a row routed elsewhere. They go through the
+    grouped GEMMs in blocks of ``block_rows`` (``moe.gemm``): one block
+    where ``T x k`` fits one (the whole of a top-1 layer), else as many
+    as ``n_held`` needs - with a chip's share of 1/8 of the experts that
+    is one block in all but pathological routings, and every block
+    streams the held weights once. Each block's rows are weighted and
+    summed back into their tokens by a one-hot product (``moe.combine``).
+    No capacity, no token dropped, whatever the routing."""
+    from apex_tpu.kernels.grouped_gemm import grouped_gemm
+
+    T, H = u.shape
+    k = choices.shape[1]
+    F = w_down.shape[1]
+    R = T * k
+    out_dtype = out_dtype or u.dtype
+    G = num_experts if experts_held is None else len(experts_held)
+    with jax.named_scope("moe.sort"):
+        flat = choices.reshape(-1)                     # row p = t * k + j
+        if experts_held is None:
+            key = flat
+        else:
+            rank = np.full((num_experts,), G, np.int32)
+            rank[list(experts_held)] = np.arange(G, dtype=np.int32)
+            key = jnp.asarray(rank)[flat]              # not held: G, last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        sizes = jnp.zeros((G + 1,), jnp.int32).at[key].add(1)[:G]
+        ends = jnp.cumsum(sizes)
+        starts = ends - sizes
+        n_held = ends[-1]
+    mb = min(int(block_rows), -(-R // 16) * 16)
+    n_blocks = -(-R // mb)
+    order = jnp.pad(order, (0, n_blocks * mb - R))
+    wflat = jnp.asarray(weights, jnp.float32).reshape(-1)
+    tokens = jnp.arange(T, dtype=jnp.int32)
+
+    def block(i, y):
+        lo = i * mb
+        rows = jax.lax.dynamic_slice_in_dim(order, lo, mb)
+        tok = rows // k
+        s = jnp.clip(starts - lo, 0, mb)
+        e = jnp.clip(ends - lo, 0, mb)
+        with jax.named_scope("moe.gemm"):
+            xs = u[tok]
+            gu = grouped_gemm(xs, w_gate_up, s, e)
+            h = jax.nn.silu(jnp.asarray(gu[:, :F], jnp.float32)) \
+                * jnp.asarray(gu[:, F:], jnp.float32)
+            ys = grouped_gemm(jnp.asarray(h, xs.dtype), w_down, s, e,
+                              out_dtype=jnp.float32)
+        with jax.named_scope("moe.combine"):
+            live = lo + jnp.arange(mb, dtype=jnp.int32) < n_held
+            wt = jnp.where(live, wflat[rows], 0.0)
+            onehot = jnp.where(tok[None, :] == tokens[:, None], wt[None, :],
+                               0.0)                              # [T, mb]
+            return y + jnp.dot(onehot, ys, precision=lax.Precision.HIGHEST)
+
+    y = jnp.zeros((T, H), jnp.float32)
+    if n_blocks == 1:
+        y = block(0, y)
+    else:
+        y = lax.fori_loop(0, (n_held + mb - 1) // mb, block, y)
+    return jnp.asarray(y, out_dtype)

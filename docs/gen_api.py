@@ -45,9 +45,11 @@ PAGES = {
                 "apex_tpu.kernels.lm_head_loss",
                 "apex_tpu.kernels.multi_tensor",
                 "apex_tpu.kernels.group_norm",
-                "apex_tpu.kernels.grouped_gemm", "apex_tpu.kernels.vmem"],
+                "apex_tpu.kernels.grouped_gemm",
+                "apex_tpu.kernels.gated_delta", "apex_tpu.kernels.vmem"],
     "models": ["apex_tpu.models", "apex_tpu.models.bert",
-               "apex_tpu.models.transformer_lm", "apex_tpu.models.zaya"],
+               "apex_tpu.models.transformer_lm", "apex_tpu.models.zaya",
+               "apex_tpu.models.qwen3_next"],
     "layers": ["apex_tpu.mlp", "apex_tpu.fused_dense"],
     "utils": ["apex_tpu.utils", "apex_tpu.utils.checkpoint",
               "apex_tpu.utils.sharded_checkpoint", "apex_tpu.utils.pytree",

@@ -167,13 +167,38 @@ def _paged_prefill(heads, d, kv_dtype, stacked=False, chunk=256,
                 *_scales(heads, kv_dtype)]
 
 
-def _grouped_gemm(tokens):
-    """One call of the expert layer's product at ZAYA1-8B's widths: 16
-    experts, gate and up fused (2048 -> 4096)."""
+def _grouped_gemm(tokens, groups=16, k=2048, n=4096):
+    """One call of the expert layer's product: by default at ZAYA1-8B's
+    widths, 16 experts, gate and up fused (2048 -> 4096)."""
     from apex_tpu.kernels.grouped_gemm import grouped_gemm
 
-    return grouped_gemm, [((tokens, 2048), BF16), ((16, 2048, 4096), BF16),
-                          ((16,), I32), ((16,), I32)]
+    return grouped_gemm, [((tokens, k), BF16), ((groups, k, n), BF16),
+                          ((groups,), I32), ((groups,), I32)]
+
+
+# Qwen3-Next-80B-A3B's linear layers as its cell holds them: 9 of them,
+# 192 slots, 32 value heads of a 128 x 128 float32 matrix
+GDN_STATE = (9, 192, 32, 128, 128)
+
+
+def _gdn_step():
+    from apex_tpu.kernels.gated_delta import gated_delta_step
+
+    def fn(state, q, k, v, g, beta, active):
+        return gated_delta_step(state, 4, q, k, v, g, beta, active)
+    rows = (192, 32, 128)
+    return fn, [(GDN_STATE, F32), (rows, F32), (rows, F32), (rows, F32),
+                ((192, 32), F32), ((192, 32), F32), ((192,), jnp.bool_)]
+
+
+def _gdn_chunk():
+    from apex_tpu.kernels.gated_delta import gated_delta_chunk
+
+    def fn(state, slot, fresh, q, k, v, g, beta):
+        return gated_delta_chunk(state, 4, slot, fresh, q, k, v, g, beta)
+    seq = (256, 32, 128)
+    return fn, [(GDN_STATE, F32), ((), I32), ((), jnp.bool_), (seq, F32),
+                (seq, F32), (seq, F32), ((256, 32), F32), ((256, 32), F32)]
 
 
 CASES = {
@@ -182,6 +207,18 @@ CASES = {
     "paged_prefill_grouped_8q2kv_x128": (
         _paged_prefill, (8, 128, BF16, True, 256, 2),
         ["paged_prefill_attention"]),
+    "paged_decode_grouped_16q2kv_x256": (
+        _paged_decode, (16, 256, BF16, True, 2), ["paged_decode_attention"]),
+    "paged_prefill_grouped_16q2kv_x256": (
+        _paged_prefill, (16, 256, BF16, True, 256, 2),
+        ["paged_prefill_attention"]),
+    "gated_delta_step_192x32": (_gdn_step, (), ["gated_delta_step"]),
+    "gated_delta_chunk_256x32": (_gdn_chunk, (), ["gated_delta_chunk"]),
+    # a block of the top-10 layer's rows over 64 held experts of 512
+    "grouped_gemm_64x_gate_up": (_grouped_gemm, (512, 64, 2048, 1024),
+                                 ["moe_grouped_gemm"]),
+    "grouped_gemm_64x_down": (_grouped_gemm, (512, 64, 512, 2048),
+                              ["moe_grouped_gemm"]),
     "grouped_gemm_decode_96": (_grouped_gemm, (96,), ["moe_grouped_gemm"]),
     "grouped_gemm_chunk_256": (_grouped_gemm, (256,), ["moe_grouped_gemm"]),
     "flash_fwd_bwd": (_flash, (), ["flash_attention_fwd",
@@ -229,12 +266,13 @@ def test_kernel_compiles_for_v5e_and_stays_a_kernel(case, one_chip, as_v5e):
         f"{case}: expected Mosaic kernels {want}, the program holds {have}"
 
 
-# The two serving configurations of the benchmark, as their engines call
+# The serving configurations of the benchmark, as their engines call
 # the kernel: (rows, query heads, K/V heads, head_dim, table pages,
 # layers, pool pages).
 CELL_GEOMETRY = {
     "gpt2_large_24x1024": (24, 20, 20, 64, 8, 36, 193),
     "zaya1_8b_96x2048": (96, 8, 2, 128, 16, 20, 1537),
+    "qwen3next_192x2048": (192, 16, 2, 256, 16, 3, 3073),
 }
 
 
@@ -268,6 +306,73 @@ def test_paged_decode_at_a_cells_geometry_fits_its_vmem(cell, one_chip,
         (working, limit)
     # nothing pool-sized beside the pool: the kernel reads it where it is
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_qwen3next_programs_at_the_cells_geometry(program, one_chip, as_v5e):
+    """The decode and the chunk program of the ``qwen3_next`` cell as the
+    engine's stateful bodies call the model - the donated cache with the
+    page pool and both state blocks in, the same buffers out - at the
+    cell's 192 slots x 2,048 positions and published widths, one period
+    of layers (3 linear + 1 full) of its three. Every kernel is there
+    once a layer that has it, the cache is aliased whole and the
+    temporaries stay a small fraction of the 1.2 GB recurrent block: no
+    select over it, no copy of it."""
+    from apex_tpu.models import Qwen3NextLM
+    from apex_tpu.serving.kv_cache import (CacheSpec, PagedKVCache, SlotAddr,
+                                           SlotState)
+
+    slots, max_pages, chunk = 192, 16, 256
+    m = Qwen3NextLM(num_layers=4, experts_held=tuple(range(64)),
+                    dtype=BF16, inference_dtype=BF16, param_dtype=BF16)
+    spec = CacheSpec.of(m)
+    sd = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)  # noqa: E731,E501
+    params = jax.tree_util.tree_map(
+        lambda a: sd(a.shape, BF16),
+        jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0),
+                                      jnp.zeros((1, 8), I32),
+                                      train=False))["params"])
+    pool = sd((spec.page_layers, slots * max_pages + 1, spec.kv_heads,
+               spec.head_dim, PAGE), BF16)
+    cache = PagedKVCache(k=pool, v=pool, state=SlotState(
+        blocks={b.name: sd((b.layers, slots) + b.shape, b.dtype or BF16)
+                for b in spec.state},
+        expert_tokens=sd((spec.counter_layers, spec.num_experts), I32)))
+
+    def run(params, cache, tokens, addr, pt, **kw):
+        st = cache.state
+        logits, (k, v, blocks, counts) = m.apply(
+            {"params": params}, tokens, train=False, state=st.blocks,
+            addr=addr, cache=(cache.k, cache.v, pt), **kw)
+        return cache.replace(k=k, v=v, state=st.replace(
+            blocks=blocks, expert_tokens=st.expert_tokens + counts)), \
+            jnp.argmax(logits[:, 0], -1)
+
+    if program == "decode":
+        def fn(params, cache, last, pt, lengths, active):
+            return run(params, cache, last[:, None], SlotAddr(active=active),
+                       pt, positions=lengths, valid=active[:, None])
+        args = [sd((slots,), I32), sd((slots, max_pages), I32),
+                sd((slots,), I32), sd((slots,), jnp.bool_)]
+        want = {"gated_delta_step": 3, "paged_decode_attention": 1,
+                "moe_grouped_gemm": 8}
+    else:
+        def fn(params, cache, tokens, pt, offset, n_valid, slot):
+            return run(params, cache, tokens,
+                       SlotAddr(slot=slot, fresh=offset == 0), pt,
+                       positions=offset[None], n_valid=n_valid[None])
+        args = [sd((1, chunk), I32), sd((1, max_pages), I32), sd((), I32),
+                sd((), I32), sd((), I32)]
+        want = {"gated_delta_chunk": 3, "paged_prefill_attention": 1,
+                "moe_grouped_gemm": 8}
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    assert kernel_calls(compiled.as_text()) == want
+    mem = compiled.memory_analysis()
+    held = sum(int(jnp.prod(jnp.asarray(a.shape))) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 200 * 2 ** 20, mem.temp_size_in_bytes
 
 
 def test_xentropy_at_unpadded_gpt2_vocab_takes_the_reference(one_chip,

@@ -1,9 +1,16 @@
-"""``serving.Engine`` + ``Scheduler`` serving the ``zaya`` architecture
-through the paged pool and the per-slot state beside it
-(``kv_cache.SlotState``), against the float32 reference's one forward
-pass - small size on the CPU (hidden 64, 4 query and 2 K/V heads of 16, 4
-experts of width 32, 3 layers, vocabulary 256; Pallas in interpret mode;
-chunk and page 128).
+"""``serving.Engine`` + ``Scheduler`` serving the models that keep state
+per slot beside their pages (``kv_cache.SlotState``, built from the
+model's ``kv_cache.CacheSpec``), each against its float32 reference's one
+forward pass, every case for both - small sizes on the CPU, Pallas in
+interpret mode, chunk and page 128:
+
+- ``zaya`` (hidden 64, 4 query and 2 K/V heads of 16, 4 experts of width
+  32, 3 layers, vocabulary 256): one block of convolution rows on every
+  layer, pages on every layer;
+- ``qwen3_next`` (hidden 64, two periods of a linear and a full layer, 4
+  value heads of 128 x 128, 16 experts at 4 a token): a float32 recurrent
+  block and the convolution's tail on the linear layers, pages on the
+  full ones only.
 
 The engine hands back tokens, not logits, so here a served token is held
 to the reference's logits: it must lie within LOGIT_TOL = 1e-4 of the
@@ -20,32 +27,65 @@ from apex_tpu import serving
 from apex_tpu.amp.policy import resolve_policy
 from apex_tpu.models import build_lm
 from apex_tpu.telemetry import MetricsRegistry
+from benchmarks.checks.tiny_qwen3next import TINY_Q3N_CFG
 from benchmarks.checks.tiny_zaya import TINY_ZAYA_CFG
+from benchmarks.lib import reference_qwen3next as rq
 from benchmarks.lib import reference_zaya as rz
 
 pytestmark = pytest.mark.serving
 
-CFG = TINY_ZAYA_CFG
 SLOTS, MAX_LEN, CHUNK = 3, 512, 128
 LOGIT_TOL = 1e-4
 
 
+class Kind:
+    """One stateful model: its configuration, its reference, and what
+    the engine must hold for it."""
+
+    def __init__(self, name, cfg, ref, *, layers, experts, per_token,
+                 pool, blocks, kv_bytes_per_token):
+        self.name, self.cfg, self.ref = name, cfg, ref
+        self.layers, self.experts, self.per_token = layers, experts, \
+            per_token
+        self.pool, self.blocks = pool, blocks
+        self.kv_bytes_per_token = kv_bytes_per_token
+        self.state_bytes_per_slot = sum(
+            int(np.prod(shape[2:])) * shape[0] * 4
+            for shape in blocks.values())       # float32 under O0
+
+    def engine(self, weights, **kw):
+        kw.setdefault("policy", resolve_policy("O0", verbose=False))
+        return serving.Engine(build_lm(self.cfg, dtype=jnp.float32),
+                              self.ref.program_tree(weights), slots=SLOTS,
+                              max_len=MAX_LEN, chunk_len=CHUNK,
+                              page_len=CHUNK, **kw)
+
+
+KINDS = {
+    "zaya": Kind("zaya", TINY_ZAYA_CFG, rz, layers=3, experts=4,
+                 per_token=1, pool=(3, 2, 16), kv_bytes_per_token=768,
+                 blocks={"rows": (3, SLOTS, 208)}),
+    "qwen3_next": Kind("qwen3_next", TINY_Q3N_CFG, rq, layers=4,
+                       experts=16, per_token=4, pool=(2, 2, 32),
+                       kv_bytes_per_token=1024,
+                       blocks={"recurrent": (2, SLOTS, 4, 128, 128),
+                               "conv": (2, SLOTS, 3, 1024)}),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(KINDS))
+def kind(request):
+    return KINDS[request.param]
+
+
 @pytest.fixture(scope="module")
-def weights():
-    return rz.seeded_weights(CFG, 3, jnp.float32)
-
-
-def _engine(weights, **kw):
-    kw.setdefault("policy", resolve_policy("O0", verbose=False))
-    return serving.Engine(build_lm(CFG, dtype=jnp.float32),
-                          rz.program_tree(weights), slots=SLOTS,
-                          max_len=MAX_LEN, chunk_len=CHUNK, page_len=CHUNK,
-                          **kw)
+def weights(kind):
+    return kind.ref.seeded_weights(kind.cfg, 3, jnp.float32)
 
 
 @pytest.fixture(scope="module")
-def engine(weights):
-    return _engine(weights, registry=MetricsRegistry())
+def engine(kind, weights):
+    return kind.engine(weights, registry=MetricsRegistry())
 
 
 def _prompt(seed, n):
@@ -71,25 +111,28 @@ def _serve(eng, slot, prompt, n_new, others=()):
     return out
 
 
-def _gaps(weights, prompt, out):
+def _gaps(kind, weights, prompt, out):
     """How far each served token's reference logit lies below the
     reference's best at its position."""
-    served, _, _ = rz.served_token_gaps(weights, CFG, prompt, out)
+    served, _, _ = kind.ref.served_token_gaps(weights, kind.cfg, prompt,
+                                              out)
     return served
 
 
 @pytest.mark.parametrize("n", [127, 128, 129, 255, 257])
-def test_prefill_then_decode_serves_the_references_tokens(engine, weights, n):
+def test_prefill_then_decode_serves_the_references_tokens(kind, engine,
+                                                          weights, n):
     """Prompts one below, at and one past a chunk (= page) boundary, and
     around the second: the state crosses the chunk boundary inside the
     chunk program and the page boundary inside decode."""
     prompt = _prompt(n, n)
     out = _serve(engine, 1, prompt, 5)
     engine.release_slot(1)
-    assert _gaps(weights, prompt, out).max() < LOGIT_TOL
+    assert _gaps(kind, weights, prompt, out).max() < LOGIT_TOL
 
 
-def test_neighbouring_slots_never_see_each_others_state(engine, weights):
+def test_neighbouring_slots_never_see_each_others_state(kind, engine,
+                                                        weights):
     pa, pb = _prompt(11, 140), _prompt(12, 90)
     alone = _serve(engine, 1, pa, 6)
     engine.release_slot(1)
@@ -100,11 +143,11 @@ def test_neighbouring_slots_never_see_each_others_state(engine, weights):
     for s in range(SLOTS):
         engine.release_slot(s)
     assert beside == alone
-    assert _gaps(weights, pa, beside).max() < LOGIT_TOL
+    assert _gaps(kind, weights, pa, beside).max() < LOGIT_TOL
 
 
-def test_a_prefilling_slot_keeps_its_state_through_others_decode(engine,
-                                                                 weights):
+def test_a_prefilling_slot_keeps_its_state_through_others_decode(
+        kind, engine, weights):
     """A request mid-prefill rides the decode batch inactive: the decode
     program must not move its state."""
     pa, pb = _prompt(21, 200), _prompt(22, 60)
@@ -118,21 +161,21 @@ def test_a_prefilling_slot_keeps_its_state_through_others_decode(engine,
     ta = engine.prefill_chunk(1, pa[CHUNK:], CHUNK)         # A: chunk 2
     for s in range(SLOTS):
         engine.release_slot(s)
-    assert _gaps(weights, pa, [int(ta)]).max() < LOGIT_TOL
+    assert _gaps(kind, weights, pa, [int(ta)]).max() < LOGIT_TOL
 
 
-def test_a_reused_slot_starts_from_zeros(engine, weights):
+def test_a_reused_slot_starts_from_zeros(kind, engine, weights):
     px, py = _prompt(31, 150), _prompt(32, 70)
     _serve(engine, 2, px, 4)              # leaves X's state in slot 2
     engine.release_slot(2)
     reused = _serve(engine, 2, py, 4)
     engine.release_slot(2)
-    assert _gaps(weights, py, reused).max() < LOGIT_TOL
+    assert _gaps(kind, weights, py, reused).max() < LOGIT_TOL
 
 
-def test_scheduler_serves_more_requests_than_slots(weights):
+def test_scheduler_serves_more_requests_than_slots(kind, weights):
     reg = MetricsRegistry()
-    eng = _engine(weights, registry=reg)
+    eng = kind.engine(weights, registry=reg)
     sched = serving.Scheduler(eng, registry=reg, max_queue=8)
     reqs = [serving.Request(prompt=_prompt(40 + i, n), max_new_tokens=4,
                             temperature=0.0)
@@ -145,35 +188,47 @@ def test_scheduler_serves_more_requests_than_slots(weights):
         sched.step()
     assert [r.status.value for r in reqs] == ["finished"] * 5
     for r in reqs:
-        assert _gaps(weights, list(r.prompt),
+        assert _gaps(kind, weights, list(r.prompt),
                      list(r.output_tokens)).max() < LOGIT_TOL
-    # counters: every prompt and decoded token routed once a layer
+    # counters: every prompt and decoded token routed to its experts
+    # (one, or four) once a layer
     counts = eng.moe_tokens_per_expert()
-    assert counts.shape == (3, 4)
+    assert counts.shape == (kind.layers, kind.experts)
     assert (counts.sum(1) == counts[0].sum()).all()
+    assert counts[0].sum() % kind.per_token == 0
     # one routing a layer for each prompt token and each decode step's
     # input token; decode batches run every slot's row, so at least that
-    assert counts[0].sum() >= sum(len(r.prompt) + 3 for r in reqs)
+    assert counts[0].sum() >= kind.per_token * sum(
+        len(r.prompt) + 3 for r in reqs)
     assert reg.counters["serving.moe.tokens_routed"] == counts[0].sum()
+    # every expert is held here, so the held count is the whole
+    assert reg.counters["serving.moe.tokens_routed_held"] \
+        == counts[0].sum()
     assert sum(reg.counters[f"serving.moe.tokens_per_expert.e{e}"]
-               for e in range(4)) == counts.sum()
+               for e in range(kind.experts)) == counts.sum()
     # a second read adds nothing
     eng.moe_tokens_per_expert()
     assert reg.counters["serving.moe.tokens_routed"] == counts[0].sum()
-    assert reg.gauges["serving.moe.experts_held"] == 4
-    assert reg.gauges["serving.kv.bytes_per_token"] == 3 * 2 * 16 * 4 * 2
-    assert reg.gauges["serving.state.bytes_per_slot"] == 3 * 208 * 4
-    assert reg.gauges["serving.kv.state_bytes"] == SLOTS * 3 * 208 * 4
+    assert reg.gauges["serving.moe.experts_held"] == kind.experts
+    assert reg.gauges["serving.moe.experts_per_token"] == kind.per_token
+    assert reg.gauges["serving.kv.page_layers"] == kind.pool[0]
+    assert reg.gauges["serving.kv.bytes_per_token"] \
+        == kind.kv_bytes_per_token
+    assert reg.gauges["serving.state.bytes_per_slot"] \
+        == kind.state_bytes_per_slot
+    assert reg.gauges["serving.state.bytes"] \
+        == SLOTS * kind.state_bytes_per_slot
     # same compiled programs as any paged engine: one chunk, one decode
     assert (eng.chunk_traces, eng.decode_traces) == (1, 1)
     assert eng.pool_stats()["pages_in_use"] == 0
 
 
-def test_default_beat_is_the_sync_oracle_with_slot_state(engine, weights):
+def test_default_beat_is_the_sync_oracle_with_slot_state(kind, engine,
+                                                         weights):
     """The dispatch-ahead default against ``pipeline_depth=0`` for a
     model with per-slot state, over a chunked backlog in which an
     ``eos_id`` ends requests with a speculated successor step in flight:
-    that step wrote K/V and convolution state for a slot since freed,
+    that step wrote K/V and per-slot state for a slot since freed,
     and the next occupant's chunk at offset 0 must reset the state
     before anything reads it - so the streams are bitwise equal and the
     re-occupied slot's tokens are still the reference's."""
@@ -209,19 +264,39 @@ def test_default_beat_is_the_sync_oracle_with_slot_state(engine, weights):
     assert engine._registry.counters["serving.heartbeat.discarded"] \
         > discarded0, "no speculated step was in flight at an EOS"
     for r in got[SLOTS:]:
-        assert _gaps(weights, list(r.prompt),
+        assert _gaps(kind, weights, list(r.prompt),
                      list(r.output_tokens)).max() < LOGIT_TOL
     assert (engine.chunk_traces, engine.decode_traces) == (1, 1)
     assert engine.pool_stats()["pages_in_use"] == 0
 
 
-def test_the_pool_and_the_state_take_their_geometry_from_the_model(engine):
+def test_the_pool_and_the_state_take_their_geometry_from_the_model(kind,
+                                                                   engine):
+    """The cache spec per kind of layer: pages only on the layers that
+    attend them, each state block on the layers that keep it, in its own
+    shape and dtype."""
     c = engine.cache
-    assert c.k.shape == (3, engine.num_pages, 2, 16, CHUNK)   # 2 K/V heads
-    assert c.state.rows.shape == (3, SLOTS, 208)
-    assert c.state.expert_tokens.shape == (3, 4)
+    page_layers, kv_heads, head_dim = kind.pool
+    assert c.k.shape == (page_layers, engine.num_pages, kv_heads, head_dim,
+                         CHUNK)
+    assert {n: b.shape for n, b in c.state.blocks.items()} == kind.blocks
+    assert c.state.expert_tokens.shape == (kind.layers, kind.experts)
+    assert c.state.bytes_per_slot() == kind.state_bytes_per_slot
+    spec = engine.cache_spec
+    assert spec.page_layers == page_layers
+    assert [b.name for b in spec.state] == list(kind.blocks)
     mem = engine.program_memory()
     assert mem["decode"]["state_bytes"] == c.state.nbytes()
+
+
+def test_a_stateless_model_states_its_pool_through_the_same_spec():
+    from apex_tpu.models.transformer_lm import TransformerLM
+    from apex_tpu.serving.kv_cache import CacheSpec
+
+    spec = CacheSpec.of(TransformerLM(vocab_size=96, hidden=32, num_layers=2,
+                                      num_heads=4))
+    assert (spec.page_layers, spec.kv_heads, spec.head_dim) == (2, 4, 8)
+    assert spec.state == () and spec.num_experts == 0
 
 
 REFUSED_BY_ENGINE = {
@@ -236,10 +311,11 @@ REFUSED_BY_ENGINE = {
 
 
 @pytest.mark.parametrize("what", sorted(REFUSED_BY_ENGINE))
-def test_the_engine_refuses_by_name_what_slot_state_breaks(weights, what):
+def test_the_engine_refuses_by_name_what_slot_state_breaks(kind, weights,
+                                                           what):
     with pytest.raises(NotImplementedError) as e:
-        _engine(weights, **REFUSED_BY_ENGINE[what])
-    assert what in str(e.value) and "'zaya'" in str(e.value)
+        kind.engine(weights, **REFUSED_BY_ENGINE[what])
+    assert what in str(e.value) and repr(kind.name) in str(e.value)
 
 
 REFUSED_BY_SCHEDULER = {
@@ -252,7 +328,8 @@ REFUSED_BY_SCHEDULER = {
 
 
 @pytest.mark.parametrize("what", sorted(REFUSED_BY_SCHEDULER))
-def test_the_scheduler_refuses_by_name_what_slot_state_breaks(engine, what):
+def test_the_scheduler_refuses_by_name_what_slot_state_breaks(kind, engine,
+                                                              what):
     with pytest.raises(NotImplementedError) as e:
         serving.Scheduler(engine, **REFUSED_BY_SCHEDULER[what])
-    assert what in str(e.value) and "'zaya'" in str(e.value)
+    assert what in str(e.value) and repr(kind.name) in str(e.value)
