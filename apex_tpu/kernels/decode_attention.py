@@ -238,6 +238,35 @@ def paged_decode_attention_reference(q, k_pool, v_pool, page_table,
                                       k_scale=k_scale, v_scale=v_scale)
 
 
+def _pool_write_tokens(pool, layer, page_ids, off, new):
+    """Write one token per batch row into the stacked paged pool, IN
+    PLACE, in plain XLA: ``pool`` ``[layers, num_pages, h, d,
+    page_len]``, ``new`` ``[B, h, d]`` (already in the pool's storage
+    dtype) lands at ``pool[layer, page_ids[b], :, :, off[b]]``; a row
+    whose page id is ``num_pages`` (past the pool) writes nothing.
+
+    The decode program does not run this any more: its one token a row
+    is written by :func:`paged_decode_attention` itself, from the page
+    its kernel holds in VMEM anyway. This is the write of the programs
+    without that kernel - speculative verify's few unaligned positions,
+    and the reference the kernel gives way to - and its oracle.
+
+    Written as read-modify-write of each row's whole write page — gather
+    the B pages, replace lane ``off[b]``, scatter the pages back —
+    because a scatter whose window is a whole page runs on the pool as
+    it lies, while a scatter of the ``[h, d]`` column alone makes the
+    TPU compiler re-lay the entire pool out (the window dims must be its
+    minor ones) and back: three fusions over ``B`` pages to store ``B``
+    columns, which is why the decode program left it. A live row's write
+    page is its own (shared pages are full); inactive rows all name the
+    sentinel page, whose contents nothing reads."""
+    pages = pool[layer, page_ids]                     # [B, h, d, pl]
+    lane = jax.lax.broadcasted_iota(jnp.int32, pages.shape, 3)
+    pages = jnp.where(lane == off[:, None, None, None], new[..., None],
+                      pages)
+    return pool.at[layer, page_ids].set(pages)
+
+
 DEFAULT_PAGED_STEP_BYTES = 512 * 1024
 _SCOPED_VMEM_ROOM = 8 * 1024 * 1024
 
@@ -259,12 +288,13 @@ def _p_rows(h):
     return -(-h // 16) * 16
 
 
-def _paged_decode_vmem(k_pool, q, pages):
+def _paged_decode_vmem(k_pool, q, pages, write=False):
     """``(working set, scoped limit asked for)`` in bytes of the paged
     decode kernel on the stacked pool ``k_pool`` at ``pages`` pages a
     step: K and V, two buffers each, of ``pages`` whole pages; the
     query and the output of every row; the stacked pieces of ``p``; the
-    float32 accumulator, (m, l) and one step's logits. The limit leaves
+    float32 accumulator, (m, l) and one step's logits; for the call
+    that writes, every row's new K and V as columns. The limit leaves
     the compiler room for the products' operands beside it."""
     _, _, h_kv, d, page_len = k_pool.shape
     B, h, _ = q.shape
@@ -275,11 +305,14 @@ def _paged_decode_vmem(k_pool, q, pages):
     q_and_out = 2 * B * rows * lanes * q.dtype.itemsize
     state = 3 * _p_rows(h) * T * 2 + rows * (C + 2 * 128 + 2 * T) * 4
     working = buffers + q_and_out + state
+    if write:
+        working += 2 * C * -(-B // page_len) * page_len * \
+            k_pool.dtype.itemsize
     return working, 2 * working + _SCOPED_VMEM_ROOM
 
 
 def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
-                         G, quant, widen):
+                         G, quant, write, widen):
     """One invocation walks every batch row's LIVE pages, ``pages`` of
     them a step, every K/V head of a page in one fetch.
 
@@ -320,20 +353,61 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
     buffers, ``4 x pages x`` a page's bytes - 1.3 MB at GPT-2 large's
     320 KB page, 2.1 MB at eight of ZAYA1-8B's 64 KB pages - plus every
     row's query and output, ``p``'s pieces, the accumulator and one
-    step's logits: 1.8 MB and 2.7 MB in all.
+    step's logits: 1.8 MB and 2.7 MB in all; the call that writes
+    holds every row's new K and V as columns beside them, 0.66 MB and
+    0.13 MB.
 
     ``quant`` (static): int8 pages widen to bfloat16 (exact) and the
     per-head scales, ``[h, 1]`` float32 columns, multiply after each
     product as in :func:`_decode_kernel`. ``widen`` (static, the CPU's
     interpreter: its dot takes no bfloat16 x bfloat16 -> float32):
     operands are widened to float32 at the product, the same numbers.
+
+    ``write`` (static: the call was handed the rows' new K/V): the
+    kernel also WRITES the decode token. ``nk_ref``/``nv_ref`` hold the
+    new K and V of every row as columns, ``[blocks, h_kv * d,
+    page_len]`` in the pool's storage type (row ``b`` is lane ``b %
+    page_len`` of block ``b // page_len``), and the pools are outputs
+    aliased to their inputs, read and written through the one (output)
+    reference. Position ``lengths[b] - 1`` lies in row ``b``'s LAST
+    live page, which the row's last step fetches anyway: once that
+    step's pages have landed, lane ``(lengths[b] - 1) % page_len`` of
+    that page is replaced in ``kbuf``/``vbuf`` by the row's new column
+    - its block of ``nk_ref`` rotated along the lanes so that the row's
+    lane lies on the write lane, and a select over that one page in
+    VMEM, both on 32-bit words (data moved, no value computed) - the
+    page's slice of the buffer is DMA'd back to where it came from, and
+    the products run on the buffer while it goes: write-then-attend on
+    the bytes the pool will hold. What makes that safe:
+
+    (a) While row ``b`` writes its page back, the next row's first step
+        is already being fetched. They never meet: a live row's write
+        page is its own (shared pages are full ones, copy-on-write), and
+        the sentinel page, which every inactive slot both writes and
+        reads, is read by nobody who keeps the result.
+    (b) No page written in a call is fetched again in it: a write page
+        sits in one row's table, and a row fetches its last page once.
+    (c) The write-back is waited on at the end of its row, before its
+        buffer can be the target of the next fetch into it (the first
+        step of the row after next, or later). A later wait buys
+        nothing where it counts: copied back from two staging pages of
+        its own and waited on two rows on, the call alone ran 6% faster
+        and the decode program it sits in not at all (that program is
+        bound by the bytes it moves, PERF.md section 6, PR 35).
+    (d) Table slots of a partial step past the row's last live page are
+        neither fetched nor written; rows of length 0 write nothing.
     """
-    if quant:
-        q_ref, ks_ref, vs_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, \
-            p_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, p_ref, acc_ref, \
-            m_ref, l_ref = refs
+    refs = list(refs)
+    q_ref = refs.pop(0)
+    ks_ref, vs_ref = (refs.pop(0), refs.pop(0)) if quant else (None, None)
+    nk_ref, nv_ref = (refs.pop(0), refs.pop(0)) if write else (None, None)
+    k_hbm, v_hbm, o_ref = refs.pop(0), refs.pop(0), refs.pop(0)
+    if write:
+        # the pools as outputs, aliased to the inputs: one reference to
+        # read and to write
+        k_hbm, v_hbm = refs.pop(0), refs.pop(0)
+    kbuf, vbuf, sem, p_ref, acc_ref, m_ref, l_ref = refs[:7]
+    wsem = refs[7] if write else None
     B, h, d = q_ref.shape
     h_kv, page_len = h // G, kbuf.shape[-1] // pages
     C, T = h_kv * d, pages * page_len
@@ -363,6 +437,15 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
             lambda r: jnp.logical_and(
                 r < B, len_ref[jnp.minimum(r, B - 1)] <= 0),
             lambda r: r + 1, b + 1)
+
+    def write_back(b, slot, jj, act):
+        """Start or wait (``act``) the copies of row ``b``'s write page,
+        slice ``jj`` of buffer ``slot``, back into the pool."""
+        page = pt_ref[b, jnp.clip(live_pages(b) - 1, 0, max_pages - 1)]
+        lanes = pl.ds(jj * page_len, page_len)
+        for x, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+            act(pltpu.make_async_copy(buf.at[slot, :, :, lanes],
+                                      hbm.at[layer, page], wsem.at[x]))
 
     def step_copies(b, i, slot, act):
         """Start or wait (``act``) the DMAs of step ``i`` of row ``b``
@@ -427,6 +510,35 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
                 def _stale():
                     vbuf[slot, :, :, jj * page_len:(jj + 1) * page_len] = \
                         jnp.zeros((h_kv, d, page_len), vbuf.dtype)
+            for jj in range(pages if write else 0):
+                @pl.when(i * pages + jj == n - 1)
+                def _write():
+                    # the row's last live page has landed: the new
+                    # token's column goes into it, and the page goes
+                    # back (hazards (a)-(d) of the docstring) while the
+                    # products below read the buffer. Row b's column is
+                    # lane b % page_len of its block of new_ref: rotated
+                    # to the write lane and selected into the page, as
+                    # 32-bit words (whole sublanes: no value is touched)
+                    off = jax.lax.rem(
+                        jnp.minimum(length, max_pages * page_len) - 1,
+                        page_len)
+                    shift = jax.lax.rem(
+                        off - jax.lax.rem(b, page_len) + page_len, page_len)
+                    lane = jax.lax.broadcasted_iota(
+                        jnp.int32, (1, page_len), 1) == off
+                    at = (slot, slice(None), slice(None),
+                          slice(jj * page_len, (jj + 1) * page_len))
+                    for new_ref, buf in ((nk_ref, kbuf), (nv_ref, vbuf)):
+                        col = pltpu.roll(
+                            pltpu.bitcast(new_ref[jax.lax.div(b, page_len)],
+                                          jnp.uint32), shift, 1)
+                        page = pltpu.bitcast(buf[at].reshape(C, page_len),
+                                             jnp.uint32)
+                        buf[at] = pltpu.bitcast(
+                            jnp.where(lane, col, page),
+                            buf.dtype).reshape(h_kv, d, page_len)
+                    write_back(b, slot, jj, lambda c: c.start())
             k = kbuf[slot].reshape(C, T).astype(k_dtype)
             v = vbuf[slot].reshape(C, T)
             if quant:
@@ -462,6 +574,11 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
             return 1 - slot
 
         slot = jax.lax.fori_loop(0, steps, step, slot)
+        if write:
+            @pl.when(n > 0)
+            def _written():
+                # any slice of either buffer: a wait counts bytes
+                write_back(b, 0, 0, lambda c: c.wait())
         l = l_ref[:, :1]
         out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)      # [h, C]
         for hh in range(h_kv):
@@ -474,30 +591,50 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
 
 @functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
 def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, layer, ks=None,
-                         vs=None, *, scale, pages, interpret):
+                         vs=None, new_k=None, new_v=None, *, scale, pages,
+                         interpret):
     """The kernel's call on the stacked pool, ``layer`` a traced scalar.
     Jitted: a model's layers differ in ``layer`` alone, so they share
     one trace and one lowered function (36 traces of this body were
-    seconds of every process's start)."""
+    seconds of every process's start). With ``new_k``/``new_v`` ``[B,
+    h_kv, d]`` the call writes them too and returns ``(out, k_pool,
+    v_pool)``, the pools aliased to the ones handed in."""
     B, h, d = q.shape
     _, _, h_kv, _, page_len = k_pool.shape
     T, C = pages * page_len, h_kv * d
-    quant = ks is not None
+    quant, write = ks is not None, new_k is not None
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                pages=pages, G=h // h_kv, quant=quant,
-                               widen=interpret)
+                               write=write, widen=interpret)
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    scale_ops = ()
+    operands = [q]
     if quant:
         # one scale a QUERY head, as a column the products' rows take
-        scale_ops = tuple(jnp.repeat(s, h // h_kv)[:, None]
-                          for s in (ks, vs))
+        operands += [jnp.repeat(s, h // h_kv)[:, None] for s in (ks, vs)]
+    if write:
+        # a row's new K (V) as a column, the form of a page's lane:
+        # [blocks, h_kv * d, page_len], row b lane b % page_len of
+        # block b // page_len
+        blocks = -(-B // page_len)
+        operands += [jnp.pad(t.reshape(B, C),
+                             ((0, blocks * page_len - B), (0, 0)))
+                     .reshape(blocks, page_len, C).swapaxes(1, 2)
+                     for t in (new_k, new_v)]
+    out_shape = [jax.ShapeDtypeStruct((B, h, d), q.dtype)]
+    out_specs, scratch, aliases = [whole], [], {}
+    if write:
+        first = 3 + len(operands)         # the scalars count
+        aliases = {first: 1, first + 1: 2}
+        out_shape += [jax.ShapeDtypeStruct(t.shape, t.dtype)
+                      for t in (k_pool, v_pool)]
+        out_specs += [in_hbm, in_hbm]
+        scratch = [pltpu.SemaphoreType.DMA((2,))]         # K|V write-back
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,            # page_table, lengths, layer
         grid=(1,),
-        in_specs=[whole] * (1 + len(scale_ops)) + [in_hbm, in_hbm],
-        out_specs=whole,
+        in_specs=[whole] * len(operands) + [in_hbm, in_hbm],
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((2, h_kv, d, T), k_pool.dtype),    # K, two buffers
             pltpu.VMEM((2, h_kv, d, T), v_pool.dtype),    # V, two buffers
@@ -506,25 +643,28 @@ def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, layer, ks=None,
             pltpu.VMEM((h, C), jnp.float32),              # acc
             pltpu.VMEM((h, 128), jnp.float32),            # m
             pltpu.VMEM((h, 128), jnp.float32),            # l
-        ],
+        ] + scratch,
     )
-    _, limit = _paged_decode_vmem(k_pool, q, pages)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, h, d), q.dtype),
+    _, limit = _paged_decode_vmem(k_pool, q, pages, write)
+    outs = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=limit),
         interpret=interpret, name="paged_decode_attention",
-    )(pt, lengths, jnp.reshape(layer, (1,)).astype(jnp.int32), q,
-      *scale_ops, k_pool, v_pool)
+    )(pt, lengths, jnp.reshape(layer, (1,)).astype(jnp.int32), *operands,
+      k_pool, v_pool)
+    return tuple(outs) if write else outs[0]
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
+                           new_k=None, new_v=None,
                            scale: Optional[float] = None,
                            k_scale=None, v_scale=None,
                            layer: Optional[int] = None,
                            interpret: bool = False):
-    """Single-token attention against a PAGED, length-masked KV pool.
+    """Single-token attention against a PAGED, length-masked KV pool,
+    and the write of that token's K/V into it.
 
     ``q`` [batch, heads, head_dim]; ``k_pool``/``v_pool``
     [num_pages, kv_heads, page_len, head_dim] (one layer of the serving
@@ -543,9 +683,23 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     [batch, max_pages] int32 maps row ``b``'s logical block ``j`` to a
     pool page (sentinel ids for unallocated blocks — masked, never
     attended); ``lengths`` [batch] int32 as in
-    :func:`decode_attention`. The current token's K/V must already be
-    written at logical position ``lengths[b] - 1`` of its row's pages.
-    ``scale`` defaults to ``1/sqrt(head_dim)``.
+    :func:`decode_attention`. ``scale`` defaults to
+    ``1/sqrt(head_dim)``.
+
+    **The current token's K/V.** Handed over as ``new_k``/``new_v``
+    ``[batch, kv_heads, head_dim]``, already in the pool's storage type
+    (the int8 tier quantises before the call), the call WRITES them at
+    logical position ``lengths[b] - 1`` of each row with ``lengths[b] >
+    0`` (at most the table's span) - in the stacked pool, layer
+    ``layer`` - then attends, and returns ``(out, k_pool, v_pool)``: the
+    decode program's form. The Pallas path does both in the kernel (the
+    pools are outputs aliased to their inputs; the write page is the
+    row's last live page, edited in the VMEM the kernel fetched it into
+    and copied back: :func:`_paged_decode_kernel`); the fallback writes
+    with :func:`_pool_write_tokens` and then runs the oracle - the same
+    bytes in the pool and the same operands under the products either
+    way. Without them the call only reads - the K/V must already be in
+    the pool - and returns ``out`` alone.
 
     Inference-only. The Pallas path leaves the pool in HBM and walks
     each row's LIVE pages (``ceil(lengths[b] / page_len)``; the table
@@ -557,7 +711,8 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     ``decode.paged_step_bytes`` (bytes in flight per buffer): no
     per-model setting. A single layer's 4-D pool is relaid to the
     stacked form first (a pool-sized copy: the form of tests and smoke
-    runs, not of serving). Unaligned shapes (``page_len`` not a
+    runs, not of serving, and read-only: it takes no ``new_k``).
+    Unaligned shapes (``page_len`` not a
     multiple of 128, a head's rows not whole tiles of the page's type)
     and non-Mosaic dtypes fall back to the gather-then-reference oracle.
     """
@@ -576,6 +731,25 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
         raise ValueError(f"paged_decode_attention: lengths "
                          f"{lengths.shape} must be [{B}]")
     _check_head_scales("paged_decode_attention", hp, k_scale, v_scale)
+    write = new_k is not None
+    if write != (new_v is not None):
+        raise ValueError("paged_decode_attention: new_k and new_v must be "
+                         "given together (a token's K and V are written "
+                         "as one)")
+    if write:
+        if layer is None:
+            raise ValueError(
+                "paged_decode_attention: new_k/new_v with a single "
+                "layer's [num_pages, heads, page_len, head_dim] pool; the "
+                "call writes only the stacked [layers, num_pages, heads, "
+                "head_dim, page_len] pool (with its layer)")
+        for nm, t, pool in (("new_k", new_k, k_pool),
+                            ("new_v", new_v, v_pool)):
+            if t.shape != (B, hp, d) or t.dtype != pool.dtype:
+                raise ValueError(
+                    f"paged_decode_attention: {nm} {t.dtype}{t.shape} "
+                    f"must be {pool.dtype}[{B}, {hp}, {d}], the pool's "
+                    f"storage type")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     from apex_tpu.kernels.flash_attention import _has_vma
@@ -586,13 +760,25 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     # float32, 16 of bfloat16, 32 of int8)
     rows = 8 if interpret else 32 // k_pool.dtype.itemsize
     pallas_ok = (d % rows == 0 and page_len % 128 == 0)
-    if not pallas_ok or (interpret and _has_vma(q)) \
-            or (not interpret and not mosaic_dtype_ok(q, k_pool, v_pool)):
-        return paged_decode_attention_reference(
-            q, k_pool, v_pool, page_table, lengths, scale=scale,
-            k_scale=k_scale, v_scale=v_scale, layer=layer)
     pt = jnp.asarray(page_table, jnp.int32)
     len32 = jnp.asarray(lengths, jnp.int32)
+    if not pallas_ok or (interpret and _has_vma(q)) \
+            or (not interpret and not mosaic_dtype_ok(q, k_pool, v_pool)):
+        if write:
+            pos = jnp.clip(len32, 1, pt.shape[1] * page_len) - 1
+            page_ids = jnp.take_along_axis(
+                pt, (pos // page_len)[:, None], axis=1)[:, 0]
+            # a row of length 0 writes nothing: its page id is past the
+            # pool, and the scatter drops it
+            page_ids = jnp.where(len32 > 0, page_ids, P)
+            k_pool = _pool_write_tokens(k_pool, layer, page_ids,
+                                        pos % page_len, new_k)
+            v_pool = _pool_write_tokens(v_pool, layer, page_ids,
+                                        pos % page_len, new_v)
+        out = paged_decode_attention_reference(
+            q, k_pool, v_pool, page_table, lengths, scale=scale,
+            k_scale=k_scale, v_scale=v_scale, layer=layer)
+        return (out, k_pool, v_pool) if write else out
     ks = vs = None
     if k_scale is not None:
         ks = jnp.asarray(k_scale, jnp.float32)
@@ -608,8 +794,11 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     pages = _pages_per_step(h_kv * d * page_len * k_pool.dtype.itemsize,
                             pt.shape[1])
     out = _paged_decode_pallas(q, k_pool, v_pool, pt, len32,
-                               jnp.int32(layer or 0), ks, vs,
+                               jnp.int32(layer or 0), ks, vs, new_k, new_v,
                                scale=float(scale), pages=pages,
                                interpret=interpret)
+    if write:
+        out, k_pool, v_pool = out
     live = (lengths > 0)[:, None, None]
-    return jnp.where(live, out, 0).astype(q.dtype)
+    out = jnp.where(live, out, 0).astype(q.dtype)
+    return (out, k_pool, v_pool) if write else out

@@ -27,6 +27,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.kernels.decode_attention import _pool_write_tokens
 from apex_tpu.kernels.flash_attention import flash_attention
 from apex_tpu.normalization import FusedLayerNorm
 
@@ -77,27 +78,6 @@ def _dense_factory(weight_quant: bool, dense_dtype, param_dtype):
     return _dense
 
 
-def _pool_write_tokens(pool, layer, page_ids, off, new):
-    """Write one token per batch row into the stacked paged pool, IN
-    PLACE: ``pool`` ``[layers, num_pages, h, d, page_len]``, ``new``
-    ``[B, h, d]`` (already in the pool's storage dtype) lands at
-    ``pool[layer, page_ids[b], :, :, off[b]]``.
-
-    Written as read-modify-write of each row's whole write page — gather
-    the B pages, replace lane ``off[b]``, scatter the pages back —
-    because a scatter whose window is a whole page runs on the pool as
-    it lies, while a scatter of the ``[h, d]`` column alone makes the
-    TPU compiler re-lay the entire pool out (the window dims must be its
-    minor ones) and back. A live row's write page is its own (shared
-    pages are full); inactive rows all name the sentinel page, whose
-    contents nothing reads."""
-    pages = pool[layer, page_ids]                     # [B, h, d, pl]
-    lane = jax.lax.broadcasted_iota(jnp.int32, pages.shape, 3)
-    pages = jnp.where(lane == off[:, None, None, None], new[..., None],
-                      pages)
-    return pool.at[layer, page_ids].set(pages)
-
-
 def _pool_write_pages(pool, layer, page_ids, new):
     """Write whole pages into the stacked paged pool, in place:
     ``new`` ``[B, h, n * page_len, d]`` (storage dtype) fills pages
@@ -120,8 +100,11 @@ class SelfAttention(nn.Module):
     - **decode** (``cache=(k_pool, v_pool, page_table)`` + ``positions``
       + the static ``layer``, S == 1): the token's K/V is written into
       the pool at ``positions[b]`` and attention runs against the cached
-      prefix, length-masked with fp32 accumulation
-      (:func:`apex_tpu.kernels.decode_attention.paged_decode_attention`).
+      prefix, length-masked with fp32 accumulation - ONE call,
+      :func:`apex_tpu.kernels.decode_attention.paged_decode_attention`
+      handed the new K/V: its kernel edits the row's last page in the
+      VMEM it fetched it into and copies that page back, so the program
+      has no gather, select or scatter of pages in front of it.
     - **chunked prefill** (same ``cache``, S > 1): S consecutive prompt
       tokens starting at cache position ``positions[b]`` — their K/V is
       written at ``[positions[b], positions[b] + S)`` and each attends
@@ -266,26 +249,20 @@ class SelfAttention(nn.Module):
                 from apex_tpu.kernels.decode_attention import \
                     paged_decode_attention
 
-                # the write page: logical block pos // page_len of
-                # each row. Inactive slots' tables point at the
-                # sentinel page, so their (discarded) write can
-                # never corrupt a live row; a live slot's write
-                # page is uniquely owned (shared pages are always
-                # full — copy-on-write by construction).
-                page_ids = jnp.take_along_axis(
-                    page_table, (pos // page_len)[:, None],
-                    axis=1)[:, 0]
-                off = pos % page_len
-                k_cache = _pool_write_tokens(
-                    k_cache, layer, page_ids, off,
-                    _store(k[:, :, 0], k_cache.dtype, ks, 1))
-                v_cache = _pool_write_tokens(
-                    v_cache, layer, page_ids, off,
-                    _store(v[:, :, 0], v_cache.dtype, vs, 1))
-                # write-then-attend: the token sees its own K/V
-                ctx = paged_decode_attention(
-                    q[:, :, 0], k_cache, v_cache, page_table,
-                    pos + 1, k_scale=ks, v_scale=vs, layer=layer)
+                # write-then-attend, both in the one call: the token's
+                # K/V goes to position pos of its row - the row's last
+                # live page, which the kernel holds in VMEM anyway and
+                # copies back edited - and the token sees it. Inactive
+                # slots' tables point at the sentinel page, so their
+                # (discarded) write can never corrupt a live row; a
+                # live slot's write page is uniquely owned (shared
+                # pages are always full — copy-on-write by
+                # construction).
+                ctx, k_cache, v_cache = paged_decode_attention(
+                    q[:, :, 0], k_cache, v_cache, page_table, pos + 1,
+                    new_k=_store(k[:, :, 0], k_cache.dtype, ks, 1),
+                    new_v=_store(v[:, :, 0], v_cache.dtype, vs, 1),
+                    k_scale=ks, v_scale=vs, layer=layer)
             else:
                 from apex_tpu.kernels.prefill_attention import \
                     paged_prefill_attention

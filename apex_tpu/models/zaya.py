@@ -58,8 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.kernels.layer_norm import rms_norm_reference
-from apex_tpu.models.transformer_lm import (_pool_write_pages,
-                                            _pool_write_tokens)
+from apex_tpu.models.transformer_lm import _pool_write_pages
 
 __all__ = ["ZayaLM"]
 
@@ -105,9 +104,11 @@ def _attend(q, k, v, cache, positions, layer, scale):
     """Causal attention of ``q [B, nq, S, d]`` over ``k, v [B, nk, S, d]``
     (``nq // nk`` query heads a K/V head). With the paged ``cache =
     (k_pool, v_pool, page_table)`` the new K/V are written IN PLACE into
-    pool layer ``layer`` at ``positions [B]`` (one token: its write page,
-    read-modify-write; a chunk: whole pages) and attention reads the pool
-    through the table; without, the sequence attends itself. Returns
+    pool layer ``layer`` at ``positions [B]`` and attention reads the pool
+    through the table (one token: written by the decode kernel itself,
+    into the row's last page as it holds it; a chunk: whole pages,
+    scattered in front of the chunk kernel); without, the sequence
+    attends itself. Returns
     ``(ctx [B, nq, S, d], (k_pool, v_pool) | None)``."""
     B, _, S, _ = q.shape
     if cache is not None:
@@ -120,18 +121,12 @@ def _attend(q, k, v, cache, positions, layer, scale):
         L = page_table.shape[1] * page_len
         p0 = jnp.clip(jnp.asarray(positions, jnp.int32), 0, L - S)
         if S == 1:
-            page_ids = jnp.take_along_axis(
-                page_table, (p0 // page_len)[:, None], axis=1)[:, 0]
-            off = p0 % page_len
-            k_pool = _pool_write_tokens(
-                k_pool, layer, page_ids, off,
-                jnp.asarray(k[:, :, 0], k_pool.dtype))
-            v_pool = _pool_write_tokens(
-                v_pool, layer, page_ids, off,
-                jnp.asarray(v[:, :, 0], v_pool.dtype))
-            ctx = paged_decode_attention(
+            ctx, k_pool, v_pool = paged_decode_attention(
                 q[:, :, 0], k_pool, v_pool, page_table, p0 + 1,
-                scale=scale, layer=layer)[:, :, None]
+                new_k=jnp.asarray(k[:, :, 0], k_pool.dtype),
+                new_v=jnp.asarray(v[:, :, 0], v_pool.dtype),
+                scale=scale, layer=layer)
+            ctx = ctx[:, :, None]
         else:
             if S % page_len:
                 raise ValueError(

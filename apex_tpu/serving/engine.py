@@ -237,7 +237,8 @@ class PendingDecode:
     immediately and the reading degenerates to today's measurement).
     ``attended`` is what each decoding row's length was
     in this step, kept for reconcile's ``serving.decode.pages_live`` /
-    ``pages_tabled`` counters: dispatch counts nothing itself."""
+    ``pages_tabled`` / ``pages_written`` counters: dispatch counts
+    nothing itself."""
 
     tokens: Any                 # [slots] int32, ON DEVICE until reconcile
     finite: Any                 # [slots] bool, ON DEVICE until reconcile
@@ -2159,7 +2160,11 @@ class Engine:
         ``serving.decode.pages_live`` (sum over decoding rows of
         ``ceil(length / page_len)``: the pages the kernel fetches) and
         ``serving.decode.pages_tabled`` (decoding rows x ``max_pages``:
-        what a walk of the whole table would fetch)."""
+        what a walk of the whole table would fetch), and how many pages
+        that kernel wrote back with the step's tokens in them,
+        ``serving.decode.pages_written`` (decoding rows x the pool's
+        layers x 2, a K and a V page: the whole of the decode program's
+        pool write since the kernel does it)."""
         if pending.reconciled:
             raise RuntimeError("PendingDecode already reconciled — each "
                                "dispatched step reads back exactly once")
@@ -2190,6 +2195,11 @@ class Engine:
             self._registry.counter_inc(
                 "serving.decode.pages_tabled",
                 pending.attended.size * self.max_pages)
+            # and wrote: each decoding row's last page, K and V, once a
+            # layer of the pool
+            self._registry.counter_inc(
+                "serving.decode.pages_written",
+                pending.attended.size * self.cache.k.shape[0] * 2)
         return out, finite, dt
 
     def sync(self) -> None:

@@ -27,8 +27,13 @@ import jax
 import jax.numpy as jnp
 
 
-def timeit(fn, *args, warmup=2, steps=10):
+def timeit(fn, *args, warmup=2, steps=10, donate=()):
     """Per-step DEVICE time of a jitted callable, ms.
+
+    ``donate``: the numbers of the arguments the callable updates in
+    place. They are donated, and each call's are the LAST ``len(donate)``
+    results of the call before (a program that writes a buffer it is
+    handed is timed without the copy an undonated buffer costs).
 
     Anchored on the profiler's device-lane occupancy
     (pyprof.device_busy busy_ms / steps): host wall clock around a
@@ -41,14 +46,22 @@ def timeit(fn, *args, warmup=2, steps=10):
 
     from apex_tpu import pyprof
 
-    fn = jax.jit(fn)
-    for _ in range(warmup):
+    fn = jax.jit(fn, donate_argnums=tuple(donate))
+    args = list(args)
+
+    def call():
         out = fn(*args)
+        if donate:
+            for n, new in zip(donate, out[len(out) - len(donate):]):
+                args[n] = new
+        return out
+    for _ in range(warmup):
+        out = call()
     jax.block_until_ready(out)
     with tempfile.TemporaryDirectory() as td:
         with pyprof.trace(td):
             for _ in range(steps):
-                out = fn(*args)
+                out = call()
             jax.block_until_ready(out)
         try:
             d = pyprof.device_busy(td)
@@ -59,7 +72,7 @@ def timeit(fn, *args, warmup=2, steps=10):
     times = []
     for _ in range(steps):
         t0 = time.perf_counter()
-        out = fn(*args)
+        out = call()
         jax.block_until_ready(out)
         times.append((time.perf_counter() - t0) * 1e3)
     times.sort()
@@ -372,27 +385,58 @@ def paged_decode_case(cell, seed=0):
     return q, kp, vp, jnp.asarray(pt), jnp.asarray(lengths)
 
 
-def paged_decode_ms(cell, case=None):
+def paged_decode_ms(cell, case=None, write=None):
     """Device ms of ONE layer's call of the kernel at ``cell``, the mean
-    over the pool's layers, and the GB of live K/V it has to read."""
-    from apex_tpu.kernels.decode_attention import paged_decode_attention
+    over the pool's layers, and the GB it has to move: the live K/V it
+    reads and, where it writes, each row's K and V page written back.
+    ``write``: ``None`` the read-only call; ``"kernel"`` the call handed
+    every row's new K/V, the decode program's form (the pools donated,
+    as the engine's are); ``"xla"`` what that call replaced, the
+    gather-select-scatter of ``_pool_write_tokens`` in front of the
+    read-only call."""
+    from apex_tpu.kernels.decode_attention import (_pool_write_tokens,
+                                                   paged_decode_attention)
 
     q, kp, vp, pt, lengths = case or paged_decode_case(cell)
+    rows, (_, _, h_kv, d, page_len) = q.shape[0], kp.shape
+    new = jax.random.normal(jax.random.PRNGKey(7), (2, rows, h_kv, d),
+                            kp.dtype)
 
     def layers(q, kp, vp, pt, lengths):
-        return sum(paged_decode_attention(q, kp, vp, pt, lengths, layer=i)
-                   .astype(jnp.float32) for i in range(PAGED_DECODE_LAYERS))
-    ms = timeit(layers, q, kp, vp, pt, lengths) / PAGED_DECODE_LAYERS
-    _, _, h_kv, d, page_len = kp.shape
+        out = 0.0
+        for i in range(PAGED_DECODE_LAYERS):
+            if write == "kernel":
+                ctx, kp, vp = paged_decode_attention(
+                    q, kp, vp, pt, lengths, new_k=new[0], new_v=new[1],
+                    layer=i)
+            else:
+                if write == "xla":
+                    pos = lengths - 1
+                    ids = jnp.take_along_axis(
+                        pt, (pos // page_len)[:, None], axis=1)[:, 0]
+                    kp = _pool_write_tokens(kp, i, ids, pos % page_len,
+                                            new[0])
+                    vp = _pool_write_tokens(vp, i, ids, pos % page_len,
+                                            new[1])
+                ctx = paged_decode_attention(q, kp, vp, pt, lengths,
+                                             layer=i)
+            out = out + ctx.astype(jnp.float32)
+        return (out, kp, vp) if write else out
+    ms = timeit(layers, q, kp, vp, pt, lengths,
+                donate=(1, 2) if write else ()) / PAGED_DECODE_LAYERS
     page_bytes = 2 * h_kv * d * page_len * kp.dtype.itemsize  # K and V
-    live = float(jnp.sum(-(-lengths // 128)))
-    return ms, live * page_bytes / 1e9
+    pages = float(jnp.sum(-(-lengths // 128))) + (rows if write else 0)
+    return ms, pages * page_bytes / 1e9
 
 
 def bench_paged_decode():
     """The paged decode kernel against its gather-then-attend oracle
     (what XLA makes of the same read) at the benchmark's two serving
-    geometries; the roofline counts the LIVE pages' bytes."""
+    geometries; the roofline counts the LIVE pages' bytes. Then the call
+    the decode program makes, which also writes the step's K/V
+    (``paged_decode_write``), against the XLA write it replaced in front
+    of the read-only kernel: bytes are the live pages read plus one K and
+    one V page a row written back."""
     from apex_tpu.kernels.decode_attention import \
         paged_decode_attention_reference
 
@@ -403,6 +447,11 @@ def bench_paged_decode():
         xla = timeit(lambda *a: paged_decode_attention_reference(
             *a, scale=1 / d ** 0.5, layer=1), *case)
         row("paged_decode", cell, ms, xla, gbytes=gbytes)
+        # each timing donates its pools: a case of its own
+        ms, gbytes = paged_decode_ms(cell, paged_decode_case(cell),
+                                     write="kernel")
+        xla, _ = paged_decode_ms(cell, paged_decode_case(cell), write="xla")
+        row("paged_decode_write", cell, ms, xla, gbytes=gbytes)
 
 
 SUITES = {"flash": bench_flash, "ln": bench_ln, "xentropy": bench_xentropy,

@@ -40,8 +40,8 @@ import pytest
 from apex_tpu import telemetry
 from apex_tpu.amp.policy import resolve_policy
 from apex_tpu.kernels.decode_attention import (
-    decode_attention_reference, gather_pages, paged_decode_attention,
-    paged_decode_attention_reference)
+    _pool_write_tokens, decode_attention_reference, gather_pages,
+    paged_decode_attention, paged_decode_attention_reference)
 from apex_tpu.kernels.prefill_attention import (
     paged_prefill_attention, paged_prefill_attention_reference,
     prefill_attention_reference)
@@ -369,6 +369,7 @@ _GEOMETRIES = {
     "g1_d64_bf16": (1, 64, "bf16", True),
     "g4_d128_bf16": (4, 128, "bf16", True),
     "g1_d64_int8": (1, 64, "int8", True),
+    "g8_d256_bf16": (8, 256, "bf16", True),
     "g4_d64_bf16_one_layer": (4, 64, "bf16", False),
     "g1_d128_int8_one_layer": (1, 128, "int8", False),
 }
@@ -440,22 +441,82 @@ def step_bytes():
         vmem.set_override("decode.paged_step_bytes", saved)
 
 
-@pytest.mark.parametrize("walk,shared", [
-    (w, sh) for w in sorted(_WALKS) for sh in (False, True)
-    if not (sh and w == "all_rows_empty")])   # nothing read, nothing shared
+def _bits(x):
+    """An array's bytes as integers: equality that NaN cannot fail."""
+    x = np.asarray(x)
+    return x.view({1: np.uint8, 2: np.uint16, 4: np.uint32}[x.itemsize])
+
+
+def _write_then_read(q, kp, vp, pt, lens, new_k, new_v, layer, **kw):
+    """What the writing call must equal bit for bit: the XLA write of
+    each live row's token (``_pool_write_tokens``; a row of length 0
+    names a page past the pool and is dropped), then the read-only
+    kernel on that pool. -> (out, k_pool, v_pool)."""
+    page_len, num_pages = kp.shape[-1], kp.shape[1]
+    lengths = np.asarray(lens)
+    pos = np.maximum(lengths - 1, 0)
+    ids = np.asarray(pt)[np.arange(len(lengths)), pos // page_len]
+    ids = jnp.asarray(np.where(lengths > 0, ids, num_pages), jnp.int32)
+    off = jnp.asarray(pos % page_len, jnp.int32)
+    kp = _pool_write_tokens(kp, layer, ids, off, new_k)
+    vp = _pool_write_tokens(vp, layer, ids, off, new_v)
+    out = paged_decode_attention(q, kp, vp, pt, lens, layer=layer,
+                                 interpret=True, **kw)
+    return out, kp, vp
+
+
+# a shared prefix under the writing call: where a write page follows the
+# shared pages at once, and with rows of length 0 between the writers
+_SHARED_WRITES = ("page_boundary_and_one_past",
+                  "empty_rows_between_live_ones")
+
+
+@pytest.mark.parametrize("walk,shared,write", [
+    (w, sh, wr) for w in sorted(_WALKS) for sh in (False, True)
+    for wr in (False, True)
+    if not (sh and w == "all_rows_empty")     # nothing read, nothing shared
+    and not (sh and wr and w not in _SHARED_WRITES)])
 @pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
 def test_paged_decode_walks_only_a_rows_live_pages(geometry, walk, shared,
-                                                   step_bytes):
+                                                   write, step_bytes):
+    """``write``: the call is also handed the rows' new K/V and must
+    equal ``_pool_write_tokens`` followed by the read-only call in BOTH
+    results, the attention output and the whole pool, bit for bit - the
+    write page in any slot of a step (``one_page_a_step`` .. three), its
+    lane 0 (a fresh page: lengths 1, 129, 257, 385), mid-page and 127
+    (128, 256, 640), rows of length 0 in between, and a shared full
+    prefix in front of each row's own write page."""
     lengths, pages = _WALKS[walk]
     if shared:
-        # a shared prefix is whole pages its readers attend in full
-        lengths = [n and max(n, 256 + i) for i, n in enumerate(lengths)]
+        # a shared prefix is whole pages its readers attend in full; a
+        # row that writes has its write page past them (copy-on-write)
+        lengths = [n and max(n, 256 + write + i)
+                   for i, n in enumerate(lengths)]
     q, kp, vp, pt, scales, stacked, dtype = _walk_case(geometry, lengths,
                                                       shared)
     lens = jnp.asarray(lengths, jnp.int32)
     layer = 1 if stacked else None
     h_kv, d = kp.shape[1], kp.shape[2]
     step_bytes(pages, h_kv * d * 128 * (1 if dtype == "int8" else 2))
+    store = jnp.int8 if dtype == "int8" else jnp.bfloat16
+    rng = np.random.default_rng(5)
+    new = [jnp.asarray(rng.integers(-127, 128, size=(len(lengths), h_kv, d))
+                       if dtype == "int8" else
+                       rng.normal(size=(len(lengths), h_kv, d)), store)
+           for _ in range(2)]
+    if write and not stacked:
+        with pytest.raises(ValueError, match="new_k.*single layer"):
+            paged_decode_attention(
+                q, *(_as_pool(t, dtype, stacked) for t in (kp, vp)), pt,
+                lens, new_k=new[0], new_v=new[1], **scales)
+        return
+    if write:
+        # the oracle's pool holds the new columns
+        pos = np.maximum(np.asarray(lengths) - 1, 0)
+        for b in np.flatnonzero(np.asarray(lengths)):
+            page = int(pt[b, pos[b] // 128])
+            kp[page, :, :, pos[b] % 128] = np.asarray(new[0][b], np.float32)
+            vp[page, :, :, pos[b] % 128] = np.asarray(new[1][b], np.float32)
     # the oracle reads a pool whose sentinel page is zeros; the kernel
     # one whose sentinel is NaN (int8 codes have no NaN: theirs is noise)
     clean = [_as_pool(t, dtype, stacked) for t in (kp, vp)]
@@ -463,8 +524,39 @@ def test_paged_decode_walks_only_a_rows_live_pages(geometry, walk, shared,
     dirty = [_as_pool(t, dtype, stacked) for t in (kp, vp)]
     want = paged_decode_attention_reference(
         q, *clean, pt, lens, scale=1 / d ** 0.5, layer=layer, **scales)
-    got = jax.jit(lambda *a: paged_decode_attention(
-        *a, layer=layer, interpret=True, **scales))(q, *dirty, pt, lens)
+    if not write:
+        got = jax.jit(lambda *a: paged_decode_attention(
+            *a, layer=layer, interpret=True, **scales))(q, *dirty, pt, lens)
+    else:
+        # the pool the call is handed lacks the new columns
+        before = [_pool_write_tokens(
+            t, layer, pt[jnp.arange(len(lengths)), jnp.asarray(pos // 128)],
+            jnp.asarray(pos % 128, jnp.int32),
+            jnp.full_like(new[0], 77 if dtype == "int8" else 1e4))
+            for t in dirty]
+        got, k_got, v_got = jax.jit(lambda q, k, v, pt, lens, nk, nv:
+                                    paged_decode_attention(
+            q, k, v, pt, lens, new_k=nk, new_v=nv, layer=layer,
+            interpret=True, **scales))(q, *before, pt, lens, *new)
+        out, k_want, v_want = _write_then_read(q, *before, pt, lens, *new,
+                                               layer, **scales)
+        assert (_bits(got) == _bits(out)).all()
+        assert (_bits(k_got) == _bits(k_want)).all()
+        assert (_bits(v_got) == _bits(v_want)).all()
+        live = np.asarray(lengths) > 0
+        if live.any():
+            # and the write is there: the pool moved, by the new columns
+            assert not (_bits(k_got) == _bits(before[0])).all()
+        for b in np.flatnonzero(live):
+            page, lane = int(pt[b, pos[b] // 128]), pos[b] % 128
+            assert (_bits(k_got[layer, page, :, :, lane])
+                    == _bits(new[0][b])).all()
+            assert (_bits(v_got[layer, page, :, :, lane])
+                    == _bits(new[1][b])).all()
+        if shared:
+            for t_got, t_in in ((k_got, before[0]), (v_got, before[1])):
+                assert (_bits(t_got[:, np.asarray(pt[0, :2])])
+                        == _bits(t_in[:, np.asarray(pt[0, :2])])).all()
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     assert got.shape == want.shape and np.isfinite(got).all()
     np.testing.assert_allclose(got, want, atol=3e-2)
@@ -763,6 +855,9 @@ def test_decode_page_counters_follow_the_rows_lengths(engine):
     assert c["serving.decode.pages_live"] == sum(
         -(-length // ep.page_len) for length in lengths)
     assert c["serving.decode.pages_tabled"] == len(lengths) * ep.max_pages
+    # a K and a V page a layer of the pool, written back by the kernel
+    assert c["serving.decode.pages_written"] == \
+        len(lengths) * ep.cache.k.shape[0] * 2
     assert c["serving.decode.steps"] == len(lengths)
     share = c["serving.decode.pages_live"] / c["serving.decode.pages_tabled"]
     assert share == pytest.approx(
@@ -771,6 +866,7 @@ def test_decode_page_counters_follow_the_rows_lengths(engine):
     text = render_summary(summarize_records([reg.snapshot()]))
     assert "serving.decode.pages_live" in text
     assert "serving.decode.pages_tabled" in text
+    assert "serving.decode.pages_written" in text
     # several rows a step: every decoding row counts its own table
     ep.reset(clear_prefixes=True)
     reg = telemetry.MetricsRegistry()
@@ -783,6 +879,8 @@ def test_decode_page_counters_follow_the_rows_lengths(engine):
         ep.set_registry(None)
     c = reg.snapshot()["counters"]
     assert c["serving.decode.pages_tabled"] == len(lengths) * ep.max_pages
+    assert c["serving.decode.pages_written"] == \
+        len(lengths) * ep.cache.k.shape[0] * 2
     assert c["serving.decode.pages_live"] == sum(
         -(-length // ep.page_len) for length in lengths)
 

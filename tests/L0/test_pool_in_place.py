@@ -7,7 +7,10 @@ buffer assignment says nothing about it (the interpreted Pallas call
 carries its operands through a loop). What the CPU CAN pin is the shape
 of the program the compiler is handed: the stacked pool
 ``[layers, num_pages, heads, head_dim, page_len]`` goes through the
-forward pass as one value, each layer's write is a scatter into it, the
+forward pass as one value, each layer's write is a scatter into it (the
+chunk and the verify program) or the decode kernel's own, its pool
+outputs aliased to its pool inputs (the decode program: no scatter of
+the pool is left in it), the
 kernels take it whole, and nothing of a layer's size or more is sliced,
 gathered, stacked, transposed or copied on the way. A program that
 slices a layer out and restacks the pool (what the engine did before)
@@ -82,6 +85,18 @@ def _walk(jaxpr):
                     yield from _walk(sub)
 
 
+def _aliased_operand(e, var):
+    """``e`` is the jitted call of a kernel that writes the pool: the
+    operand of ``e`` that its output ``var`` is aliased to by the
+    ``pallas_call``'s ``input_output_aliases``."""
+    inner = e.params["jaxpr"].jaxpr
+    (call,) = [k for k in inner.eqns if k.primitive.name == "pallas_call"]
+    out = call.outvars.index(inner.outvars[e.outvars.index(var)])
+    (src,) = [i for i, o in call.params["input_output_aliases"] if o == out]
+    assert call.invars[src].aval.shape == var.aval.shape
+    return e.invars[inner.invars.index(call.invars[src])]
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("name", ["decode", "chunk", "verify"])
 def test_paged_program_writes_the_pool_in_place(lm_and_params, name, quant):
@@ -111,20 +126,31 @@ def test_paged_program_writes_the_pool_in_place(lm_and_params, name, quant):
                  if tuple(v.aval.shape) == pool_shape]
         assert len(pools) == 2, f"{name}: a kernel reads {e.invars}"
 
-    # 3. each pool output is a chain of scatters rooted at its input
+    # 3. each pool output is a chain of writes rooted at its input:
+    # scatters in the chunk and the verify program; in the decode
+    # program the kernels themselves, each pool output aliased to its
+    # pool input, and no scatter of the pool at all
     n_params = len(jax.tree.leaves(eng.params))
     producer = {o: e for e in jaxpr.eqns for o in e.outvars}
     writes = {"decode": 1, "chunk": 1, "verify": DRAFT + 1}[name]
+    link = "jit" if name == "decode" else "scatter"
     for which in (0, 1):                        # cache leaves: k, v, ...
         var, steps = jaxpr.outvars[which], 0
         while var in producer:
             e = producer[var]
-            assert e.primitive.name == "scatter", \
+            assert e.primitive.name == link, \
                 f"{name}: the pool passes through {e.primitive.name}"
-            var, steps = e.invars[0], steps + 1
+            var = (_aliased_operand(e, var) if name == "decode"
+                   else e.invars[0])
+            steps += 1
         assert var is jaxpr.invars[n_params + which], \
             f"{name}: pool output {which} is not rooted at its input"
         assert steps == LAYERS * writes
+    if name == "decode":
+        scattered = [e for e in _walk(jaxpr)
+                     if e.primitive.name.startswith("scatter")
+                     and tuple(e.outvars[0].aval.shape) == pool_shape]
+        assert not scattered, f"decode still scatters the pool: {scattered}"
 
 
 def test_program_memory_keys_and_gauges(lm_and_params):

@@ -276,36 +276,56 @@ CELL_GEOMETRY = {
 }
 
 
+@pytest.mark.parametrize("write", [False, True], ids=["read", "write"])
 @pytest.mark.parametrize("cell", sorted(CELL_GEOMETRY))
-def test_paged_decode_at_a_cells_geometry_fits_its_vmem(cell, one_chip,
-                                                        as_v5e):
+def test_paged_decode_at_a_cells_geometry_fits_its_vmem(cell, write,
+                                                        one_chip, as_v5e):
     """The whole stacked pool of a benchmark cell, one middle layer
     read: one kernel, its pages a step read off the page's bytes, and
     the working set it states under the scoped limit it asks for (which
-    the compile above all accepts)."""
+    the compile above all accepts). ``write``: the decode program's
+    call, handed the rows' new K/V and the donated pools - still the one
+    kernel, the pools its outputs aliased to its inputs, and no other
+    instruction of the program yields anything pool-shaped: no copy, no
+    select, no scatter."""
+    import re
+
     from apex_tpu.kernels import decode_attention as da
     rows, h, h_kv, d, table, layers, pool = CELL_GEOMETRY[cell]
     shapes = [((rows, h, d), BF16), ((layers, pool, h_kv, d, PAGE), BF16),
               ((layers, pool, h_kv, d, PAGE), BF16), ((rows, table), I32),
               ((rows,), I32)]
+    if write:
+        shapes += [((rows, h_kv, d), BF16)] * 2
     args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip)
             for s, t in shapes]
 
-    def fn(q, k, v, pt, lengths):
-        return da.paged_decode_attention(q, k, v, pt, lengths,
-                                         layer=layers // 2)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert kernel_calls(compiled.as_text()) == {"paged_decode_attention": 1}
+    def fn(q, k, v, pt, lengths, new_k=None, new_v=None):
+        return da.paged_decode_attention(q, k, v, pt, lengths, new_k=new_k,
+                                         new_v=new_v, layer=layers // 2)
+    compiled = jax.jit(fn, donate_argnums=(1, 2) if write else ()).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert kernel_calls(text) == {"paged_decode_attention": 1}
     page_bytes = h_kv * d * PAGE * 2
     pages = da._pages_per_step(page_bytes, table)
     step_bytes = vmem.overrides()["decode.paged_step_bytes"]
     assert 1 <= pages <= table
     assert pages == 1 or pages * page_bytes <= step_bytes
-    working, limit = da._paged_decode_vmem(args[1], args[0], pages)
+    working, limit = da._paged_decode_vmem(args[1], args[0], pages, write)
     assert 4 * pages * page_bytes < working < limit <= 64 * 2 ** 20, \
         (working, limit)
     # nothing pool-sized beside the pool: the kernel reads it where it is
-    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 8 * 2 ** 20
+    makes_a_pool = set(re.findall(
+        rf"= bf16\[{layers},{pool},{h_kv},{d},{PAGE}\]\S* ([\w-]+)\(", text))
+    if write:
+        # and writes it where it is: both pools go out as they came in
+        assert mem.alias_size_in_bytes == 2 * layers * pool * page_bytes
+        assert makes_a_pool == {"parameter", "get-tuple-element"}
+    else:
+        assert makes_a_pool == {"parameter"}
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])

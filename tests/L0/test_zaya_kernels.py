@@ -10,7 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from apex_tpu.kernels.decode_attention import (decode_attention_reference,
+from apex_tpu.kernels.decode_attention import (_pool_write_tokens,
+                                               decode_attention_reference,
                                                gather_pages,
                                                paged_decode_attention)
 from apex_tpu.kernels.grouped_gemm import (group_ranges, grouped_gemm,
@@ -66,11 +67,15 @@ def test_grouped_heads_match_the_repeated_heads_oracle(h, h_kv, d):
     [1, 384, 0],          # one token beside a full table, an empty row
     [256, 257, 1],        # live pages 2, 3, 1 at two pages a step
 ], ids=["boundary", "one_and_full_and_empty", "ragged_steps"])
-@pytest.mark.parametrize("h,h_kv,d", [(8, 2, 128), (4, 4, 64)])
-def test_paged_decode_walk_under_grouped_heads(h, h_kv, d, lens):
+@pytest.mark.parametrize("write", [False, True], ids=["read", "write"])
+@pytest.mark.parametrize("h,h_kv,d", [(8, 2, 128), (4, 4, 64), (16, 2, 256)])
+def test_paged_decode_walk_under_grouped_heads(h, h_kv, d, lens, write):
     """The decode kernel's steps of several pages, live pages only, at
-    the expert model's head geometry and at plain heads: float32, so the
-    tolerance is summation order. The sentinel page is NaN."""
+    the expert models' head geometries (8q/2kv x 128, 16q/2kv x 256) and
+    at plain heads: float32, so the tolerance is summation order. The
+    sentinel page is NaN. ``write``: the call is handed the rows' new
+    K/V too - its output and its pool must equal, bit for bit, the XLA
+    write (``_pool_write_tokens``) followed by the read-only call."""
     from apex_tpu.kernels import vmem
     q, _, kp, vp, pt = _pool_case(h, h_kv, d, seed=3)
     G, scale = h // h_kv, 1.0 / np.sqrt(d)
@@ -78,6 +83,14 @@ def test_paged_decode_walk_under_grouped_heads(h, h_kv, d, lens):
     pt = jnp.asarray(np.array([[1, 2, 7], [3, 4, 5], [6, 8, 2]], np.int32))
     live = -(-np.asarray(lens) // 128)
     pt = jnp.where(np.arange(3)[None, :] < live[:, None], pt, 0)
+    if write:
+        nk, nv = jax.random.normal(jax.random.PRNGKey(9), (2, 3, h_kv, d))
+        pos = np.maximum(np.asarray(lens) - 1, 0)
+        # a row of length 0 names a page past the pool: dropped
+        ids = jnp.where(lens > 0, pt[np.arange(3), pos // 128], kp.shape[1])
+        k_in, v_in = kp.at[:, 0].set(jnp.nan), vp.at[:, 0].set(jnp.nan)
+        kp = _pool_write_tokens(kp, 1, ids, jnp.asarray(pos % 128), nk)
+        vp = _pool_write_tokens(vp, 1, ids, jnp.asarray(pos % 128), nv)
     k, v = gather_pages(kp, pt, 1), gather_pages(vp, pt, 1)
     want = decode_attention_reference(q, jnp.repeat(k, G, 1),
                                       jnp.repeat(v, G, 1), lens, scale=scale)
@@ -86,6 +99,15 @@ def test_paged_decode_walk_under_grouped_heads(h, h_kv, d, lens):
     try:
         got = jax.jit(lambda *a: paged_decode_attention(*a, layer=1))(
             q, kp, vp, pt, lens)
+        if write:
+            out, k_got, v_got = jax.jit(
+                lambda q, k, v, pt, lens, nk, nv: paged_decode_attention(
+                    q, k, v, pt, lens, new_k=nk, new_v=nv, layer=1))(
+                q, k_in, v_in, pt, lens, nk, nv)
+            assert np.array_equal(np.asarray(out), np.asarray(got))
+            for t_got, t_want in ((k_got, kp), (v_got, vp)):
+                assert np.array_equal(np.asarray(t_got), np.asarray(t_want),
+                                      equal_nan=True)
     finally:
         vmem.remove_override("decode.paged_step_bytes")
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6

@@ -371,3 +371,92 @@ def test_serving_attention_matches_reference(tpu_backend, kind, kv):
     assert got.shape == want.shape and got.dtype == want.dtype
     assert bool(jnp.all(jnp.isfinite(jnp.asarray(got, jnp.float32))))
     _close(got, want, 2e-2)
+
+
+# (rows, query heads, K/V heads, head_dim, table pages, pool dtype): the
+# served models' head geometries, and the int8 tier
+_WRITE_CASES = {
+    "mha_20x64_bf16": (24, 20, 20, 64, 8, "bf16"),
+    "gqa_8q2kv_x128_bf16": (32, 8, 2, 128, 16, "bf16"),
+    "gqa_16q2kv_x256_bf16": (16, 16, 2, 256, 16, "bf16"),
+    "mha_12x64_int8": (8, 12, 12, 64, 5, "int8"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WRITE_CASES))
+def test_paged_decode_writes_the_token_it_attends(tpu_backend, case):
+    """The decode program's call - the rows' new K/V handed to the
+    kernel, which edits each row's last page in VMEM and copies it back
+    while it multiplies - against what it replaced, the XLA page write
+    in front of the read-only kernel: the attention output and the whole
+    pool, every layer of it, bit for bit. Compiled and RUN: the order of
+    the kernel's DMAs is the one thing the interpreter cannot show.
+    Lengths cover a fresh page (lane 0), a page's last lane, mid-page,
+    one token, a full table and rows of length 0 in between."""
+    from apex_tpu.kernels.decode_attention import (_pool_write_tokens,
+                                                   paged_decode_attention)
+    from apex_tpu.utils.chip import kernel_calls
+
+    rows, h, h_kv, d, table, kv = _WRITE_CASES[case]
+    rng = np.random.default_rng(3)
+    layers, page = 3, 128
+    pool = rows * table + 1
+    shape = (layers, pool, h_kv, d, page)
+    if kv == "int8":
+        store = jnp.int8
+        draw = lambda s: jnp.asarray(rng.integers(-127, 128, s), store)  # noqa: E731,E501
+        scales = dict(
+            k_scale=jnp.asarray(rng.uniform(0.01, 0.03, h_kv), jnp.float32),
+            v_scale=jnp.asarray(rng.uniform(0.01, 0.03, h_kv), jnp.float32))
+    else:
+        store = jnp.bfloat16
+        draw = lambda s: jnp.asarray(rng.standard_normal(s), store)  # noqa: E731,E501
+        scales = {}
+    kp, vp, q = draw(shape), draw(shape), jnp.asarray(
+        rng.standard_normal((rows, h, d)), jnp.bfloat16)
+    new_k, new_v = draw((rows, h_kv, d)), draw((rows, h_kv, d))
+    L = table * page
+    lengths = rng.integers(1, L + 1, size=rows)
+    lengths[:8] = [1, 0, 129, 128, 0, L, 2 * page + 1, 300]
+    pt = rng.permutation(np.arange(1, pool)).reshape(rows, table)
+    pt[np.arange(table)[None, :] >= -(-lengths[:, None] // page)] = 0
+    pos = np.maximum(lengths - 1, 0)
+    ids = np.where(lengths > 0, pt[np.arange(rows), pos // page], pool)
+    pt, lens, ids, off = (jnp.asarray(t, jnp.int32)
+                          for t in (pt, lengths, ids, pos % page))
+
+    def writes(q, kp, vp):
+        outs = []
+        for layer in range(layers):
+            out, kp, vp = paged_decode_attention(
+                q, kp, vp, pt, lens, new_k=new_k, new_v=new_v, layer=layer,
+                **scales)
+            outs.append(out)
+        return jnp.stack(outs), kp, vp
+
+    def write_then_read(q, kp, vp):
+        outs = []
+        for layer in range(layers):
+            kp = _pool_write_tokens(kp, layer, ids, off, new_k)
+            vp = _pool_write_tokens(vp, layer, ids, off, new_v)
+            outs.append(paged_decode_attention(q, kp, vp, pt, lens,
+                                               layer=layer, **scales))
+        return jnp.stack(outs), kp, vp
+
+    compiled = jax.jit(writes, donate_argnums=(1, 2)).lower(
+        q, kp, vp).compile()
+    assert kernel_calls(compiled.as_text()) == \
+        {"paged_decode_attention": layers}
+    want = jax.jit(write_then_read)(q, kp, vp)
+    got = compiled(q, kp + 0, vp + 0)           # copies: these are donated
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        bits = {1: np.uint8, 2: np.uint16}[g.itemsize]
+        assert g.shape == w.shape and (g.view(bits) == w.view(bits)).all()
+    # and the new columns are where the rows' lengths say
+    live = np.flatnonzero(lengths)
+    k_got = np.asarray(got[1])
+    bits = {1: np.uint8, 2: np.uint16}[k_got.itemsize]
+    cols = k_got[:, np.asarray(ids)[live], :, :, np.asarray(off)[live]]
+    assert (cols.view(bits)                     # [live rows, layers, h, d]
+            == np.asarray(new_k)[live][:, None].view(bits)).all()
