@@ -62,6 +62,7 @@ from apex_tpu.kernels import mosaic_dtype_ok, vmem
 
 __all__ = ["decode_attention_reference",
            "paged_decode_attention", "paged_decode_attention_reference",
+           "mla_decode_attention", "mla_decode_attention_reference",
            "gather_pages"]
 
 _NEG_INF = -1e30
@@ -267,6 +268,17 @@ def _pool_write_tokens(pool, layer, page_ids, off, new):
     return pool.at[layer, page_ids].set(pages)
 
 
+def _write_slots(pt, lengths, page_len, num_pages):
+    """Where the XLA write of a decode token lands: ``(page ids [B], lane
+    [B])`` of position ``lengths - 1`` (at most the table's span). A row
+    of length 0 writes nothing: its page id is past the pool, and the
+    scatter drops it."""
+    pos = jnp.clip(lengths, 1, pt.shape[1] * page_len) - 1
+    page_ids = jnp.take_along_axis(pt, (pos // page_len)[:, None],
+                                   axis=1)[:, 0]
+    return jnp.where(lengths > 0, page_ids, num_pages), pos % page_len
+
+
 DEFAULT_PAGED_STEP_BYTES = 512 * 1024
 _SCOPED_VMEM_ROOM = 8 * 1024 * 1024
 
@@ -312,7 +324,7 @@ def _paged_decode_vmem(k_pool, q, pages, write=False):
 
 
 def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
-                         G, quant, write, widen):
+                         G, quant, write, widen, vdim=None):
     """One invocation walks every batch row's LIVE pages, ``pages`` of
     them a step, every K/V head of a page in one fetch.
 
@@ -396,18 +408,32 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
         bound by the bytes it moves, PERF.md section 6, PR 35).
     (d) Table slots of a partial step past the row's last live page are
         neither fetched nor written; rows of length 0 write nothing.
+
+    ``vdim`` (static; :func:`mla_decode_attention`): the LATENT page
+    kind. There is ONE pool of one row ``[d]`` a token that every query
+    head reads (``h_kv`` 1, no block-diagonal query), and the value is
+    the first ``vdim`` rows of the page as it lies in the K buffer: one
+    fetch of a page serves both products, one write-back the token's
+    row. No V operand, buffer or new-V exists; everything else - the
+    walk, the double buffer, the write's hazards - is the text above.
     """
     refs = list(refs)
+    latent = vdim is not None
+    n_pools = 1 if latent else 2
     q_ref = refs.pop(0)
     ks_ref, vs_ref = (refs.pop(0), refs.pop(0)) if quant else (None, None)
-    nk_ref, nv_ref = (refs.pop(0), refs.pop(0)) if write else (None, None)
-    k_hbm, v_hbm, o_ref = refs.pop(0), refs.pop(0), refs.pop(0)
+    news = [refs.pop(0) for _ in range(n_pools)] if write else []
+    hbms = [refs.pop(0) for _ in range(n_pools)]
+    o_ref = refs.pop(0)
     if write:
         # the pools as outputs, aliased to the inputs: one reference to
         # read and to write
-        k_hbm, v_hbm = refs.pop(0), refs.pop(0)
-    kbuf, vbuf, sem, p_ref, acc_ref, m_ref, l_ref = refs[:7]
-    wsem = refs[7] if write else None
+        hbms = [refs.pop(0) for _ in range(n_pools)]
+    bufs = [refs.pop(0) for _ in range(n_pools)]
+    kbuf, vbuf = bufs[0], bufs[-1]
+    pools = list(zip(hbms, bufs))
+    sem, p_ref, acc_ref, m_ref, l_ref = refs[:5]
+    wsem = refs[5] if write else None
     B, h, d = q_ref.shape
     h_kv, page_len = h // G, kbuf.shape[-1] // pages
     C, T = h_kv * d, pages * page_len
@@ -443,7 +469,7 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
         slice ``jj`` of buffer ``slot``, back into the pool."""
         page = pt_ref[b, jnp.clip(live_pages(b) - 1, 0, max_pages - 1)]
         lanes = pl.ds(jj * page_len, page_len)
-        for x, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+        for x, (hbm, buf) in enumerate(pools):
             act(pltpu.make_async_copy(buf.at[slot, :, :, lanes],
                                       hbm.at[layer, page], wsem.at[x]))
 
@@ -458,8 +484,7 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
             def _page():
                 page = pt_ref[b, jnp.minimum(j, max_pages - 1)]
                 lanes = pl.ds(jj * page_len, page_len)
-                for x, (hbm, buf) in enumerate(((k_hbm, kbuf),
-                                                (v_hbm, vbuf))):
+                for x, (hbm, buf) in enumerate(pools):
                     act(pltpu.make_async_copy(
                         hbm.at[layer, page], buf.at[slot, :, :, lanes],
                         sem.at[x, slot]))
@@ -467,12 +492,15 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
     # the block-diagonal query: q tiled h_kv times along the lanes by a
     # product with [I I .. I] (exact: one term a sum), masked to each
     # query head's own K/V head
-    tile = (jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (d, C), 1), d)
-            == jax.lax.broadcasted_iota(jnp.int32, (d, C), 0)
-            ).astype(q_ref.dtype)
-    own = (jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (h, C), 1), d)
-           == jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (h, C), 0),
-                          G))
+    if not latent:
+        tile = (jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (d, C), 1),
+                            d)
+                == jax.lax.broadcasted_iota(jnp.int32, (d, C), 0)
+                ).astype(q_ref.dtype)
+        own = (jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (h, C), 1),
+                           d)
+               == jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (h, C),
+                                                       0), G))
     if split:
         p_ref[...] = jnp.zeros_like(p_ref)
 
@@ -491,8 +519,11 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-        qbd = jnp.where(own, dot(q_ref[b], tile, ((1,), (0,))),
-                        0.0).astype(k_dtype)                  # [h, C]
+        if latent:
+            qbd = q_ref[b].astype(k_dtype)                    # [h, d]
+        else:
+            qbd = jnp.where(own, dot(q_ref[b], tile, ((1,), (0,))),
+                            0.0).astype(k_dtype)              # [h, C]
 
         def step(i, slot):
             last = i + 1 >= steps
@@ -529,7 +560,7 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
                         jnp.int32, (1, page_len), 1) == off
                     at = (slot, slice(None), slice(None),
                           slice(jj * page_len, (jj + 1) * page_len))
-                    for new_ref, buf in ((nk_ref, kbuf), (nv_ref, vbuf)):
+                    for new_ref, buf in zip(news, bufs):
                         col = pltpu.roll(
                             pltpu.bitcast(new_ref[jax.lax.div(b, page_len)],
                                           jnp.uint32), shift, 1)
@@ -540,7 +571,8 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
                             buf.dtype).reshape(h_kv, d, page_len)
                     write_back(b, slot, jj, lambda c: c.start())
             k = kbuf[slot].reshape(C, T).astype(k_dtype)
-            v = vbuf[slot].reshape(C, T)
+            v = kbuf[slot, 0, :vdim, :] if latent \
+                else vbuf[slot].reshape(C, T)
             if quant:
                 v = v.astype(bf16)
             s = dot(qbd, k, ((1,), (0,))) * scale             # [h, T]
@@ -581,31 +613,40 @@ def _paged_decode_kernel(pt_ref, len_ref, layer_ref, *refs, scale, pages,
                 write_back(b, 0, 0, lambda c: c.wait())
         l = l_ref[:, :1]
         out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)      # [h, C]
-        for hh in range(h_kv):
-            o_ref[b, hh * G:(hh + 1) * G, :] = out[
-                hh * G:(hh + 1) * G, hh * d:(hh + 1) * d].astype(o_ref.dtype)
+        if latent:
+            o_ref[b] = out.astype(o_ref.dtype)                # [h, vdim]
+        else:
+            for hh in range(h_kv):
+                o_ref[b, hh * G:(hh + 1) * G, :] = out[
+                    hh * G:(hh + 1) * G,
+                    hh * d:(hh + 1) * d].astype(o_ref.dtype)
         return slot
 
     jax.lax.fori_loop(0, B, row, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret",
+                                             "vdim"))
 def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, layer, ks=None,
                          vs=None, new_k=None, new_v=None, *, scale, pages,
-                         interpret):
+                         interpret, vdim=None):
     """The kernel's call on the stacked pool, ``layer`` a traced scalar.
     Jitted: a model's layers differ in ``layer`` alone, so they share
     one trace and one lowered function (36 traces of this body were
     seconds of every process's start). With ``new_k``/``new_v`` ``[B,
     h_kv, d]`` the call writes them too and returns ``(out, k_pool,
-    v_pool)``, the pools aliased to the ones handed in."""
+    v_pool)``, the pools aliased to the ones handed in. ``vdim``: the
+    latent page kind (``v_pool`` and ``new_v`` None; the output is ``[B,
+    h, vdim]`` float32 and the call returns ``(out, pool)``)."""
     B, h, d = q.shape
     _, _, h_kv, _, page_len = k_pool.shape
     T, C = pages * page_len, h_kv * d
     quant, write = ks is not None, new_k is not None
+    latent = vdim is not None
+    pools = (k_pool,) if latent else (k_pool, v_pool)
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                pages=pages, G=h // h_kv, quant=quant,
-                               write=write, widen=interpret)
+                               write=write, widen=interpret, vdim=vdim)
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     operands = [q]
@@ -620,27 +661,26 @@ def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, layer, ks=None,
         operands += [jnp.pad(t.reshape(B, C),
                              ((0, blocks * page_len - B), (0, 0)))
                      .reshape(blocks, page_len, C).swapaxes(1, 2)
-                     for t in (new_k, new_v)]
-    out_shape = [jax.ShapeDtypeStruct((B, h, d), q.dtype)]
+                     for t in ((new_k,) if latent else (new_k, new_v))]
+    out_shape = [jax.ShapeDtypeStruct((B, h, vdim), jnp.float32) if latent
+                 else jax.ShapeDtypeStruct((B, h, d), q.dtype)]
     out_specs, scratch, aliases = [whole], [], {}
     if write:
         first = 3 + len(operands)         # the scalars count
-        aliases = {first: 1, first + 1: 2}
-        out_shape += [jax.ShapeDtypeStruct(t.shape, t.dtype)
-                      for t in (k_pool, v_pool)]
-        out_specs += [in_hbm, in_hbm]
+        aliases = {first + x: 1 + x for x in range(len(pools))}
+        out_shape += [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in pools]
+        out_specs += [in_hbm] * len(pools)
         scratch = [pltpu.SemaphoreType.DMA((2,))]         # K|V write-back
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,            # page_table, lengths, layer
         grid=(1,),
-        in_specs=[whole] * len(operands) + [in_hbm, in_hbm],
+        in_specs=[whole] * len(operands) + [in_hbm] * len(pools),
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((2, h_kv, d, T), k_pool.dtype),    # K, two buffers
-            pltpu.VMEM((2, h_kv, d, T), v_pool.dtype),    # V, two buffers
+        scratch_shapes=[           # K (and V): two buffers each
+            pltpu.VMEM((2, h_kv, d, T), t.dtype) for t in pools] + [
             pltpu.SemaphoreType.DMA((2, 2)),              # (K|V, buffer)
             pltpu.VMEM((3 * _p_rows(h), T), jnp.bfloat16),  # p's pieces
-            pltpu.VMEM((h, C), jnp.float32),              # acc
+            pltpu.VMEM((h, vdim if latent else C), jnp.float32),  # acc
             pltpu.VMEM((h, 128), jnp.float32),            # m
             pltpu.VMEM((h, 128), jnp.float32),            # l
         ] + scratch,
@@ -651,9 +691,10 @@ def _paged_decode_pallas(q, k_pool, v_pool, pt, lengths, layer, ks=None,
         input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=limit),
-        interpret=interpret, name="paged_decode_attention",
+        interpret=interpret,
+        name="mla_decode_attention" if latent else "paged_decode_attention",
     )(pt, lengths, jnp.reshape(layer, (1,)).astype(jnp.int32), *operands,
-      k_pool, v_pool)
+      *pools)
     return tuple(outs) if write else outs[0]
 
 
@@ -765,16 +806,9 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     if not pallas_ok or (interpret and _has_vma(q)) \
             or (not interpret and not mosaic_dtype_ok(q, k_pool, v_pool)):
         if write:
-            pos = jnp.clip(len32, 1, pt.shape[1] * page_len) - 1
-            page_ids = jnp.take_along_axis(
-                pt, (pos // page_len)[:, None], axis=1)[:, 0]
-            # a row of length 0 writes nothing: its page id is past the
-            # pool, and the scatter drops it
-            page_ids = jnp.where(len32 > 0, page_ids, P)
-            k_pool = _pool_write_tokens(k_pool, layer, page_ids,
-                                        pos % page_len, new_k)
-            v_pool = _pool_write_tokens(v_pool, layer, page_ids,
-                                        pos % page_len, new_v)
+            page_ids, off = _write_slots(pt, len32, page_len, P)
+            k_pool = _pool_write_tokens(k_pool, layer, page_ids, off, new_k)
+            v_pool = _pool_write_tokens(v_pool, layer, page_ids, off, new_v)
         out = paged_decode_attention_reference(
             q, k_pool, v_pool, page_table, lengths, scale=scale,
             k_scale=k_scale, v_scale=v_scale, layer=layer)
@@ -802,3 +836,81 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     live = (lengths > 0)[:, None, None]
     out = jnp.where(live, out, 0).astype(q.dtype)
     return (out, k_pool, v_pool) if write else out
+
+
+# ------------------------------------------------- latent pages (MLA)
+def mla_decode_attention_reference(q, pool, page_table, lengths, *,
+                                   value_dim: int, scale: float = 1.0,
+                                   layer=None):
+    """fp32-math oracle of :func:`mla_decode_attention` without the
+    write: gather the rows' latent pages, score every head's query
+    against the whole row, take the value from its first ``value_dim``
+    columns. Returns ``[b, h, value_dim]`` float32."""
+    rows = gather_pages(pool, page_table, layer)          # [B, 1, L, d]
+    return decode_attention_reference(
+        jnp.asarray(q, jnp.float32), rows, rows[..., :value_dim], lengths,
+        scale=scale)
+
+
+def mla_decode_attention(q, pool, page_table, lengths, *, value_dim: int,
+                         new_row=None, scale: float = 1.0, layer: int = 0,
+                         interpret: bool = False):
+    """Single-token ABSORBED latent attention against a paged pool of
+    latent rows, and the write of that token's row into it.
+
+    ``q`` ``[batch, heads, d]``: each head's query in the LATENT's
+    coordinates, ``[W_kvb,k^T q_nope | q_rope]``; ``pool`` the stacked
+    ``[layers, num_pages, 1, d, page_len]`` pool of the latent page kind
+    (:class:`~apex_tpu.serving.kv_cache.CacheSpec`, ``value_dim`` > 0):
+    one row ``[latent (value_dim) | rotary key]`` a token, key and value
+    both, for all heads. Scores are ``scale * q . row``; the value is the
+    row's first ``value_dim`` columns, so the result ``[batch, heads,
+    value_dim]`` (float32) is ``sum_t p_t latent_t``, which the caller
+    takes through ``W_kvb,v``. ``new_row`` ``[batch, d]`` (the pool's
+    dtype) is written at position ``lengths[b] - 1`` first, in the
+    kernel, as :func:`paged_decode_attention` writes K/V; the call then
+    returns ``(out, pool)``.
+
+    The Pallas path is :func:`_paged_decode_kernel` with ``vdim``: ONE
+    fetch of a page serves both products (about ``4 heads`` operations a
+    byte: 60 at 32 heads, where a grouped-head cell has 8). It runs
+    under the name ``mla_decode_attention``. Unaligned shapes fall back
+    to the XLA write and the oracle."""
+    B, h, d = q.shape
+    if pool.ndim != 5 or pool.shape[2] != 1 or pool.shape[3] != d \
+            or not 0 < value_dim <= d:
+        raise ValueError(f"mla_decode_attention: pool {pool.shape} must be "
+                         f"[layers, num_pages, 1, {d}, page_len] with the "
+                         f"value its first {value_dim} columns")
+    P, page_len = pool.shape[1], pool.shape[4]
+    write = new_row is not None
+    if write and (new_row.shape != (B, d) or new_row.dtype != pool.dtype):
+        raise ValueError(f"mla_decode_attention: new_row {new_row.dtype}"
+                         f"{new_row.shape} must be {pool.dtype}[{B}, {d}]")
+    from apex_tpu.kernels.flash_attention import _has_vma
+    if jax.default_backend() == "cpu":
+        interpret = True
+    rows = 8 if interpret else 32 // pool.dtype.itemsize
+    pallas_ok = d % rows == 0 and value_dim % rows == 0 \
+        and page_len % 128 == 0
+    pt = jnp.asarray(page_table, jnp.int32)
+    len32 = jnp.asarray(lengths, jnp.int32)
+    new = None if not write else new_row[:, None, :]
+    if not pallas_ok or (interpret and _has_vma(q)) \
+            or (not interpret and not mosaic_dtype_ok(q, pool)):
+        if write:
+            pool = _pool_write_tokens(
+                pool, layer, *_write_slots(pt, len32, page_len, P), new)
+        out = mla_decode_attention_reference(
+            q, pool, pt, len32, value_dim=value_dim, scale=scale,
+            layer=layer)
+        return (out, pool) if write else out
+    pages = _pages_per_step(d * page_len * pool.dtype.itemsize, pt.shape[1])
+    out = _paged_decode_pallas(
+        jnp.asarray(q, pool.dtype), pool, None, pt, len32, jnp.int32(layer),
+        None, None, new, None, scale=float(scale), pages=pages,
+        interpret=interpret, vdim=int(value_dim))
+    if write:
+        out, pool = out
+    out = jnp.where((len32 > 0)[:, None, None], out, 0.0)
+    return (out, pool) if write else out

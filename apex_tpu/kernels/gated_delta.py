@@ -31,6 +31,33 @@ Two programs use it, and each has a kernel here:
   nothing, so the state a padded chunk leaves is its last VALID
   position's.
 
+**A decay per key channel** (Kimi delta attention;
+:class:`apex_tpu.models.ling.LingLM`): ``g`` may be a vector ``[dk]`` a
+head instead of a scalar, ``S <- diag(exp(g)) S``. The decay's shape is
+the operand's: ``g [.., H]`` is the scalar rule, ``g [.., H, dk]`` the
+per-channel one, in the oracles and in both entry points.
+
+- The STEP kernel is one body for both: it is handed columns ``k``, a
+  query column, ``a beta k`` and ``a`` a head (``a = exp(g)``), and a
+  scalar ``a`` is a constant column. Per channel the query column is ``a
+  q`` (``o = S^T (a q) + (k . q) u``; the scalar form multiplies ``a``
+  after, as it always did, so its results are the same bits). It runs
+  under the name ``kda_step`` so that a trace tells the two apart.
+- The CHUNK form no longer factors: ``A[i, j] = beta_i sum_c k_i[c] k_j[c]
+  e^(gc_i[c] - gc_j[c])`` has the decays INSIDE the product, ``(k_i
+  e^(gc_i - r)) . (k_j e^(r - gc_j))`` for a reference row ``r``. One
+  ``r`` a sub-chunk would need ``e^(+5 x 64)`` at a log-decay of -5 a
+  token; so a sub-chunk's 64 rows are taken in blocks of ``KDA_BLOCK`` =
+  16, ``r`` the block's MIDDLE row: the rows' factors and the block's own
+  columns' lie within ``e^(+-8 |g|)`` (``e^(+-40)`` at -5, finite down to
+  about -10 a token), columns before the block have ``r - gc_j <= 0``
+  (underflow to 0 is the true value's), columns after it are masked and
+  their exponent is capped. :func:`kda_chunk` (``name="kda_chunk"``) is a
+  sibling kernel, not a mode of ``gated_delta_chunk``: four small products
+  a sub-chunk where the scalar rule has one, and ``exp`` over ``[rows,
+  dk]`` where it has ``[rows, rows]`` - the scalar cells keep the kernel
+  they were measured with.
+
 ``layer`` is an operand (scalar prefetch), so every linear layer of a
 model shares one traced and lowered kernel. Matrix products are float32
 at ``Precision.HIGHEST``: the state is float32 and ``A`` is a matrix of
@@ -52,11 +79,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["gated_delta_step", "gated_delta_chunk", "gated_delta_recurrence",
-           "gated_delta_step_reference", "gated_delta_chunk_reference"]
+           "gated_delta_step_reference", "gated_delta_chunk_reference",
+           "kda_chunk"]
 
 STEP_KERNEL = "gated_delta_step"
 CHUNK_KERNEL = "gated_delta_chunk"
+KDA_STEP_KERNEL = "kda_step"
+KDA_CHUNK_KERNEL = "kda_chunk"
 SUB = 64                    # the chunked form's sub-chunk
+KDA_BLOCK = 16              # rows of a sub-chunk that share a reference row
+KDA_EXP_CAP = 80.0          # exponent cap of the (masked) later columns
 STEP_BYTES = 2 << 20        # state a grid step of the step kernel takes
 HI = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
@@ -66,11 +98,12 @@ F32 = jnp.float32
 
 def gated_delta_recurrence(q, k, v, g, beta, s0):
     """The recurrence itself, token by token: ``q, k [B, T, H, dk]``,
-    ``v [B, T, H, dv]``, ``g, beta [B, T, H]``, ``s0 [B, H, dk, dv]`` ->
-    ``(o [B, T, H, dv], s_T)``, all float32."""
+    ``v [B, T, H, dv]``, ``beta [B, T, H]``, ``g [B, T, H]`` (a decay a
+    head) or ``[B, T, H, dk]`` (a decay a key channel), ``s0 [B, H, dk,
+    dv]`` -> ``(o [B, T, H, dv], s_T)``, all float32."""
     def step(S, x):
         qt, kt, vt, gt, bt = x
-        S = jnp.exp(gt)[..., None, None] * S
+        S = _decay(gt, S) * S
         r = jnp.einsum("bhkv,bhk->bhv", S, kt, precision=HI)
         S = S + kt[..., None] * (bt[..., None] * (vt - r))[..., None, :]
         return S, jnp.einsum("bhkv,bhk->bhv", S, qt, precision=HI)
@@ -80,11 +113,37 @@ def gated_delta_recurrence(q, k, v, g, beta, s0):
     return jnp.moveaxis(o, 0, 1), sT
 
 
+def _decay(g, S):
+    """``exp(g)`` shaped to scale the rows of ``S [.., dk, dv]``: ``g [..]``
+    a head or ``[.., dk]`` a key channel."""
+    return jnp.exp(g).reshape(g.shape + (1,) * (S.ndim - g.ndim))
+
+
+def _kda_products(Q, K, gc, sub, block=KDA_BLOCK):
+    """``(sum_c K_i K_j e^(gc_i - gc_j), the same with Q_i)`` ``[.., sub,
+    sub]`` for ``j <= i`` (garbage, finite, above the diagonal) from ``Q,
+    K, gc [.., sub, dk]``, row block by row block around the block's
+    middle row (module docstring)."""
+    A, P = [], []
+    for lo in range(0, sub, block):
+        hi = min(lo + block, sub)
+        r = gc[..., (lo + hi) // 2:(lo + hi) // 2 + 1, :]
+        rowf = jnp.exp(gc[..., lo:hi, :] - r)
+        Kc = K * jnp.exp(jnp.minimum(r - gc, KDA_EXP_CAP))
+        A.append(jnp.einsum("...ik,...jk->...ij", K[..., lo:hi, :] * rowf,
+                            Kc, precision=HI))
+        P.append(jnp.einsum("...ik,...jk->...ij", Q[..., lo:hi, :] * rowf,
+                            Kc, precision=HI))
+    return jnp.concatenate(A, -2), jnp.concatenate(P, -2)
+
+
 def gated_delta_chunk_reference(q, k, v, g, beta, s0, sub: int = SUB):
     """The chunked form in plain jnp, batched (module docstring): same
-    operands and results as :func:`gated_delta_recurrence`; ``T`` is
-    padded to a multiple of ``sub`` with positions that write nothing."""
+    operands and results as :func:`gated_delta_recurrence` (``g`` a head
+    or a key channel); ``T`` is padded to a multiple of ``sub`` with
+    positions that write nothing."""
     B, T, H, dk = q.shape
+    per_channel = g.ndim == 4
     pad = -T % sub
     if pad:
         z = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))  # noqa: E731,E501
@@ -95,6 +154,25 @@ def gated_delta_chunk_reference(q, k, v, g, beta, s0, sub: int = SUB):
         jnp.asarray(t, F32).reshape((B, n, sub) + t.shape[2:]), (1, 3), (0, 2))
     ii = jnp.arange(sub)[:, None]
     jj = jnp.arange(sub)[None, :]
+
+    def one_kda(S, x):
+        Q, K, V, G, Bt = x                     # G [B, H, sub, dk]
+        gc = jnp.cumsum(G, -2)
+        eg = jnp.exp(gc)
+        kk, qk = _kda_products(Q, K, gc, sub)
+        A = jnp.where(ii > jj, Bt[..., None] * kk, 0.0)
+        rhs = Bt[..., None] * (V - jnp.einsum("bhik,bhkv->bhiv", K * eg, S,
+                                              precision=HI))
+        U = jax.scipy.linalg.solve_triangular(
+            A + jnp.eye(sub, dtype=F32), rhs, lower=True, unit_diagonal=True)
+        O = jnp.einsum("bhik,bhkv->bhiv", Q * eg, S, precision=HI) \
+            + jnp.einsum("bhij,bhjv->bhiv", jnp.where(ii >= jj, qk, 0.0), U,
+                         precision=HI)
+        gl = gc[..., -1:, :]
+        S = jnp.exp(gl[..., 0, :])[..., None] * S \
+            + jnp.einsum("bhik,bhiv->bhkv", K * jnp.exp(gl - gc), U,
+                         precision=HI)
+        return S, O
 
     def one(S, x):
         Q, K, V, G, Bt = x                     # [B, H, sub, d], [B, H, sub]
@@ -117,7 +195,8 @@ def gated_delta_chunk_reference(q, k, v, g, beta, s0, sub: int = SUB):
             + jnp.einsum("bhik,bhiv->bhkv", Kd, U, precision=HI)
         return S, O
 
-    sT, o = jax.lax.scan(one, jnp.asarray(s0, F32),
+    sT, o = jax.lax.scan(one_kda if per_channel else one,
+                         jnp.asarray(s0, F32),
                          (blk(q), blk(k), blk(v), blk(g), blk(beta)))
     o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(B, T + pad, H, -1)
     return o[:, :T], sT
@@ -126,7 +205,7 @@ def gated_delta_chunk_reference(q, k, v, g, beta, s0, sub: int = SUB):
 def gated_delta_step_reference(state, layer, q, k, v, g, beta, active):
     """:func:`gated_delta_step` in jnp: a select over the layer's block
     (the small-shape fallback and the oracle, never the chip's path)."""
-    S = jnp.exp(jnp.asarray(g, F32))[..., None, None] * state[layer]
+    S = _decay(jnp.asarray(g, F32), state[layer]) * state[layer]
     r = jnp.einsum("bhkv,bhk->bhv", S, k, precision=HI)
     S = S + k[..., None] * (beta[..., None] * (v - r))[..., None, :]
     o = jnp.einsum("bhkv,bhk->bhv", S, q, precision=HI)
@@ -176,8 +255,9 @@ def gated_delta_step(state, layer, q, k, v, g, beta, active, *,
     """One token a slot (module docstring). ``state [layers, slots, H, dk,
     dv]`` float32, updated IN PLACE (aliased) for the rows where
     ``active [slots]``; ``layer`` int (an operand); ``q, k [slots, H,
-    dk]``, ``v [slots, H, dv]``, ``g, beta [slots, H]``. Returns ``(o
-    [slots, H, dv] float32, state)``."""
+    dk]``, ``v [slots, H, dv]``, ``beta [slots, H]``, ``g [slots, H]`` or
+    (a decay a key channel: the same kernel body, named ``kda_step``)
+    ``[slots, H, dk]``. Returns ``(o [slots, H, dv] float32, state)``."""
     Ls, B, H, dk, dv = state.shape
     if jax.default_backend() == "cpu":
         interpret = True
@@ -187,11 +267,15 @@ def gated_delta_step(state, layer, q, k, v, g, beta, active, *,
                                           active)
     hb = _step_heads(H, dk, dv)
     nb = H // hb
-    a = jnp.exp(g)                                           # [B, H]
-    # what the kernel broadcasts along the lanes, as COLUMNS: k, q,
-    # a beta k and a, head by head within a block of hb heads
-    cols = jnp.stack([k, q, (a * beta)[..., None] * k,
-                      jnp.broadcast_to(a[..., None], k.shape)], 1)
+    per_channel = g.ndim == 3
+    a = jnp.exp(g)                                     # [B, H] | [B, H, dk]
+    # what the kernel broadcasts along the lanes, as COLUMNS: k, the
+    # query, a beta k and a, head by head within a block of hb heads
+    if per_channel:
+        cols = jnp.stack([k, a * q, a * beta[..., None] * k, a], 1)
+    else:
+        cols = jnp.stack([k, q, (a * beta)[..., None] * k,
+                          jnp.broadcast_to(a[..., None], k.shape)], 1)
     cols = cols.reshape(B, 4, nb, hb, dk).transpose(0, 2, 4, 1, 3) \
         .reshape(B, nb, dk, 4 * hb)
     bv = beta[..., None] * v
@@ -215,11 +299,15 @@ def gated_delta_step(state, layer, q, k, v, g, beta, active, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=8 * hb * dk * dv * 4 + (16 << 20)),
-        interpret=interpret, name=STEP_KERNEL,
+        interpret=interpret,
+        name=KDA_STEP_KERNEL if per_channel else STEP_KERNEL,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       jnp.asarray(active, jnp.int32), state, cols, bv)
-    # o = S'^T q = a S^T q + (k . q) u
-    o = a[..., None] * sq + jnp.sum(k * q, -1, keepdims=True) * u
+    # o = S'^T q = a S^T q + (k . q) u; per channel the kernel's query
+    # column was a q already
+    if not per_channel:
+        sq = a[..., None] * sq
+    o = sq + jnp.sum(k * q, -1, keepdims=True) * u
     return o, state
 
 
@@ -268,14 +356,19 @@ def gated_delta_chunk(state, layer, slot, fresh, q, k, v, g, beta, *,
     """``T`` tokens of one slot (module docstring). ``state [layers,
     slots, H, dk, dv]`` float32, slot ``slot``'s heads of ``layer``
     updated IN PLACE (aliased), read as zeros where ``fresh``; ``q, k [T,
-    H, dk]``, ``v [T, H, dv]``, ``g, beta [T, H]``. Returns ``(o [T, H,
+    H, dk]``, ``v [T, H, dv]``, ``beta [T, H]``, ``g [T, H]`` or (a decay
+    a key channel: :func:`kda_chunk`) ``[T, H, dk]``. Returns ``(o [T, H,
     dv] float32, state)``."""
     Ls, B, H, dk, dv = state.shape
     T = q.shape[0]
     if jax.default_backend() == "cpu":
         interpret = True
     q, k, v, g, beta = (jnp.asarray(t, F32) for t in (q, k, v, g, beta))
-    if dv % 128 or dk % 8 or T % SUB or state.dtype != F32:
+    aligned = not (dv % 128 or dk % 8 or T % SUB or state.dtype != F32)
+    if g.ndim == 3 and aligned and dk % 128 == 0:
+        return kda_chunk(state, layer, slot, fresh, q, k, v, g, beta,
+                         interpret=interpret)
+    if not aligned or g.ndim == 3:
         s0 = jnp.where(fresh, 0.0, state[layer, slot])[None]
         o, sT = gated_delta_chunk_reference(q[None], k[None], v[None],
                                             g[None], beta[None], s0)
@@ -316,4 +409,95 @@ def gated_delta_chunk(state, layer, slot, fresh, q, k, v, g, beta, *,
         interpret=interpret, name=CHUNK_KERNEL,
     )(meta, state, heads_first(q), heads_first(k), heads_first(v), cols,
       rows)
+    return jnp.moveaxis(o, 0, 1), state
+
+
+# ------------------------------------------- chunk kernel, decay a channel
+
+def _kda_chunk_kernel(meta_ref, s_ref, q_ref, k_ref, v_ref, g_ref, c_ref,
+                      d_ref, so_ref, o_ref, *, hb, n_sub, sub, block):
+    fresh = meta_ref[2] != 0
+    ii = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    nn = (((1,), (0,)), ((), ()))                     # x @ y
+    nt = (((1,), (1,)), ((), ()))                     # x @ y^T
+    tn = (((0,), (0,)), ((), ()))                     # x^T @ y
+    dot = functools.partial(jax.lax.dot_general, precision=HI,
+                            preferred_element_type=F32)
+
+    def head(j, _):
+        S = jnp.where(fresh, 0.0, s_ref[j])                  # [dk, dv]
+        dec = d_ref[j]                                       # [dk, n_sub]
+        for s in range(n_sub):
+            rows = pl.ds(s * sub, sub)
+            Q, K, V = q_ref[j, rows, :], k_ref[j, rows, :], v_ref[j, rows, :]
+            GC = g_ref[j, rows, :]                           # [sub, dk]
+            bcol = c_ref[j, rows, :][:, 0:1]                 # [sub, 1]
+            EG = jnp.exp(GC)
+            # the decays inside the products, a block of rows around its
+            # middle row at a time (module docstring)
+            A, P = [], []
+            for lo in range(0, sub, block):
+                mid = lo + block // 2
+                r = GC[mid:mid + 1, :]                       # [1, dk]
+                rowf = jnp.exp(GC[lo:lo + block, :] - r)
+                Kc = K * jnp.exp(jnp.minimum(r - GC, KDA_EXP_CAP))
+                A.append(dot(K[lo:lo + block, :] * rowf, Kc, nt))
+                P.append(dot(Q[lo:lo + block, :] * rowf, Kc, nt))
+            A = jnp.where(ii > jj, bcol * jnp.concatenate(A, 0), 0.0)
+            P = jnp.where(ii >= jj, jnp.concatenate(P, 0), 0.0)
+            U = bcol * (V - dot(K * EG, S, nn))
+            # (I + A) U = rhs by forward substitution, a column a step
+            for t in range(sub - 1):
+                U = U - A[:, t:t + 1] * U[t:t + 1, :]
+            o_ref[j, rows, :] = dot(Q * EG, S, nn) + dot(P, U, nn)
+            glast = GC[sub - 1:sub, :]                       # [1, dk]
+            S = dec[:, s:s + 1] * S + dot(K * jnp.exp(glast - GC), U, tn)
+        so_ref[j] = S
+        return _
+
+    jax.lax.fori_loop(0, hb, head, None)
+
+
+def kda_chunk(state, layer, slot, fresh, q, k, v, g, beta, *,
+              interpret: bool = False):
+    """:func:`gated_delta_chunk` with a decay a key channel, ``g [T, H,
+    dk]`` (module docstring); the other operands and the results as
+    there. ``dk`` a multiple of 128, ``T`` of ``SUB``."""
+    Ls, B, H, dk, dv = state.shape
+    T = q.shape[0]
+    n_sub = T // SUB
+    # q, k, v, gc and o of hb heads, double-buffered, within ~24 MB
+    hb = max(h for h in (8, 4, 2, 1) if H % h == 0 and (h * T <= 4096
+                                                        or h == 1))
+    heads_first = lambda t: jnp.moveaxis(t, 1, 0)            # noqa: E731
+    gc = jnp.cumsum(heads_first(g).reshape(H, n_sub, SUB, dk), 2)
+    # beta as a COLUMN, and e^gc_C of each sub-chunk as columns over dk
+    cols = jnp.concatenate([beta.T[..., None], jnp.zeros((H, T, 7), F32)],
+                           -1)
+    dec = jnp.exp(gc[:, :, -1, :]).transpose(0, 2, 1)        # [H, dk, n]
+    kernel = functools.partial(_kda_chunk_kernel, hb=hb, n_sub=n_sub,
+                               sub=SUB, block=KDA_BLOCK)
+    seq = lambda d: pl.BlockSpec((hb, T, d), lambda h, m: (h, 0, 0))  # noqa: E731,E501
+    block = pl.BlockSpec((None, None, hb, dk, dv),
+                         lambda h, m: (m[0], m[1], h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(H // hb,),
+        in_specs=[block, seq(dk), seq(dk), seq(dv), seq(dk), seq(8),
+                  pl.BlockSpec((hb, dk, n_sub), lambda h, m: (h, 0, 0))],
+        out_specs=[block, seq(dv)])
+    meta = jnp.stack([jnp.asarray(layer, jnp.int32),
+                      jnp.asarray(slot, jnp.int32),
+                      jnp.asarray(fresh, jnp.int32)])
+    state, o = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, F32),
+                   jax.ShapeDtypeStruct((H, T, dv), F32)],
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=48 << 20),
+        interpret=interpret, name=KDA_CHUNK_KERNEL,
+    )(meta, state, heads_first(q), heads_first(k), heads_first(v),
+      gc.reshape(H, T, dk), cols, dec)
     return jnp.moveaxis(o, 0, 1), state

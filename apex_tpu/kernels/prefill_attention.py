@@ -82,7 +82,8 @@ from apex_tpu.kernels.decode_attention import (_check_head_scales,
                                                gather_pages)
 
 __all__ = ["prefill_attention", "prefill_attention_reference",
-           "paged_prefill_attention", "paged_prefill_attention_reference"]
+           "paged_prefill_attention", "paged_prefill_attention_reference",
+           "mla_prefill_attention", "mla_prefill_attention_reference"]
 
 _NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
@@ -527,3 +528,169 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
         vs = jnp.asarray(v_scale, jnp.float32)
     return _paged_prefill_pallas(q, k_pool, v_pool, pt, off32, scale, bq,
                                  interpret, ks, vs, layer).astype(q.dtype)
+
+
+# ------------------------------------------------- latent pages (MLA)
+MLA_BLOCK_TOKENS = 32       # chunk tokens (x heads rows) a q block
+MLA_PAGES_PER_STEP = 4      # pool pages a KV step
+
+
+def mla_prefill_attention_reference(q, pool, page_table, offsets, *,
+                                    value_dim: int, scale: float = 1.0,
+                                    layer=None):
+    """fp32-math oracle of :func:`mla_prefill_attention`: ``q [b, C, h,
+    d]`` against the gathered latent rows, shifted-causal, the value the
+    rows' first ``value_dim`` columns. Returns ``[b, C, h, value_dim]``
+    float32."""
+    rows = gather_pages(pool, page_table, layer)          # [B, 1, L, d]
+    out = prefill_attention_reference(
+        jnp.moveaxis(jnp.asarray(q, jnp.float32), 1, 2), rows,
+        rows[..., :value_dim], offsets, scale=scale)
+    return jnp.moveaxis(out, 1, 2)
+
+
+def _mla_prefill_kernel(pt_ref, off_ref, q_ref, *refs, scale, heads,
+                        block_tokens, page_len, pages, vdim, widen):
+    """Grid (b, q block, KV step). A q block is ``block_tokens``
+    consecutive chunk tokens x ALL heads (row ``r`` is token ``r //
+    heads``), a KV step ``pages`` pool pages, each fetched once for every
+    head and for both products: scores against the whole ``[d, page_len]``
+    page, values from its first ``vdim`` rows. The (m, l) recurrence and
+    the global-position mask are :func:`_paged_prefill_kernel`'s; the
+    operands go to the products as stored (bfloat16), ``p`` rounded to
+    the page's type."""
+    kv_refs, (o_ref, acc_ref, m_ref, l_ref) = refs[:pages], refs[pages:]
+    b, qi, ji = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nj = pl.num_programs(2)
+    T = pages * page_len
+    R = block_tokens * heads
+    first = off_ref[b] + qi * block_tokens        # the block's first token
+
+    def dot(a, bb, dims):
+        if widen:
+            a, bb = a.astype(jnp.float32), bb.astype(jnp.float32)
+        return jax.lax.dot_general(a, bb, (dims, ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    @pl.when(ji == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    # skip steps entirely past this q block's LAST global position
+    @pl.when(ji * T <= first + block_tokens - 1)
+    def _body():
+        kv = kv_refs[0][...] if pages == 1 else jnp.concatenate(
+            [r[...] for r in kv_refs], axis=1)               # [d, T]
+        s = dot(q_ref[0], kv, ((1,), (0,))) * scale          # [R, T]
+        rows = first + jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (R, T), 0), heads)
+        cols = ji * T + jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
+        s = jnp.where(cols <= rows, s, _NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:] = jnp.broadcast_to(
+            alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
+            l_ref.shape)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        acc_ref[:] = acc_ref[:] * alpha + dot(
+            p.astype(kv.dtype), kv[:vdim, :], ((1,), (1,)))  # [R, vdim]
+
+    @pl.when(ji == nj - 1)
+    def _finish():
+        l = l_ref[:, :1]
+        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
+
+
+def mla_prefill_attention(q, pool, page_table, offsets, *, value_dim: int,
+                          scale: float = 1.0, layer: int = 0,
+                          block_tokens: Optional[int] = None,
+                          pages_per_step: Optional[int] = None,
+                          interpret: bool = False):
+    """Chunk-of-queries ABSORBED latent attention against a paged pool of
+    latent rows (the chunk's own rows already written at ``[offsets[b],
+    offsets[b] + C)``).
+
+    ``q`` ``[batch, C, heads, d]``: the chunk's queries in the latent's
+    coordinates (``[W_kvb,k^T q_nope | q_rope]``, token-major); ``pool``
+    the stacked ``[layers, num_pages, 1, d, page_len]`` pool of the
+    latent page kind; the other operands as
+    :func:`paged_prefill_attention`. Returns ``[batch, C, heads,
+    value_dim]`` float32, ``sum_t p_t latent_t`` a head, for the caller
+    to take through ``W_kvb,v``.
+
+    Why absorbed here too, and not the live latents expanded to per-head
+    keys and values in front of the grouped-head kernel: the expansion is
+    a ``[context, heads x (d_nope + d_v)]`` temporary of the WHOLE table's
+    span (the chunk program is one fixed shape: 0.7 GB at 33,792
+    positions x 32 heads) written and read every chunk whatever the
+    offset, where this kernel's pages are read once a q block as they
+    lie and its walk stops at the chunk's own extent. It pays for that in
+    operations - 2 (d + value_dim) a position and head against 2 (d_nope +
+    d_rope + d_v) expanded, 3.4 times at 576/512 against 192/128 - on
+    the MXU with every head sharing the page (``heads x block_tokens``
+    rows a product). Runs under the name ``mla_prefill_attention``.
+    Unaligned shapes fall back to the oracle."""
+    B, C, h, d = q.shape
+    if pool.ndim != 5 or pool.shape[2] != 1 or pool.shape[3] != d \
+            or not 0 < value_dim <= d:
+        raise ValueError(f"mla_prefill_attention: pool {pool.shape} must "
+                         f"be [layers, num_pages, 1, {d}, page_len] with "
+                         f"the value its first {value_dim} columns")
+    page_len = pool.shape[4]
+    from apex_tpu.kernels.flash_attention import _fit_block, _has_vma
+    bt = _fit_block(block_tokens or MLA_BLOCK_TOKENS, C, 8)
+    pt = jnp.asarray(page_table, jnp.int32)
+    off32 = jnp.asarray(offsets, jnp.int32)
+    max_pages = pt.shape[1]
+    pages = max(1, min(pages_per_step or MLA_PAGES_PER_STEP, max_pages))
+    if jax.default_backend() == "cpu":
+        interpret = True
+    rows = 8 if interpret else 32 // pool.dtype.itemsize
+    pallas_ok = (C % bt == 0 and (bt * h) % 8 == 0 and d % rows == 0
+                 and value_dim % rows == 0 and page_len % 128 == 0)
+    if not pallas_ok or (interpret and _has_vma(q)) \
+            or (not interpret and not mosaic_dtype_ok(q, pool)):
+        return mla_prefill_attention_reference(
+            q, pool, pt, off32, value_dim=value_dim, scale=scale,
+            layer=layer)
+    kernel = functools.partial(
+        _mla_prefill_kernel, scale=float(scale), heads=h, block_tokens=bt,
+        page_len=page_len, pages=pages, vdim=int(value_dim),
+        widen=interpret)
+
+    def page_spec(x):
+        def index(b, i, j, pt, off):
+            # the walk stops at the chunk's last reachable page: later
+            # steps re-issue that block index and fetch nothing
+            last = (off[b] + (C - 1)) // page_len
+            return (layer, pt[b, jnp.minimum(j * pages + x, last)], 0, 0, 0)
+        return pl.BlockSpec((None, None, None, d, page_len), index)
+
+    R = bt * h
+    q_spec = pl.BlockSpec((1, R, d), lambda b, i, j, pt, off: (b, i, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,            # page_table, offsets
+        grid=(B, C // bt, -(-max_pages // pages)),
+        in_specs=[q_spec] + [page_spec(x) for x in range(pages)],
+        out_specs=pl.BlockSpec((1, R, value_dim),
+                               lambda b, i, j, pt, off: (b, i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((R, value_dim), jnp.float32),   # acc
+            pltpu.VMEM((R, 128), jnp.float32),         # m (col 0 live)
+            pltpu.VMEM((R, 128), jnp.float32),         # l (col 0 live)
+        ])
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, C * h, value_dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret, name="mla_prefill_attention",
+    )(pt, off32, jnp.asarray(q, pool.dtype).reshape(B, C * h, d),
+      *([pool] * pages))
+    return out.reshape(B, C, h, value_dim)

@@ -52,20 +52,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from apex_tpu.models.zaya import (_attend, _einsum32, _Groups, _last_valid,
-                                  _Leaves, _rotary)
+from apex_tpu.models.lm_layers import (Groups, Leaves, einsum32, f32 as _f32,
+                                       gated_mlp, held_experts, last_valid,
+                                       paged_attend, positions_of, rms,
+                                       rotary, short_conv)
 
 __all__ = ["Qwen3NextLM"]
 
-_f32 = lambda t: jnp.asarray(t, jnp.float32)                    # noqa: E731
-
 
 def _rms(x, w, eps, centred=True):
-    """RMSNorm over the last axis in float32: ``(1 + w)`` (zero-centred,
-    the model's sublayer, final and q/k norms) or plain ``w``."""
-    x = _f32(x)
-    y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
-    return y * ((1.0 + _f32(w)) if centred else _f32(w))
+    """The model's norms: zero-centred ``(1 + w)`` (sublayer, final and
+    q/k norms) unless told plain."""
+    return rms(x, w, eps, centred)
 
 
 class Qwen3NextLM(nn.Module):
@@ -151,12 +149,12 @@ class Qwen3NextLM(nn.Module):
 
         B, S, _ = u.shape
         nk, nv = self.lin_key_heads, self.lin_value_heads
-        dk, dv, K = self.lin_key_dim, self.lin_value_dim, self.conv_kernel
+        dk, dv = self.lin_key_dim, self.lin_value_dim
         kw, vw = nk * dk, nv * dv
         with jax.named_scope("gdn.proj"):
             qkvz = jnp.dot(u, jnp.asarray(lp["w_qkvz"], cdt))
             x, z = qkvz[..., :2 * kw + vw], qkvz[..., 2 * kw + vw:]
-            ba = _einsum32("bsh,hn->bsn", u, jnp.asarray(lp["w_ba"], cdt))
+            ba = einsum32("bsh,hn->bsn", u, jnp.asarray(lp["w_ba"], cdt))
             beta = jax.nn.sigmoid(ba[..., :nv])
             g = -jnp.exp(_f32(lp["a_log"])) * jax.nn.softplus(
                 ba[..., nv:] + _f32(lp["dt_bias"]))
@@ -164,16 +162,9 @@ class Qwen3NextLM(nn.Module):
                 beta = jnp.where(mask[..., None], beta, 0.0)
                 g = jnp.where(mask[..., None], g, 0.0)
         with jax.named_scope("gdn.conv"):
-            # depthwise, causal: tap K - 1 on the current position
-            xs = jnp.concatenate([jnp.asarray(tail, x.dtype), x], 1)
-            w = _f32(lp["conv_w"])                              # [C, K]
-            c = sum(w[:, j] * _f32(xs[:, j:j + S]) for j in range(K))
+            # depthwise, causal; the tail is the last K - 1 REAL inputs
+            c, new_tail = short_conv(x, tail, lp["conv_w"], mask)
             c = jax.nn.silu(c)
-            # the inputs of the last K - 1 REAL positions
-            n = jnp.full((B,), S, jnp.int32) if mask is None \
-                else jnp.sum(mask, 1).astype(jnp.int32)
-            new_tail = jax.vmap(lambda row, i: jax.lax.dynamic_slice_in_dim(
-                row, i, K - 1, axis=0))(xs, n)
             q = c[..., :kw].reshape(B, S, nk, dk)
             k = c[..., kw:2 * kw].reshape(B, S, nk, dk)
             v = c[..., 2 * kw:].reshape(B, S, nv, dv)
@@ -219,18 +210,13 @@ class Qwen3NextLM(nn.Module):
             v = jnp.dot(u, jnp.asarray(lp["wv"], cdt)).reshape(B, S, nk, d)
             q = _rms(q, lp["q_norm"], self.rms_eps)
             k = _rms(k, lp["k_norm"], self.rms_eps)
-            if positions is None:
-                pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None],
-                                       (B, S))
-            else:
-                pos = jnp.asarray(positions, jnp.int32)[:, None] \
-                    + jnp.arange(S, dtype=jnp.int32)[None]
+            pos = positions_of(positions, B, S)
             rot = int(d * self.partial_rotary_factor)
-            q = jnp.asarray(_rotary(q, pos, self.rope_theta, rot), cdt)
-            k = jnp.asarray(_rotary(k, pos, self.rope_theta, rot), cdt)
+            q = jnp.asarray(rotary(q, pos, self.rope_theta, rot), cdt)
+            k = jnp.asarray(rotary(k, pos, self.rope_theta, rot), cdt)
             q, k, v = (jnp.moveaxis(t, 1, 2) for t in (q, k, v))
         with jax.named_scope("attn.attn"):
-            ctx, aux = _attend(q, k, v, cache, positions, index,
+            ctx, aux = paged_attend(q, k, v, cache, positions, index,
                                1.0 / np.sqrt(d))
         with jax.named_scope("attn.gate"):
             ctx = _f32(jnp.moveaxis(ctx, 1, 2)) * jax.nn.sigmoid(_f32(gate))
@@ -242,8 +228,6 @@ class Qwen3NextLM(nn.Module):
         """``u [B, S, H]`` (normed, compute dtype) -> ``(y [B, S, H]
         float32, each token's experts [B, S, k], tokens per expert [E]
         int32 over the ``valid [B, S]`` tokens)``."""
-        from apex_tpu.transformer.moe import dropless_topk_experts
-
         B, S, H = u.shape
         kk = self.experts_per_token
         flat = u.reshape(B * S, H)
@@ -253,25 +237,16 @@ class Qwen3NextLM(nn.Module):
             top, choice = jax.lax.top_k(p, kk)
             weights = top / jnp.sum(top, -1, keepdims=True)
             choice = choice.astype(jnp.int32)
-        y = dropless_topk_experts(
-            flat, weights, choice,
-            jnp.asarray(lp["experts"]["w_gate_up"], cdt),
-            jnp.asarray(lp["experts"]["w_down"], cdt),
+        y, counts = held_experts(
+            flat, weights, choice, lp["experts"], cdt,
             num_experts=self.num_experts, experts_held=self.experts_held,
-            out_dtype=jnp.float32)
+            valid=valid)
         with jax.named_scope("moe.shared"):
             sp = lp["shared"]
-            F = self.shared_width
-            gu = jnp.dot(flat, jnp.asarray(sp["w_gate_up"], cdt))
-            h = jax.nn.silu(_f32(gu[:, :F])) * _f32(gu[:, F:])
-            ys = _einsum32("tf,fh->th", jnp.asarray(h, cdt),
-                           jnp.asarray(sp["w_down"], cdt))
-            gate = jax.nn.sigmoid(_einsum32(
+            ys = gated_mlp(flat, sp["w_gate_up"], sp["w_down"], cdt)
+            gate = jax.nn.sigmoid(einsum32(
                 "th,h->t", flat, jnp.asarray(sp["w_gate"], cdt)))
             y = y + gate[:, None] * ys
-        counts = jnp.zeros((self.num_experts,), jnp.int32).at[
-            choice.reshape(-1)].add(jnp.repeat(
-                valid.reshape(-1).astype(jnp.int32), kk))
         return y.reshape(B, S, H), choice.reshape(B, S, kk), counts
 
     # -------------------------------------------------------------- model
@@ -328,7 +303,7 @@ class Qwen3NextLM(nn.Module):
         if self.inference_dtype is not None:
             cdt = self.inference_dtype
         B, S = tokens.shape
-        emb = _Leaves((("embedding", (self.vocab_size, self.hidden),
+        emb = Leaves((("embedding", (self.vocab_size, self.hidden),
                         "normal02"),), self.param_dtype,
                       name="wte")()["embedding"]
         x = jnp.asarray(emb[tokens], cdt)
@@ -349,7 +324,7 @@ class Qwen3NextLM(nn.Module):
         specs = {full: self._layer_spec(full) for full in (False, True)}
         for i in range(self.num_layers):
             full = self.is_full(i)
-            lp = _Groups(specs[full], self.param_dtype, name=f"layer_{i}")()
+            lp = Groups(specs[full], self.param_dtype, name=f"layer_{i}")()
             u = jnp.asarray(_rms(x, lp["attn_norm"]["scale"], self.rms_eps),
                             cdt)
             if full:
@@ -377,16 +352,16 @@ class Qwen3NextLM(nn.Module):
             self.sow("intermediates", "expert_choice", choice)
             counts.append(cnt)
             x = jnp.asarray(_f32(x) + y, cdt)
-        norm_f = _Leaves((("scale", (self.hidden,), "zeros"),),
+        norm_f = Leaves((("scale", (self.hidden,), "zeros"),),
                          self.param_dtype, name="norm_f")()["scale"]
-        head = _Leaves((("kernel", (self.hidden, self.vocab_size),
+        head = Leaves((("kernel", (self.hidden, self.vocab_size),
                          "lecun"),), self.param_dtype,
                        name="head")()["kernel"]
         if n_valid is not None:
-            x = _last_valid(x, n_valid)[:, None]             # [B, 1, H]
+            x = last_valid(x, n_valid)[:, None]             # [B, 1, H]
         x = jnp.asarray(_rms(x, norm_f, self.rms_eps), cdt)
         # the head is its own matrix; float32 logits from a half product
-        logits = _einsum32("bsh,hv->bsv", x, jnp.asarray(head, cdt))
+        logits = einsum32("bsh,hv->bsv", x, jnp.asarray(head, cdt))
         if pools is None:
             return logits
         blocks = {"recurrent": rec,

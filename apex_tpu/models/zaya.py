@@ -58,129 +58,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.kernels.layer_norm import rms_norm_reference
-from apex_tpu.models.transformer_lm import _pool_write_pages
+from apex_tpu.models.lm_layers import (Groups, Leaves, einsum32, last_valid,
+                                       paged_attend, positions_of, rotary,
+                                       shift)
 
 __all__ = ["ZayaLM"]
-
-
-def _einsum32(spec, a, b):
-    """``einsum`` of half operands accumulated (and returned) in float32:
-    the MXU's own form. The CPU backend's dot takes no bf16 x bf16 ->
-    f32, so there the operands are widened first (the same products,
-    exact in float32, the same sums)."""
-    if jax.default_backend() == "cpu":
-        a, b = jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)
-    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
-
-
-def _shift(x, prev):
-    """``x_{t-1}`` along axis 1 of ``x [B, S, ...]``, the row before the
-    first taken from ``prev [B, ...]``."""
-    return jnp.concatenate([prev[:, None], x[:, :-1]], axis=1)
-
-
-def _rotary(x, pos, theta, rot):
-    """Half-split rotary on the first ``rot`` of the last axis of ``x [B,
-    S, heads, d]`` (float32) at absolute positions ``pos [B, S]``."""
-    half = rot // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
-    ang = jnp.asarray(pos, jnp.float32)[..., None] * inv
-    cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
-    x1, x2 = x[..., :half], x[..., half:rot]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
-                            x[..., rot:]], -1)
-
-
-def _last_valid(x, n_valid):
-    """Row ``n_valid[b] - 1`` of ``x [B, S, ...]`` -> ``[B, ...]``."""
-    if n_valid is None:
-        return x[:, -1]
-    idx = jnp.clip(jnp.asarray(n_valid, jnp.int32) - 1, 0, x.shape[1] - 1)
-    return jax.vmap(lambda row, i: jax.lax.dynamic_index_in_dim(
-        row, i, keepdims=False))(x, idx)
-
-
-def _attend(q, k, v, cache, positions, layer, scale):
-    """Causal attention of ``q [B, nq, S, d]`` over ``k, v [B, nk, S, d]``
-    (``nq // nk`` query heads a K/V head). With the paged ``cache =
-    (k_pool, v_pool, page_table)`` the new K/V are written IN PLACE into
-    pool layer ``layer`` at ``positions [B]`` and attention reads the pool
-    through the table (one token: written by the decode kernel itself,
-    into the row's last page as it holds it; a chunk: whole pages,
-    scattered in front of the chunk kernel); without, the sequence
-    attends itself. Returns
-    ``(ctx [B, nq, S, d], (k_pool, v_pool) | None)``."""
-    B, _, S, _ = q.shape
-    if cache is not None:
-        from apex_tpu.kernels.decode_attention import \
-            paged_decode_attention
-        from apex_tpu.kernels.prefill_attention import \
-            paged_prefill_attention
-        k_pool, v_pool, page_table = cache
-        page_len = k_pool.shape[4]
-        L = page_table.shape[1] * page_len
-        p0 = jnp.clip(jnp.asarray(positions, jnp.int32), 0, L - S)
-        if S == 1:
-            ctx, k_pool, v_pool = paged_decode_attention(
-                q[:, :, 0], k_pool, v_pool, page_table, p0 + 1,
-                new_k=jnp.asarray(k[:, :, 0], k_pool.dtype),
-                new_v=jnp.asarray(v[:, :, 0], v_pool.dtype),
-                scale=scale, layer=layer)
-            ctx = ctx[:, :, None]
-        else:
-            if S % page_len:
-                raise ValueError(
-                    f"paged chunk prefill needs S ({S}) to be a "
-                    f"multiple of page_len ({page_len})")
-            idx = (p0 // page_len)[:, None] + jnp.arange(
-                S // page_len, dtype=jnp.int32)[None, :]
-            pages = jnp.take_along_axis(page_table, idx, axis=1)
-            k_pool = _pool_write_pages(
-                k_pool, layer, pages, jnp.asarray(k, k_pool.dtype))
-            v_pool = _pool_write_pages(
-                v_pool, layer, pages, jnp.asarray(v, v_pool.dtype))
-            ctx = paged_prefill_attention(
-                q, k_pool, v_pool, page_table, p0, scale=scale,
-                layer=layer)
-        aux = (k_pool, v_pool)
-    else:
-        from apex_tpu.kernels.prefill_attention import \
-            prefill_attention
-        ctx = prefill_attention(q, k, v,
-                                jnp.zeros((B,), jnp.int32),
-                                scale=scale)
-        aux = None
-    return ctx, aux
-
-
-_INITS = {"ones": nn.initializers.ones, "zeros": nn.initializers.zeros,
-          "lecun": nn.initializers.lecun_normal(),
-          "normal02": nn.initializers.normal(0.02)}
-
-
-class _Leaves(nn.Module):
-    """The parameters of one named group, as a dict: ``spec`` is
-    ``((leaf, shape, init), ...)``."""
-
-    spec: Tuple
-    param_dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self):
-        return {leaf: self.param(leaf, _INITS[init], shape, self.param_dtype)
-                for leaf, shape, init in self.spec}
-
-
-class _Groups(nn.Module):
-    """One layer's groups: ``{module: {leaf: array}}``."""
-
-    spec: Tuple
-    param_dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self):
-        return {mod: _Leaves(leaves, self.param_dtype, name=mod)()
-                for mod, leaves in self.spec}
 
 
 class ZayaLM(nn.Module):
@@ -265,13 +147,13 @@ class ZayaLM(nn.Module):
             f32 = lambda t: jnp.asarray(t, jnp.float32)         # noqa: E731
             a = f32(lp["conv0_w"])                              # [zw, 2]
             c1 = jnp.asarray(
-                a[:, 0] * f32(_shift(z, z_prev)) + a[:, 1] * f32(z)
+                a[:, 0] * f32(shift(z, z_prev)) + a[:, 1] * f32(z)
                 + f32(lp["conv0_b"]), cdt)
             w1 = jnp.asarray(lp["conv1_w"], cdt)                # [nz, 2, d, d]
             heads = lambda t: t.reshape(B, S, nz, d)            # noqa: E731
-            c2 = _einsum32("bshd,hde->bshe", heads(_shift(c1, c1_prev)),
+            c2 = einsum32("bshd,hde->bshe", heads(shift(c1, c1_prev)),
                            w1[:, 0]) \
-                + _einsum32("bshd,hde->bshe", heads(c1), w1[:, 1]) \
+                + einsum32("bshd,hde->bshe", heads(c1), w1[:, 1]) \
                 + f32(lp["conv1_b"])
             qh = f32(qt).reshape(B, S, nq, d)
             kh = f32(kt).reshape(B, S, nk, d)
@@ -282,23 +164,18 @@ class ZayaLM(nn.Module):
                 jnp.sum(jnp.square(t), -1, keepdims=True)))
             q = norm(q)
             k = norm(k) * jnp.exp(f32(lp["tau"]))[None, None, :, None]
-            if positions is None:
-                pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None],
-                                       (B, S))
-            else:
-                pos = jnp.asarray(positions, jnp.int32)[:, None] \
-                    + jnp.arange(S, dtype=jnp.int32)[None]
+            pos = positions_of(positions, B, S)
             rot = int(d * self.partial_rotary_factor)
-            q = jnp.asarray(_rotary(q, pos, self.rope_theta, rot), cdt)
-            k = jnp.asarray(_rotary(k, pos, self.rope_theta, rot), cdt)
-            v = jnp.stack([v1, _shift(v2, v2_prev)], 2)         # [B, S, 2, d]
+            q = jnp.asarray(rotary(q, pos, self.rope_theta, rot), cdt)
+            k = jnp.asarray(rotary(k, pos, self.rope_theta, rot), cdt)
+            v = jnp.stack([v1, shift(v2, v2_prev)], 2)         # [B, S, 2, d]
             q, k, v = (jnp.moveaxis(t, 1, 2) for t in (q, k, v))
-            row = jnp.concatenate([_last_valid(z, n_valid),
-                                   _last_valid(c1, n_valid),
-                                   _last_valid(v2, n_valid)], -1)
+            row = jnp.concatenate([last_valid(z, n_valid),
+                                   last_valid(c1, n_valid),
+                                   last_valid(v2, n_valid)], -1)
         scale = 1.0 / np.sqrt(d)
         with jax.named_scope("cca.attn"):
-            ctx, aux = _attend(q, k, v, cache, positions, layer, scale)
+            ctx, aux = paged_attend(q, k, v, cache, positions, layer, scale)
             ctx = jnp.moveaxis(ctx, 1, 2).reshape(B, S, nq * d)
             out = jnp.dot(jnp.asarray(ctx, cdt), jnp.asarray(lp["wo"], cdt))
         return out, aux, row
@@ -382,7 +259,7 @@ class ZayaLM(nn.Module):
             cdt = self.inference_dtype
         B, S = tokens.shape
         W = self.slot_state_width
-        emb = _Leaves((("embedding", (self.vocab_size, self.hidden),
+        emb = Leaves((("embedding", (self.vocab_size, self.hidden),
                          "normal02"),), self.param_dtype,
                        name="wte")()["embedding"]
         layer_spec = self._layer_spec()
@@ -410,7 +287,7 @@ class ZayaLM(nn.Module):
                 + (f32(fx) + f32(rp["b_h"])) * f32(rp["s_h"]), cdt)
 
         for i in range(self.num_layers):
-            lp = _Groups(layer_spec, self.param_dtype, name=f"layer_{i}")()
+            lp = Groups(layer_spec, self.param_dtype, name=f"layer_{i}")()
             # float32 inside, back in the compute dtype
             u = rms_norm_reference(x, lp["attn_norm"]["scale"], self.rms_eps)
             out, aux, row = self._attention(
@@ -430,14 +307,14 @@ class ZayaLM(nn.Module):
             self.sow("intermediates", "expert_choice", choice)
             counts.append(cnt)
             x = residual(x, y, lp["moe_res"])
-        norm_f = _Leaves((("scale", (self.hidden,), "ones"),),
+        norm_f = Leaves((("scale", (self.hidden,), "ones"),),
                          self.param_dtype, name="norm_f")()["scale"]
         if n_valid is not None:
-            x = _last_valid(x, n_valid)[:, None]             # [B, 1, H]
+            x = last_valid(x, n_valid)[:, None]             # [B, 1, H]
         x = rms_norm_reference(x, norm_f, self.rms_eps)
         # tied head, float32 logits: a bf16 x bf16 product accumulated in
         # float32 (the embedding is never widened whole)
-        logits = _einsum32("bsh,vh->bsv", x, jnp.asarray(emb, cdt))
+        logits = einsum32("bsh,vh->bsv", x, jnp.asarray(emb, cdt))
         new_state = jnp.stack(rows).astype(state.dtype)      # [L, B, W]
         if blocks is not None:
             new_state = {"rows": addr.write(blocks["rows"], new_state)}

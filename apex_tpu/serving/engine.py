@@ -643,7 +643,7 @@ class Engine:
                 layers=layers, num_pages=num_pages, heads=kv_heads,
                 page_len=page_len, head_dim=head_dim,
                 dtype=cache_dtype, k_scale=k_scale, v_scale=v_scale,
-                state=state)
+                state=state, value_dim=spec_.value_dim)
         else:
             # heads-axis pool sharding: each shard holds
             # [layers, num_pages, heads/tp, head_dim, page_len] —
@@ -975,10 +975,8 @@ class Engine:
         if self._registry is None:
             return
         c = self.cache
-        per_token = c.layers * c.heads * c.head_dim \
-            * np.dtype(c.dtype).itemsize * 2
         self._registry.gauge_set("serving.kv.bytes_per_token",
-                                 float(per_token))
+                                 float(c.bytes_per_token()))
         self._registry.gauge_set("serving.kv.page_layers", float(c.layers))
         if getattr(c, "state", None) is not None:
             # what a slot holds beside its pages, whatever its length

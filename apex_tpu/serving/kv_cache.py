@@ -91,6 +91,14 @@ class CacheSpec:
     ``num_experts`` is the shape of the device-side counter of tokens
     routed (0 experts: no expert layer).
 
+    ``value_dim`` > 0 states the LATENT page kind (multi-head latent
+    attention): a token keeps ONE row of ``head_dim`` values a page layer
+    (``kv_heads`` 1) that is key and value both, for all the query heads -
+    the value is the row's first ``value_dim`` columns (the latent), the
+    columns after it are key only (the rotary key). There is then one
+    pool and no V pool (:class:`PagedKVCache` holds a V of zero heads),
+    and the model's kernels read a page once for both products.
+
     A model states it as ``model.cache_spec()`` -> a plain dict with
     these keys (``state`` a list of ``(name, layers, shape, dtype)``);
     the engine builds the pool and the state from it alone, so a new
@@ -102,6 +110,16 @@ class CacheSpec:
     state: Tuple[StateBlock, ...] = ()
     counter_layers: int = 0
     num_experts: int = 0
+    value_dim: int = 0
+
+    def __post_init__(self):
+        if self.value_dim and not (self.kv_heads == 1
+                                   and 0 < self.value_dim <= self.head_dim):
+            raise ValueError(
+                "cache_spec: a latent page (value_dim > 0) is one row a "
+                f"token, its value a prefix of it; got kv_heads="
+                f"{self.kv_heads}, head_dim={self.head_dim}, value_dim="
+                f"{self.value_dim}")
 
     @classmethod
     def of(cls, model) -> "CacheSpec":
@@ -228,7 +246,9 @@ class PagedKVCache:
     as the programs' temporaries)."""
 
     k: jnp.ndarray        # [layers, num_pages, heads, head_dim, page_len]
-    v: jnp.ndarray        # [layers, num_pages, heads, head_dim, page_len]
+    # the same shape, or ZERO heads under the latent page kind
+    # (CacheSpec.value_dim): the one pool is `k`, value and key both
+    v: jnp.ndarray
     # quantized storage tier (kv_quant): per-[layer, head] fp32 dequant
     # scales; None on the bf16 default. Per-head — NOT per-page — so a
     # copy-on-write share never copies scale state alongside its pages.
@@ -271,22 +291,30 @@ class PagedKVCache:
 
     def nbytes(self) -> int:
         """Device bytes held by the pool (both K and V)."""
-        return int(self.k.size * self.k.dtype.itemsize * 2)
+        return int((self.k.size + self.v.size) * self.k.dtype.itemsize)
+
+    def bytes_per_token(self) -> int:
+        """Pool bytes one cached position takes, over all page layers."""
+        return self.nbytes() // (self.num_pages * self.page_len)
 
     @classmethod
     def create(cls, *, layers: int, num_pages: int, heads: int,
                page_len: int, head_dim: int, dtype: Any = jnp.bfloat16,
-               k_scale=None, v_scale=None, state=None) -> "PagedKVCache":
+               k_scale=None, v_scale=None, state=None,
+               value_dim: int = 0) -> "PagedKVCache":
         """Allocate a zeroed pool (``dtype`` normally the amp half
         dtype, or int8 with the scale pair under the engine's
         ``kv_quant`` tier). ``num_pages`` INCLUDES the page-0 sentinel,
         so the usable capacity is ``(num_pages - 1) * page_len``
-        positions."""
+        positions. ``value_dim`` > 0: the latent page kind, one pool
+        (:class:`CacheSpec`)."""
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "sentinel/garbage page)")
         shape = (layers, num_pages, heads, head_dim, page_len)
-        return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+        v_shape = (layers, num_pages, 0 if value_dim else heads, head_dim,
+                   page_len)
+        return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(v_shape, dtype),
                    k_scale=k_scale, v_scale=v_scale, state=state)
 
     def layer_view(self):

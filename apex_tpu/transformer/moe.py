@@ -35,7 +35,8 @@ from jax import lax
 from apex_tpu.comm import AXIS_EXPERT
 
 __all__ = ["MoEMLP", "top1_routing", "top2_routing", "router_z_loss",
-           "dropless_top1_experts", "dropless_topk_experts"]
+           "dropless_top1_experts", "dropless_topk_experts",
+           "group_limited_sigmoid_topk"]
 
 
 def _scatter_to_slots(mask, pos, gate, capacity):
@@ -290,6 +291,34 @@ def dropless_top1_experts(u, gate, choice, w_gate_up, w_down, *,
 
 
 TOPK_BLOCK_ROWS = 512
+
+
+def group_limited_sigmoid_topk(logits, bias, *, k: int, n_group: int,
+                               topk_group: int, scale: float = 1.0):
+    """The choosing of a sigmoid router limited to groups (DeepSeek-V3's):
+    ``logits [T, E]`` float32 -> ``(weights [T, k] float32, choices [T, k]
+    int32)``, what :func:`dropless_topk_experts` takes.
+
+    Scores ``s = sigmoid(logits)``. The SELECTION runs on ``s + bias``
+    (``bias [E]``, a balancing buffer that takes part in the choice only):
+    the ``E`` experts lie in ``n_group`` contiguous groups, a group's
+    score is the sum of its two best ``s + bias``, the ``topk_group`` best
+    groups stay, and the ``k`` best experts among them are chosen. The
+    WEIGHTS are ``s`` of the chosen, without the bias, normalised over
+    the ``k`` and times ``scale``. Ties go to the lower index at every
+    step (``lax.top_k``)."""
+    T, E = logits.shape
+    s = jax.nn.sigmoid(jnp.asarray(logits, jnp.float32))
+    sel = s + jnp.asarray(bias, jnp.float32)
+    groups = sel.reshape(T, n_group, E // n_group)
+    group_score = jnp.sum(lax.top_k(groups, 2)[0], -1)         # [T, n_group]
+    kept = lax.top_k(group_score, topk_group)[1]               # [T, topk]
+    keep = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], kept].set(True)
+    sel = jnp.where(jnp.repeat(keep, E // n_group, axis=1), sel, -jnp.inf)
+    choices = lax.top_k(sel, k)[1].astype(jnp.int32)
+    w = jnp.take_along_axis(s, choices, -1)
+    return w / jnp.sum(w, -1, keepdims=True) * scale, choices
 
 
 def dropless_topk_experts(u, weights, choices, w_gate_up, w_down, *,

@@ -49,7 +49,8 @@ PAGES = {
                 "apex_tpu.kernels.gated_delta", "apex_tpu.kernels.vmem"],
     "models": ["apex_tpu.models", "apex_tpu.models.bert",
                "apex_tpu.models.transformer_lm", "apex_tpu.models.zaya",
-               "apex_tpu.models.qwen3_next"],
+               "apex_tpu.models.qwen3_next", "apex_tpu.models.ling",
+               "apex_tpu.models.lm_layers"],
     "layers": ["apex_tpu.mlp", "apex_tpu.fused_dense"],
     "utils": ["apex_tpu.utils", "apex_tpu.utils.checkpoint",
               "apex_tpu.utils.sharded_checkpoint", "apex_tpu.utils.pytree",

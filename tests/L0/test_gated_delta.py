@@ -2,6 +2,12 @@
 the recurrence itself (a ``lax.scan`` over time), in interpret mode on the
 CPU at the published head size (128 x 128) and a few heads.
 
+Every case runs for both shapes of the decay: a scalar a head (``g [..,
+H]``: the gated delta rule, kernels ``gated_delta_step`` /
+``gated_delta_chunk``) and a vector a head (``g [.., H, dk]``: Kimi delta
+attention, the same step body as ``kda_step`` and the sibling
+``kda_chunk``).
+
 Everything is float32 and the kernels do the recurrence's sums in another
 order (the chunked form solves a triangular system a sub-chunk): outputs
 of order 0.1 and states of order 1 agree to 2e-6; a state the kernel must
@@ -19,8 +25,8 @@ B, T, H, DK, DV = 3, 128, 8, 128, 128
 TOL = 2e-6
 
 
-@pytest.fixture(scope="module")
-def case():
+@pytest.fixture(scope="module", params=["decay_a_head", "decay_a_channel"])
+def case(request):
     rng = np.random.default_rng(0)
     unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731,E501
     f = lambda x: jnp.asarray(x, jnp.float32)                       # noqa: E731
@@ -28,13 +34,15 @@ def case():
     k = f(unit(rng.normal(size=(B, T, H, DK))))
     v = f(rng.normal(size=(B, T, H, DV)))
     g = f(-0.1 * np.abs(rng.normal(size=(B, T, H))))
+    if request.param == "decay_a_channel":
+        g = f(-0.1 * np.abs(rng.normal(size=(B, T, H, DK))))
     beta = f(rng.uniform(0.1, 0.9, size=(B, T, H)))
     s0 = f(0.1 * rng.normal(size=(B, H, DK, DV)))
     o, sT = jax.jit(gd.gated_delta_recurrence)(q, k, v, g, beta, s0)
     # two layers of state: the kernels work on layer 1, layer 0 is zeros
     state = jnp.stack([jnp.zeros_like(s0), s0])
     return dict(q=q, k=k, v=v, g=g, beta=beta, s0=s0, o=o, sT=sT,
-                state=state)
+                state=state, channel=request.param == "decay_a_channel")
 
 
 def _err(a, b):
@@ -122,10 +130,56 @@ def test_shapes_the_tiling_does_not_take_fall_back_to_the_jnp_form():
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32) * 0.3  # noqa: E731,E501
     state = f(1, 2, 2, 16, 16)
     q, k, v = f(2, 2, 16), f(2, 2, 16), f(2, 2, 16)
-    g, beta = -jnp.abs(f(2, 2)), jax.nn.sigmoid(f(2, 2))
-    o, st = gd.gated_delta_step(state, 0, q, k, v, g, beta,
-                                np.array([True, True]))
+    beta = jax.nn.sigmoid(f(2, 2))
+    for g in (-jnp.abs(f(2, 2)), -jnp.abs(f(2, 2, 16))):
+        o, st = gd.gated_delta_step(state, 0, q, k, v, g, beta,
+                                    np.array([True, True]))
+        want_o, want_s = gd.gated_delta_recurrence(
+            q[:, None], k[:, None], v[:, None], g[:, None], beta[:, None],
+            state[0])
+        assert _err(o, want_o[:, 0]) < TOL and _err(st[0], want_s) < TOL
+
+
+def test_a_decay_a_channel_that_is_constant_is_the_decay_a_head(case):
+    """The scalar rule through the per-channel kernels: ``g [.., H]``
+    broadcast over ``dk`` gives what the scalar kernels give (which are
+    untouched: the scalar entry points run the code they ran)."""
+    c = case
+    if c["channel"]:
+        pytest.skip("the scalar case's own check")
+    wide = jnp.broadcast_to(c["g"][..., None], c["g"].shape + (DK,))
+    args = lambda g, t: tuple(c[n][t] if n != "g" else g[t]  # noqa: E731
+                              for n in ("q", "k", "v", "g", "beta"))
+    o1, s1 = _chunk(c["state"], 2, False, *args(c["g"], 2))
+    o2, s2 = _chunk(c["state"], 2, False, *args(wide, 2))
+    assert _err(o1, o2) < TOL and _err(s1, s2) < TOL
+    step = jax.jit(lambda st, *a: gd.gated_delta_step(st, 1, *a))
+    act = np.array([True, True, True])
+    o1, s1 = step(c["state"], *args(c["g"], (slice(None), 0)), act)
+    o2, s2 = step(c["state"], *args(wide, (slice(None), 0)), act)
+    assert _err(o1, o2) < TOL and _err(s1, s2) < TOL
+
+
+@pytest.mark.parametrize("form", ["kernel", "jnp"])
+def test_the_lower_bound_over_a_whole_sub_chunk_stays_finite(case, form):
+    """A log-decay of -5 a token on every channel of the first sub-chunk
+    (e^-320 across it: one reference row a sub-chunk would need e^+320)
+    and mixed decays after it: finite, and the recurrence's numbers."""
+    c = case
+    if not c["channel"]:
+        pytest.skip("the scalar rule's decays are a matrix of differences")
+    rng = np.random.default_rng(5)
+    g = jnp.asarray(-5.0 * rng.uniform(0, 1, size=(T, H, DK)), jnp.float32)
+    g = g.at[:gd.SUB].set(-5.0)
+    q, k, v, beta = (c[n][0] for n in ("q", "k", "v", "beta"))
     want_o, want_s = gd.gated_delta_recurrence(
-        q[:, None], k[:, None], v[:, None], g[:, None], beta[:, None],
-        state[0])
-    assert _err(o, want_o[:, 0]) < TOL and _err(st[0], want_s) < TOL
+        q[None], k[None], v[None], g[None], beta[None], c["s0"][:1])
+    if form == "kernel":
+        o, st = _chunk(c["state"], 0, False, q, k, v, g, beta)
+        s = st[1, 0]
+    else:
+        o, s = gd.gated_delta_chunk_reference(
+            q[None], k[None], v[None], g[None], beta[None], c["s0"][:1])
+        o, s = o[0], s[0]
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
+    assert _err(o, want_o[0]) < TOL and _err(s, want_s[0]) < 5 * TOL

@@ -395,6 +395,81 @@ def test_qwen3next_programs_at_the_cells_geometry(program, one_chip, as_v5e):
     assert mem.temp_size_in_bytes < 200 * 2 ** 20, mem.temp_size_in_bytes
 
 
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_ling3_programs_at_the_cells_geometry(program, one_chip, as_v5e):
+    """The decode and the chunk program of the ``ling_v3`` cell as the
+    engine's stateful bodies call the model, at the cell's 32 slots x
+    33,792 positions, 1,024-row chunks and published widths, its whole
+    six layers (2 dense + 4 expert MLPs, 5 Kimi delta layers + 1 latent):
+    every kernel is there once a layer that has it - the latent layer's
+    under their own names, with ONE pool and a V of zero heads - the cache
+    is aliased whole, and the temporaries stay far under the 1.25 GB pool
+    and the 0.34 GB recurrent block: no select over, no copy of either,
+    and no expansion of the latents."""
+    from apex_tpu.models import LingLM
+    from apex_tpu.serving.kv_cache import (CacheSpec, PagedKVCache, SlotAddr,
+                                           SlotState)
+
+    slots, max_pages, chunk = 32, 264, 1024
+    m = LingLM(num_layers=6, experts_held=tuple(range(128)),
+               dtype=BF16, inference_dtype=BF16, param_dtype=BF16)
+    spec = CacheSpec.of(m)
+    assert (spec.kv_heads, spec.head_dim, spec.value_dim) == (1, 576, 512)
+    sd = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)  # noqa: E731,E501
+    params = jax.tree_util.tree_map(
+        lambda a: sd(a.shape, BF16),
+        jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0),
+                                      jnp.zeros((1, 8), I32),
+                                      train=False))["params"])
+    pages = slots * max_pages + 1
+    cache = PagedKVCache(
+        k=sd((spec.page_layers, pages, 1, spec.head_dim, PAGE), BF16),
+        v=sd((spec.page_layers, pages, 0, spec.head_dim, PAGE), BF16),
+        state=SlotState(
+            blocks={b.name: sd((b.layers, slots) + b.shape, b.dtype or BF16)
+                    for b in spec.state},
+            expert_tokens=sd((spec.counter_layers, spec.num_experts), I32)))
+
+    def run(params, cache, tokens, addr, pt, **kw):
+        st = cache.state
+        logits, (k, v, blocks, counts) = m.apply(
+            {"params": params}, tokens, train=False, state=st.blocks,
+            addr=addr, cache=(cache.k, cache.v, pt), **kw)
+        return cache.replace(k=k, v=v, state=st.replace(
+            blocks=blocks, expert_tokens=st.expert_tokens + counts)), \
+            jnp.argmax(logits[:, 0], -1)
+
+    if program == "decode":
+        def fn(params, cache, last, pt, lengths, active):
+            return run(params, cache, last[:, None], SlotAddr(active=active),
+                       pt, positions=lengths, valid=active[:, None])
+        args = [sd((slots,), I32), sd((slots, max_pages), I32),
+                sd((slots,), I32), sd((slots,), jnp.bool_)]
+        want = {"kda_step": 5, "mla_decode_attention": 1,
+                "moe_grouped_gemm": 8}
+    else:
+        def fn(params, cache, tokens, pt, offset, n_valid, slot):
+            return run(params, cache, tokens,
+                       SlotAddr(slot=slot, fresh=offset == 0), pt,
+                       positions=offset[None], n_valid=n_valid[None])
+        args = [sd((1, chunk), I32), sd((1, max_pages), I32), sd((), I32),
+                sd((), I32), sd((), I32)]
+        want = {"kda_chunk": 5, "mla_prefill_attention": 1,
+                "moe_grouped_gemm": 8}
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    assert kernel_calls(compiled.as_text()) == want
+    mem = compiled.memory_analysis()
+    held = sum(int(jnp.prod(jnp.asarray(a.shape))) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(cache))
+    assert held > 1.5e9
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 600 * 2 ** 20, mem.temp_size_in_bytes
+    print(f"ling3 {program}: arguments {mem.argument_size_in_bytes}, "
+          f"aliased {mem.alias_size_in_bytes}, temporaries "
+          f"{mem.temp_size_in_bytes}")
+
+
 def test_xentropy_at_unpadded_gpt2_vocab_takes_the_reference(one_chip,
                                                             as_v5e):
     """The gate chip_smoke's per-kernel check exists for: at 50257 (not

@@ -10,7 +10,12 @@ interpret mode, chunk and page 128:
 - ``qwen3_next`` (hidden 64, two periods of a linear and a full layer, 4
   value heads of 128 x 128, 16 experts at 4 a token): a float32 recurrent
   block and the convolution's tail on the linear layers, pages on the
-  full ones only.
+  full ones only;
+- ``ling_v3`` (hidden 64, one period of five Kimi delta layers of 2 heads
+  of 128 x 128 and one latent layer, 16 experts in 4 groups at 4 a token
+  on four of the six layers): the same two blocks on the linear layers, a
+  decay a key channel; ONE pool of latent rows (32 + 16 wide, no V pool)
+  on the latent layer, written and read absorbed.
 
 The engine hands back tokens, not logits, so here a served token is held
 to the reference's logits: it must lie within LOGIT_TOL = 1e-4 of the
@@ -27,8 +32,10 @@ from apex_tpu import serving
 from apex_tpu.amp.policy import resolve_policy
 from apex_tpu.models import build_lm
 from apex_tpu.telemetry import MetricsRegistry
+from benchmarks.checks.tiny_ling3 import TINY_LING_CFG
 from benchmarks.checks.tiny_qwen3next import TINY_Q3N_CFG
 from benchmarks.checks.tiny_zaya import TINY_ZAYA_CFG
+from benchmarks.lib import reference_ling3 as rl
 from benchmarks.lib import reference_qwen3next as rq
 from benchmarks.lib import reference_zaya as rz
 
@@ -70,6 +77,11 @@ KINDS = {
                        kv_bytes_per_token=1024,
                        blocks={"recurrent": (2, SLOTS, 4, 128, 128),
                                "conv": (2, SLOTS, 3, 1024)}),
+    # layers: the four that have experts; the pool one latent row a token
+    "ling_v3": Kind("ling_v3", TINY_LING_CFG, rl, layers=4, experts=16,
+                    per_token=4, pool=(1, 1, 48), kv_bytes_per_token=192,
+                    blocks={"recurrent": (5, SLOTS, 2, 128, 128),
+                            "conv": (5, SLOTS, 3, 768)}),
 }
 
 
