@@ -143,7 +143,8 @@ def _layer_pool_shape(name, k_pool, v_pool, layer):
     - one layer alone, ``[num_pages, heads, page_len, head_dim]``
       (``layer=None``);
     - the serving engine's whole STACKED pool ``[layers, num_pages,
-      heads, head_dim, page_len]`` with the static ``layer`` to read.
+      heads, head_dim, page_len]`` with the ``layer`` to read (a
+      traced layer is the kernels' scalar operand and is not checked).
       Its pages are held transposed (``page_len`` is the lane
       dimension) because that is the one form the chip stores without
       padding AND the kernels' page DMA reads as it lies: with
@@ -164,37 +165,12 @@ def _layer_pool_shape(name, k_pool, v_pool, layer):
                          f"to read, a single layer's pool takes none")
     if layer is None:
         return k_pool.shape
-    if not 0 <= int(layer) < k_pool.shape[0]:
+    if not isinstance(layer, jax.core.Tracer) \
+            and not 0 <= int(layer) < k_pool.shape[0]:
         raise ValueError(f"{name}: layer {layer} outside the pool's "
                          f"{k_pool.shape[0]} layers")
     _, P, h, d, page_len = k_pool.shape
     return P, h, page_len, d
-
-
-def _page_block_spec(page_len, d, page_idx, layer):
-    """The K/V ``BlockSpec`` of the paged PREFILL kernel: one pool page
-    of one head, chosen by the scalar-prefetch index map ``page_idx``
-    (the paged decode kernel takes no block of the pool: it leaves it in
-    HBM and fetches whole pages of all heads itself). On a
-    stacked pool the static ``layer`` is one more (squeezed) block index
-    in front and the page arrives as it is stored, ``[d, page_len]``
-    (the kernel body's ``kt`` form): it is DMA'd out of the pool where
-    it lives, and no layer of the pool is ever sliced out in HBM."""
-    if layer is None:
-        return pl.BlockSpec((1, 1, page_len, d), page_idx)
-    return pl.BlockSpec((None, 1, 1, d, page_len),
-                        lambda *a: (layer,) + page_idx(*a))
-
-
-def _page_dots(kt: bool):
-    """``dot_general`` dimension numbers of a page's two products, for a
-    page held ``[page_len, d]`` or (``kt``, the stacked pool's form)
-    ``[d, page_len]``: (q·K^T contracting ``d``, p·V contracting
-    ``page_len``). Same products either way; only which axis of the
-    page block is contracted moves."""
-    if kt:
-        return (((1,), (0,)), ((), ())), (((1,), (1,)), ((), ()))
-    return (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ()))
 
 
 def gather_pages(pool, page_table, layer=None):

@@ -35,32 +35,32 @@ of I/O dtype, and a pure-jnp reference that doubles as the CPU/unaligned
 fallback and the test oracle. Pad rows of a final partial chunk compute
 garbage that the engine never samples from.
 
-Block geometry rides the shared tuned-override registry
-(:mod:`apex_tpu.kernels.vmem`) under ``decode.chunk_block_q``
+The contiguous kernel's block geometry rides the shared tuned-override
+registry (:mod:`apex_tpu.kernels.vmem`) under ``decode.chunk_block_q``
 (sublane-multiple 8) and ``decode.chunk_block_k`` (lane-multiple 128).
 
 **Paged variant** (:func:`paged_prefill_attention`): the block-table
-refactor's chunk-ingestion kernel. Same shifted-causal online softmax,
-but K/V arrive from a dense page pool through a ``[batch, max_pages]``
-page table rather than a contiguous cache row: the KV grid dimension
-walks the row's page list via scalar-prefetch block index maps (page
-``j`` of row ``b`` DMAs pool page ``page_table[b, j]``, clamped at the
-row's last reachable page ``(offsets[b] + C - 1) // page_len`` so grid
-steps past the chunk's extent re-issue the same block index and cost
-no new DMA — the fetch walk is O(offset + C) like the compute, not
-O(max_pages)), the q-block × page skip runs on global positions
-exactly as the contiguous kernel's q-block × k-block skip. The q-block
-knob is ``decode.page_block_q`` (the KV block is pinned to one page —
-the pool's DMA granule).
+chunk-ingestion kernel, in the form of the paged decode kernel. Same
+shifted-causal online softmax, but K/V live in a dense page pool
+reached through a ``[batch, max_pages]`` page table. The pool stays in
+HBM, the table, the offsets and the layer ride SMEM (scalar prefetch),
+and a grid step walks ONE row's live pages, ``ceil((offsets[b] + C) /
+page_len)`` of them and never the rest of the table: ``pages`` whole
+pages a DMA step (``decode.paged_step_bytes``, the decode kernel's one
+tuned number, over the page's bytes), every K/V head of a page in one
+contiguous fetch, double-buffered. Every query head of the chunk is
+attended against each page it holds - a K/V head's group of query
+heads folded into the rows of one product - and a (q block, page) pair
+past the block's last global position is skipped, so a chunk costs
+O(offset + C) in DMA and MXU work alike (:func:`_paged_prefill_kernel`).
 
 **Tensor parallelism** (``serving.Engine(mesh=...)``): no sharded
-variant needed — the grid's heads dimension simply shrinks. A
-heads-sharded pool (``heads/tp`` per shard) gives each shard the same
-index maps over fewer heads-axis blocks of its own pool slice; no DMA
-or mask ever crosses heads, so the per-shard kernel is unchanged math
-over its head subset and attention adds no collectives to the sharded
-serving programs (the block knobs above tune per-shard exactly as they
-do single-chip — same shapes per head, fewer heads).
+variant needed. A heads-sharded pool (``heads/tp`` per shard) gives
+each shard the same kernels over its own pool slice and its own query
+heads: the paged kernel's page is the local heads' page (its pages a
+step follow from that page's bytes); no DMA or mask ever crosses
+heads, so the per-shard kernel is unchanged math over its head subset
+and attention adds no collectives to the sharded serving programs.
 """
 
 from __future__ import annotations
@@ -74,11 +74,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.kernels import mosaic_dtype_ok, vmem
-from apex_tpu.kernels.decode_attention import (_check_head_scales,
+from apex_tpu.kernels.decode_attention import (_SCOPED_VMEM_ROOM,
+                                               _check_head_scales,
                                                _group_size,
                                                _layer_pool_shape,
-                                               _page_block_spec,
-                                               _page_dots, _repeat_kv_heads,
+                                               _pages_per_step,
+                                               _repeat_kv_heads,
                                                gather_pages)
 
 __all__ = ["prefill_attention", "prefill_attention_reference",
@@ -317,137 +318,286 @@ def paged_prefill_attention_reference(q, k_pool, v_pool, page_table,
                                        k_scale=k_scale, v_scale=v_scale)
 
 
-def _paged_prefill_kernel(pt_ref, off_ref, *refs, scale, block_q,
-                          page_len, quant, kt=False, G=1):
-    """Grid (b, h, nq, max_pages): one batch row x head, q-blocked
-    chunk, one pool page per KV step. :func:`_prefill_kernel`'s (m, l)
-    recurrence and global-position shifted-causal mask; the page the
-    DMA fetched was chosen by the scalar-prefetch index map. ``quant``
-    (static) adds two scalar-prefetch scale refs and the fused per-head
-    dequant multiplies. ``kt`` (static): the page blocks are
-    ``[d, page_len]``, the stacked pool's form. ``G`` (static): query
-    heads per K/V head; the grid walks QUERY heads and the index map
-    fetched K/V head ``hh // G``'s page."""
-    qk_dims, pv_dims = _page_dots(kt)
+def _block_rows(block_q, C, G, itemsize):
+    """Query rows of ONE head a q block: ``block_q``, or by default
+    ``128 // G`` so that a block of a group's ``G`` heads is 128 rows
+    (the MXU's side), as an aligned divisor of the chunk: 8 rows, or 16
+    where ``G`` bfloat16 blocks are folded into one (the packed tile the
+    fold merges along). What does not divide the chunk is the caller's
+    gate."""
+    from apex_tpu.kernels.flash_attention import _fit_block
+    rows = 16 if G > 1 and itemsize < 4 else 8
+    return _fit_block(block_q or max(1, 128 // G), C, rows)
+
+
+def _paged_prefill_vmem(q, k_pool, pages, bq, heads):
+    """``(working set, scoped limit asked for)`` in bytes of the paged
+    chunk kernel on the stacked pool ``k_pool`` at ``pages`` pages a
+    step, ``bq`` rows a head a q block and ``heads`` K/V heads a body:
+    K and V, two buffers each, of ``pages`` whole pages; the row's query
+    and output blocks, two of each (the pipeline's); the float32
+    accumulator and (m, l) of every head's rows; the logits, ``p`` and
+    its pieces of a body's widest products. The limit leaves the
+    compiler room for the products' operands beside it."""
+    _, h, C, d = q.shape
+    _, _, h_kv, _, page_len = k_pool.shape
+    lanes = -(-d // 128) * 128
+    buffers = 2 * 2 * pages * h_kv * d * page_len * k_pool.dtype.itemsize
+    q_and_out = 2 * 2 * h * C * lanes * q.dtype.itemsize
+    state = h * C * (lanes + 2 * 128) * 4
+    logits = 6 * heads * (h // h_kv) * bq * pages * page_len * 4
+    working = buffers + q_and_out + state + logits
+    return working, 2 * working + _SCOPED_VMEM_ROOM
+
+
+def _paged_prefill_kernel(pt_ref, off_ref, layer_ref, *refs, scale, pages,
+                          heads, G, quant, widen):
+    """Grid ``(batch,)``: one step walks ONE row's live pages, ``pages``
+    of them a DMA step, every K/V head of a page in one fetch, and
+    attends every query head of the chunk against each page it holds.
+
+    The pool stays in HBM (``k_hbm``/``v_hbm``, the stacked ``[layers,
+    num_pages, h_kv, d, page_len]`` form). The row's chunk reaches
+    global position ``offsets[b] + C - 1``, so its walk is the first
+    ``ceil((offsets[b] + C) / page_len)`` entries of its table, never the
+    rest: a page ``[h_kv, d, page_len]`` of layer ``layer_ref[0]`` (a
+    scalar operand: a model's layers run ONE traced and compiled kernel)
+    is one contiguous stretch of HBM and one ``make_async_copy`` into its
+    ``page_len`` lanes of a ``[h_kv, d, pages * page_len]`` VMEM buffer
+    (:func:`_paged_decode_kernel`'s buffer); K and V have two such
+    buffers each, and the next step's pages are in flight while this
+    step's are multiplied. Table slots of a step past the row's live
+    pages are never fetched: their V lanes are zeroed (0 x NaN is NaN)
+    and their K columns lie past every query's position, so the mask
+    takes them.
+
+    Arithmetic (:func:`_prefill_kernel`'s (m, l) recurrence, float32
+    state in VMEM scratch across the row's steps, the shifted-causal mask
+    on global positions): the chunk's queries arrive ``[h, nq, bq, d]``;
+    a q block of K/V head ``hh`` is its group's ``G`` query heads' ``bq``
+    rows folded into ``R = G x bq`` rows. A product spans the first
+    ``w`` pages of the step, ``[R, d] x [d, w x page_len]`` against the
+    head's keys as stored (bfloat16 x bfloat16 is exact in the float32
+    sum), and ``p @ V^T`` takes ``p`` float32 as its three bfloat16
+    pieces ``hi + mid + lo`` (24 bits of mantissa). ``w`` is the least of
+    ``1, 2, 4, ..., pages`` that covers the pages of the step both live
+    and reached by the q block's last position: an early chunk's blocks
+    multiply the prefix they see, a deep one's the whole step in one
+    wide product (a product has a fixed cost that a narrow one repeats).
+    A step nothing of which the block reaches is skipped. ``heads`` K/V
+    heads are unrolled in one body: their products are independent, so
+    the compiler overlaps one's vector work with another's matrix work,
+    where one product at a time leaves each unit waiting on the other
+    (PERF.md section 6, PR 39).
+
+    ``quant`` (static): int8 pages widen to bfloat16 (exact) and the
+    per-head scales (scalar operands) multiply after each product.
+    ``widen`` (static, the CPU's interpreter: its dot takes no bfloat16
+    x bfloat16 -> float32): operands are widened to float32 at the
+    product, the same numbers."""
     if quant:
-        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, \
-            l_ref = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        ks_ref, vs_ref, *refs = refs
+    (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc_ref, m_ref,
+     l_ref) = refs
+    _, h, nq, bq, d = q_ref.shape
+    h_kv, page_len = h // G, kbuf.shape[-1] // pages
+    R, C = G * bq, nq * bq
+    max_pages = pt_ref.shape[1]
     b = pl.program_id(0)
-    hh = pl.program_id(1)
-    kv = hh if G == 1 else hh // G    # the K/V head of this query head
-    qi = pl.program_id(2)
-    ji = pl.program_id(3)
-    nj = pl.num_programs(3)
-    offset = off_ref[b]
+    layer, offset = layer_ref[0], off_ref[b]
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    split = kbuf.dtype != f32            # p as three bfloat16 pieces
+    # the K product's operands: as stored (int8 widened) where q is
+    # bfloat16 too, else float32
+    k_dtype = bf16 if q_ref.dtype == bf16 and split else f32
+    n = jnp.minimum(jax.lax.div(offset + C + page_len - 1, page_len),
+                    max_pages)                    # the row's live pages
+    steps = jax.lax.div(n + pages - 1, pages)
 
-    @pl.when(ji == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def dot(a, bb, dims):
+        if widen:
+            a, bb = a.astype(f32), bb.astype(f32)
+        return jax.lax.dot_general(a, bb, (dims, ((), ())),
+                                   preferred_element_type=f32)
 
-    # skip pages entirely past this q-block's LAST global position
-    @pl.when(ji * page_len <= offset + qi * block_q + block_q - 1)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [bq, d]
-        k = k_ref[0, 0].astype(jnp.float32)      # [pl, d] (kt: [d, pl])
-        s = jax.lax.dot_general(
-            q, k, qk_dims,
-            preferred_element_type=jnp.float32) * scale      # [bq, pl]
+    def copies(i, slot, act):
+        """Start or wait (``act``) the DMAs of step ``i`` on buffer
+        ``slot``: its live pages only."""
+        for jj in range(pages):
+            j = i * pages + jj
+
+            @pl.when(j < n)
+            def _page():
+                page = pt_ref[b, jnp.minimum(j, max_pages - 1)]
+                lanes = pl.ds(jj * page_len, page_len)
+                for x, (hbm, buf) in enumerate(((k_hbm, kbuf),
+                                                (v_hbm, vbuf))):
+                    act(pltpu.make_async_copy(hbm.at[layer, page],
+                                              buf.at[slot, :, :, lanes],
+                                              sem.at[x, slot]))
+
+    def queries(hh, qi):
+        if G == 1:
+            return q_ref[0, hh, qi]
+        return q_ref[0, pl.ds(hh * G, G), qi].reshape(R, d)
+
+    def attend(slot, i, w, hh, qi, first):
+        """One head's q block against the first ``w`` pages of step
+        ``i``: the (m, l, acc) update, in the scratch."""
+        W = w * page_len
+        m_prev, l_prev = m_ref[hh, qi, :, :1], l_ref[hh, qi, :, :1]
+        k = kbuf[slot, hh, :, :W]                             # [d, W]
+        v = vbuf[slot, hh, :, :W]
         if quant:
-            s = s * ks_ref[kv]
-        rows = offset + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, page_len), 0)
-        cols = ji * page_len + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, page_len), 1)
-        s = jnp.where(cols <= rows, s, _NEG_INF)
-        m_prev = m_ref[:, :1]                                # [bq, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                               # [bq, pl]
+            v = v.astype(bf16)
+        s = dot(queries(hh, qi).astype(k_dtype), k.astype(k_dtype),
+                ((1,), (0,))) * scale                         # [R, W]
+        if quant:
+            s = s * ks_ref[hh]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0)
+        if G > 1:
+            rows = jax.lax.rem(rows, bq)
+        cols = i * pages * page_len + jax.lax.broadcasted_iota(
+            jnp.int32, (R, W), 1)
+        s = jnp.where(cols <= first + rows, s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_ref[0, 0].astype(jnp.float32), pv_dims,
-            preferred_element_type=jnp.float32)
+        l_ref[hh, qi] = jnp.broadcast_to(
+            alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), (R, 128))
+        m_ref[hh, qi] = jnp.broadcast_to(m_new, (R, 128))
+        if split:
+            hi = p.astype(bf16)
+            rest = p - hi.astype(f32)
+            mid = rest.astype(bf16)
+            lo = (rest - mid.astype(f32)).astype(bf16)
+            pv = sum(dot(piece, v, ((1,), (1,)))
+                     for piece in (hi, mid, lo))              # [R, d]
+        else:
+            pv = dot(p, v, ((1,), (1,)))
         if quant:
-            pv = pv * vs_ref[kv]
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+            pv = pv * vs_ref[hh]
+        acc_ref[hh, qi] = acc_ref[hh, qi] * alpha + pv
 
-    @pl.when(ji == nj - 1)
-    def _finish():
-        l = l_ref[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+    # the widths a product may take: 1, 2, 4, ... pages, and the step
+    widths = sorted({min(1 << x, pages)
+                     for x in range(pages.bit_length() + 1)})
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    copies(0, 0, lambda c: c.start())
+
+    def step(i, slot):
+        @pl.when(i + 1 < steps)
+        def _prefetch():
+            copies(i + 1, 1 - slot, lambda c: c.start())
+
+        copies(i, slot, lambda c: c.wait())
+        for jj in range(1, pages):
+            @pl.when(i * pages + jj >= n)
+            def _stale():
+                vbuf[slot, :, :, jj * page_len:(jj + 1) * page_len] = \
+                    jnp.zeros((h_kv, d, page_len), vbuf.dtype)
+
+        def body(hg, qi):
+            first = offset + qi * bq                # the block's first row
+            # the step's pages both live and reached by the block
+            need = jnp.minimum(
+                jnp.minimum(n, jax.lax.div(first + bq - 1, page_len) + 1)
+                - i * pages, pages)
+            for lo, w in zip([0] + widths, widths):
+                @pl.when(jnp.logical_and(need > lo, need <= w))
+                def _product():
+                    for x in range(heads):
+                        attend(slot, i, w, hg * heads + x, qi, first)
+
+        _each_block(h_kv // heads, nq, body)
+        return 1 - slot
+
+    jax.lax.fori_loop(0, steps, step, 0)
+
+    def finish(hh, qi):
+        l = l_ref[hh, qi, :, :1]
+        # every row attends at least its own position, so l > 0 always;
+        # the guard only keeps a mis-called kernel finite
+        out = acc_ref[hh, qi] / jnp.where(l == 0.0, 1.0, l)     # [R, d]
+        if G == 1:
+            o_ref[0, hh, qi] = out.astype(o_ref.dtype)
+        else:
+            o_ref[0, pl.ds(hh * G, G), qi] = out.reshape(
+                G, bq, d).astype(o_ref.dtype)
+
+    _each_block(h_kv, nq, finish)
 
 
-def _paged_prefill_pallas(q, k_pool, v_pool, pt, offsets, scale, bq,
-                          interpret, ks=None, vs=None, layer=None):
+def _heads_a_body(h_kv, G, bq):
+    """K/V heads whose products one body of the chunk kernel unrolls:
+    the most that divide ``h_kv`` and keep a body near 1,280 rows of
+    products (10 heads of 128 rows in GPT-2 large, both of ZAYA1-8B's
+    and of Qwen3-Next's 2)."""
+    most = max(1, 1280 // (G * bq))
+    return max(x for x in range(1, min(h_kv, most) + 1) if h_kv % x == 0)
+
+
+def _each_block(outer, nq, body):
+    """``body(x, qi)`` for every ``x < outer`` (a K/V head, or a body's
+    group of them) and q block ``qi < nq``, in two loops."""
+    def each(x, carry):
+        def blk(qi, carry):
+            body(x, qi)
+            return carry
+        return jax.lax.fori_loop(0, nq, blk, carry)
+    jax.lax.fori_loop(0, outer, each, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "pages", "block_q",
+                                             "interpret"))
+def _paged_prefill_pallas(q, k_pool, v_pool, pt, offsets, layer, ks=None,
+                          vs=None, *, scale, pages, block_q, interpret):
+    """The kernel's call on the stacked pool, ``layer`` a traced scalar.
+    Jitted: a model's layers differ in ``layer`` alone, so they share one
+    trace and one lowered kernel (:func:`_paged_decode_pallas`'s
+    reason)."""
     B, h, C, d = q.shape
-    kt = layer is not None           # stacked pool: pages [d, page_len]
-    page_len = k_pool.shape[-1 if kt else -2]
-    max_pages = pt.shape[1]
+    _, _, h_kv, _, page_len = k_pool.shape
+    G, nq = h // h_kv, C // block_q
     quant = ks is not None
-    G = h // k_pool.shape[2 if kt else 1]    # query heads per K/V head
-    kv_head = (lambda hh: hh) if G == 1 else (lambda hh: hh // G)
+    heads = _heads_a_body(h_kv, G, block_q)
     kernel = functools.partial(_paged_prefill_kernel, scale=scale,
-                               block_q=bq, page_len=page_len,
-                               quant=quant, kt=kt, G=G)
-
-    # the dequant scales ride as two extra scalar-prefetch operands;
-    # the index maps' variadic tails absorb them (only the kernel body
-    # reads them)
-    def _q_idx(b, hh, i, j, pt, off, *_scales):
-        return (b, hh, i, 0)
-
-    def _kv_page(b, hh, i, j, pt, off, *_scales):
-        # Bound the DMA extent by the chunk's offset: row b's queries
-        # reach global position off[b] + C - 1 at most, so pages past
-        # index (off[b] + C - 1) // page_len are never computed over
-        # (the kernel's q-block × page skip). Clamping the page walk
-        # there makes every later grid step re-issue the SAME block
-        # index, which the Pallas pipeline does not re-fetch — the
-        # kernel stops paying DMA for the max_pages tail just as it
-        # already stopped paying MXU for it. Computed steps always have
-        # j <= last, so the clamp never changes what the compute reads
-        # (outputs stay bitwise identical to the oracle).
-        last = (off[b] + (C - 1)) // page_len
-        return (pt[b, jnp.minimum(j, last)], kv_head(hh), 0, 0)
-
-    kv_spec = _page_block_spec(page_len, d, _kv_page, layer)
-    n_prefetch, extra_ops = (4, (ks, vs)) if quant else (2, ())
-
+                               pages=pages, heads=heads, G=G, quant=quant,
+                               widen=interpret)
+    # the chunk split into q blocks along a leading axis: a free reshape
+    blocks = pl.BlockSpec((1, h, nq, block_q, d),
+                          lambda b, *_: (b, 0, 0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    scalars = [pt, offsets, jnp.reshape(layer, (1,)).astype(jnp.int32)]
+    if quant:
+        scalars += [ks, vs]
+    R = G * block_q
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,  # page_table, offsets[, ks, vs]
-        grid=(B, h, C // bq, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), _q_idx),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, d), _q_idx),
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),      # acc
-            pltpu.VMEM((bq, 128), jnp.float32),    # m (col 0 live)
-            pltpu.VMEM((bq, 128), jnp.float32),    # l (col 0 live)
+        num_scalar_prefetch=len(scalars),  # table, offsets, layer[, ks, vs]
+        grid=(B,),
+        in_specs=[blocks, in_hbm, in_hbm],
+        out_specs=blocks,
+        scratch_shapes=[          # K and V: two buffers of `pages` each
+            pltpu.VMEM((2, h_kv, d, pages * page_len), t.dtype)
+            for t in (k_pool, v_pool)] + [
+            pltpu.SemaphoreType.DMA((2, 2)),              # (K|V, buffer)
+            pltpu.VMEM((h_kv, nq, R, d), jnp.float32),    # acc
+            pltpu.VMEM((h_kv, nq, R, 128), jnp.float32),  # m
+            pltpu.VMEM((h_kv, nq, R, 128), jnp.float32),  # l
         ],
     )
-    return pl.pallas_call(
+    _, limit = _paged_prefill_vmem(q, k_pool, pages, block_q, heads)
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, h, C, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, h, nq, block_q, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=limit),
         interpret=interpret, name="paged_prefill_attention",
-    )(pt, offsets, *extra_ops, q, k_pool, v_pool)
-
-
-def _resolve_page_block_q(block_q):
-    if block_q is None:
-        block_q = vmem.get_override("decode.page_block_q",
-                                    DEFAULT_BLOCK_Q, multiple=8)
-    return block_q
+    )(*scalars, q.reshape(B, h, nq, block_q, d), k_pool, v_pool)
+    return out.reshape(B, h, C, d)
 
 
 def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
@@ -464,33 +614,34 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
     ``v_pool`` [num_pages, kv_heads, page_len, head_dim] (one layer of
     the serving pool; ``kv_heads`` divides ``heads``, query head ``i``
     reading K/V head ``i // (heads // kv_heads)``), or the whole stacked
-    pool [layers, num_pages, kv_heads,
-    head_dim, page_len] with the static ``layer`` to attend (the
-    serving engine's form, pages transposed; the layer is one more
-    block index of the page DMA — no layer is sliced out);
+    pool [layers, num_pages, kv_heads, head_dim, page_len] with the
+    ``layer`` to attend (the serving engine's form, pages transposed;
+    the layer is handed to the kernel as a scalar, so a model's layers
+    share ONE traced and lowered kernel, and may be a traced value; no
+    layer is sliced out);
     ``page_table`` [batch, max_pages] int32;
     ``offsets`` [batch] int32. Query row ``i`` attends logical cache
     positions ``[0, offsets[b] + i]`` — the shifted-causal mask of
     chunked prefill, unchanged by the paging. ``scale`` defaults to
     ``1/sqrt(head_dim)``.
 
-    Inference-only. The Pallas path walks each row's page list via
-    scalar-prefetch index maps and skips pages past each q-block's last
-    global position — O(offset + C) MXU work per chunk, same as the
-    contiguous kernel, over a pool that is dense and shared instead of
-    slot-partitioned. The DMA extent is bounded the same way: the page
-    index map clamps at each row's last reachable page
-    (``(offsets[b] + C - 1) // page_len``), so grid steps past the
-    prefix re-issue the same block index and the pipeline fetches
-    nothing new — an early chunk of a long prompt pays O(offset + C)
-    DMA, not O(max_pages) (the clamp only ever retargets steps whose
-    compute is skipped, so outputs are bitwise unchanged). Unaligned
-    shapes and non-Mosaic dtypes fall back to the gather-then-reference
-    oracle.
-
-    Tuned geometry: ``decode.page_block_q`` in the
-    :mod:`apex_tpu.kernels.vmem` override registry (the KV block is one
-    pool page by construction).
+    Inference-only. The Pallas path leaves the pool in HBM and walks each
+    row's LIVE pages (``ceil((offsets[b] + C) / page_len)``; the table
+    past them costs neither a DMA nor a step), several pages a step and
+    every K/V head of a page in one fetch, double-buffered; every query
+    head of the chunk is attended against each page it holds, a K/V
+    head's group of query heads folded into the rows of one product
+    (:func:`_paged_prefill_kernel`), and a (q block, page) pair past the
+    block's last position is skipped: O(offset + C) DMA and MXU work a
+    chunk, over a pool that is dense and shared. Pages a step follow
+    from the page's bytes and ``decode.paged_step_bytes``, the decode
+    kernel's one tuned number; ``block_q`` (query rows of one head a
+    block) defaults to ``128 // (heads // kv_heads)``. A single layer's
+    4-D pool is relaid to the stacked form first (a pool-sized copy: the
+    form of tests, not of serving). Unaligned shapes (``page_len`` not a
+    multiple of 128, a head's rows not whole tiles of the page's type, a
+    chunk the q block does not divide) and non-Mosaic dtypes fall back to
+    the gather-then-reference oracle.
     """
     B, h, C, d = q.shape
     P, hp, page_len, dp = _layer_pool_shape("paged_prefill_attention",
@@ -499,7 +650,7 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
         raise ValueError(f"paged_prefill_attention: pools "
                          f"{k_pool.shape}/{v_pool.shape} do not match q "
                          f"{q.shape}")
-    _group_size("paged_prefill_attention", h, hp)
+    G = _group_size("paged_prefill_attention", h, hp)
     if page_table.ndim != 2 or page_table.shape[0] != B:
         raise ValueError(f"paged_prefill_attention: page_table "
                          f"{page_table.shape} must be [{B}, max_pages]")
@@ -509,12 +660,14 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
     _check_head_scales("paged_prefill_attention", hp, k_scale, v_scale)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    from apex_tpu.kernels.flash_attention import _fit_block, _has_vma
-    bq = _fit_block(_resolve_page_block_q(block_q), C, 8)
+    from apex_tpu.kernels.flash_attention import _has_vma
     if jax.default_backend() == "cpu":
         interpret = True
-    pallas_ok = (C % bq == 0 and bq % 8 == 0 and d % 8 == 0
-                 and page_len % 128 == 0)
+    bq = _block_rows(block_q, C, G, q.dtype.itemsize)
+    # a page is read as [head_dim, page_len] slabs of each head: compiled,
+    # a head's rows must fill whole tiles of the stored type
+    rows = 8 if interpret else 32 // k_pool.dtype.itemsize
+    pallas_ok = C % bq == 0 and d % rows == 0 and page_len % 128 == 0
     if not pallas_ok or (interpret and _has_vma(q)) \
             or (not interpret and not mosaic_dtype_ok(q, k_pool, v_pool)):
         return paged_prefill_attention_reference(
@@ -526,8 +679,18 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, offsets, *,
     if k_scale is not None:
         ks = jnp.asarray(k_scale, jnp.float32)
         vs = jnp.asarray(v_scale, jnp.float32)
-    return _paged_prefill_pallas(q, k_pool, v_pool, pt, off32, scale, bq,
-                                 interpret, ks, vs, layer).astype(q.dtype)
+    if layer is None:
+        # one layer's [num_pages, heads, page_len, d] pool relaid to the
+        # stacked form the kernel reads (paged_decode_attention's way)
+        k_pool, v_pool = (jnp.swapaxes(t, -1, -2)[None]
+                          for t in (k_pool, v_pool))
+    pages = _pages_per_step(hp * d * page_len * k_pool.dtype.itemsize,
+                            pt.shape[1])
+    return _paged_prefill_pallas(
+        q, k_pool, v_pool, pt, off32,
+        jnp.asarray(0 if layer is None else layer, jnp.int32), ks, vs,
+        scale=float(scale), pages=pages, block_q=bq,
+        interpret=interpret).astype(q.dtype)
 
 
 # ------------------------------------------------- latent pages (MLA)

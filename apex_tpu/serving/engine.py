@@ -267,7 +267,8 @@ class PendingPrefill:
     force-early lint covers the dispatch half by name, exactly like the
     decode region. ``final`` and the timestamps are host bookkeeping so
     reconcile can finish the chunk's counters identically to the
-    synchronous path."""
+    synchronous path; ``offset`` is where the chunk began, kept for
+    reconcile's ``serving.chunk.pages_live`` / ``pages_tabled``."""
 
     token: Any                  # scalar int32, ON DEVICE until reconcile
     finite: Any                 # scalar bool, ON DEVICE until reconcile
@@ -275,6 +276,7 @@ class PendingPrefill:
     final: bool
     t_dispatch: float
     dispatch_s: float
+    offset: int
     reconciled: bool = False
     launch: Optional[int] = None    # id of the dispatch's engine.launch
 
@@ -1538,7 +1540,7 @@ class Engine:
         return PendingPrefill(
             token=token, finite=finite, slot=slot, final=final,
             t_dispatch=t0, dispatch_s=time.perf_counter() - t0,
-            launch=self._last_launch)
+            offset=offset, launch=self._last_launch)
 
     def prefill_chunk_reconcile(self, pending: PendingPrefill) -> int:
         """Force a dispatched chunk's sampled token to host and finish
@@ -1546,7 +1548,14 @@ class Engine:
         ``serving.prefill_chunk_s`` / ``serving.prefill.chunks`` /
         ``serving.tokens_generated`` counters) — the batched-readback
         half of the dispatch-ahead prefill split. Returns the host
-        token; a throwaway unless the chunk was ``final``."""
+        token; a throwaway unless the chunk was ``final``.
+
+        It also counts how much of the row's page table the chunk
+        kernel walked, from the offset the dispatch recorded:
+        ``serving.chunk.pages_live`` (``ceil((offset + chunk_len) /
+        page_len)``, at most the table: the pages the kernel fetches for
+        the program's padded chunk) and ``serving.chunk.pages_tabled``
+        (``max_pages``: what a walk of the whole table would fetch)."""
         if pending.reconciled:
             raise RuntimeError("PendingPrefill already reconciled")
         pending.reconciled = True
@@ -1562,6 +1571,12 @@ class Engine:
                 "serving.prefill_chunk_s",
                 pending.dispatch_s + time.perf_counter() - tw)
             self._registry.counter_inc("serving.prefill.chunks")
+            self._registry.counter_inc(
+                "serving.chunk.pages_live",
+                min(-(-(pending.offset + self.chunk_len) // self.page_len),
+                    self.max_pages))
+            self._registry.counter_inc("serving.chunk.pages_tabled",
+                                       self.max_pages)
             if pending.final:
                 self._registry.counter_inc("serving.tokens_generated")
         if pending.final:

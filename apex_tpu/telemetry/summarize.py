@@ -33,6 +33,8 @@ __all__ = ["load_records", "summarize_records", "render_summary",
 COUNTER_SHARES = (
     ("dispatch-ahead engagement", "serving.heartbeat.dispatched_ahead",
      "serving.decode.steps"),
+    ("chunk kernel's walk of the table", "serving.chunk.pages_live",
+     "serving.chunk.pages_tabled"),
 )
 
 #: hlo_category substrings that identify collective/communication ops

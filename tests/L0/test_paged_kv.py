@@ -584,6 +584,146 @@ def test_a_rows_nan_page_never_reaches_another_rows_result(step_bytes):
     np.testing.assert_allclose(got[1:], want[1:], atol=3e-2)
 
 
+# -------------------------- the chunk kernel's walk over a row's live pages
+# (query heads a K/V head, head_dim, page dtype, stacked pool): B's 20 / 20
+# heads of 64, D's 8 / 2 of 128, E's 16 / 2 of 256, the int8 tier, and one
+# layer's float32 pool. Two K/V heads each. Table entries past a row's live
+# pages are the SENTINEL page 0, filled with NaN.
+_CHUNK_GEOMETRIES = {
+    "g1_d64_bf16": (1, 64, "bf16", True),
+    "g4_d128_bf16": (4, 128, "bf16", True),
+    "g8_d256_bf16": (8, 256, "bf16", True),
+    "g1_d64_int8": (1, 64, "int8", True),
+    "g2_d32_f32_one_layer": (2, 32, "f32", False),
+}
+_CHUNK_MAXP = 6
+# name -> (two rows' offsets, chunk length, pages a step)
+_CHUNK_WALKS = {
+    # 4 and 1 live pages of a table of 6: the dead tail is NaN
+    "deep_offset_short_walk": ([384, 0], 64, 1),
+    # unaligned offsets (a verify's): the last live page partly live
+    "last_page_partly_live": ([200, 131], 32, 2),
+    # q blocks of 128 rows a head (G = 1): a block before a page's first
+    # position skips it
+    "q_blocks_skip_pages": ([0, 128], 256, 1),
+    # 3 and 5 live pages at 3 a step: a step whose last slots are dead
+    "several_pages_a_step": ([256, 448], 128, 3),
+    "whole_table": ([704, 0], 64, 2),
+}
+
+
+def _chunk_case(geometry, offsets, C, seed=13):
+    """``(q, clean pools, dirty pools, table, offsets, scales, layer)``:
+    every row its own pages in a shuffled order, entries past its live
+    pages the sentinel - zeros in the oracle's pools, NaN in the
+    kernel's (int8 has no NaN: noise)."""
+    G, d, dtype, stacked = _CHUNK_GEOMETRIES[geometry]
+    rng = np.random.default_rng(seed)
+    h_kv, B, PL = 2, len(offsets), 128
+    NP_ = 1 + B * _CHUNK_MAXP
+    shape = (NP_, h_kv, d, PL)
+    scales = {}
+    if dtype == "int8":
+        kp, vp = (rng.integers(-127, 128, size=shape).astype(np.float32)
+                  for _ in range(2))
+        scales = {"k_scale": jnp.asarray(rng.uniform(.01, .03, h_kv),
+                                         jnp.float32),
+                  "v_scale": jnp.asarray(rng.uniform(.01, .03, h_kv),
+                                         jnp.float32)}
+    else:
+        kp, vp = (rng.normal(size=shape).astype(np.float32)
+                  for _ in range(2))
+    pt = rng.permutation(np.arange(1, NP_)).reshape(B, _CHUNK_MAXP)
+    live = -(-(np.asarray(offsets) + C) // PL)
+    for b in range(B):
+        pt[b, live[b]:] = 0
+    qdt = jnp.float32 if dtype == "f32" else jnp.bfloat16
+    q = jnp.asarray(rng.normal(size=(B, h_kv * G, C, d)), qdt)
+    store = {"int8": jnp.int8, "bf16": jnp.bfloat16, "f32": jnp.float32}[
+        dtype]
+
+    def pool(pages):
+        if not stacked:
+            return jnp.asarray(pages.swapaxes(-1, -2), store)
+        other = np.full_like(pages, -128 if dtype == "int8" else np.nan)
+        return jnp.asarray(np.stack([other, pages, other]), store)
+    clean = [pool(t) for t in (kp, vp)]
+    kp[0], vp[0] = (101, 101) if dtype == "int8" else (np.nan, np.nan)
+    dirty = [pool(t) for t in (kp, vp)]
+    return (q, clean, dirty, jnp.asarray(pt, jnp.int32),
+            jnp.asarray(offsets, jnp.int32), scales, 1 if stacked else None)
+
+
+@pytest.mark.parametrize("walk", sorted(_CHUNK_WALKS))
+@pytest.mark.parametrize("geometry", sorted(_CHUNK_GEOMETRIES))
+def test_paged_prefill_walks_only_a_chunks_live_pages(geometry, walk,
+                                                      step_bytes):
+    """The chunk kernel walks ``ceil((offset + C) / page_len)`` pages of
+    a row's table, every head of a page in one fetch and ``pages`` of
+    them a step, and gives the gather-then-reference oracle's result:
+    grouped heads folded into a product's rows, the int8 tier's per-head
+    scales, a page partly live, q blocks that skip a page, a step whose
+    last slots are dead - and never a NaN from the table's dead tail."""
+    offsets, C, pages = _CHUNK_WALKS[walk]
+    q, clean, dirty, pt, offs, scales, layer = _chunk_case(geometry,
+                                                           offsets, C)
+    G, d, dtype, _ = _CHUNK_GEOMETRIES[geometry]
+    step_bytes(pages, 2 * d * 128 * {"int8": 1, "bf16": 2, "f32": 4}[dtype])
+    want = paged_prefill_attention_reference(
+        q, *clean, pt, offs, scale=1 / d ** 0.5, layer=layer, **scales)
+    got = jax.jit(lambda *a: paged_prefill_attention(
+        *a, layer=layer, interpret=True, **scales))(q, *dirty, pt, offs)
+    assert got.shape == want.shape and got.dtype == q.dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want,
+                               atol=1e-5 if dtype == "f32" else 3e-2)
+
+
+def test_a_rows_nan_page_never_reaches_another_rows_chunk(step_bytes):
+    """Two pages a step: row 0's second page holds NaN and stays behind
+    in the buffer row 1's one live page is fetched into next. Row 0 is
+    NaN; row 1 must read as if it were not there."""
+    q, clean, dirty, pt, offs, _, layer = _chunk_case(
+        "g1_d64_bf16", [128, 0], 64)
+    step_bytes(2, 2 * 64 * 128 * 2)
+    k = np.asarray(dirty[0], np.float32)
+    v = np.asarray(dirty[1], np.float32)
+    k[layer, int(pt[0, 1])], v[layer, int(pt[0, 1])] = np.nan, np.nan
+    want = paged_prefill_attention_reference(q, *clean, pt, offs,
+                                             scale=0.125, layer=layer)
+    got = paged_prefill_attention(q, jnp.asarray(k, jnp.bfloat16),
+                                  jnp.asarray(v, jnp.bfloat16), pt, offs,
+                                  layer=layer, interpret=True)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isnan(got[0]).any()
+    np.testing.assert_allclose(got[1], want[1], atol=3e-2)
+
+
+def test_paged_prefill_layer_is_an_operand():
+    """``layer`` reaches the kernel as a scalar operand: under ``jit`` a
+    traced layer reads that layer of the stacked pool, two layers of one
+    pool give each its own result, one trace serving both."""
+    rng = np.random.default_rng(3)
+    LYR, B, H, D, NP_, MAXP, C = 3, 1, 2, 64, 5, 4, 128
+    kp, vp = (jnp.asarray(rng.normal(size=(LYR, NP_, H, D, 128)),
+                          jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(B, H, C, D)), jnp.bfloat16)
+    pt = jnp.asarray([[3, 1, 4, 2]], jnp.int32)
+    offs = jnp.asarray([256], jnp.int32)
+    call = jax.jit(lambda layer: paged_prefill_attention(
+        q, kp, vp, pt, offs, layer=layer, interpret=True))
+    outs = []
+    for layer in (0, 2):
+        got = np.asarray(call(jnp.int32(layer)), np.float32)
+        want = np.asarray(paged_prefill_attention_reference(
+            q, kp, vp, pt, offs, scale=0.125, layer=layer), np.float32)
+        np.testing.assert_allclose(got, want, atol=3e-2)
+        outs.append(got)
+    assert not (outs[0] == outs[1]).all()
+    assert call._cache_size() == 1
+
+
 # ------------------------------------------------------------ engines
 def _tiny_lm(max_seq_len=64, **kw):
     return TransformerLM(vocab_size=VOCAB, hidden=32, num_layers=2,
@@ -883,6 +1023,39 @@ def test_decode_page_counters_follow_the_rows_lengths(engine):
         len(lengths) * ep.cache.k.shape[0] * 2
     assert c["serving.decode.pages_live"] == sum(
         -(-length // ep.page_len) for length in lengths)
+
+
+def test_chunk_page_counters_follow_the_rows_offsets(engine):
+    """``serving.chunk.pages_live`` / ``pages_tabled``: the share of the
+    page table the chunk kernel walks, from the offsets the chunks were
+    dispatched at. A prompt of ``p`` tokens is ``ceil(p / chunk_len)``
+    chunks at offsets ``0, chunk_len, ...``; the program attends its
+    padded chunk, so a chunk at ``o`` walks ``ceil((o + chunk_len) /
+    page_len)`` pages of a table of ``max_pages``."""
+    from apex_tpu.telemetry.summarize import (render_summary,
+                                              summarize_records)
+    ep = engine
+    ep.reset(clear_prefixes=True)
+    reg = telemetry.MetricsRegistry()
+    ep.set_registry(reg)
+    jobs = [(5, 3), (17, 2), (24, 1), (8, 4)]  # (prompt length, new tokens)
+    try:
+        Scheduler(ep, registry=reg).run(
+            [Request(prompt=list(range(1, p + 1)), max_new_tokens=n)
+             for p, n in jobs])
+    finally:
+        ep.set_registry(None)
+    c = reg.snapshot()["counters"]
+    offsets = [o for p, _ in jobs for o in range(0, p, ep.chunk_len)]
+    assert c["serving.prefill.chunks"] == len(offsets)
+    assert c["serving.chunk.pages_live"] == sum(
+        -(-(o + ep.chunk_len) // ep.page_len) for o in offsets)
+    assert c["serving.chunk.pages_tabled"] == len(offsets) * ep.max_pages
+    share = c["serving.chunk.pages_live"] / c["serving.chunk.pages_tabled"]
+    assert 0 < share < 1
+    text = render_summary(summarize_records([reg.snapshot()]))
+    assert f"chunk kernel's walk of the table (serving.chunk.pages_live " \
+        f"/ serving.chunk.pages_tabled) {share:.4f}" in text
 
 
 def test_paged_reset_keeps_warm_prefix_pages_unless_cleared(engine):

@@ -245,6 +245,9 @@ CASES = {
                                    ["paged_prefill_attention"]),
     "paged_prefill_stacked_int8": (_paged_prefill, (12, 64, I8, True),
                                    ["paged_prefill_attention"]),
+    # speculative verify's 8 rows (draft_len 7): one q block of 8
+    "paged_prefill_verify_8_rows": (_paged_prefill, (12, 64, BF16, True, 8),
+                                    ["paged_prefill_attention"]),
     "prefill_bf16": (_prefill, (12, 64, BF16), ["prefill_attention"]),
     "paged_prefill_bf16": (_paged_prefill, (12, 64, BF16),
                            ["paged_prefill_attention"]),
@@ -326,6 +329,49 @@ def test_paged_decode_at_a_cells_geometry_fits_its_vmem(cell, write,
         assert makes_a_pool == {"parameter", "get-tuple-element"}
     else:
         assert makes_a_pool == {"parameter"}
+
+
+def test_gpt2_large_chunk_program_traces_its_chunk_kernel_once(
+        one_chip, as_v5e, monkeypatch):
+    """Cell B's chunk program as the engine calls the model: GPT-2
+    large's 36 layers of 20 heads x 64, one row of 256 tokens over a
+    table of 8 pages of the 193-page stacked pool, donated. It compiles
+    for v5e with ONE ``paged_prefill_attention`` a layer, and the
+    kernel's body is traced once for all 36 (``layer`` is its scalar
+    operand, not a static argument)."""
+    import importlib
+
+    from apex_tpu.models.transformer_lm import TransformerLM
+    pa = importlib.import_module("apex_tpu.kernels.prefill_attention")
+    traced = []
+    body = pa._paged_prefill_kernel
+    monkeypatch.setattr(pa, "_paged_prefill_kernel",
+                        lambda *a, **kw: traced.append(1) or body(*a, **kw))
+    pa._paged_prefill_pallas.clear_cache()
+    layers = 36
+    m = TransformerLM(vocab_size=50257, hidden=1280, num_layers=layers,
+                      num_heads=20, max_seq_len=1024, dtype=BF16)
+    sd = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)  # noqa: E731,E501
+    params = jax.tree_util.tree_map(
+        lambda a: sd(a.shape, BF16),
+        jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0),
+                                      jnp.zeros((1, 8), I32),
+                                      train=False))["params"])
+    pool = sd((layers, 193, 20, 64, PAGE), BF16)
+
+    def chunk(params, k, v, tokens, pt, offset):
+        logits, (k, v) = m.apply({"params": params}, tokens, train=False,
+                                 cache=(k, v, pt), positions=offset[None])
+        return k, v, jnp.argmax(logits[0, -1])
+    compiled = jax.jit(chunk, donate_argnums=(1, 2)).lower(
+        params, pool, pool, sd((1, 256), I32), sd((1, MAX_PAGES), I32),
+        sd((), I32)).compile()
+    assert len(traced) == 1
+    assert kernel_calls(compiled.as_text())["paged_prefill_attention"] \
+        == layers
+    # the pool is read where it lies: a few tens of MB of temporaries
+    # beside 2.3 GB of pages
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
 
 
 # The top-k layers' products as the cells' chunk programs call the kernel

@@ -96,18 +96,24 @@ def test_chunk_prefill_keys_are_tuned():
 
 def test_paged_kernel_keys_are_tuned():
     """The paged-pool satellite: the block-table kernels' knobs ship
-    tuned values — ``decode.page_block_q`` (the paged prefill kernel's
-    q block; the KV block is pinned to one pool page) and
-    ``decode.page_len`` (the Engine's default page size — the pool's
-    sharing/DMA granule). A fresh paged engine on v5e silicon must not
-    fall back to emulator-era defaults for its two hottest programs."""
+    tuned values — ``decode.page_len`` (the Engine's default page size —
+    the pool's sharing/DMA granule), consumed by code. A fresh paged
+    engine on v5e silicon must not fall back to emulator-era defaults
+    for its two hottest programs. ``decode.page_block_q`` (the paged
+    chunk kernel's q block while it walked one head of one table page a
+    grid step) is retired: the kernel walks a row's live pages with
+    every head of a page in one fetch, its q block follows from the
+    group of heads, and no code reads the key, so no table may carry
+    it."""
     table = _table_keys()
-    for key in ("decode.page_block_q", "decode.page_len"):
-        assert key in table, f"{key} missing from the tuned tables"
+    assert "decode.page_len" in table, \
+        "decode.page_len missing from the tuned tables"
+    assert "decode.page_block_q" not in table, \
+        "decode.page_block_q is retired: no code consumes it"
     refs = _referenced_keys({"decode"})
-    for key in ("decode.page_block_q", "decode.page_len"):
-        assert key in refs, f"{key} is in the tables but no code " \
-            "consumes it (stale sweep row)"
+    assert "decode.page_len" in refs, "decode.page_len is in the " \
+        "tables but no code consumes it (stale sweep row)"
+    assert "decode.page_block_q" not in refs
 
 
 def test_prefix_copy_sources_are_linted_and_carry_no_tuned_keys():
@@ -138,8 +144,8 @@ def test_prefix_copy_sources_are_linted_and_carry_no_tuned_keys():
 def test_speculative_verify_owes_the_tables_no_keys():
     """The speculative-decoding satellite, in the copy-program pattern:
     the verify program is the chunk-append machinery at a different
-    shape — its attention rides the EXISTING ``decode.chunk_block_*`` /
-    ``decode.page_block_q`` knobs and the drafter is pure host python —
+    shape — its attention rides the EXISTING paged chunk kernel and its
+    knobs and the drafter is pure host python —
     so no ``decode.verify_*`` key may exist in the tables (a row no
     code consumes would be a dead sweep; if a dedicated verify kernel
     ever lands, its keys get the existence/staleness treatment
@@ -279,7 +285,7 @@ def test_sharded_serving_owes_the_tables_no_new_keys():
     table = {k for k in _table_keys() if k.startswith("decode.")}
     assert table == {
         "decode.chunk_block_q", "decode.chunk_block_k",
-        "decode.page_block_q", "decode.page_len",
+        "decode.page_len",
         # the paged decode kernel's bytes in flight a buffer (PR 31):
         # per shard it sees the local heads' page and reads its pages a
         # step off that, so sharding needs no value of its own
