@@ -13,6 +13,7 @@ from .resnet import (  # noqa: F401
     ResNet152, create_model)
 from .transformer_lm import (  # noqa: F401
     TransformerBlock, TransformerLM, create_lm)
+from .keye import KeyeLM  # noqa: F401
 from .ling import LingLM  # noqa: F401
 from .qwen3_next import Qwen3NextLM  # noqa: F401
 from .zaya import ZayaLM  # noqa: F401
@@ -43,7 +44,12 @@ def build_lm(config: dict, **overrides):
     ``moe_shared_expert_intermediate_size``, ``num_experts_per_tok``,
     ``n_group``, ``topk_group``, ``routed_scaling_factor``; ``num_experts``
     and ``published.num_experts`` as for ``qwen3_next``; the published
-    file names no ``model_type``, the name is this repo's). A file may
+    file names no ``model_type``, the name is this repo's), ``KeyeVL2``
+    -> :class:`KeyeLM` (Keye-VL-2.0's language model: the full-attention
+    keys as ``qwen3_next``'s, ``rope_theta``, ``num_experts_per_tok``,
+    ``moe_intermediate_size`` and ``sa_config``'s ``indexer_num_heads``,
+    ``indexer_head_dim`` and ``topk``; ``num_experts`` and
+    ``published.num_experts`` as for ``qwen3_next``). A file may
     state ``published`` (the source's value of each key it cut) and
     ``changed`` (why), which only ``published.num_experts`` is read from.
     ``overrides`` are fields of the model class (``dtype``,
@@ -169,13 +175,55 @@ def build_lm(config: dict, **overrides):
             rms_eps=float(config["rms_norm_eps"]),
             max_seq_len=int(config["max_position_embeddings"]),
             **overrides)
+    if kind == "KeyeVL2":
+        sa = config.get("sa_config") or {}
+        rope = config.get("rope_scaling") or {}
+        if int(config.get("decoder_sparse_step", 1)) != 1 \
+                or config.get("mlp_only_layers") \
+                or config.get("use_sliding_window") \
+                or config.get("sliding_window") \
+                or config.get("tie_word_embeddings") \
+                or config.get("shared_expert_intermediate_size") \
+                or not config.get("norm_topk_prob", True) \
+                or config.get("attention_bias") \
+                or rope.get("rope_type", "default") != "default" \
+                or int(sa.get("indexer_num_kv_heads", 1)) != 1 \
+                or not sa:
+            raise NotImplementedError(
+                "build_lm: KeyeVL2 as published only - every layer sparse "
+                "attention (an indexer with one shared key) over experts, "
+                "renormalised top-k weights and no shared expert, no "
+                "sliding window, default rotary, no attention bias, an "
+                "untied head")
+        held = int(config["num_experts"])
+        routed = int(config.get("published", {}).get("num_experts", held))
+        if held != routed:
+            overrides.setdefault("experts_held", tuple(range(held)))
+        return KeyeLM(
+            vocab_size=int(config["vocab_size"]),
+            hidden=int(config["hidden_size"]),
+            num_layers=int(config["num_hidden_layers"]),
+            num_heads=int(config["num_attention_heads"]),
+            num_kv_heads=int(config["num_key_value_heads"]),
+            head_dim=int(config["head_dim"]),
+            rope_theta=float(config["rope_theta"]),
+            index_heads=int(sa["indexer_num_heads"]),
+            index_dim=int(sa["indexer_head_dim"]),
+            index_topk=int(sa["topk"]),
+            num_experts=routed,
+            experts_per_token=int(config["num_experts_per_tok"]),
+            expert_width=int(config["moe_intermediate_size"]),
+            rms_eps=float(config["rms_norm_eps"]),
+            max_seq_len=int(config["max_position_embeddings"]),
+            **overrides)
     raise ValueError(f"build_lm: no model for model_type {kind!r} "
-                     "(have: 'gpt2', 'zaya', 'qwen3_next', 'ling_v3')")
+                     "(have: 'gpt2', 'zaya', 'qwen3_next', 'ling_v3', "
+                     "'KeyeVL2')")
 
 __all__ = [
     "BasicBlock", "Bottleneck", "ResNet", "ResNet18", "ResNet34", "ResNet50",
     "ResNet101", "ResNet152", "create_model",
     "TransformerLM", "TransformerBlock", "create_lm", "ZayaLM",
-    "Qwen3NextLM", "LingLM", "build_lm",
+    "Qwen3NextLM", "LingLM", "KeyeLM", "build_lm",
     "BertConfig", "BertModel", "BertForPreTraining", "create_bert",
 ]

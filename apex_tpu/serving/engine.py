@@ -279,6 +279,7 @@ class PendingPrefill:
     offset: int
     reconciled: bool = False
     launch: Optional[int] = None    # id of the dispatch's engine.launch
+    n_tokens: int = 0               # the chunk's real tokens
 
 
 class _Upload:
@@ -547,12 +548,24 @@ class Engine:
         self.cache_spec = spec_ = CacheSpec.of(model)
         layers = spec_.page_layers
         kv_heads, head_dim = spec_.kv_heads, spec_.head_dim
-        # per-slot state beside the pages (kv_cache.SlotState): what is
-        # not built for it is refused by name HERE, never run wrong
+        # per-slot state beside the pages (kv_cache.SlotState), an index
+        # pool beside K/V (the index page kind): what is not built for
+        # them is refused by name HERE, never run wrong
         self.slot_state = bool(spec_.state)
         self.model_kind = str(getattr(model, "model_kind",
                                       type(model).__name__))
+        beside = []
         if self.slot_state:
+            beside.append("per-slot state (" + ", ".join(
+                b.name for b in spec_.state) + " beside its pages)")
+        if spec_.index_dim:
+            beside.append("the index page kind (an index key a position "
+                          "beside its K/V pages)")
+        #: what a model keeps beside its K/V pages that the options which
+        #: copy, move or re-enter a request from pages alone do not
+        #: carry (the Scheduler refuses its side of them by it); None
+        self.beside_pages = " and ".join(beside) or None
+        if self.beside_pages:
             for on, what in (
                     (prefix_pool > 0, "prefix_cache retention "
                      "(prefix_pool > 0)"),
@@ -567,11 +580,10 @@ class Engine:
                 if on:
                     raise NotImplementedError(
                         f"serving.Engine: {what} is not built for a model "
-                        f"with per-slot state ({self.model_kind!r}: "
-                        f"{', '.join(b.name for b in spec_.state)} beside "
-                        "its pages); it re-enters or "
-                        "re-shapes a request from pages alone, and the "
-                        "slot's state would be wrong")
+                        f"with {self.beside_pages} ({self.model_kind!r}); "
+                        "it re-enters or re-shapes a request from its K/V "
+                        "pages alone, and what the model keeps beside "
+                        "them would be wrong")
         # quantized-cache storage tier (independent of the COMPUTE half
         # dtype the policy picks): int8 K/V with per-[layer, head] fp32
         # scales, resolved HERE so a degenerate calibration (absmax 0 /
@@ -679,14 +691,15 @@ class Engine:
         self.num_pages = num_pages
         if mesh is None:
             state = None
-            if self.slot_state:
+            if self.slot_state or spec_.num_experts:
                 state = SlotState.create(spec_, slots=self.slots,
                                          dtype=half)
             self.cache = PagedKVCache.create(
                 layers=layers, num_pages=num_pages, heads=kv_heads,
                 page_len=page_len, head_dim=head_dim,
                 dtype=cache_dtype, k_scale=k_scale, v_scale=v_scale,
-                state=state, value_dim=spec_.value_dim)
+                state=state, value_dim=spec_.value_dim,
+                row_pages=spec_.row_pages, index_dim=spec_.index_dim)
         else:
             # heads-axis pool sharding: each shard holds
             # [layers, num_pages, heads/tp, head_dim, page_len] —
@@ -853,9 +866,11 @@ class Engine:
         # tensor-parallel axis (params split per the rule table, the
         # pool on heads, every host operand replicated); mesh=None
         # wraps nothing — the verbatim single-chip programs
-        # a model with per-slot state runs the same two programs
-        # with the state threaded through (one more operand each)
-        stateful = self.slot_state
+        # a model with per-slot state (or an expert counter, or an index
+        # pool) runs the same two programs with what it keeps beside the
+        # pages threaded through (one more operand each)
+        self._threaded = stateful = self.cache.state is not None \
+            or self.cache.index is not None
         self._jit_decode = jax.jit(
             self._state_decode_impl if stateful else
             self._tp_wrap(self._paged_decode_impl, 2),
@@ -1317,16 +1332,20 @@ class Engine:
     # small block through a select, a large one by its own kernel - and
     # hands the same buffers back, like the pool.
     def _state_apply(self, params, cache, tokens, addr, page_table, **kw):
-        """One model call with the state blocks and their addressing in;
-        the cache with pools, blocks and expert counters updated out."""
+        """One model call with the state blocks and their addressing in
+        (and the index pool, as a fourth cache entry, where the spec has
+        one); the cache with pools, blocks and expert counters updated
+        out."""
         st = cache.state
-        logits, (k2, v2, blocks, counts) = self._model.apply(
+        index = () if cache.index is None else (cache.index,)
+        logits, (k2, v2, blocks, counts, *index) = self._model.apply(
             {"params": params}, tokens, train=False, state=st.blocks,
-            addr=addr, cache=(cache.k, cache.v, page_table), **kw)
+            addr=addr, cache=(cache.k, cache.v, page_table) + index, **kw)
         st = st.replace(blocks=blocks)
         if st.expert_tokens.shape[1]:
             st = st.replace(expert_tokens=st.expert_tokens + counts)
-        return logits, cache.replace(k=k2, v=v2, state=st)
+        cache = cache.replace(k=k2, v=v2, state=st)
+        return logits, cache.replace(index=index[0]) if index else cache
 
     def _state_chunk_impl(self, params, cache, tokens, pt_row, offset,
                           n_valid, temperature, fault_bias, key, slot):
@@ -1367,7 +1386,7 @@ class Engine:
         """The trailing operand of a stateful engine's chunk call (its
         slot); nothing on a model without slot state, whose
         programs keep the operands they always had."""
-        return (np.int32(slot),) if self.slot_state else ()
+        return (np.int32(slot),) if self._threaded else ()
 
     def moe_tokens_per_expert(self) -> Optional[np.ndarray]:
         """Tokens routed to each expert by every program since the
@@ -1540,7 +1559,7 @@ class Engine:
         return PendingPrefill(
             token=token, finite=finite, slot=slot, final=final,
             t_dispatch=t0, dispatch_s=time.perf_counter() - t0,
-            offset=offset, launch=self._last_launch)
+            offset=offset, launch=self._last_launch, n_tokens=n)
 
     def prefill_chunk_reconcile(self, pending: PendingPrefill) -> int:
         """Force a dispatched chunk's sampled token to host and finish
@@ -1577,6 +1596,11 @@ class Engine:
                     self.max_pages))
             self._registry.counter_inc("serving.chunk.pages_tabled",
                                        self.max_pages)
+            if self.cache_spec.index_topk:
+                # the chunk's real tokens at offset + i attend offset + i
+                # + 1 positions live and keep at most index_topk of them
+                live = pending.offset + 1 + np.arange(pending.n_tokens)
+                self._count_selection(live)
             if pending.final:
                 self._registry.counter_inc("serving.tokens_generated")
         if pending.final:
@@ -2186,7 +2210,7 @@ class Engine:
             up(temperatures, jnp.float32),
             up(fault_bias), up.run(self._next_key),
             *self._lora_args(up=up),
-            *((up(act),) if self.slot_state else ())))
+            *((up(act),) if self._threaded else ())))
         self.cache, tokens, finite = self._runtime_call(
             "decode", lambda: self._jit_decode(self.params, self.cache,
                                                *ops))
@@ -2263,7 +2287,21 @@ class Engine:
             self._registry.counter_inc(
                 "serving.decode.pages_written",
                 pending.attended.size * self.cache.k.shape[0] * 2)
+            if self.cache_spec.index_topk:
+                self._count_selection(pending.attended)
         return out, finite, dt
+
+    def _count_selection(self, live) -> None:
+        """The index page kind's selection in one program: each query
+        row attending ``live`` positions keeps ``min(live, index_topk)``
+        of them - ``serving.dsa.positions_live`` and
+        ``serving.dsa.positions_selected``, per layer."""
+        live = np.asarray(live, np.int64)
+        self._registry.counter_inc("serving.dsa.positions_live",
+                                   float(live.sum()))
+        self._registry.counter_inc(
+            "serving.dsa.positions_selected",
+            float(np.minimum(live, self.cache_spec.index_topk).sum()))
 
     def sync(self) -> None:
         """Explicit device barrier: block until every dispatched
@@ -2627,6 +2665,10 @@ class Engine:
             if self.slot_state:
                 # donated and aliased with the pool: part of alias_bytes
                 out[name]["state_bytes"] = self.cache.state.nbytes()
+            if self.cache.index is not None:
+                # likewise the index pool
+                out[name]["index_bytes"] = int(
+                    self.cache.index.size * self.cache.index.dtype.itemsize)
         if self._registry is not None:
             self._registry.gauge_set("serving.kv.pool_bytes",
                                      float(self.cache.nbytes()))
@@ -2655,7 +2697,7 @@ class Engine:
                     self.params, self.cache, *decode_ops, slots_f32,
                     slots_f32, self._key, *self._lora_args(),
                     *((np.zeros(self.slots, bool),)
-                      if self.slot_state else ())),
+                      if self._threaded else ())),
                 "chunk": self._jit_chunk.lower(
                     self.params, self.cache, *chunk_ops, *scalars,
                     *self._lora_args(0), *self._slot_arg(0)),

@@ -99,6 +99,17 @@ class CacheSpec:
     pool and no V pool (:class:`PagedKVCache` holds a V of zero heads),
     and the model's kernels read a page once for both products.
 
+    ``row_pages`` states the CONTIGUOUS-ROW page layout: a page is
+    ``[page_len, kv_heads * head_dim]``, one position's keys (values) of
+    all heads one row, so that a kernel fetching scattered positions
+    reads one row each (the default holds a page ``[kv_heads, head_dim,
+    page_len]``, positions in the lanes). ``index_dim`` > 0 states the
+    INDEX page kind beside K/V: every page layer also keeps one
+    ``index_dim``-wide index key a position (a learned sparse attention's
+    indexer), in a pool of its own addressed by the same page ids
+    (:attr:`PagedKVCache.index`); ``index_topk`` is how many positions a
+    token keeps by it (the engine counts the selection with it).
+
     A model states it as ``model.cache_spec()`` -> a plain dict with
     these keys (``state`` a list of ``(name, layers, shape, dtype)``);
     the engine builds the pool and the state from it alone, so a new
@@ -111,6 +122,9 @@ class CacheSpec:
     counter_layers: int = 0
     num_experts: int = 0
     value_dim: int = 0
+    row_pages: bool = False
+    index_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self):
         if self.value_dim and not (self.kv_heads == 1
@@ -135,7 +149,8 @@ class CacheSpec:
                        for n, l, sh, dt in d.pop("state", ()))
         if len({b.name for b in blocks}) != len(blocks):
             raise ValueError("cache_spec: state block names must differ")
-        return cls(state=blocks, **{k: int(v) for k, v in d.items()})
+        return cls(state=blocks, **{k: bool(v) if k == "row_pages"
+                                    else int(v) for k, v in d.items()})
 
 
 @flax.struct.dataclass
@@ -254,11 +269,22 @@ class PagedKVCache:
     # copy-on-write share never copies scale state alongside its pages.
     k_scale: Optional[jnp.ndarray] = None   # [layers, heads] fp32
     v_scale: Optional[jnp.ndarray] = None   # [layers, heads] fp32
-    # per-slot state beside the pages (a model whose CacheSpec has state);
-    # None — no leaf, the pytree the programs always had — otherwise
+    # per-slot state beside the pages (a model whose CacheSpec has state
+    # or an expert counter); None — no leaf, the pytree the programs
+    # always had — otherwise
     state: Optional[SlotState] = None
+    # the index page kind (CacheSpec.index_dim): [layers, num_pages,
+    # index_dim, page_len], a page of index keys held transposed like a
+    # K/V page, addressed by the same page ids; None — no leaf — otherwise
+    index: Optional[jnp.ndarray] = None
 
     # ------------------------------------------------------------- geometry
+    @property
+    def row_pages(self) -> bool:
+        """The contiguous-row layout (:class:`CacheSpec`): K and V are
+        ``[layers, num_pages, page_len, heads * head_dim]``."""
+        return self.k.ndim == 4
+
     @property
     def layers(self) -> int:
         return self.k.shape[0]
@@ -269,20 +295,25 @@ class PagedKVCache:
 
     @property
     def heads(self) -> int:
-        return self.k.shape[2]
+        """K/V heads of a page (1 in the contiguous-row layout, whose
+        row holds them all)."""
+        return 1 if self.row_pages else self.k.shape[2]
 
     @property
     def head_dim(self) -> int:
+        """Values a head (the whole row in the contiguous-row
+        layout)."""
         return self.k.shape[3]
 
     @property
     def page_len(self) -> int:
-        return self.k.shape[4]
+        return self.k.shape[2] if self.row_pages else self.k.shape[4]
 
     @property
-    def page_shape(self) -> Tuple[int, int, int]:
-        """One page as stored: ``(heads, head_dim, page_len)`` — the
-        trailing dims of the pool and of every swapped page block."""
+    def page_shape(self) -> Tuple[int, ...]:
+        """One page as stored: ``(heads, head_dim, page_len)`` (or
+        ``(page_len, width)`` in rows) — the trailing dims of the pool and
+        of every swapped page block."""
         return self.k.shape[2:]
 
     @property
@@ -290,8 +321,11 @@ class PagedKVCache:
         return self.k.dtype
 
     def nbytes(self) -> int:
-        """Device bytes held by the pool (both K and V)."""
-        return int((self.k.size + self.v.size) * self.k.dtype.itemsize)
+        """Device bytes held by the pool (K, V and the index keys)."""
+        index = 0 if self.index is None \
+            else self.index.size * self.index.dtype.itemsize
+        return int((self.k.size + self.v.size) * self.k.dtype.itemsize
+                   + index)
 
     def bytes_per_token(self) -> int:
         """Pool bytes one cached position takes, over all page layers."""
@@ -301,21 +335,30 @@ class PagedKVCache:
     def create(cls, *, layers: int, num_pages: int, heads: int,
                page_len: int, head_dim: int, dtype: Any = jnp.bfloat16,
                k_scale=None, v_scale=None, state=None,
-               value_dim: int = 0) -> "PagedKVCache":
+               value_dim: int = 0, row_pages: bool = False,
+               index_dim: int = 0) -> "PagedKVCache":
         """Allocate a zeroed pool (``dtype`` normally the amp half
         dtype, or int8 with the scale pair under the engine's
         ``kv_quant`` tier). ``num_pages`` INCLUDES the page-0 sentinel,
         so the usable capacity is ``(num_pages - 1) * page_len``
-        positions. ``value_dim`` > 0: the latent page kind, one pool
-        (:class:`CacheSpec`)."""
+        positions. ``value_dim`` > 0: the latent page kind, one pool;
+        ``row_pages``: the contiguous-row layout; ``index_dim`` > 0: the
+        index pool beside K/V (:class:`CacheSpec`)."""
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "sentinel/garbage page)")
-        shape = (layers, num_pages, heads, head_dim, page_len)
-        v_shape = (layers, num_pages, 0 if value_dim else heads, head_dim,
-                   page_len)
+        if row_pages:
+            shape = v_shape = (layers, num_pages, page_len,
+                               heads * head_dim)
+        else:
+            shape = (layers, num_pages, heads, head_dim, page_len)
+            v_shape = (layers, num_pages, 0 if value_dim else heads,
+                       head_dim, page_len)
+        index = None if not index_dim else jnp.zeros(
+            (layers, num_pages, index_dim, page_len), dtype)
         return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(v_shape, dtype),
-                   k_scale=k_scale, v_scale=v_scale, state=state)
+                   k_scale=k_scale, v_scale=v_scale, state=state,
+                   index=index)
 
     def layer_view(self):
         """The ``(k, v)`` pool pair the paged model path consumes."""

@@ -555,10 +555,11 @@ class Scheduler:
             raise ValueError("max_queue must be >= 1")
         if chunk_budget < 1:
             raise ValueError("chunk_budget must be >= 1")
-        if getattr(engine, "slot_state", False):
-            # a model with per-slot state beside its pages: what
-            # re-enters a request mid-stream from pages alone is refused
-            # by name, as serving.Engine refuses its own side of it
+        beside = getattr(engine, "beside_pages", None)
+        if beside:
+            # a model with per-slot state or an index pool beside its K/V
+            # pages: what re-enters a request mid-stream from pages alone
+            # is refused by name, as serving.Engine refuses its own side
             for on, what in (
                     (retain_prefixes, "prefix_cache retention "
                      "(retain_prefixes)"),
@@ -570,9 +571,9 @@ class Scheduler:
                 if on:
                     raise NotImplementedError(
                         f"serving.Scheduler: {what} is not built for a "
-                        f"model with per-slot state "
-                        f"({getattr(engine, 'model_kind', '?')!r}): the "
-                        "slot's state is not snapshotted with its pages")
+                        f"model with {beside} "
+                        f"({getattr(engine, 'model_kind', '?')!r}): it is "
+                        "not carried with the request's K/V pages")
         if pipeline_depth < 0:
             raise ValueError("pipeline_depth must be >= 0 (0 = the "
                              "synchronous oracle beat)")

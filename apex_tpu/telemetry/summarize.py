@@ -14,6 +14,15 @@ the latency half of the comm-health story whose bytes half lives in the
 ``comm.*`` counters. A serving capture also gets the device's idle time
 by host phase (:func:`phase_idle`) and the engine's host-device boundary
 beat by beat (:func:`boundary_by_beat`).
+
+Under the counter table the summary prints the counters that read as a
+share of another (``COUNTER_SHARES``): the dispatch-ahead engagement
+(``serving.heartbeat.dispatched_ahead / serving.decode.steps``), the chunk
+kernel's walk of the page table (``serving.chunk.pages_live /
+pages_tabled``) and, for a model with learned sparse attention, the share
+of its live positions a query keeps (``serving.dsa.positions_selected /
+serving.dsa.positions_live``: 1 where every context is shorter than the
+selection, ``topk / context`` on long ones).
 """
 
 from __future__ import annotations
@@ -35,6 +44,8 @@ COUNTER_SHARES = (
      "serving.decode.steps"),
     ("chunk kernel's walk of the table", "serving.chunk.pages_live",
      "serving.chunk.pages_tabled"),
+    ("positions a sparse-attention query keeps",
+     "serving.dsa.positions_selected", "serving.dsa.positions_live"),
 )
 
 #: hlo_category substrings that identify collective/communication ops

@@ -46,11 +46,12 @@ PAGES = {
                 "apex_tpu.kernels.multi_tensor",
                 "apex_tpu.kernels.group_norm",
                 "apex_tpu.kernels.grouped_gemm",
-                "apex_tpu.kernels.gated_delta", "apex_tpu.kernels.vmem"],
+                "apex_tpu.kernels.gated_delta", "apex_tpu.kernels.dsa",
+                "apex_tpu.kernels.vmem"],
     "models": ["apex_tpu.models", "apex_tpu.models.bert",
                "apex_tpu.models.transformer_lm", "apex_tpu.models.zaya",
                "apex_tpu.models.qwen3_next", "apex_tpu.models.ling",
-               "apex_tpu.models.lm_layers"],
+               "apex_tpu.models.keye", "apex_tpu.models.lm_layers"],
     "layers": ["apex_tpu.mlp", "apex_tpu.fused_dense"],
     "utils": ["apex_tpu.utils", "apex_tpu.utils.checkpoint",
               "apex_tpu.utils.sharded_checkpoint", "apex_tpu.utils.pytree",

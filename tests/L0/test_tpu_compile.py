@@ -17,6 +17,7 @@ loads the packaged v5e blocks for the duration of a case. A compile
 that passes is not a chip run (``chip_smoke.py`` is).
 """
 
+import math
 import os
 import subprocess
 import sys
@@ -553,6 +554,84 @@ def test_ling3_programs_at_the_cells_geometry(program, one_chip, as_v5e):
     assert not [line for line in compiled.as_text().splitlines()
                 if " while(" in line and "._experts/" in line]
     print(f"ling3 {program}: arguments {mem.argument_size_in_bytes}, "
+          f"aliased {mem.alias_size_in_bytes}, temporaries "
+          f"{mem.temp_size_in_bytes}")
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_keye_programs_at_the_cells_geometry(program, one_chip, as_v5e):
+    """The decode and the chunk program of the ``KeyeVL2`` cell as the
+    engine's threaded bodies call the model - the donated cache with the
+    contiguous-row K/V pools, the index pool and the expert counter in,
+    the same buffers out - at the cell's 8 slots x 65,536 positions,
+    1,024-row chunks and published widths, two of its eight layers. Each
+    DSA kernel is there once a layer (the decode's attention over the
+    gathered selection, the chunk's over the live pages under the
+    selection as a mask), the cache (2.3 GB at two layers, 9.1 GB at
+    eight) is aliased whole and the
+    temporaries stay far under it: no copy of a pool, and no head's
+    ``[chunk, context]`` array - the chunk's one ``[1,024, 65,536]``
+    float32 score array is the indexer's weighted sum (256 MB)."""
+    from apex_tpu.models import KeyeLM
+    from apex_tpu.serving.kv_cache import (CacheSpec, PagedKVCache, SlotAddr,
+                                           SlotState)
+
+    slots, max_pages, chunk = 8, 512, 1024
+    m = KeyeLM(num_layers=2, experts_held=tuple(range(16)),
+               dtype=BF16, inference_dtype=BF16, param_dtype=BF16)
+    spec = CacheSpec.of(m)
+    assert spec.row_pages and (spec.index_dim, spec.index_topk) == (64, 2048)
+    sd = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)  # noqa: E731,E501
+    params = jax.tree_util.tree_map(
+        lambda a: sd(a.shape, BF16),
+        jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0),
+                                      jnp.zeros((1, 8), I32),
+                                      train=False))["params"])
+    pages = slots * max_pages + 1
+    width = spec.kv_heads * spec.head_dim
+    cache = PagedKVCache(
+        k=sd((spec.page_layers, pages, PAGE, width), BF16),
+        v=sd((spec.page_layers, pages, PAGE, width), BF16),
+        index=sd((spec.page_layers, pages, spec.index_dim, PAGE), BF16),
+        state=SlotState(blocks={}, expert_tokens=sd(
+            (spec.counter_layers, spec.num_experts), I32)))
+
+    def run(params, cache, tokens, pt, **kw):
+        st = cache.state
+        logits, (k, v, _, counts, index) = m.apply(
+            {"params": params}, tokens, train=False, state={},
+            addr=SlotAddr(), cache=(cache.k, cache.v, pt, cache.index),
+            **kw)
+        return cache.replace(k=k, v=v, index=index, state=st.replace(
+            expert_tokens=st.expert_tokens + counts)), \
+            jnp.argmax(logits[:, 0], -1)
+
+    if program == "decode":
+        def fn(params, cache, last, pt, lengths, active):
+            return run(params, cache, last[:, None], pt, positions=lengths,
+                       valid=active[:, None])
+        args = [sd((slots,), I32), sd((slots, max_pages), I32),
+                sd((slots,), I32), sd((slots,), jnp.bool_)]
+        want = {"dsa_index": 2, "dsa_select": 2, "dsa_attend": 2,
+                "moe_grouped_gemm": 4}
+    else:
+        def fn(params, cache, tokens, pt, offset, n_valid):
+            return run(params, cache, tokens, pt, positions=offset[None],
+                       n_valid=n_valid[None])
+        args = [sd((1, chunk), I32), sd((1, max_pages), I32), sd((), I32),
+                sd((), I32)]
+        want = {"dsa_index": 2, "dsa_select": 2, "dsa_attend_chunk": 2,
+                "moe_grouped_gemm": 4}
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    assert kernel_calls(compiled.as_text()) == want
+    mem = compiled.memory_analysis()
+    held = sum(math.prod(a.shape) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(cache))
+    assert held > 2.2e9
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 1.5 * 2 ** 30, mem.temp_size_in_bytes
+    print(f"keye {program}: arguments {mem.argument_size_in_bytes}, "
           f"aliased {mem.alias_size_in_bytes}, temporaries "
           f"{mem.temp_size_in_bytes}")
 
