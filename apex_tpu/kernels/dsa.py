@@ -23,7 +23,11 @@ Layouts (the serving engine's, :class:`~apex_tpu.serving.kv_cache
   heads are one row, so a selected position is one row to fetch, and at
   ``head_dim`` 128 every lane tile is full.
 
-Kernels, each under its own name in a device trace:
+Kernels, each under its own name in a device trace. Each walks the
+program's LIVE span, not its page table's: ``ceil((max_b q_pos[b] + Q) /
+T)`` steps of ``T`` positions (:func:`live_steps`), the bound computed on
+the device from ``q_pos``, so a context that fills a quarter of the table
+costs a quarter of its steps:
 
 - :func:`dsa_index` (``dsa_index``): the scores ``[B, Q, page_table
   span]`` float32 of ``Q`` queries a row against the row's LIVE index
@@ -32,7 +36,11 @@ Kernels, each under its own name in a device trace:
   steps past the row's last query position re-issue the last live page
   and fetch nothing). The ``nh`` heads are the rows of one product (row
   ``t * nh + j``), ReLU'd, weighted and summed in VMEM: no head's
-  ``[Q, context]`` array reaches HBM, only their weighted sum.
+  ``[Q, context]`` array reaches HBM, only their weighted sum. A score
+  is ``-inf`` past its query's position up to the walk's end; the
+  columns at or past ``live_steps x T`` are UNSPECIFIED (never written):
+  every consumer below reads a column only at or before its query's
+  position.
 - :func:`dsa_select` (``dsa_select``): the exact k-th largest score of a
   row as a threshold, by bisection over the float's order bits (32
   counting passes over the row's live columns), then - only where a
@@ -41,8 +49,9 @@ Kernels, each under its own name in a device trace:
   ``(thr, cut)``: position ``s <= pos`` is kept iff ``key(I_s) > thr`` or
   ``key(I_s) == thr and s <= cut`` (:func:`selected_mask`). A row with no
   more than ``k`` live positions keeps them all (``thr`` the least int).
-  ``lax.top_k`` at 64k columns sorts; this reads the row 34 times in
-  VMEM (50 with ties to cut).
+  ``lax.top_k`` at 64k columns sorts; this fetches and converts a row
+  block's live tiles only and reads them 34 times in VMEM (50 with ties
+  to cut); a column past its row's position is never read as a score.
 - :func:`dsa_attend` (``dsa_attend``): one token a row (decode) against
   the ``k`` positions gathered from the pool (:func:`gather_selected`),
   every query head of a K/V head in one product.
@@ -75,10 +84,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["order_key", "index_scores_reference", "dsa_index",
-           "select_reference", "dsa_select", "selected_mask",
-           "selected_positions", "gather_selected", "attend_reference",
-           "dsa_attend", "attend_chunk_reference", "dsa_attend_chunk"]
+__all__ = ["order_key", "live_steps", "table_walk",
+           "index_scores_reference", "dsa_index", "select_reference",
+           "dsa_select", "selected_mask", "selected_positions",
+           "gather_selected", "attend_reference", "dsa_attend",
+           "attend_chunk_reference", "dsa_attend_chunk"]
 
 _NEG_INF = -1e30                # the attention kernels' mask (finite)
 _INT_MIN = np.int32(-2 ** 31)
@@ -107,6 +117,50 @@ def order_key(x):
     b = jax.lax.bitcast_convert_type(jnp.asarray(x, jnp.float32) + 0.0,
                                      jnp.int32)
     return b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def live_steps(top, queries, step):
+    """``ceil((top + queries) / step)``: the steps of ``step`` positions
+    that hold every causal position of ``queries`` queries a row whose
+    deepest row starts at position ``top`` (a host int or a traced
+    scalar)."""
+    return (top + queries + step - 1) // step
+
+
+def _index_geometry(Q, mp, pages_per_step=None, block_q=None):
+    """:func:`dsa_index`'s ``(pages a step, query block)``."""
+    pages = pages_per_step or (INDEX_PAGES_PER_STEP if Q > 1 else 16)
+    while mp % pages:
+        pages //= 2
+    return pages, min(block_q or INDEX_BLOCK_Q, Q)
+
+
+def _attend_geometry(C, mp, pages_per_step=None, block_q=None):
+    """:func:`dsa_attend_chunk`'s ``(pages a step, query block)``."""
+    pages = pages_per_step or ATTEND_PAGES_PER_STEP
+    while mp % pages:
+        pages //= 2
+    return pages, min(block_q or ATTEND_BLOCK_Q, C)
+
+
+def table_walk(top, rows, queries, max_pages, page_len):
+    """``(walked, tabled)``: the grid steps one layer's :func:`dsa_index`
+    and, for a chunk (``queries > 1``), :func:`dsa_attend_chunk` take for
+    ``rows`` rows of ``queries`` queries whose deepest row starts at
+    position ``top``, and the steps a walk of the whole table of
+    ``max_pages`` would take. Host integers (the engine's
+    ``serving.dsa.steps_walked`` / ``steps_tabled``)."""
+    geometries = [_index_geometry(queries, max_pages)]
+    if queries > 1:
+        geometries.append(_attend_geometry(queries, max_pages))
+    walked = tabled = 0
+    for pages, bq in geometries:
+        steps = max_pages // pages
+        blocks = rows * (queries // bq)
+        walked += blocks * min(live_steps(top, queries, pages * page_len),
+                               steps)
+        tabled += blocks * steps
+    return walked, tabled
 
 
 # ------------------------------------------------------------------ indexer
@@ -169,15 +223,13 @@ def dsa_index(q, w, pool, page_table, q_pos, *, layer: int, scale: float,
     """The indexer's scores (module docstring): ``q [B, Q, nh, d]`` (the
     pool's dtype), ``w [B, Q, nh]``, ``pool [layers, P, d, page_len]``,
     ``page_table [B, max_pages]``, ``q_pos [B]`` -> ``[B, Q, max_pages *
-    page_len]`` float32 (``-inf`` past a query's position). Runs under the
-    name ``dsa_index``."""
+    page_len]`` float32 (``-inf`` past a query's position; the columns at
+    or past ``live_steps(max(q_pos), Q, T) x T``, ``T`` the positions of
+    a step, unspecified). Runs under the name ``dsa_index``."""
     B, Q, nh, d = q.shape
-    mp = page_table.shape[1]
     page_len = pool.shape[3]
-    pages = pages_per_step or (INDEX_PAGES_PER_STEP if Q > 1 else 16)
-    while mp % pages:
-        pages //= 2
-    bq = min(block_q or INDEX_BLOCK_Q, Q)
+    pages, bq = _index_geometry(Q, page_table.shape[1], pages_per_step,
+                                block_q)
     interpret = _interpret(interpret)
     if Q % bq or (bq * nh) % 8 or page_len % 128 or d % 8:
         return index_scores_reference(q, w, pool, page_table, q_pos,
@@ -200,6 +252,8 @@ def _index_pallas(layer, pt, pos, q, w, pool, *, scale, heads, block_q,
     B, R, d = q.shape
     Q, bq = R // heads, block_q
     mp, page_len = pt.shape[1], pool.shape[3]
+    steps = jnp.minimum(live_steps(jnp.max(pos), Q, pages * page_len),
+                        mp // pages)
     kernel = functools.partial(
         _index_kernel, scale=scale, heads=heads, block_q=bq,
         page_len=page_len, pages=pages, widen=interpret)
@@ -212,7 +266,7 @@ def _index_pallas(layer, pt, pos, q, w, pool, *, scale, heads, block_q,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,            # layer, page_table, q_pos
-        grid=(B, Q // bq, mp // pages),
+        grid=(B, Q // bq, steps),
         in_specs=[pl.BlockSpec((1, bq * heads, d),
                                lambda b, i, j, lyr, pt, pos: (b, i, 0)),
                   pl.BlockSpec((1, bq * heads, 1),
@@ -266,15 +320,58 @@ def selected_mask(scores, thr, cut, pos):
     return (cols <= pos) & ((key > thr) | ((key == thr) & (cols <= cut)))
 
 
-def _select_kernel(pos_s, pos_ref, s_ref, thr_ref, cut_ref, key_ref, *, k,
-                   rows, tile, nbits):
+def _select_kernel(pos_s, pos_ref, s_hbm, thr_ref, cut_ref, buf, key_ref,
+                   sem, *, k, rows, tile, nbits):
+    """Grid ``(N // rows,)``, in order: a step selects one block of
+    ``rows`` rows. Its live tiles (those up to the block's deepest
+    position) are DMAed from HBM into ``buf`` as at most ``log2(span /
+    tile) + 1`` runs of ``2^x`` tiles, a semaphore each - the next
+    block's while this block's passes run - and each run is converted to
+    order keys in ``key_ref``, a column past its row's position taken as
+    the least int: nothing past a row's position is read as a score."""
     i = pl.program_id(0)
-    span = s_ref.shape[1]
-    key_ref[...] = order_key(s_ref[...])
-    top = pos_s[i * rows]
-    for r in range(1, rows):
-        top = jnp.maximum(top, pos_s[i * rows + r])
-    ntiles = jnp.minimum(top // tile + 1, span // tile)
+    span = key_ref.shape[1]
+    sizes = [1 << x for x in reversed(range((span // tile).bit_length()))]
+
+    def live_tiles(blk):
+        top = pos_s[blk * rows]
+        for r in range(1, rows):
+            top = jnp.maximum(top, pos_s[blk * rows + r])
+        return jnp.minimum(top // tile + 1, span // tile)
+
+    def runs(blk, act):
+        """``act(first column, width, x)`` for each run of block
+        ``blk``'s live tiles: run ``x`` is ``sizes[x]`` tiles wide."""
+        n, lo = live_tiles(blk), jnp.int32(0)
+        for x, size in enumerate(sizes):
+            @pl.when((n & size) != 0)
+            def _run(lo=lo, x=x, size=size):
+                act(lo, size * tile, x)
+            lo = lo + jnp.where((n & size) != 0, size * tile, 0)
+
+    def copy(blk, lo, width, x):
+        cols = pl.ds(pl.multiple_of(lo, tile), width)
+        return pltpu.make_async_copy(s_hbm.at[pl.ds(blk * rows, rows), cols],
+                                     buf.at[:, cols], sem.at[x])
+
+    @pl.when(i == 0)
+    def _first():
+        runs(0, lambda lo, width, x: copy(0, lo, width, x).start())
+
+    def fill(lo, width, x):
+        copy(i, lo, width, x).wait()
+        cols = pl.ds(pl.multiple_of(lo, tile), width)
+        at = lo + jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+        key_ref[:, cols] = jnp.where(at <= pos_ref[...],
+                                     order_key(buf[:, cols]), _INT_MIN)
+
+    runs(i, fill)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _next():
+        runs(i + 1, lambda lo, width, x: copy(i + 1, lo, width, x).start())
+
+    ntiles = live_tiles(i)
 
     def count(pred):
         def body(c, acc):
@@ -316,9 +413,10 @@ def _select_kernel(pos_s, pos_ref, s_ref, thr_ref, cut_ref, key_ref, *, k,
 
 
 def dsa_select(scores, pos, k: int, *, interpret: bool = False):
-    """:func:`select_reference` as a kernel: ``scores [N, span]`` float32
-    (``-inf`` past each row's position ``pos [N]``) -> ``(thr, cut)``
-    int32 ``[N]``. Runs under the name ``dsa_select``."""
+    """:func:`select_reference` as a kernel: ``scores [N, span]`` float32,
+    read only at or before each row's position ``pos [N]`` (what lies past
+    it, ``-inf``, NaN or never written, is not read as a score) -> ``(thr,
+    cut)`` int32 ``[N]``. Runs under the name ``dsa_select``."""
     N, span = scores.shape
     interpret = _interpret(interpret)
     rows = SELECT_ROWS if N % SELECT_ROWS == 0 else N
@@ -341,14 +439,18 @@ def _select_pallas(pos, scores, *, k, rows, tile, interpret):
         num_scalar_prefetch=1,
         grid=(N // rows,),
         in_specs=[pl.BlockSpec((rows, 1), lambda i, p: (i, 0)),
-                  pl.BlockSpec((rows, span), lambda i, p: (i, 0))],
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[pl.BlockSpec((rows, 1), lambda i, p: (i, 0))] * 2,
-        scratch_shapes=[pltpu.VMEM((rows, span), jnp.int32)])
+        scratch_shapes=[pltpu.VMEM((rows, span), jnp.float32),
+                        pltpu.VMEM((rows, span), jnp.int32),
+                        pltpu.SemaphoreType.DMA(
+                            ((span // tile).bit_length(),))])
     thr, cut = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((N, 1), jnp.int32)] * 2,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",), vmem_limit_bytes=64 << 20),
+            # in order: a step starts the next block's DMAs
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=64 << 20),
         interpret=interpret, name="dsa_select",
     )(pos, pos[:, None], scores)
     return thr[:, 0], cut[:, 0]
@@ -564,18 +666,16 @@ def dsa_attend_chunk(q, k_pool, v_pool, page_table, q_pos, scores, thr,
     """:func:`attend_chunk_reference` as a kernel: grid (row, query
     block, page step); a step reads ``pages_per_step`` pages of K and V
     once for every query head, the kept columns re-derived from the
-    step's scores, online softmax across steps; steps past the block's
-    last query fetch nothing. Runs under the name
-    ``dsa_attend_chunk``."""
+    step's scores, online softmax across steps; the walk ends at the
+    program's live span (:func:`live_steps`), and steps past the block's
+    last query fetch nothing. ``scores`` is read only at or before each
+    query's position. Runs under the name ``dsa_attend_chunk``."""
     B, C, nq, d = q.shape
     page_len, W = k_pool.shape[2], k_pool.shape[3]
     nk = W // d
     G = nq // nk
-    mp = page_table.shape[1]
-    bq = min(block_q or ATTEND_BLOCK_Q, C)
-    pages = pages_per_step or ATTEND_PAGES_PER_STEP
-    while mp % pages:
-        pages //= 2
+    pages, bq = _attend_geometry(C, page_table.shape[1], pages_per_step,
+                                 block_q)
     interpret = _interpret(interpret)
     if C % bq or bq % 8 or d % 128 or page_len % 128:
         return attend_chunk_reference(
@@ -609,6 +709,7 @@ def _attend_chunk_pallas(layer, pos, pt, qg, scores, thr, cut, k_pool,
     mp = pt.shape[1]
     page_len, W = k_pool.shape[2], k_pool.shape[3]
     T = pages * page_len
+    steps = jnp.minimum(live_steps(jnp.max(pos), C, T), mp // pages)
     kernel = functools.partial(
         _attend_chunk_kernel, scale=scale, groups=nk, G=G, d=d,
         block_q=bq, page_len=page_len, pages=pages, widen=interpret)
@@ -625,7 +726,7 @@ def _attend_chunk_pallas(layer, pos, pt, qg, scores, thr, cut, k_pool,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,            # layer, q_pos, page_table
-        grid=(B, C // bq, mp // pages),
+        grid=(B, C // bq, steps),
         in_specs=[pl.BlockSpec((1, nk, G * bq, d),
                                lambda b, i, j, lyr, pos, pt: (b, i, 0, 0)),
                   pl.BlockSpec((1, bq, T), score_index),

@@ -167,6 +167,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.kernels import vmem
+from apex_tpu.kernels.dsa import table_walk
 from apex_tpu.log_util import get_logger
 from apex_tpu.telemetry import tracing
 
@@ -243,14 +244,17 @@ class PendingDecode:
     immediately and the reading degenerates to today's measurement).
     ``attended`` is what each decoding row's length was
     in this step, kept for reconcile's ``serving.decode.pages_live`` /
-    ``pages_tabled`` / ``pages_written`` counters: dispatch counts
-    nothing itself."""
+    ``pages_tabled`` / ``pages_written`` counters, and ``deepest`` the
+    largest position the program was handed (every slot's, decoding or
+    not), for ``serving.dsa.steps_walked``: dispatch counts nothing
+    itself."""
 
     tokens: Any                 # [slots] int32, ON DEVICE until reconcile
     finite: Any                 # [slots] bool, ON DEVICE until reconcile
     active: np.ndarray          # [slots] bool, host dispatch mask
     t_dispatch: float
     attended: np.ndarray        # [decoding rows] int lengths
+    deepest: int = 0
     reconciled: bool = False
     launch: Optional[int] = None    # id of the dispatch's engine.launch
 
@@ -1600,7 +1604,10 @@ class Engine:
                 # the chunk's real tokens at offset + i attend offset + i
                 # + 1 positions live and keep at most index_topk of them
                 live = pending.offset + 1 + np.arange(pending.n_tokens)
-                self._count_selection(live)
+                self._count_selection(
+                    live, min(pending.offset,
+                              self.max_pages * self.page_len
+                              - self.chunk_len), 1, self.chunk_len)
             if pending.final:
                 self._registry.counter_inc("serving.tokens_generated")
         if pending.final:
@@ -2216,11 +2223,12 @@ class Engine:
                                                *ops))
         # write-then-attend: a row at position p attends p + 1
         attended = np.minimum(self._host_len[act], self.max_len - 1) + 1
+        deepest = min(int(self._host_len.max()), self.max_len - 1)
         grow = act & (self._host_len < self.max_len)
         self._host_len[grow] += 1
         return PendingDecode(tokens=tokens, finite=finite, active=act,
                              t_dispatch=t0, attended=attended,
-                             launch=self._last_launch)
+                             deepest=deepest, launch=self._last_launch)
 
     def decode_reconcile(self, pending: PendingDecode, valid=None):
         """Read a dispatched decode step back to the host — ONE batched
@@ -2288,20 +2296,31 @@ class Engine:
                 "serving.decode.pages_written",
                 pending.attended.size * self.cache.k.shape[0] * 2)
             if self.cache_spec.index_topk:
-                self._count_selection(pending.attended)
+                self._count_selection(pending.attended, pending.deepest,
+                                      self.slots, 1)
         return out, finite, dt
 
-    def _count_selection(self, live) -> None:
+    def _count_selection(self, live, deepest, rows, queries) -> None:
         """The index page kind's selection in one program: each query
         row attending ``live`` positions keeps ``min(live, index_topk)``
         of them - ``serving.dsa.positions_live`` and
-        ``serving.dsa.positions_selected``, per layer."""
+        ``serving.dsa.positions_selected``, per layer; and the grid steps
+        the program's index and chunk attention kernels walk, ``rows``
+        rows of ``queries`` queries whose deepest row starts at position
+        ``deepest`` (:func:`~apex_tpu.kernels.dsa.table_walk`), against
+        the steps a walk of the whole table would take -
+        ``serving.dsa.steps_walked`` and ``serving.dsa.steps_tabled``,
+        per layer."""
         live = np.asarray(live, np.int64)
         self._registry.counter_inc("serving.dsa.positions_live",
                                    float(live.sum()))
         self._registry.counter_inc(
             "serving.dsa.positions_selected",
             float(np.minimum(live, self.cache_spec.index_topk).sum()))
+        walked, tabled = table_walk(deepest, rows, queries, self.max_pages,
+                                    self.page_len)
+        self._registry.counter_inc("serving.dsa.steps_walked", walked)
+        self._registry.counter_inc("serving.dsa.steps_tabled", tabled)
 
     def sync(self) -> None:
         """Explicit device barrier: block until every dispatched

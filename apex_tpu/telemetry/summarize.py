@@ -22,7 +22,9 @@ kernel's walk of the page table (``serving.chunk.pages_live /
 pages_tabled``) and, for a model with learned sparse attention, the share
 of its live positions a query keeps (``serving.dsa.positions_selected /
 serving.dsa.positions_live``: 1 where every context is shorter than the
-selection, ``topk / context`` on long ones).
+selection, ``topk / context`` on long ones) and the share of the page
+table its kernels walk (``serving.dsa.steps_walked /
+serving.dsa.steps_tabled``).
 """
 
 from __future__ import annotations
@@ -46,6 +48,8 @@ COUNTER_SHARES = (
      "serving.chunk.pages_tabled"),
     ("positions a sparse-attention query keeps",
      "serving.dsa.positions_selected", "serving.dsa.positions_live"),
+    ("sparse-attention kernels' walk of the table",
+     "serving.dsa.steps_walked", "serving.dsa.steps_tabled"),
 )
 
 #: hlo_category substrings that identify collective/communication ops

@@ -265,6 +265,38 @@ def test_the_engine_serves_it_and_counts_the_selection():
     assert eng.moe_tokens_per_expert().sum() == 2 * 8 * (300 + 3)
 
 
+def test_the_engine_counts_the_steps_its_dsa_kernels_walk():
+    """An engine of 32-page tables (4,096 positions) serving a 200-token
+    prompt and two decode steps: each kernel walks the program's live
+    span, so the registry's ``serving.dsa.steps_walked`` is the steps
+    that hold the deepest query and ``steps_tabled`` what the whole table
+    would cost, a layer each."""
+    from apex_tpu.telemetry.summarize import render_summary
+
+    cfg = dict(CFG, num_hidden_layers=2)
+    p = rk.seeded_weights(cfg, 3, jnp.float32)
+    reg = MetricsRegistry()
+    eng = serving.Engine(build_lm(cfg, dtype=jnp.float32),
+                         rk.program_tree(p), slots=2, max_len=32 * PL,
+                         chunk_len=PL, page_len=PL, registry=reg,
+                         policy=amp.resolve_policy("O0", verbose=False))
+    out = [eng.prefill_chunked(0, [int(t) for t in _tokens(5, 200)])]
+    for _ in range(2):
+        out.append(int(eng.decode_step(np.asarray([out[-1], 0], np.int32),
+                                       np.asarray([True, False]),
+                                       np.zeros(2, np.float32))[0]))
+    # two chunks (offsets 0 and 128): the indexer and the attention each
+    # walk one step of four pages, of eight; two decode steps of two
+    # rows: the indexer walks one step of sixteen pages, of two
+    walked, tabled = 2 * 2 * 1 + 2 * 2 * 1, 2 * 2 * 8 + 2 * 2 * 2
+    assert reg.counters["serving.dsa.steps_walked"] == walked
+    assert reg.counters["serving.dsa.steps_tabled"] == tabled
+    text = render_summary({"metrics": {}, "steps": {},
+                           "counters": dict(reg.counters)})
+    assert "sparse-attention kernels' walk of the table" in text
+    assert f"{walked / tabled:.4f}" in text
+
+
 # --------------------------------------------------------------- refusals
 REFUSED_BY_ENGINE = {
     "prefix_cache retention": dict(prefix_pool=2),
